@@ -22,13 +22,6 @@ import time
 
 import numpy as np
 
-if os.environ.get("BENCH_PREWARM", "0") not in ("", "0"):
-    # serialized-executable mode.  Setting MXNET_AOT before mxnet_tpu
-    # imports also makes the package bootstrap install the XLA codegen
-    # flag that keeps persisted CPU artifacts self-contained (the
-    # canonical copy of that logic lives in mxnet_tpu/__init__.py).
-    os.environ.setdefault("MXNET_AOT", "1")
-
 _T0 = time.time()
 
 
@@ -51,7 +44,7 @@ def build_trainer(batch=None, remat_policy=None, aot=None,
     activation-rematerialization policy for the backward pass — see
     mxnet_tpu.remat.list_policies().  ``aot`` (or the MXNET_AOT env
     default) enables the serialized-executable store, so a prewarmed
-    machine skips the ~97 s step-0 compile (tools/prewarm.py).
+    machine skips the step-0 compile (tools/prewarm.py).
     ``mesh``/``layout`` (or MXNET_MESH / MXNET_LAYOUT) select a named
     sharding topology + per-parameter layout (docs/sharding.md); the
     defaults stay single-device, and the emitted BENCH JSON records
@@ -66,12 +59,13 @@ def build_trainer(batch=None, remat_policy=None, aot=None,
     from mxnet_tpu.gluon.model_zoo import vision
 
     if batch is None:
-        # bs256: best measured utilization (flat 128-512, OOM at 1024 —
-        # docs/perf_notes.md MFU section)
         batch = int(os.environ.get("BENCH_BATCH", "256"))
     on_tpu = any(d.platform != "cpu" for d in jax.devices())
     if not on_tpu:
-        batch = min(batch, 16)  # keep CPU smoke runs fast
+        # the CPU callers (tools/prewarm.py specs, the remat sweep's
+        # smoke mode) only need the program, not the size; main()
+        # refuses to report a number from here
+        batch = min(batch, 16)
 
     # precision: an explicit dtype_policy= (or BENCH_DTYPE_POLICY) wins;
     # default is the mixed-precision recipe on the chip (bf16 compute,
@@ -103,27 +97,31 @@ def run_prewarm():
     """BENCH_PREWARM=1: run tools/prewarm.py first, so this process's
     warmup step 0 is a *warm start* (deserialize) and the subprocess's
     measured compile is the *cold start* — both become parsed BENCH
-    JSON fields and the cold-start trajectory is tracked like img/s."""
+    JSON fields and the cold-start trajectory is tracked like img/s.
+
+    The child needs the chip, and a chip belongs to one process at a
+    time: main() calls this before it imports jax, and the child has
+    exited (chip released) before this process initialises a backend."""
     import subprocess
 
-    os.environ.setdefault("MXNET_AOT", "1")
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "run_prewarm() must run before this process imports jax: the "
+            "prewarm child needs the chip this process would hold")
     cmd = [sys.executable,
            os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "tools", "prewarm.py"),
            "--model", "bench_resnet50", "--json"]
     log("BENCH_PREWARM: %s" % " ".join(cmd))
-    proc = subprocess.run(cmd, stdout=subprocess.PIPE, text=True)
+    proc = subprocess.run(cmd, stdout=subprocess.PIPE, text=True,
+                          env=dict(os.environ, MXNET_AOT="1"))
     if proc.returncode not in (0, 2):
         # rc 2 = valid run with some AOT fallbacks: the JSON summary
         # (and the populated store) is still there and still worth
-        # reporting — only a hard failure loses the cold numbers
-        log("prewarm exited %d; continuing cold" % proc.returncode)
-        return None
-    try:
-        info = json.loads(proc.stdout.strip().splitlines()[-1])
-    except (ValueError, IndexError) as e:
-        log("prewarm output unparsable (%s); continuing cold" % e)
-        return None
+        # reporting — anything else has no cold numbers to report
+        sys.exit("prewarm exited %d: no cold-start measurement"
+                 % proc.returncode)
+    info = json.loads(proc.stdout.strip().splitlines()[-1])
     if proc.returncode == 2:
         log("prewarm reported %d fallback(s); cold numbers still "
             "recorded" % info.get("fallbacks", 0))
@@ -141,7 +139,7 @@ def _host_gap_p50():
 
 def ledger_records(result):
     """The run's perf_ledger record(s): the classic bench fields stay
-    top-level (r02-r05 continuity), the topology/precision fields are
+    top-level, the topology/precision fields are
     ALSO stamped into provenance so every ledger row is comparable
     without knowing this emitter's layout.  The tier-1 schema guard
     calls this with a canned result."""
@@ -186,33 +184,31 @@ def run_dtype_compare(policies, steps):
 
 
 def main():
+    prewarm_info = None
+    if os.environ.get("BENCH_PREWARM", "0") not in ("", "0"):
+        prewarm_info = run_prewarm()   # before jax: see its docstring
+        os.environ.setdefault("MXNET_AOT", "1")
     log("importing jax/mxnet_tpu")
     import jax
 
     from mxnet_tpu import telemetry
 
+    if all(d.platform == "cpu" for d in jax.devices()):
+        sys.exit("bench.py reports a device metric and found no "
+                 "accelerator (jax.devices() = %s); it has no CPU mode"
+                 % (jax.devices(),))
     steps = int(os.environ.get("BENCH_STEPS", "40"))
     warmup = int(os.environ.get("BENCH_WARMUP", "2"))
-    k_env = os.environ.get("BENCH_STEPS_PER_CALL", "")
-    prewarm_info = None
-    if os.environ.get("BENCH_PREWARM", "0") not in ("", "0"):
-        prewarm_info = run_prewarm()
-    trainer, x, y, batch, on_tpu = build_trainer()
-    # fused-loop K: 4 on the chip (the scan compile is amortized by the
-    # AOT store / persistent cache); 1 on the CPU smoke — ResNet's
-    # second ~50 s compile would double the smoke-run budget, and K=1
-    # reuses the single-step executable while still exercising the
-    # async dispatch path.  BENCH_STEPS_PER_CALL overrides both.
-    k = int(k_env) if k_env else (4 if on_tpu else 1)
-    if not on_tpu:
-        steps = min(steps, 4)
-        warmup = 1
+    trainer, x, y, batch, _on_tpu = build_trainer()
+    # fused-loop K (the scan executable is its own compile, amortized
+    # by the AOT store / persistent cache)
+    k = int(os.environ.get("BENCH_STEPS_PER_CALL", "") or 4)
     log("devices=%s batch=%d steps=%d" % (jax.devices(), batch, steps))
     log("model built + host-initialized; compiling train step")
     # host-gap attribution (mxnet_tpu_host_gap_seconds) for both phases
     telemetry.enable()
 
-    # warmup/compile — timed per step so the ~97 s cold-start (the
+    # warmup/compile — timed per step so the cold start (the
     # ROADMAP AOT-compile item) is a parsed per-run metric with a
     # trajectory, not a stderr-only log line.  Step 0 carries the XLA
     # compile (or the persistent-cache load); later warmup steps are
@@ -279,7 +275,7 @@ def main():
         "warmup_seconds": round(warmup_secs, 2),
         "warmup_step_seconds": warmup_step_secs,
         # topology attribution (docs/sharding.md): {} / null =
-        # single-device, the historical BENCH_r* configuration
+        # single-device
         "mesh_shape": trainer.mesh_shape,
         "layout": trainer.layout_name,
         # host-overlap attribution (ISSUE 10): sync vs async+fused

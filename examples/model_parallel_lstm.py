@@ -22,12 +22,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-
-import _common
-
-_common.force_platform_from_env()
 
 import mxnet_tpu as mx
 from mxnet_tpu import nd, gluon, parallel

@@ -31,9 +31,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from _common import force_platform_from_env  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import gluon  # noqa: E402
@@ -408,7 +405,6 @@ if __name__ == "__main__":
     # tiny smoke run: one eager forward + one sharded train step
     import numpy as np
 
-    force_platform_from_env()
     from mxnet_tpu import nd, parallel
 
     lm = TransformerLM(vocab_size=128, d_model=64, n_heads=4, n_layers=2,

@@ -1,7 +1,7 @@
 """Ahead-of-time compilation: serialized XLA executables + an artifact
 store, so a restarting trainer or a freshly spawned serving replica
 starts at warm-cache speed instead of paying the full trace+compile
-cold start (bench.py measures ~97 s for the ResNet-50 train step).
+cold start.
 
 The deployable unit is the *compiled executable*, not the traced
 program — the core lesson of the end-to-end compiler line (TVM, the
@@ -58,8 +58,7 @@ from . import tracing as _tracing
 
 __all__ = ["AOTStore", "AOTFunction", "resolve_aot", "default_store",
            "environment_fingerprint", "executable_key", "unwrap",
-           "set_store", "clear_store", "ensure_serializable_cpu_codegen",
-           "SCHEMA_VERSION"]
+           "set_store", "clear_store", "SCHEMA_VERSION"]
 
 SCHEMA_VERSION = 1
 
@@ -112,21 +111,6 @@ def environment_fingerprint():
     }
 
 
-_tracer_cls = None
-
-
-def _get_tracer_cls():
-    global _tracer_cls
-    if _tracer_cls is None:
-        try:
-            from jax.core import Tracer
-
-            _tracer_cls = Tracer
-        except Exception:  # pragma: no cover - stable across jax 0.4.x
-            _tracer_cls = ()
-    return _tracer_cls
-
-
 def _leaf_sig(leaf):
     """(shape, dtype, weak_type, device) of one argument leaf.  Devices
     matter: serving pins one replica per device, and an executable
@@ -146,6 +130,27 @@ def _leaf_sig(leaf):
         except Exception:
             dev = ""
     return (shape, dtype, weak, dev)
+
+
+def _execution_devices(leaves):
+    """The device assignment jit gives a call with these argument
+    leaves, in assignment order: the mesh of a NamedSharding argument,
+    else the one device a committed argument pins, else the default
+    device.  A stored executable must be loaded onto exactly these —
+    left to itself, deserialization spreads a one-device program over
+    every local device and the first dispatch fails."""
+    import jax
+
+    pinned = None
+    for leaf in leaves:
+        sharding = getattr(leaf, "sharding", None)
+        mesh = getattr(sharding, "mesh", None)
+        if mesh is not None:
+            return list(mesh.devices.flat)
+        if pinned is None and sharding is not None \
+                and getattr(leaf, "committed", False):
+            pinned = next(iter(sharding.device_set))
+    return [pinned if pinned is not None else jax.devices()[0]]
 
 
 def _signature(args, kwargs=None):
@@ -474,23 +479,6 @@ def default_store():
     return store
 
 
-def ensure_serializable_cpu_codegen():
-    """Best-effort ``--xla_cpu_parallel_codegen_split_count=1`` env
-    injection (see the matching block in ``mxnet_tpu/__init__.py`` —
-    the canonical copy, applied when ``MXNET_AOT=1`` is already set at
-    import).  jax 0.4.x XLA:CPU splits large modules across
-    parallel-codegen object files and executable serialization drops
-    the extra symbols; artifacts persisted without this flag load only
-    in the process that wrote them.  Effective only if XLA has not yet
-    parsed its flags (i.e. call before the first compile); a late call
-    is harmless — mismatched artifacts fail loudly at load and
-    recompile."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_parallel_codegen_split_count" not in flags:
-        os.environ["XLA_FLAGS"] = \
-            (flags + " --xla_cpu_parallel_codegen_split_count=1").strip()
-
-
 def set_store(store):
     """Install a process-wide store override (``config.enable_aot``):
     a path, an :class:`AOTStore`, True (default dir), or False/None to
@@ -502,8 +490,6 @@ def set_store(store):
         store = default_store()
     elif store is False:
         store = None
-    if store is not None:
-        ensure_serializable_cpu_codegen()
     _override = store
 
 
@@ -543,21 +529,6 @@ def resolve_aot(spec):
 # ---------------------------------------------------------------------------
 # the jit wrapper
 # ---------------------------------------------------------------------------
-
-
-def multi_device_deserialization_safe():
-    """Whether this process may DESERIALIZE multi-device executables.
-
-    jax 0.4.x mis-deserializes multi-device CPU executables — the same
-    bug :func:`mxnet_tpu.config.compile_cache_safe` version-gates the
-    persistent compile cache for.  Measured here too: an AOT-loaded
-    8-virtual-device sharded train step returns *wrong losses* (single-
-    device artifacts round-trip fine, so only multi-device loads are
-    gated).  Saves still happen: the store stays correct, this process
-    just recompiles, and a fixed jax gets the hits back."""
-    from . import config as _config
-
-    return _config.compile_cache_safe()
 
 
 def unwrap(fn):
@@ -613,10 +584,9 @@ class AOTFunction:
         # is kept to one pass and no string building beyond the leaf
         # device names
         leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
-        tracer_cls = _get_tracer_cls()
         sig_parts = []
         for leaf in leaves:
-            if isinstance(leaf, tracer_cls):
+            if isinstance(leaf, jax.core.Tracer):
                 # being traced into an outer program (vjp-of-jit,
                 # eval_shape through the wrapper): only the raw jit
                 # can inline
@@ -668,6 +638,8 @@ class AOTFunction:
     def _acquire(self, sig, args, kwargs, info=None):
         """Lower, look up, load-or-compile, publish.  Any exception
         degrades to the jit path (counted + warned)."""
+        import jax
+
         tel = _telemetry.enabled()
         try:
             t0 = time.perf_counter()
@@ -678,22 +650,8 @@ class AOTFunction:
                                  extra=self._extra)
             if info is not None:
                 info["key"] = key
-            # multi-device arguments (a "," joined device list in any
-            # leaf sig) + an affected jax line: loading would return a
-            # silently-wrong executable — treat as a miss and recompile
-            gated = any("," in (s[3] or "") for s in sig[0]) and \
-                not multi_device_deserialization_safe()
-            if gated:
-                _warn_once(
-                    "desergate:" + self.label,
-                    "AOT %s: multi-device executable loads are disabled "
-                    "on this jax (0.4.x multi-device CPU "
-                    "deserialization bug; see "
-                    "aot.multi_device_deserialization_safe) — "
-                    "compiling instead" % self.label)
-                if info is not None:
-                    info["deser_gated"] = True
-            compiled = None if gated else self._try_load(key)
+            compiled = self._try_load(key, _execution_devices(
+                jax.tree_util.tree_leaves((args, kwargs))))
             if compiled is not None:
                 if tel:
                     _telemetry.AOT_CACHE_HITS.inc()
@@ -759,9 +717,10 @@ class AOTFunction:
                 self._compiled[sig] = self._FALLBACK
             return self._FALLBACK
 
-    def _try_load(self, key):
-        """Deserialize a stored executable, or None on any mismatch or
-        damage (the store already warned)."""
+    def _try_load(self, key, devices):
+        """Deserialize a stored executable onto ``devices`` (the
+        assignment it was compiled for — they ride in the key), or None
+        on any mismatch or damage (the store already warned)."""
         payload = self.store.load_payload(key)
         if payload is None:
             return None
@@ -772,7 +731,8 @@ class AOTFunction:
             from jax.experimental import serialize_executable as _se
 
             ser, in_tree, out_tree = pickle.loads(payload)
-            return _se.deserialize_and_load(ser, in_tree, out_tree)
+            return _se.deserialize_and_load(ser, in_tree, out_tree,
+                                            execution_devices=devices)
         except Exception as e:
             _warn_once("deserialize:" + key,
                        "AOT %s: stored executable %s failed to "

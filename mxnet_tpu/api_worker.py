@@ -6,7 +6,8 @@ symbol load + infer-shape, executor bind/forward/backward: the subset
 that powers a cpp-package-style client that *trains*, not just
 predicts.  Same worker-process design as predict_worker.py (no
 libpython linkage in the host app, crash isolation; the per-call IPC is
-noise next to the XLA compute).
+noise next to the XLA compute).  As there, this worker owns the chip and
+the C parent stays off jax: one worker per chip.
 
 Wire protocol (little-endian, over stdin/stdout; shared framing with
 the predict worker):

@@ -33,11 +33,6 @@ FLAGS = {
         "ThreadedEnginePerDevice", str, "honored",
         "NaiveEngine forces synchronous dispatch (race-detection oracle); "
         "anything else keeps jax async dispatch (engine.py)"),
-    "MXNET_PLATFORM": (
-        "", str, "honored",
-        "pin the jax backend ('cpu'/'tpu') before init — multi-process "
-        "launcher workers use this to stay off the single accelerator "
-        "(__init__.py)"),
     "MXNET_PROFILER_AUTOSTART": (
         "0", _pbool, "honored", "start the jax trace at import"),
     "MXNET_TEST_PLATFORM": (
@@ -146,20 +141,16 @@ FLAGS = {
     "MXNET_COMPILE_CACHE": (
         "1", _pbool, "honored",
         "persistent XLA compilation cache: the second process-level run "
-        "of the same program skips compilation (bench.py pays ~97 s "
-        "cold)"),
-    "MXNET_COMPILE_CACHE_DIR": (
-        os.path.join(os.path.expanduser("~"), ".cache", "mxnet_tpu",
-                     "xla"),
-        str, "honored",
-        "directory backing the persistent compilation cache"),
+        "of the same program loads the executable instead of compiling "
+        "it.  JAX_COMPILATION_CACHE_DIR places the cache; unset, it is "
+        "<checkout>/.jax_cache (config.COMPILE_CACHE_DIR).  0 = off"),
     "MXNET_AOT": (
         "0", _pbool, "honored",
         "ahead-of-time executable store (aot.py): jit'd hot paths "
         "(Executor, CachedOp, ShardedTrainer.step, serving.Predictor) "
         "lower+compile once and serialize the executable; later "
-        "processes deserialize instead of recompiling — kills the "
-        "~97 s bench.py cold start.  Per-site override via aot="),
+        "processes deserialize instead of recompiling.  Per-site "
+        "override via aot="),
     "MXNET_AOT_DIR": (
         os.path.join(os.path.expanduser("~"), ".cache", "mxnet_tpu",
                      "aot"),
@@ -235,7 +226,8 @@ FLAGS = {
     "MXNET_PEAK_TFLOPS": (
         "", str, "honored",
         "accelerator peak TFLOP/s for the MFU gauge (overrides the "
-        "docs/mfu_probe.json ceiling; '' = probe artifact or no MFU)"),
+        "published peak telemetry.DEVICE_PEAKS lists for the device "
+        "kind; '' = the table, and no MFU for an unlisted device)"),
     "MXNET_ASYNC_METRICS": (
         "0", _pbool, "honored",
         "non-blocking train-step metrics (parallel/train.py): step() "
@@ -517,6 +509,12 @@ FLAGS = {
 
 _warned = set()
 
+#: where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
+#: does not place it: one fixed path inside the checkout (.gitignore'd)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
 
 def get(name):
     """Parsed value of a registered flag (env overrides default)."""
@@ -547,55 +545,6 @@ def describe():
     rows = ["%-36s %-9s default=%-10s %s" % (n, d[2], d[0], d[3])
             for n, d in sorted(FLAGS.items())]
     return "\n".join(rows)
-
-
-def _cache_deser_affected(version):
-    """Is ``version`` of jax affected by the multi-device CPU persistent-
-    cache mis-deserialization (repro in docs/perf_notes.md: cache-warm
-    8-virtual-device allreduce returns wrong loss)?  Observed on the
-    0.4.x line; treat everything below 0.5.0 as affected and newer
-    releases as fixed (the deserialization path was rewritten), so the
-    cache comes back exactly where it matters most as soon as the
-    installed jax moves off the buggy line.  Unparseable versions count
-    as affected — the failure mode of a wrong "safe" is silently wrong
-    training losses."""
-    try:
-        parts = tuple(int(x) for x in str(version).split(".")[:2])
-    except (TypeError, ValueError):
-        return True
-    return parts < (0, 5)
-
-
-def compile_cache_safe(jax_version=None):
-    """Whether the persistent compile cache is safe to enable by default.
-
-    jax 0.4.x deserializes MULTI-DEVICE CPU executables incorrectly
-    (measured: a cache-warm 8-virtual-device allreduce step returns
-    wrong loss values — examples/distributed_horovod_style.py fails its
-    equivalence check on the second run).  The guard is VERSION-GATED:
-    under a forced-host-device-count CPU mesh the bootstrap skips the
-    cache only when the installed jax is on an affected line
-    (:func:`_cache_deser_affected`); unaffected jax keeps the cache
-    even there.  Real accelerators and plain single-device CPU always
-    keep it, and an explicit ``enable_compile_cache()`` call still
-    works everywhere.  ``jax_version`` overrides the installed version
-    (tests)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" in flags:
-        multi = False
-        for tok in flags.split():
-            if tok.startswith("--xla_force_host_platform_device_count"):
-                try:
-                    multi = int(tok.split("=", 1)[1]) > 1
-                except (IndexError, ValueError):
-                    multi = True
-        if multi:
-            if jax_version is None:
-                import jax
-
-                jax_version = jax.__version__
-            return not _cache_deser_affected(jax_version)
-    return True
 
 
 def fusion_cost_table(table):
@@ -671,49 +620,21 @@ def enable_flight_recorder(on=True, directory=None):
         tracing.disable_flight_recorder()
 
 
-def enable_compile_cache(cache_dir=None, min_compile_time_secs=None):
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def enable_compile_cache():
+    """Place jax's persistent compilation cache; returns its directory.
 
-    Called from package bootstrap when ``MXNET_COMPILE_CACHE`` is on
-    (the default): a second process compiling the same XLA program loads
-    the cached executable from disk instead of recompiling — bench.py's
-    ~97 s ResNet-50 train-step compile becomes a one-time cost per
-    machine.  Safe to call before or after backend init (the flag is
-    read at compile time).  Returns the cache dir, or None when the
-    cache could not be enabled (unwritable dir, jax too old).
-    """
+    Called from package bootstrap unless ``MXNET_COMPILE_CACHE=0``.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it
+    and nothing is set here.  Otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR` — the directory is part of the cache key,
+    so it is one fixed path, never derived from ``~``, a pid or a
+    temporary name.  The flag is read at compile time, so this works
+    before or after backend init."""
     import jax
 
-    cache_dir = cache_dir or get("MXNET_COMPILE_CACHE_DIR")
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        if min_compile_time_secs is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_compile_time_secs))
-    except Exception as e:
-        # roll back so a False/None return really means "cache off" —
-        # a half-applied config would cache executables while the
-        # caller believes it does not
-        try:
-            jax.config.update("jax_compilation_cache_dir", prev)
-        except Exception:
-            pass
-        warnings.warn("persistent compilation cache disabled: %s" % e)
-        return None
-    if prev != cache_dir:
-        # jax pins the cache object to the dir seen at first use;
-        # re-pointing after any compile needs an explicit reset.
-        # Best-effort private API: at bootstrap nothing has compiled
-        # yet, so a missing reset hook does not invalidate the enable.
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-    return cache_dir
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
 def markdown_table():

@@ -5,9 +5,16 @@ TPU-native design: a Context names a jax.Device.  ``tpu(i)`` is the native
 accelerator context; ``gpu(i)`` is accepted as an alias for the i-th
 accelerator so reference scripts run unmodified; ``cpu()`` maps to the host
 platform.  Under jit tracing, contexts are advisory — XLA owns placement.
+
+A ``tpu(i)``/``gpu(i)`` context never silently lands somewhere else: an
+index past the accelerator count raises, and so does asking for an
+accelerator when JAX found none — unless the process was started with
+``JAX_PLATFORMS=cpu`` (the test harness), where the alias names the host
+devices on purpose.
 """
 from __future__ import annotations
 
+import os
 import threading
 
 from .base import MXNetError
@@ -27,8 +34,7 @@ def _jax():
 
 
 class Context:
-    """A device context. devtype 'cpu'|'tpu' ('gpu' aliases 'tpu' when TPUs
-    are present, else 'cpu')."""
+    """A device context. devtype 'cpu'|'tpu' ('gpu' aliases 'tpu')."""
 
     devtype2str = {1: "cpu", 2: "gpu", 3: "cpu_pinned", 5: "tpu"}
     devstr2type = {"cpu": 1, "gpu": 2, "cpu_pinned": 3, "tpu": 5}
@@ -58,18 +64,26 @@ class Context:
 
     @property
     def jax_device(self):
-        """The jax.Device this context names (accelerator if available)."""
+        """The jax.Device this context names."""
         jax = _jax()
         if self.device_type in ("cpu", "cpu_pinned"):
             try:
                 return jax.local_devices(backend="cpu")[0]
             except RuntimeError:
                 return jax.devices()[0]
-        accels = Context._accelerators()
-        if accels:
-            return accels[self.device_id % len(accels)]
-        # gpu()/tpu() requested but only CPU present: degrade gracefully
-        return jax.devices()[self.device_id % len(jax.devices())]
+        devs = Context._accelerators()
+        if not devs:
+            if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+                raise MXNetError(
+                    "%s requested but JAX found no accelerator "
+                    "(jax.devices() = %s); start the process with "
+                    "JAX_PLATFORMS=cpu to run on the host on purpose"
+                    % (self, jax.devices()))
+            devs = jax.devices()
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError("%s requested but only %d device(s) present"
+                             % (self, len(devs)))
+        return devs[self.device_id]
 
     # --- parity API ------------------------------------------------------
     def __hash__(self):

@@ -16,8 +16,9 @@ TPU-native: the quantize/dequantize hot loops are Pallas kernels — the
 gradient streams HBM->VMEM once per grid step, the VPU computes codes
 for a (128, 128) fp32 tile and packs them into an (8, 128) int32 block
 (16 consecutive sublanes fold into each code row, keeping the 128-lane
-dimension dense).  On non-TPU backends the same kernels run through the
-Pallas interpreter, so one code path serves tests and production.
+dimension dense).  Off the TPU (the CPU test harness) the same kernels run
+through the Pallas interpreter, so one code path serves tests and
+production.
 """
 from __future__ import annotations
 
@@ -37,9 +38,11 @@ _TILE = _BLOCK_ROWS * _LANES
 
 
 def _use_interpret():
+    """Interpret the kernels only where the devices are not TPUs (the
+    CPU test harness); on the chip they compile through Mosaic."""
     import jax
 
-    return jax.default_backend() != "tpu"
+    return jax.devices()[0].platform != "tpu"
 
 
 # ---------------------------------------------------------------------------

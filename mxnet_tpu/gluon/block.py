@@ -50,6 +50,17 @@ def _is_tracing():
     return getattr(_trace_state, "active", False)
 
 
+# Tracing a block swaps its Parameters' arrays for tracers IN PLACE, so
+# two threads tracing blocks that share Parameters (AsyncPredictor builds
+# one Predictor per device over one net, and each compiles on its own
+# worker thread) would save and restore each other's tracers — leaked
+# tracers, or a Parameter left holding another trace's cast weights.
+# Every swap-trace-restore window holds this lock; it is re-entrant
+# (traces nest within a thread) and is only ever held while tracing,
+# never while a compiled program runs.
+_param_swap_lock = threading.RLock()
+
+
 @contextlib.contextmanager
 def swapped_params(params, arrays, training=False):
     """Trace a block's forward against externally supplied parameter
@@ -57,26 +68,27 @@ def swapped_params(params, arrays, training=False):
     matching entry of ``arrays`` (typically jit tracers), activates the
     NDArray trace state, pins autograd ``training``, and restores
     everything on exit.  The one param-swap recipe shared by the traced
-    front-ends (``serving.Predictor.from_block``'s pattern;
-    ``generate.GenerationEngine`` and ``tools/bench_decode.py`` use
-    this helper directly)."""
+    front-ends (``serving.Predictor.from_block``,
+    ``generate.GenerationEngine``, ``tools/bench_decode.py``).  Holds
+    :data:`_param_swap_lock` for the whole window."""
     from .. import autograd
 
-    saved = []
-    prev_train = autograd.set_training(training)
-    prev_trace = getattr(_trace_state, "active", False)
-    _trace_state.active = True
-    try:
-        for p, arr in zip(params, arrays):
-            d = p.data()
-            saved.append((d, d._data))
-            d._data = arr
-        yield
-    finally:
-        _trace_state.active = prev_trace
-        autograd.set_training(prev_train)
-        for d, old in saved:
-            d._data = old
+    with _param_swap_lock:
+        saved = []
+        prev_train = autograd.set_training(training)
+        prev_trace = getattr(_trace_state, "active", False)
+        _trace_state.active = True
+        try:
+            for p, arr in zip(params, arrays):
+                d = p.data()
+                saved.append((d, d._data))
+                d._data = arr
+            yield
+        finally:
+            _trace_state.active = prev_trace
+            autograd.set_training(prev_train)
+            for d, old in saved:
+                d._data = old
 
 
 def _abstract_eval_forward(block, args):
@@ -114,7 +126,8 @@ def _abstract_eval_forward(block, args):
                                   else tuple(r.shape),
                                   getattr(r, "dtype", _np.float32))
              for r in raws]
-    return jax.eval_shape(probe, *specs)
+    with _param_swap_lock:   # reads the live params: no swap in flight
+        return jax.eval_shape(probe, *specs)
 
 
 def _flatten_nested(out):
@@ -491,16 +504,17 @@ class CachedOp:
                 # (cast to the policy compute dtype per override rule —
                 # norm params stay f32 under bf16_mixed)
                 saved = []
-                for p, arr in zip(self._param_list, params):
-                    d = p.data()
-                    saved.append((d, d._data))
-                    d._data = arr if dt_policy is None else \
-                        dt_policy.cast_compute(p.name, arr)
-                try:
-                    out = block.hybrid_forward_dispatch(*nd_inputs)
-                finally:
-                    for d, old in saved:
-                        d._data = old
+                with _param_swap_lock:
+                    for p, arr in zip(self._param_list, params):
+                        d = p.data()
+                        saved.append((d, d._data))
+                        d._data = arr if dt_policy is None else \
+                            dt_policy.cast_compute(p.name, arr)
+                    try:
+                        out = block.hybrid_forward_dispatch(*nd_inputs)
+                    finally:
+                        for d, old in saved:
+                            d._data = old
                 flat_out, tmpl = _flatten_nested(out)
                 outs = [o._data for o in flat_out]
                 aux_params = [p for (p, _v) in sink]

@@ -352,7 +352,13 @@ class WorkerClient:
     def __init__(self, host, port, rank, num_workers):
         self.rank = rank
         self.num_workers = num_workers
-        self._sock = socket.create_connection((host, port), timeout=600)
+        from .checkpoint import retry
+
+        # the launcher starts the server and the workers together: a
+        # worker that comes up first retries until the server listens
+        connect = retry(socket.create_connection, retries=8, backoff=0.25,
+                        exceptions=(ConnectionRefusedError,))
+        self._sock = connect((host, port), timeout=600)
         self._lock = threading.Lock()
         self._rpc(op="hello", rank=rank)
 

@@ -6,14 +6,17 @@ TPU-native equivalents: a pread-based RecordIO reader/indexer, a
 libjpeg batch decoder running on C++ threads (no GIL), and a
 size-bucketed buffer pool with statistics.
 
-The shared library is built on demand with the system toolchain
-(``make -C cpp``); if the build or load fails — no g++, no libjpeg —
-``available()`` returns False and every consumer falls back to the
-pure-Python path, so the framework stays functional without it.
+The shared library is an untracked build product: it is built on demand
+from the tracked sources with the system toolchain (``make -C cpp``)
+whenever it is absent or older than them.  If the build or load fails —
+no g++, no libjpeg — ``available()`` returns False, every consumer falls
+back to the pure-Python path, and that is logged once, loudly: the
+fallback is slower, never silent.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -22,6 +25,8 @@ import numpy as np
 
 __all__ = ["available", "lib", "recordio_index", "decode_batch",
            "pool_stats", "pool_clear", "RecordReader"]
+
+_logger = logging.getLogger("mxnet_tpu.native")
 
 _CPP_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "cpp")
@@ -39,13 +44,22 @@ def _load():
             return _lib
         _tried = True
         try:
-            if not os.path.exists(_SO) or (
-                    os.path.getmtime(_SO) < os.path.getmtime(
-                        os.path.join(_CPP_DIR, "mxtpu_runtime.cc"))):
-                subprocess.run(["make", "-C", _CPP_DIR], check=True,
-                               capture_output=True)
+            sources = [os.path.join(_CPP_DIR, f)
+                       for f in os.listdir(_CPP_DIR)
+                       if f.endswith((".cc", ".h"))]
+            if not os.path.exists(_SO) or os.path.getmtime(_SO) < max(
+                    os.path.getmtime(f) for f in sources):
+                subprocess.run(["make", "-C", _CPP_DIR,
+                                "libmxtpu_runtime.so"], check=True,
+                               capture_output=True, text=True)
             lib = ctypes.CDLL(_SO)
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            _logger.error(
+                "NATIVE RUNTIME UNAVAILABLE — falling back to the "
+                "pure-Python IO/storage paths (slower).  Building/loading "
+                "%s failed: %s%s", _SO, e,
+                "\n" + e.stderr[-2000:]
+                if getattr(e, "stderr", None) else "")
             _lib = None
             return None
         lib.mxtpu_recordio_open.restype = ctypes.c_void_p

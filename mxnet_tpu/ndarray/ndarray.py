@@ -605,11 +605,9 @@ _EAGER_JIT_SKIP = {"_index_static", "take"}
 
 def _trace_state_clean():
     """True when no jax trace (jit/vjp/eval_shape) is in progress."""
-    try:
-        from jax._src.core import trace_state_clean
-    except ImportError:  # future jax: public location
-        from jax.core import trace_state_clean
-    return trace_state_clean()
+    import jax
+
+    return jax.core.trace_ctx.is_top_level()
 
 
 def _freeze_attrs(v):

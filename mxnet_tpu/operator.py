@@ -301,10 +301,9 @@ def _custom_surface(array_type, invoke):
 
 def _eager_custom(prop, inputs, n_out):
     """Concrete (non-traced) execution: run the user op directly on host
-    numpy — no pure_callback, so this works on accelerators whose PJRT
-    plugin lacks host-callback support — and tape a custom backward that
-    reuses the SAME operator instance and the saved forward tensors
-    (stateful/nondeterministic ops stay consistent)."""
+    numpy — no host callback needed outside a trace — and tape a custom
+    backward that reuses the SAME operator instance and the saved
+    forward tensors (stateful/nondeterministic ops stay consistent)."""
     from . import autograd
     from .ndarray.ndarray import NDArray
 

@@ -17,8 +17,9 @@ score matrix reaches the residuals; XLA re-fuses the recomputation); a
 hand-written Pallas backward is a further optimization, not a semantic
 change.
 
-On non-TPU backends the same kernel runs with ``interpret=True`` (slow,
-for tests); the entry points pick the mode automatically.
+``interpret=None`` resolves from the devices present: compiled through
+Mosaic on a TPU, the Pallas interpreter elsewhere (slow — the CPU test
+harness); pass ``interpret=False`` to refuse the interpreter outright.
 """
 from __future__ import annotations
 
@@ -191,7 +192,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     import jax.numpy as jnp
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.devices()[0].platform != "tpu"
     scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
 
     qt = jnp.swapaxes(q, 1, 2)   # (B, H, T, D)

@@ -36,27 +36,15 @@ DATA_AXES = ("dp", "fsdp")
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=None,
               **kwargs):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (with ``check_vma``); older
-    releases only have the deprecated ``jax.experimental.shard_map``
-    (with the ``check_rep`` spelling of the same knob).  Every shard_map
-    in this package (and the tests) goes through this shim so the code
-    is warning-free on both sides of the rename (VERDICT r5 #8).
-    """
+    """``jax.shard_map`` with ``check_vma`` passed only when given —
+    the one spelling every shard_map in this package (and the tests)
+    goes through."""
     import jax
 
-    native = getattr(jax, "shard_map", None)
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    if native is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return native(f, **kw)
-    from jax.experimental import shard_map as _sm_mod
-
     if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _sm_mod.shard_map(f, **kw)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 class MeshConfig:

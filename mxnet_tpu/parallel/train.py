@@ -705,6 +705,9 @@ class ShardedTrainer:
             # follows the weight — see dtype_policy module doc)
             stack.enter_context(_dtp.scope(policy))
             try:
+                # the in-place param swap is shared state across threads
+                # tracing this net (see block._param_swap_lock)
+                stack.enter_context(_block_mod._param_swap_lock)
                 saved = []
                 for i, (p, arr) in enumerate(zip(params_objs,
                                                  param_arrays)):

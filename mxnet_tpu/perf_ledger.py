@@ -1,10 +1,9 @@
 """Perf observatory: versioned BENCH records, the append-only run
 ledger, and step-time attribution.
 
-Four rounds of headline benches (BENCH_r02-r05) sat flat at ~2180 img/s
-while PRs 8-11 shipped real wins — because a single steady-state number
-can neither say *where* a step's milliseconds go nor survive comparison
-under noise.  This module is the measurement substrate that fixes both:
+A single steady-state number can neither say *where* a step's
+milliseconds go nor survive comparison under noise.  This module is the
+measurement substrate that fixes both:
 
 * **Records** — :func:`make_record` builds one versioned BENCH row
   (``schema_version``, ``metric``/``value``/``unit``, plus provenance:

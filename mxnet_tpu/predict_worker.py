@@ -11,6 +11,11 @@ version coupling for the host app, crash isolation, and the IPC cost
 (one round-trip per forward) is noise next to the XLA compute it
 triggers.
 
+One process for each chip: THIS worker is the chip's owner.  The C parent
+(libmxtpu_runtime.so and the host app) never loads jax, so it can fork
+the worker freely; one host app driving several workers on one chip is
+not supported — the second worker's backend init fails or hangs.
+
 Wire protocol (little-endian, over stdin/stdout):
     request  = u8 opcode | u64 payload_len | payload
     response = u8 status (0 ok, 1 error) | u64 payload_len | payload

@@ -28,6 +28,7 @@ them back except lazily inside functions.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import sys
 import threading
@@ -35,10 +36,13 @@ import time
 
 from . import config as _config
 
+_logger = logging.getLogger("mxnet_tpu.telemetry")
+
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
            "enabled", "enable", "disable", "counter", "gauge", "histogram",
            "span", "scrape", "dump", "collect", "reset",
            "TelemetryReporter", "set_peak_flops", "peak_flops",
+           "DEVICE_PEAKS",
            "serve_scrape", "stop_scrape", "scrape_server",
            "set_exemplar_source", "register_status_provider",
            "unregister_status_provider", "statusz", "varz",
@@ -631,8 +635,9 @@ TRAIN_STEP_FLOPS = gauge(
 TRAIN_MFU = gauge(
     "mxnet_tpu_train_mfu_ratio",
     "Model FLOPs utilization: step_flops / step_seconds / peak_flops "
-    "(peak from set_peak_flops, MXNET_PEAK_TFLOPS, or docs/"
-    "mfu_probe.json).")
+    "(peak from set_peak_flops, MXNET_PEAK_TFLOPS, or the "
+    "telemetry.DEVICE_PEAKS entry of the device kind; not reported for "
+    "an unlisted device).")
 # mesh / sharding (parallel.mesh + parallel.train; see docs/sharding.md)
 MESH_DEVICES = gauge(
     "mxnet_tpu_mesh_devices",
@@ -1036,12 +1041,9 @@ GOODPUT_TORN_LINES = counter(
 _bridge_lock = threading.Lock()
 _bridge_installed = False
 
-_BACKEND_COMPILE_EVENTS = (
-    # jax 0.4.x name, and the _sec-suffixed spelling used by other
-    # versions — match either so the bridge survives jax upgrades
-    "/jax/core/compile/backend_compile_duration",
-    "/jax/core/compile/backend_compile_time_sec",
-)
+# fires around every executable acquisition, persistent-cache hits
+# included (jax wraps compile_or_get_cached in it)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _on_jax_event(event, **kw):
@@ -1056,7 +1058,7 @@ def _on_jax_event(event, **kw):
 def _on_jax_duration(event, duration_secs, **kw):
     if not _enabled:
         return
-    if event in _BACKEND_COMPILE_EVENTS:
+    if event == _BACKEND_COMPILE_EVENT:
         COMPILES.inc()
         COMPILE_SECONDS.observe(duration_secs)
         # feed the goodput ledger's compile bucket (no-op unless a
@@ -1092,30 +1094,38 @@ def _install_jax_bridge():
 # MFU peak-FLOPs resolution
 # ---------------------------------------------------------------------------
 
+#: published per-chip peaks keyed by jax ``device_kind``:
+#: (bf16 FLOP/s, HBM bytes/s, source).  A device that is not listed has
+#: no peak — and therefore no MFU — never a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9,
+                    'Google Cloud documentation, "TPU v5e"'),
+}
+
 _peak_flops = None       # explicit set_peak_flops value
-_peak_resolved = None    # cached (found, value) from env/probe
+_peak_resolved = None    # cached (value,) from the env flag / table
 
 
 def set_peak_flops(flops_per_sec):
     """Pin the accelerator peak FLOP/s used by the MFU gauge (overrides
-    MXNET_PEAK_TFLOPS and the probe artifact).  Pass None to unpin."""
+    MXNET_PEAK_TFLOPS and the device table).  Pass None to unpin."""
     global _peak_flops, _peak_resolved
     _peak_flops = None if flops_per_sec is None else float(flops_per_sec)
     _peak_resolved = None
 
 
 def peak_flops():
-    """Best-known accelerator peak FLOP/s, or None.
+    """Peak FLOP/s of one device of this process, or None.
 
     Resolution order: :func:`set_peak_flops` > ``MXNET_PEAK_TFLOPS`` env
-    flag > the matmul/conv ceiling measured into ``docs/mfu_probe.json``
-    by ``tools/bench_mfu.py`` (repo checkouts only).
-    """
+    flag > :data:`DEVICE_PEAKS` for ``jax.devices()[0].device_kind``.  An
+    unlisted device kind yields None — no MFU is reported — and says so
+    once."""
     global _peak_resolved
     if _peak_flops is not None:
         return _peak_flops
     if _peak_resolved is not None:
-        return _peak_resolved[1]
+        return _peak_resolved[0]
     val = None
     raw = _config.get("MXNET_PEAK_TFLOPS")
     if raw:
@@ -1124,17 +1134,17 @@ def peak_flops():
         except ValueError:
             pass
     if val is None:
-        probe = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "docs", "mfu_probe.json")
-        try:
-            with open(probe) as f:
-                data = json.load(f)
-            tflops = max(max(r["tflops"] for r in data["matmul"]),
-                         data["conv"]["tflops"])
-            val = tflops * 1e12
-        except Exception:
-            val = None
-    _peak_resolved = (val is not None, val)
+        import jax
+
+        kind = jax.devices()[0].device_kind
+        if kind in DEVICE_PEAKS:
+            val = DEVICE_PEAKS[kind][0]
+        else:
+            _logger.warning(
+                "no published peak for device_kind %r in "
+                "telemetry.DEVICE_PEAKS: MFU is not reported (set "
+                "MXNET_PEAK_TFLOPS to supply one)", kind)
+    _peak_resolved = (val,)
     return val
 
 
@@ -1531,9 +1541,7 @@ class TelemetryReporter:
                              % (interval,))
         self.path = os.fspath(path) if path is not None else None
         self.callback = callback
-        import logging
-
-        self.logger = logger or logging.getLogger("mxnet_tpu.telemetry")
+        self.logger = logger or _logger
         self._stop = threading.Event()
         self._thread = None
 

@@ -54,6 +54,13 @@ def args():
             jax.device_put(jnp.ones((4, 2))))
 
 
+def assert_hit_executed(fn):
+    """The loaded executable itself served the calls: equal outputs alone
+    cannot tell, because a failed dispatch degrades to the plain jit."""
+    assert isinstance(fn, aot.AOTFunction) and fn._compiled
+    assert all(e is not fn._FALLBACK for e in fn._compiled.values())
+
+
 # ---------------------------------------------------------------------------
 # round-trip + counters
 # ---------------------------------------------------------------------------
@@ -77,6 +84,8 @@ def test_roundtrip_same_outputs_and_counters(store, telemetry_on):
     # the steady-state path reuses the loaded executable (no new hits)
     np.testing.assert_array_equal(np.asarray(af2(x, y)), want)
     assert tel.AOT_CACHE_HITS.value() == 1
+    assert_hit_executed(af2)
+    assert tel.AOT_FALLBACKS.value(reason="dispatch") == 0
 
 
 def test_new_signature_is_a_new_entry(store):
@@ -225,6 +234,7 @@ def test_trainer_prewarm_then_step_matches_plain(store):
     tr2 = _tiny_trainer(store, wv)
     assert tr2.prewarm([xb], yb)["status"] == "hit"
     assert [float(tr2.step([xb], yb)) for _ in range(2)] == loss_plain
+    assert_hit_executed(tr2._step_fn)
 
 
 def test_trainer_prewarm_reports_disabled_without_store():
@@ -250,6 +260,7 @@ def test_predictor_prewarm_and_predict(store):
                       aot=aot.AOTStore(store.path))
     assert [i["status"] for i in pred2.prewarm()] == ["hit"]
     np.testing.assert_array_equal(list(pred2.predict([x]))[0], x * 2.0)
+    assert_hit_executed(pred2._jit_chain)
 
 
 def test_predictor_prewarm_requires_pinned_contract(store):

@@ -153,12 +153,9 @@ def test_async_skip_policy_counts_after_drain():
 def test_fused_loop_fsdp_tp_aot_roundtrip(tmp_path):
     """steps_per_call composes with the PR 9 layouts and the PR 8 AOT
     store: dp=2 x fsdp=2 x tp=2 fused loop, second trainer round-trips
-    through the store (cache hit where deserialization is safe; on the
-    jax 0.4.x multi-device-CPU line loads are version-gated and the
-    trainer recompiles) — numerics identical either way."""
+    through the store (a cache hit, loaded onto the mesh's devices and
+    executed) — numerics identical."""
     import jax
-
-    from mxnet_tpu import aot
 
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device CPU mesh")
@@ -192,12 +189,12 @@ def test_fused_loop_fsdp_tp_aot_roundtrip(tmp_path):
                 tr.drain()
                 runs.append(np.asarray(losses).copy())
         np.testing.assert_array_equal(runs[0], runs[1])
-        if aot.multi_device_deserialization_safe():
-            assert telemetry.AOT_CACHE_HITS.value() >= 1
-        else:
-            # the gate turned the load into a recompile; both runs
-            # still persisted their executables for a fixed jax
-            assert telemetry.AOT_CACHE_MISSES.value() >= 2
+        # the second trainer loaded the 8-device executable onto the
+        # mesh's devices and ran it (no silent fallback to the jit)
+        assert telemetry.AOT_CACHE_HITS.value() >= 1
+        assert telemetry.AOT_FALLBACKS.value(reason="dispatch") == 0
+        assert all(e is not tr._step_k_fn._FALLBACK
+                   for e in tr._step_k_fn._compiled.values())
     finally:
         telemetry.reset()
         telemetry.disable()

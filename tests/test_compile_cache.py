@@ -1,17 +1,21 @@
-"""Persistent XLA compilation cache (config.enable_compile_cache).
+"""Persistent XLA compilation cache: placed from outside.
 
-bench.py pays ~97 s of XLA compilation on every cold run; the package
-bootstrap now points jax's persistent compilation cache at
-``MXNET_COMPILE_CACHE_DIR`` so a cache-warm run loads the executable
-from disk instead.  The cold/warm drill runs the same jit twice against
-a tmp cache dir: the first compile writes an entry, and after the
-in-memory executable cache is dropped the second compile is served from
-disk (observed via jax's own cache-hit monitoring event) and is not
-slower than the cold compile.
+The contract (mxnet_tpu.config.enable_compile_cache, called by the package
+bootstrap):
 
-The drill runs in a SUBPROCESS: it must call ``jax.clear_caches()``,
-which would throw away every compiled program the rest of the suite has
-accumulated in this process.
+* ``JAX_COMPILATION_CACHE_DIR`` set — jax reads it itself and importing
+  mxnet_tpu sets no cache directory in code;
+* unset — the cache lives at one fixed path inside the checkout
+  (``config.COMPILE_CACHE_DIR``, git-ignored), never under ``~`` or a
+  temporary name: the path is part of the cache key;
+* ``MXNET_COMPILE_CACHE=0`` — off.
+
+The cold/warm drill runs the same jit twice against the env-placed
+directory: the first compile writes an entry, and after the in-memory
+executable cache is dropped the second is served from disk (observed via
+jax's own cache-hit monitoring event).  It runs in a SUBPROCESS: it must
+call ``jax.clear_caches()``, which would throw away every compiled program
+the rest of the suite has accumulated in this process.
 """
 import os
 import subprocess
@@ -19,96 +23,85 @@ import sys
 
 import jax
 
-import mxnet_tpu as mx  # noqa: F401  (bootstrap wires the default cache)
+import mxnet_tpu as mx  # noqa: F401  (bootstrap places the default cache)
 from mxnet_tpu import config
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _DRILL = r"""
 import os, sys, time
 import numpy as np
-import mxnet_tpu  # bootstrap
-from mxnet_tpu import config
-import jax, jax.numpy as jnp
+import jax
+import jax.monitoring
 
-cache_dir = config.enable_compile_cache(cache_dir=sys.argv[1],
-                                        min_compile_time_secs=0.0)
-assert cache_dir, "cache could not be enabled"
+placed = os.environ["JAX_COMPILATION_CACHE_DIR"]
+updates = []
+_update = jax.config.update
+jax.config.update = lambda k, v: (updates.append(k), _update(k, v))[1]
+import mxnet_tpu  # bootstrap
+jax.config.update = _update
+assert jax.config.jax_compilation_cache_dir == placed, \
+    jax.config.jax_compilation_cache_dir
+assert "jax_compilation_cache_dir" not in updates, updates
+
+import jax.numpy as jnp
 events = []
-from jax._src import monitoring
-monitoring.register_event_listener(events.append)
+jax.monitoring.register_event_listener(lambda e, **kw: events.append(e))
 
 def f(x):
     return jnp.sin(x) @ jnp.cos(x.T) + jnp.tanh(x).sum()
 
 x = jnp.asarray(np.random.RandomState(0).rand(64, 64), jnp.float32)
-t0 = time.perf_counter()
 cold = jax.jit(f)(x).block_until_ready()
-t_cold = time.perf_counter() - t0
-entries = [e for e in os.listdir(cache_dir) if e.endswith("-cache")]
+entries = [e for e in os.listdir(placed) if e.endswith("-cache")]
 assert entries, "first compile wrote no cache entry"
 
 events.clear()
 jax.clear_caches()  # drop in-memory executables; disk cache remains
-t0 = time.perf_counter()
 warm = jax.jit(f)(x).block_until_ready()
-t_warm = time.perf_counter() - t0
 assert "/jax/compilation_cache/cache_hits" in events, \
     "second compile missed the persistent cache: %s" % [
         e for e in events if "cache" in e]
 np.testing.assert_allclose(np.asarray(warm), np.asarray(cold), atol=1e-6)
-# the warm path skips XLA compilation; generous slack for noisy boxes,
-# but a cache load must not cost more than the cold compile
-assert t_warm < t_cold * 1.5, (t_cold, t_warm)
-print("DRILL OK cold=%.4f warm=%.4f entries=%d"
-      % (t_cold, t_warm, len(entries)))
+print("DRILL OK entries=%d" % len(entries))
 """
 
 
-def test_same_jit_twice_hits_disk_cache(tmp_path):
-    # single-device subprocess: the multi-device CPU harness is exactly
-    # where the cache is (correctly) gated off — see the guard test
-    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="")
-    r = subprocess.run(
-        [sys.executable, "-c", _DRILL, str(tmp_path / "xla")],
-        capture_output=True, text=True, timeout=300, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+def _run(code, **env):
+    base = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR", "MXNET_COMPILE_CACHE")}
+    return subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, cwd=REPO,
+        env=dict(base, JAX_PLATFORMS="cpu", XLA_FLAGS="", **env))
+
+
+def test_env_places_the_cache_and_a_second_compile_hits_it(tmp_path):
+    r = _run(_DRILL, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"),
+             JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
     assert r.returncode == 0, r.stdout + r.stderr
     assert "DRILL OK" in r.stdout, r.stdout
 
 
-def test_bootstrap_guard_on_multi_device_cpu(monkeypatch):
-    """jax 0.4.x mis-deserializes multi-device CPU executables (wrong
-    allreduce numerics on a cache-warm run), so the bootstrap must NOT
-    enable the cache under the forced-host-device-count harness."""
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-    assert config.compile_cache_safe() is False
-    monkeypatch.setenv("XLA_FLAGS", "")
-    assert config.compile_cache_safe() is True
-    # this very test process runs under the 8-device harness: bootstrap
-    # must have left the cache off
-    if "xla_force_host_platform_device_count=8" in \
-            os.environ.get("XLA_FLAGS", ""):
-        assert jax.config.jax_compilation_cache_dir is None
+def test_unset_env_means_one_fixed_path_inside_the_checkout():
+    assert config.COMPILE_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    # this process: whoever started the suite may have placed the cache
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    assert jax.config.jax_compilation_cache_dir == \
+        (placed or config.COMPILE_CACHE_DIR)
+    # a fresh process with nothing exported
+    r = _run("import jax, mxnet_tpu; "
+             "print(jax.config.jax_compilation_cache_dir)")
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert r.stdout.split()[-1] == config.COMPILE_CACHE_DIR
 
 
-def test_bootstrap_default_and_env_override(tmp_path, monkeypatch):
-    # flag registry: defaults on, dir under ~/.cache
-    assert config.get("MXNET_COMPILE_CACHE") is True
-    assert "mxnet_tpu" in config.get("MXNET_COMPILE_CACHE_DIR")
-    target = str(tmp_path / "override")
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", target)
-    assert config.get("MXNET_COMPILE_CACHE_DIR") == target
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        got = config.enable_compile_cache()
-        assert got == target
-        assert os.path.isdir(target)
-        assert jax.config.jax_compilation_cache_dir == target
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
+def test_off_switch_leaves_the_cache_unset():
+    assert config.get("MXNET_COMPILE_CACHE") is True    # default: on
+    r = _run("import jax, mxnet_tpu; "
+             "print(jax.config.jax_compilation_cache_dir)",
+             MXNET_COMPILE_CACHE="0")
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert r.stdout.split()[-1] == "None"

@@ -394,27 +394,3 @@ def test_trace_view_top_ops_and_autotune_ranking(tmp_path, capsys):
     assert "1024" in lines[0]  # 512 bytes x 2 calls
     rows = autotune.rank_trace_ops(str(p))
     assert rows[0][0] == "Conv" and rows[0][3] == 1024.0
-
-
-# ---------------------------------------------------------------------------
-# satellite: compile-cache version gate
-# ---------------------------------------------------------------------------
-
-
-def test_compile_cache_guard_is_version_gated(monkeypatch):
-    from mxnet_tpu import config
-
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-    # affected line (the documented 0.4.x repro) stays guarded
-    assert config.compile_cache_safe(jax_version="0.4.37") is False
-    assert config.compile_cache_safe(jax_version="0.4.13") is False
-    # unaffected lines re-enable the cache on the multi-device harness
-    assert config.compile_cache_safe(jax_version="0.5.0") is True
-    assert config.compile_cache_safe(jax_version="0.6.2") is True
-    assert config.compile_cache_safe(jax_version="1.0") is True
-    # unparseable -> conservative (wrong losses beat a slow compile)
-    assert config.compile_cache_safe(jax_version="garbage") is False
-    # single-device: always safe, version never consulted
-    monkeypatch.setenv("XLA_FLAGS", "")
-    assert config.compile_cache_safe(jax_version="0.4.37") is True

@@ -70,8 +70,8 @@ def test_resnet_hybridize_and_train_step():
 
 def test_eager_resnet50_forward_is_fast():
     """The per-op jit cache must keep un-hybridized (eager) dispatch usable:
-    one warm bs1 ResNet-50 forward in well under a second (round-1 regression:
-    ~97s per forward without the cache)."""
+    one warm bs1 ResNet-50 forward in well under a second (the round-1
+    regression recompiled every op on every forward)."""
     import time
 
     net = vision.resnet50_v1(classes=10)

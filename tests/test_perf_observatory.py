@@ -586,14 +586,30 @@ def test_gate_unusable_input_is_rc2(tmp_path, capsys):
 # perf_report: backfill + single-run + diff
 # ---------------------------------------------------------------------------
 
+def _legacy_capture(tmp_path, name, value):
+    """A pre-schema driver bench capture (the shape --backfill reads);
+    the value is synthetic."""
+    row = {"metric": "resnet50_train_images_per_sec_per_chip",
+           "value": value, "unit": "images/sec"}
+    path = tmp_path / (name + ".json")
+    path.write_text(json.dumps({
+        "n": 1, "cmd": "python bench.py", "rc": 0,
+        "tail": "[bench] 40 steps\n%s\n" % json.dumps(row),
+        "parsed": row}))
+    return str(path)
+
+
 def test_backfill_ingests_legacy_run_files(tmp_path, capsys):
     import perf_report
 
     ledger = str(tmp_path / "hist.jsonl")
-    files = [os.path.join(REPO, "BENCH_r0%d.json" % i)
+    files = [_legacy_capture(tmp_path, "BENCH_r0%d" % i, 100.0 + i)
              for i in (2, 3, 4, 5)]
-    files += [os.path.join(REPO, "MULTICHIP_r01.json"),
-              os.path.join(REPO, "MULTIHOST_r04.json")]
+    multichip = tmp_path / "MULTICHIP_r01.json"
+    multichip.write_text(json.dumps(
+        {"n_devices": 8, "rc": 1, "ok": False, "skipped": False,
+         "tail": "AssertionError: need 8 devices\n"}))
+    files += [str(multichip), os.path.join(REPO, "MULTIHOST_r04.json")]
     assert perf_report.main(["--ledger", ledger, "--backfill"]
                             + files) == 0
     capsys.readouterr()
@@ -606,7 +622,7 @@ def test_backfill_ingests_legacy_run_files(tmp_path, capsys):
     assert all(r["backfill"] for r in recs)
     assert {r["run_id"] for r in heads} == \
         {"BENCH_r02", "BENCH_r03", "BENCH_r04", "BENCH_r05"}
-    # the flat-line is now queryable history the report renders
+    # the legacy runs are now queryable history the report renders
     assert perf_report.main(["--ledger", ledger]) == 0
     out = capsys.readouterr().out
     assert "resnet50_train_images_per_sec_per_chip" in out
@@ -653,7 +669,7 @@ def test_diff_against_backfilled_baseline_zero_fills_attribution(
     # a real backfilled baseline (provenance unknown, no attribution)
     assert perf_report.main(
         ["--ledger", ledger, "--backfill",
-         os.path.join(REPO, "BENCH_r05.json")]) == 0
+         _legacy_capture(tmp_path, "BENCH_r05", 105.0)]) == 0
     capsys.readouterr()
     # a modern run whose attribution has an extra custom bucket
     rec = _gate_rec("runNew", 300.0, 2100.0, 12.0,

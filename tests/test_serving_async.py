@@ -639,6 +639,48 @@ def test_from_block_multi_replica_devices():
         ap.close()
 
 
+def test_replicas_trace_one_shared_net_concurrently():
+    """One Predictor per device over ONE net, each compiling on its own
+    worker thread: the in-place param swap of a trace must not cross
+    threads (block._param_swap_lock).  More tracing threads than cores
+    and a shortened switch interval force the interleaving that, without
+    the lock, leaks tracers or leaves bf16 casts in the net's params."""
+    import sys
+
+    import jax
+
+    from mxnet_tpu import nd
+    from mxnet_tpu.gluon import nn
+
+    n = len(jax.devices())
+    net = nn.HybridSequential()
+    for _ in range(24):                 # deep enough that traces overlap
+        net.add(nn.Dense(16, activation="relu"))
+    net.add(nn.Dense(3))
+    net.initialize()
+    example = np.random.rand(2, 16).astype(np.float32)
+    ap = AsyncPredictor.from_block(net, example, replicas=n, chain=1,
+                                   batch_window_ms=0.0,
+                                   dtype_policy="bf16_mixed")
+    batches = [np.random.rand(2, 16).astype(np.float32)
+               for _ in range(4 * n)]
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        futs = [ap.submit(b) for b in batches]
+        outs = [f.result(timeout=120) for f in futs]
+    finally:
+        sys.setswitchinterval(prev)
+        ap.close(timeout=30)
+    assert ap.stats()["healthy_replicas"] == n      # nobody was ejected
+    for p in net.collect_params().values():
+        arr = p.data()._data
+        assert not isinstance(arr, jax.core.Tracer) \
+            and np.dtype(arr.dtype) == np.float32, p.name
+    ref = net(nd.array(batches[0])).asnumpy()
+    np.testing.assert_allclose(outs[0], ref, rtol=5e-2, atol=5e-2)
+
+
 # ---------------------------------------------------------------------------
 # warm pool + auto-heal probes (PR 8)
 # ---------------------------------------------------------------------------

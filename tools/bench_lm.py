@@ -186,17 +186,15 @@ def run(mesh=None, layout=None, steps=20, warmup=2, steps_per_call=None,
     tokens_per_step = cfg["batch"] * cfg["seq"]
     tps_sync = tokens_per_step * steps / dt
     tps = tokens_per_step * calls * k / dt_async
-    # MFU two ways: the XLA cost-analysis gauge (exact program FLOPs)
-    # when a peak is known, else the 6N analytic accounting only
+    # MFU from the 6N analytic accounting against the published peak of
+    # this device kind times the devices the step ran on; a device the
+    # table does not list (the CPU harness) has no peak and gets no MFU
     peak = telemetry.peak_flops()
     step_secs = dt_async / (calls * k)
     model_flops = cfg["flops_per_token"] * tokens_per_step
-    mfu = None
-    # on the CPU harness the docs/mfu_probe.json peak describes the
-    # chip, not this host — only report MFU when the peak matches the
-    # backend (or the operator pinned one via MXNET_PEAK_TFLOPS)
-    if peak and (cfg["on_tpu"] or os.environ.get("MXNET_PEAK_TFLOPS")):
-        mfu = round(model_flops / step_secs / peak, 4)
+    n_dev = trainer.mesh.devices.size if trainer.mesh is not None else 1
+    mfu = round(model_flops / step_secs / (peak * n_dev), 4) \
+        if peak else None
     result = {
         "metric": "transformer_lm_train_tokens_per_sec",
         "value": round(tps, 2),

@@ -49,13 +49,11 @@ def _free_port():
 
 
 def collective_worker(rank, n_procs, dev_per_proc, port):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     os.environ["MXNET_DIST_COORDINATOR"] = "127.0.0.1:%d" % port
     os.environ["MXNET_DIST_NUM_PROCS"] = str(n_procs)
     os.environ["MXNET_DIST_PROC_ID"] = str(rank)
 
+    import jax
     import numpy as np
 
     import mxnet_tpu as mx
@@ -129,7 +127,6 @@ def ps_server(port, n_workers):
     os.environ.update({
         "DMLC_ROLE": "server", "DMLC_PS_ROOT_URI": "127.0.0.1",
         "DMLC_PS_ROOT_PORT": str(port), "DMLC_NUM_WORKER": str(n_workers),
-        "MXNET_PLATFORM": "cpu",
     })
     from mxnet_tpu.kvstore_server import run_server
 
@@ -140,7 +137,7 @@ def ps_worker(rank, port, n_workers):
     os.environ.update({
         "DMLC_ROLE": "worker", "DMLC_RANK": str(rank),
         "DMLC_PS_ROOT_URI": "127.0.0.1", "DMLC_PS_ROOT_PORT": str(port),
-        "DMLC_NUM_WORKER": str(n_workers), "MXNET_PLATFORM": "cpu",
+        "DMLC_NUM_WORKER": str(n_workers),
     })
     import numpy as np
 
@@ -290,7 +287,7 @@ def run(n_procs=2, dev_per_proc=4, json_path=None, mesh=None):
 
     # --- 2. parameter-server dist_sync round ---
     port = _free_port()
-    env_ps = dict(os.environ, MXNET_PLATFORM="cpu", JAX_PLATFORMS="cpu")
+    env_ps = dict(os.environ, JAX_PLATFORMS="cpu")
     sp = subprocess.Popen(
         [sys.executable, HERE, "--ps-server", str(port), str(n_procs)],
         env=env_ps, cwd=REPO, stdout=subprocess.PIPE,

@@ -6,6 +6,12 @@ tools/launch.py + dmlc-core tracker).
 processes on this machine with the DMLC_* env contract the framework's
 KVStoreDist / parallel.init_distributed read — the same pattern the
 reference's CI uses for dist kvstore tests (SURVEY §4).
+
+One process for each chip: this launcher never imports jax, the server
+process is pinned to the host CPU (it only reduces host buffers), and
+more than one local worker is refused unless ``JAX_PLATFORMS=cpu`` is
+exported — each worker would otherwise initialise the accelerator, which
+belongs to one process at a time (the second one fails or hangs).
 """
 from __future__ import annotations
 
@@ -36,6 +42,14 @@ def main():
     parser.add_argument("--sync-dst-dir", default=None)
     parser.add_argument("command", nargs=argparse.REMAINDER)
     args = parser.parse_args()
+    if args.num_workers > 1 and \
+            os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        parser.error(
+            "--launcher local would start %d workers on this host and "
+            "each would initialise the accelerator, which belongs to one "
+            "process at a time; export JAX_PLATFORMS=cpu to run them on "
+            "the host CPU, or start one worker per host under the "
+            "cluster scheduler" % args.num_workers)
 
     port = _free_port()
     base_env = dict(os.environ)
@@ -48,7 +62,7 @@ def main():
 
     procs = []
     # server role
-    server_env = dict(base_env, DMLC_ROLE="server")
+    server_env = dict(base_env, DMLC_ROLE="server", JAX_PLATFORMS="cpu")
     procs.append(subprocess.Popen(
         [sys.executable, "-c",
          "from mxnet_tpu.kvstore_server import run_server; run_server()"],

@@ -20,7 +20,8 @@ exceed the measured step; the decisive signals for "memory-bound" are
 fusion discounting real traffic, the program is bandwidth-limited).
 
 Run on an idle chip:
-    python tools/mfu_accounting.py [--batch 256] [--json docs/mfu_accounting.json]
+    python tools/bench_mfu.py --json probe.json
+    python tools/mfu_accounting.py --mfu-probe probe.json [--batch 256]
 """
 import argparse
 import json
@@ -45,10 +46,10 @@ def main():
                    default=int(os.environ.get("BENCH_BATCH", "256")))
     p.add_argument("--steps", type=int, default=20)
     p.add_argument("--json", default=None)
-    p.add_argument("--mfu-probe",
-                   default=os.path.join(os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))), "docs",
-                       "mfu_probe.json"))
+    p.add_argument("--mfu-probe", default=None,
+                   help="ceilings JSON written by tools/bench_mfu.py "
+                        "--json on the same device (none = raw "
+                        "counters, no roofline verdict)")
     args = p.parse_args()
 
     import jax
@@ -88,10 +89,9 @@ def main():
         % (secs * 1e3, img_s, lv))
 
     ceilings = {}
-    if not os.path.exists(args.mfu_probe):
-        log("WARNING: probe artifact %s not found — emitting raw "
-            "counters WITHOUT the roofline verdict (run "
-            "tools/bench_mfu.py first)" % args.mfu_probe)
+    if not args.mfu_probe:
+        log("no --mfu-probe: emitting raw counters WITHOUT the roofline "
+            "verdict (run tools/bench_mfu.py --json first)")
     else:
         with open(args.mfu_probe) as f:
             probe = json.load(f)

@@ -1,7 +1,7 @@
 """Noise-aware perf regression gate over the BENCH run ledger.
 
-Four rounds of headline benches (r02-r05) spread ~0.5% around 2183
-img/s while real regressions hide below log tails — this gate makes
+Repeated headline benches spread around their mean while real
+regressions hide below log tails — this gate makes
 "did this PR regress a metric" a nonzero exit code instead of a
 judgement call:
 
@@ -31,7 +31,7 @@ seconds-level tier-1 smoke on CPU and a sub-second CI step anywhere.
     python tools/perf_gate.py --ledger perf_ledger.jsonl
 
     # explicit baseline files (legacy driver captures work too):
-    python tools/perf_gate.py --baseline BENCH_r0*.json \
+    python tools/perf_gate.py --baseline baseline.jsonl \
         --candidate perf_ledger.jsonl
 
 Exit codes: 0 = within bands, 1 = regression (metric + bucket named),
@@ -129,8 +129,8 @@ def _median(vals):
 
 def seeded_tolerance(samples, floor, spread_factor):
     """max(floor, spread_factor x relative spread of the baseline) —
-    r02-r05's 0.49% headline spread seeds a ~1% band under the default
-    factor, and the floor keeps single-sample baselines honest."""
+    a 0.5% baseline spread seeds a ~1% band under the default factor,
+    and the floor keeps single-sample baselines honest."""
     if len(samples) >= 2:
         mean = sum(samples) / len(samples)
         if mean:
@@ -232,8 +232,8 @@ def main(argv=None):
                    help="one ledger holding both sides: candidate = "
                         "newest run, baseline = every earlier run")
     p.add_argument("--baseline", nargs="+", metavar="PATH",
-                   help="baseline ledgers/run files (.jsonl or legacy "
-                        "BENCH_r*.json driver captures)")
+                   help="baseline ledgers/run files (.jsonl or "
+                        "pre-schema driver bench captures)")
     p.add_argument("--candidate", nargs="+", metavar="PATH",
                    help="candidate ledger/run file(s); the newest run "
                         "inside is the one gated")

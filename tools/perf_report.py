@@ -12,10 +12,10 @@ through ``mxnet_tpu/perf_ledger.py`` and renders
   with its noise-free attribution story ("device_compute -4.1%,
   host_other +9.3%"), the decision view the on-chip payoff sweep
   flips defaults from;
-* **--backfill** — ingests the pre-schema run files (BENCH_r0*.json
-  driver captures, MULTICHIP/MULTIHOST dryrun artifacts) into the
-  ledger with provenance marked ``unknown``, so the r02-r05 flat-line
-  is queryable history instead of dead files.
+* **--backfill** — ingests pre-schema run files (driver bench
+  captures, multichip/multihost dryrun artifacts such as
+  MULTIHOST_r04.json) into the ledger with provenance marked
+  ``unknown``, so they are queryable history instead of dead files.
 
 Stdlib-only on purpose (perf_ledger is loaded standalone, no jax
 import): reporting the history must stay a sub-second operation.
@@ -24,7 +24,7 @@ import): reporting the history must stay a sub-second operation.
     python tools/perf_report.py --ledger perf_ledger.jsonl --run a1b2c3
     python tools/perf_report.py --ledger perf_ledger.jsonl --diff A B
     python tools/perf_report.py --ledger perf_ledger.jsonl \
-        --backfill BENCH_r0*.json MULTICHIP_r0*.json MULTIHOST_r0*.json
+        --backfill MULTIHOST_r0*.json
 """
 import argparse
 import importlib.util
@@ -56,7 +56,7 @@ pl = load_perf_ledger()
 def backfill_file(path):
     """Records for one legacy run artifact.  Recognized shapes:
 
-    * driver bench captures (``BENCH_r0*.json``): ``parsed`` when the
+    * driver bench captures (``tail`` + ``parsed``/``cmd``): ``parsed`` when the
       driver extracted the JSON line, else the stdout ``tail`` is
       scanned with the legacy brace heuristic;
     * multichip dryruns (``n_devices``/``ok``): a 0/1 pass metric;
@@ -357,8 +357,9 @@ def main(argv=None):
                    help="attributed delta between two run ids "
                         "('latest'/'prev' resolve positionally)")
     p.add_argument("--backfill", nargs="+", metavar="FILE",
-                   help="ingest legacy run files (BENCH_r0*.json / "
-                        "MULTICHIP / MULTIHOST) into the ledger")
+                   help="ingest pre-schema run files (driver bench "
+                        "captures, multichip/multihost dryrun "
+                        "artifacts) into the ledger")
     p.add_argument("--trace", help="unified chrome trace to merge into "
                                    "the single-run view")
     p.add_argument("--telemetry", help="telemetry.dump() JSON to merge "

@@ -31,19 +31,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))  # quantize_model (int8 spec)
 
-# jax 0.4.x XLA:CPU splits large modules across parallel-codegen object
-# files and executable serialization only captures the entry module — a
-# deserialized ResNet-50-sized executable then aborts with "Symbols not
-# found" (the AOT layer degrades it to a recompile, loudly).  Forcing a
-# single codegen unit makes the serialized artifact self-contained.
-# Must be in the environment BEFORE XLA first compiles, hence here at
-# CLI start and not inside mxnet_tpu.  Runtime performance of the
-# compiled program is unchanged; only compile-time parallelism is.
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_cpu_parallel_codegen_split_count" not in _flags:
-    os.environ["XLA_FLAGS"] = \
-        (_flags + " --xla_cpu_parallel_codegen_split_count=1").strip()
-
 
 def log(msg):
     print("[prewarm] %s" % msg, file=sys.stderr, flush=True)
