@@ -165,14 +165,18 @@ FLAGS = {
         "rebuild and compile the whole workload ahead of rollout"),
     "MXNET_TRACE": (
         "0", _pbool, "honored",
-        "hierarchical span tracing (tracing.py): step/request/checkpoint "
-        "spans with trace/span/parent IDs into a bounded ring buffer, "
-        "exportable as one Chrome/Perfetto trace.json; off = one branch "
-        "per call site"),
+        "hierarchical span tracing (tracing.py): every layer's spans "
+        "(requests, checkpoints, aot:*, fusion:*) with trace/span/parent "
+        "IDs into a bounded ring buffer, exportable as one "
+        "Chrome/Perfetto trace.json; off = one branch per call site. "
+        "The step-level spans of the train and serving loops, gc pauses "
+        "and compiles are kept whether or not it is set"),
     "MXNET_TRACE_BUFFER": (
-        "4096", _pint, "honored",
+        "32768", _pint, "honored",
         "span ring-buffer capacity (oldest spans evicted first; "
-        "evictions counted in mxnet_tpu_trace_spans_dropped_total)"),
+        "evictions counted in mxnet_tpu_trace_spans_dropped_total). "
+        "The default holds four 60 s serving windows of 13 spans a "
+        "tick at 10 ticks/s, in about 16 MiB of host memory when full"),
     "MXNET_FLIGHT_RECORDER": (
         "0", _pbool, "honored",
         "black-box postmortem bundles (trace + telemetry + thread stacks "

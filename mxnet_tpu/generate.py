@@ -576,25 +576,34 @@ class GenerationEngine:
         alongside (fixed shape) but their output is discarded."""
         if not self._active.any():
             return {}
-        key = self._next_key()
-        t0 = time.perf_counter()
-        next_tok, _logits, ck, cv = self._jit_decode(
-            self._params, self._cache_k, self._cache_v,
-            self._cur_tok.copy(), self._pos.copy(), key)
-        self._cache_k, self._cache_v = ck, cv
-        self._last_logits = _logits
-        toks = np.asarray(next_tok)
-        _telemetry.DECODE_STEP_SECONDS.observe(time.perf_counter() - t0)
-        out = {}
-        for slot in np.nonzero(self._active)[0]:
-            slot = int(slot)
-            tok = int(toks[slot])
-            out[slot] = tok
-            self._cur_tok[slot] = tok
-            self._pos[slot] += 1
-        _telemetry.DECODE_TOKENS.inc(len(out))
-        _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
-        self._note_occupancy()
+        # the step's phases as always-kept spans (docs/observability.md
+        # "Spans of the hot loops"); DECODE_STEP_SECONDS reads the span
+        with _tracing.begin("engine.decode", args={
+                "slots": int(self._active.sum()),
+                "live": int(self._pos[self._active].sum())}) as step:
+            with _tracing.begin("engine.decode:prep"):
+                key = self._next_key()
+                cur_tok, pos = self._cur_tok.copy(), self._pos.copy()
+            with _tracing.begin("engine.decode:launch"):
+                next_tok, _logits, ck, cv = self._jit_decode(
+                    self._params, self._cache_k, self._cache_v,
+                    cur_tok, pos, key)
+                self._cache_k, self._cache_v = ck, cv
+                self._last_logits = _logits
+            with _tracing.begin("engine.decode:readback"):
+                toks = np.asarray(next_tok)
+            with _tracing.begin("engine.decode:post"):
+                out = {}
+                for slot in np.nonzero(self._active)[0]:
+                    slot = int(slot)
+                    tok = int(toks[slot])
+                    out[slot] = tok
+                    self._cur_tok[slot] = tok
+                    self._pos[slot] += 1
+                _telemetry.DECODE_TOKENS.inc(len(out))
+                _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
+                self._note_occupancy()
+        _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
 
     def evict(self, slot, reason):
@@ -1227,36 +1236,42 @@ class PagedGenerationEngine:
         st = self._pending[slot]
         toks, filled, n = st["tokens"], st["filled"], st["n"]
         count = min(self._chunk, n - filled)
-        chunk = np.zeros((1, self._chunk), np.int32)
-        chunk[0, :count] = toks[filled:filled + count]
-        wpage = np.zeros(self._chunk, np.int32)
-        woff = np.zeros(self._chunk, np.int32)
-        row = self._page_table[slot]
-        for j in range(count):
-            p = filled + j
-            wpage[j] = row[p // self._page_size]
-            woff[j] = p % self._page_size
-        sampled, logits, pk, pv = self._jit_chunk(
-            self._params, self._pool_k, self._pool_v,
-            self._page_table[slot:slot + 1].copy(), chunk,
-            np.asarray([filled], np.int32), wpage, woff,
-            self._lane_keys[slot:slot + 1].copy())
-        self._pool_k, self._pool_v = pk, pv
-        self._last_logits = logits
-        self._chunks_run += 1
-        _telemetry.DECODE_PREFILL_CHUNKS.inc()
-        if filled + count < n:
-            st["filled"] = filled + count
-            return None
-        tok = int(np.asarray(sampled)[0, count - 1])
-        del self._pending[slot]
-        self._pos[slot] = n
-        self._cur_tok[slot] = tok
-        self._active[slot] = True
-        self._history[slot].append(tok)
-        if self._prefix_share:
-            self._register_prefix(slot, toks, n)
-        self._note_occupancy()
+        final = filled + count >= n
+        with _tracing.begin("engine.prefill", args={
+                "slot": int(slot), "filled": int(filled),
+                "count": int(count), "final": final}):
+            chunk = np.zeros((1, self._chunk), np.int32)
+            chunk[0, :count] = toks[filled:filled + count]
+            wpage = np.zeros(self._chunk, np.int32)
+            woff = np.zeros(self._chunk, np.int32)
+            row = self._page_table[slot]
+            for j in range(count):
+                p = filled + j
+                wpage[j] = row[p // self._page_size]
+                woff[j] = p % self._page_size
+            with _tracing.begin("engine.prefill:launch"):
+                sampled, logits, pk, pv = self._jit_chunk(
+                    self._params, self._pool_k, self._pool_v,
+                    self._page_table[slot:slot + 1].copy(), chunk,
+                    np.asarray([filled], np.int32), wpage, woff,
+                    self._lane_keys[slot:slot + 1].copy())
+                self._pool_k, self._pool_v = pk, pv
+                self._last_logits = logits
+            self._chunks_run += 1
+            _telemetry.DECODE_PREFILL_CHUNKS.inc()
+            if not final:
+                st["filled"] = filled + count
+                return None
+            with _tracing.begin("engine.prefill:readback"):
+                tok = int(np.asarray(sampled)[0, count - 1])
+            del self._pending[slot]
+            self._pos[slot] = n
+            self._cur_tok[slot] = tok
+            self._active[slot] = True
+            self._history[slot].append(tok)
+            if self._prefix_share:
+                self._register_prefix(slot, toks, n)
+            self._note_occupancy()
         return slot, tok
 
     def admit(self, token_ids, slot=None):
@@ -1282,57 +1297,67 @@ class PagedGenerationEngine:
         cap = min(self._capacity, self.model_config["max_len"])
         active = [int(b) for b in np.nonzero(self._active)[0]]
         C = K + 1 if K > 0 else 1
-        tokens = np.zeros((B, C), np.int32)
-        drafts = {}
-        for b in active:
-            tokens[b, 0] = self._cur_tok[b]
-            if K > 0:
-                room = cap - 1 - int(self._pos[b])
-                d = _ngram_draft(self._history[b], self._spec_ngram,
-                                 min(K, room)) if room > 0 else []
-                drafts[b] = d
-                tokens[b, 1:1 + len(d)] = d
-            else:
-                drafts[b] = []
-        wpage = np.zeros(B * C, np.int32)
-        woff = np.zeros(B * C, np.int32)
-        for b in active:
-            for j in range(len(drafts[b]) + 1):
-                p = int(self._pos[b]) + j
-                wpage[b * C + j] = self._page_table[b, p // self._page_size]
-                woff[b * C + j] = p % self._page_size
-        key = self._lane_keys.copy()
-        t0 = time.perf_counter()
-        sampled, logits, pk, pv = self._jit_chunk(
-            self._params, self._pool_k, self._pool_v,
-            self._page_table.copy(), tokens,
-            self._pos.astype(np.int32).copy(), wpage, woff, key)
-        self._pool_k, self._pool_v = pk, pv
-        self._last_logits = logits
-        sampled = np.asarray(sampled)
-        _telemetry.DECODE_STEP_SECONDS.observe(time.perf_counter() - t0)
-        out = {}
-        emitted_total = 0
-        for b in active:
-            d = drafts[b]
-            acc = 0
-            while acc < len(d) and d[acc] == sampled[b, acc]:
-                acc += 1
-            emitted = [int(t) for t in sampled[b, :acc + 1]]
-            if d:
-                self._spec_drafted += len(d)
-                self._spec_accepted += acc
-                self._spec_steps += 1
-                _telemetry.DECODE_SPEC_DRAFTED.inc(len(d))
-                _telemetry.DECODE_SPEC_ACCEPTED.inc(acc)
-            out[b] = emitted
-            emitted_total += len(emitted)
-            self._cur_tok[b] = emitted[-1]
-            self._pos[b] += len(emitted)
-            self._history[b].extend(emitted)
-        _telemetry.DECODE_TOKENS.inc(emitted_total)
-        _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
-        self._note_occupancy()
+        # the step's phases as always-kept spans (docs/observability.md
+        # "Spans of the hot loops"); DECODE_STEP_SECONDS reads the span
+        with _tracing.begin("engine.decode", args={
+                "slots": len(active),
+                "live": int(self._pos[active].sum())}) as step:
+            with _tracing.begin("engine.decode:prep"):
+                tokens = np.zeros((B, C), np.int32)
+                drafts = {}
+                for b in active:
+                    tokens[b, 0] = self._cur_tok[b]
+                    if K > 0:
+                        room = cap - 1 - int(self._pos[b])
+                        d = _ngram_draft(self._history[b], self._spec_ngram,
+                                         min(K, room)) if room > 0 else []
+                        drafts[b] = d
+                        tokens[b, 1:1 + len(d)] = d
+                    else:
+                        drafts[b] = []
+                wpage = np.zeros(B * C, np.int32)
+                woff = np.zeros(B * C, np.int32)
+                for b in active:
+                    for j in range(len(drafts[b]) + 1):
+                        p = int(self._pos[b]) + j
+                        wpage[b * C + j] = \
+                            self._page_table[b, p // self._page_size]
+                        woff[b * C + j] = p % self._page_size
+                key = self._lane_keys.copy()
+                table = self._page_table.copy()
+                pos = self._pos.astype(np.int32).copy()
+            with _tracing.begin("engine.decode:launch"):
+                sampled, logits, pk, pv = self._jit_chunk(
+                    self._params, self._pool_k, self._pool_v,
+                    table, tokens, pos, wpage, woff, key)
+                self._pool_k, self._pool_v = pk, pv
+                self._last_logits = logits
+            with _tracing.begin("engine.decode:readback"):
+                sampled = np.asarray(sampled)
+            with _tracing.begin("engine.decode:post"):
+                out = {}
+                emitted_total = 0
+                for b in active:
+                    d = drafts[b]
+                    acc = 0
+                    while acc < len(d) and d[acc] == sampled[b, acc]:
+                        acc += 1
+                    emitted = [int(t) for t in sampled[b, :acc + 1]]
+                    if d:
+                        self._spec_drafted += len(d)
+                        self._spec_accepted += acc
+                        self._spec_steps += 1
+                        _telemetry.DECODE_SPEC_DRAFTED.inc(len(d))
+                        _telemetry.DECODE_SPEC_ACCEPTED.inc(acc)
+                    out[b] = emitted
+                    emitted_total += len(emitted)
+                    self._cur_tok[b] = emitted[-1]
+                    self._pos[b] += len(emitted)
+                    self._history[b].extend(emitted)
+                _telemetry.DECODE_TOKENS.inc(emitted_total)
+                _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
+                self._note_occupancy()
+        _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
 
     def evict(self, slot, reason):
@@ -1705,12 +1730,14 @@ class TokenServer:
                 "prefill", "expired waiting for a decode slot"))
 
     def _admissions(self):
+        """Move queued requests into free slots; returns how many."""
         eng = self._engine
+        admitted = 0
         while eng.free_slots() > 0:
             with self._cond:
                 req = self._admit_locked_pop()
             if req is None:
-                return
+                break
             t_pick = time.monotonic()
             req.t_pickup = t_pick
             ex = {"trace_id": _tracing.TRACE_ID,
@@ -1736,6 +1763,7 @@ class TokenServer:
                     eng, "last_prefix_hit_tokens", None) or None
                 with self._cond:
                     self._by_slot[slot] = req
+                admitted += 1
                 continue
             try:
                 slot, tok = eng.admit(req.tokens)
@@ -1751,7 +1779,9 @@ class TokenServer:
             _telemetry.DECODE_TTFT_SECONDS.observe(req.ttft, exemplar=ex)
             with self._cond:
                 self._by_slot[slot] = req
+            admitted += 1
             self._deliver(req, slot, tok)
+        return admitted
 
     def _deliver(self, req, slot, tok):
         """Append one generated token and apply the finish/evict
@@ -1830,37 +1860,26 @@ class TokenServer:
               "span_id": req.span.span_id} \
             if req.span is not None else None
         _telemetry.DECODE_TTFT_SECONDS.observe(req.ttft, exemplar=ex)
-        self._deliver(req, slot, tok)
+        with _tracing.begin("serve.deliver", args={"tokens": 1}):
+            self._deliver(req, slot, tok)
 
     def _loop(self):
+        # one span tree a tick, kept whether or not tracing is on
+        # (docs/observability.md "Spans of the hot loops")
         while True:
             with self._cond:
-                while self._running and not self._queue \
-                        and not self._by_slot:
-                    self._cond.wait(0.02)
+                if self._running and not self._queue and not self._by_slot:
+                    with _tracing.begin("serve.idle"):
+                        while self._running and not self._queue \
+                                and not self._by_slot:
+                            self._cond.wait(0.02)
                 if not self._running:
                     return
+                active, queued = len(self._by_slot), len(self._queue)
             try:
-                self._sweep_queue()
-                self._admissions()
-                if self._incremental:
-                    self._prefill_tick()
-                toks = self._engine.decode_step()
-                for slot, tok in toks.items():
-                    with self._cond:
-                        req = self._by_slot.get(slot)
-                    if req is None:
-                        self._engine.evict(slot, "cancelled")
-                        continue
-                    # paged engines may emit several verified tokens
-                    # per step; _deliver's finish rules apply per token
-                    # (speculative overshoot past eos/max_new is
-                    # truncated here, so output matches non-spec)
-                    for t in (tok if isinstance(tok, list) else [tok]):
-                        if not self._deliver(req, slot, t):
-                            break
-                if self._shedder is not None:
-                    self._shedder.update()
+                with _tracing.begin("serve.tick", cpu=True, args={
+                        "slots": active, "queue": queued}):
+                    self._tick()
             except Exception as e:
                 # a broken engine (failed dispatch after donation) can
                 # serve nobody: fail everything typed and stop
@@ -1877,6 +1896,36 @@ class TokenServer:
                     self._fail(req, ReplicaFailed(
                         "decode loop failed: %s" % (e,), cause=e))
                 return
+
+    def _tick(self):
+        with _tracing.begin("serve.admit") as sp:
+            self._sweep_queue()
+            sp.set(admitted=self._admissions())
+        if self._incremental:
+            self._prefill_tick()
+        toks = self._engine.decode_step()
+        if toks:
+            with _tracing.begin("serve.deliver", args={
+                    "tokens": sum(len(t) if isinstance(t, list) else 1
+                                  for t in toks.values())}):
+                self._deliver_step(toks)
+        if self._shedder is not None:
+            self._shedder.update()
+
+    def _deliver_step(self, toks):
+        for slot, tok in toks.items():
+            with self._cond:
+                req = self._by_slot.get(slot)
+            if req is None:
+                self._engine.evict(slot, "cancelled")
+                continue
+            # paged engines may emit several verified tokens per step;
+            # _deliver's finish rules apply per token (speculative
+            # overshoot past eos/max_new is truncated here, so output
+            # matches non-spec)
+            for t in (tok if isinstance(tok, list) else [tok]):
+                if not self._deliver(req, slot, t):
+                    break
 
     # -- lifecycle -------------------------------------------------------
 

@@ -87,24 +87,18 @@ class _MetricFetcher:
                 if item is None:
                     return
                 step, n_steps, acc = item
-                sp = _tracing.begin(
-                    "step:fetch", args={"step": step, "steps": n_steps}) \
-                    if _tracing.enabled() else None
                 try:
-                    host = np.asarray(acc)  # blocks on device completion
-                    self._apply(step, n_steps, host, async_mode=True)
+                    # step-level span: kept whether or not tracing is on
+                    with _tracing.begin("step:fetch", args={
+                            "step": step, "steps": n_steps}):
+                        host = np.asarray(acc)  # blocks on the device
+                        self._apply(step, n_steps, host, async_mode=True)
                 except Exception as e:
                     # never let a poisoned fetch kill the thread: wait()
                     # would deadlock with no consumer left.  The first
                     # error is kept for the next drain boundary.
                     if self.error is None:
                         self.error = e
-                    if sp is not None:
-                        sp.end(error=True)
-                        sp = None
-                finally:
-                    if sp is not None:
-                        sp.end()
             finally:
                 self._q.task_done()
                 if _telemetry.enabled():
@@ -983,15 +977,13 @@ class ShardedTrainer:
             self._lazy_init(example_inputs=raw_in)
         if self._step_fn is None:
             self._build(len(raw_in))
-        sp = _tracing.begin("ShardedTrainer.step",
-                            args={"step": self.global_step + 1}) \
-            if _tracing.enabled() else None
+        # step-level span: kept whether or not tracing is on, with the
+        # calling thread's CPU time (a stalled step's first question)
         try:
-            return self._step_inner(raw_in, raw_label)
+            with _tracing.begin("ShardedTrainer.step", cpu=True,
+                                args={"step": self.global_step + 1}):
+                return self._step_inner(raw_in, raw_label)
         except Exception as e:
-            if sp is not None:
-                sp.end(error=True)
-                sp = None
             # black-box bundle for the crashing step (no-op unless the
             # flight recorder is armed; the span above is already closed
             # with status=error so the bundle shows it).  The reason is
@@ -1000,9 +992,6 @@ class ShardedTrainer:
             _tracing.record_crash("exception-step", e,
                                   extra={"layer": "ShardedTrainer.step"})
             raise
-        finally:
-            if sp is not None:
-                sp.end()
 
     def step_many(self, batches):
         """Run ``steps_per_call`` train steps as ONE fused XLA call.
@@ -1043,21 +1032,14 @@ class ShardedTrainer:
             self._build(n_in)
         if self._step_k_fn is None:
             self._build_k(n_in)
-        sp = _tracing.begin("ShardedTrainer.step_many",
-                            args={"step": self.global_step + 1, "k": K}) \
-            if _tracing.enabled() else None
         try:
-            return self._step_many_inner(raws)
+            with _tracing.begin("ShardedTrainer.step_many", cpu=True, args={
+                    "step": self.global_step + 1, "k": K}):
+                return self._step_many_inner(raws)
         except Exception as e:
-            if sp is not None:
-                sp.end(error=True)
-                sp = None
             _tracing.record_crash("exception-step", e,
                                   extra={"layer": "ShardedTrainer.step_many"})
             raise
-        finally:
-            if sp is not None:
-                sp.end()
 
     def prewarm(self, inputs, label):
         """Compile — or load from the AOT store — the step executable
@@ -1166,17 +1148,12 @@ class ShardedTrainer:
             span_args = {"step": self.global_step + 1}
             if n > 1:
                 span_args["k"] = n
-            dsp = _tracing.begin("step:dispatch", args=span_args) \
-                if _tracing.enabled() else None
-            try:
+            with _tracing.begin("step:dispatch", args=span_args):
                 new_params, new_state, loss_out, new_metrics = \
                     _profiler.timed_call(
                         label, fn,
                         (self.param_arrays, self.opt_state) + call_args
                         + (self._metrics_acc,))
-            finally:
-                if dsp is not None:
-                    dsp.end()
             next_step = self.global_step + n
             # single-assignment snapshot: the preemption handler may fire
             # between any two bytecodes, and must never observe params
@@ -1233,14 +1210,9 @@ class ShardedTrainer:
         accumulator right inside the step.  Lives OUTSIDE the hot-path
         functions so the no-host-sync guard can assert the async path
         never reaches a blocking read."""
-        sp = _tracing.begin("step:fetch",
-                            args={"step": step, "steps": n, "sync": True}) \
-            if _tracing.enabled() else None
-        try:
+        with _tracing.begin("step:fetch",
+                            args={"step": step, "steps": n, "sync": True}):
             host = np.asarray(acc)
-        finally:
-            if sp is not None:
-                sp.end()
         self._apply_metrics_host(step, n, host, async_mode=False)
 
     def _apply_metrics_host(self, step, n, host, async_mode=True):

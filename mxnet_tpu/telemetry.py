@@ -870,8 +870,8 @@ DECODE_TOKENS = counter(
     "Tokens generated across all decode slots.")
 DECODE_STEP_SECONDS = histogram(
     "mxnet_tpu_decode_step_seconds",
-    "Wall time of one fixed-shape decode dispatch (all slots advance "
-    "one token).")
+    "Wall time of one fixed-shape decode step (all slots advance one "
+    "token), prep to post: read from the engine.decode span.")
 DECODE_BATCH_TOKENS = histogram(
     "mxnet_tpu_decode_batch_tokens",
     "Active slots per decode step (the continuous-batching batch-size "

@@ -30,18 +30,38 @@ stacks like TVM's or the TPU learned-cost-model work) need:
   reason (:data:`FLIGHT_MIN_INTERVAL`) so a NaN storm produces one
   bundle, not thousands.
 
-Both features are OFF by default and cost one branch per instrumented
-call site when off (``MXNET_TRACE=1`` / ``MXNET_FLIGHT_RECORDER=1`` at
-import, or :func:`enable` / :func:`enable_flight_recorder` at runtime).
+* **One clock with the device** — every span is also a
+  ``jax.profiler.TraceAnnotation`` named ``mx:<name>``: a no-op while no
+  profiler session runs, and while one runs the span lies in the
+  ``.xplane.pb`` on the thread that opened it, on the clock of the
+  runtime's launches and of the device lines, so a device idle gap can
+  be charged to what the host was doing in it.
 
-Import-light by design (stdlib + ``config`` + ``telemetry``):
-``profiler`` and ``checkpoint`` are imported lazily inside functions so
-every runtime layer can import this module without cycles.
+**What is kept always, and what ``MXNET_TRACE=1`` adds.**  The
+step-level spans of the two hot loops (``ShardedTrainer.step`` /
+``step_many`` with ``step:dispatch`` and ``step:fetch``; the
+``TokenServer`` worker's ``serve.*`` and the engines' ``engine.*``
+trees), and every garbage collection (``gc``) and every phase of JAX
+acquiring a program (``compile:trace``, ``compile:lower``,
+``compile:executable``) that lasts :data:`RARE_SPAN_MIN_SECONDS` or more
+are recorded whether or not tracing is enabled: their call sites open
+spans without asking :func:`enabled`.  ``MXNET_TRACE=1`` (or
+:func:`enable`) adds the spans of every other layer (requests, checkpoints, ``aot:*``, ``fusion:*``,
+``telemetry.span`` scopes), whose sites cost one branch when it is off.
+The flight recorder is OFF by default (``MXNET_FLIGHT_RECORDER=1`` /
+:func:`enable_flight_recorder`); a bundle holds the last steps of either
+loop whether or not tracing was enabled beforehand.
+
+Import-light by design (stdlib + ``config`` + ``telemetry`` + the
+profiler's annotation class): ``profiler`` and ``checkpoint`` are
+imported lazily inside functions so every runtime layer can import this
+module without cycles.
 """
 from __future__ import annotations
 
 import collections
 import contextvars
+import gc
 import itertools
 import json
 import logging
@@ -57,9 +77,14 @@ import uuid
 from . import config as _config
 from . import telemetry as _telemetry
 
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+except ImportError:  # docs tooling without jax: spans still record
+    _Annotation = None
+
 __all__ = ["TRACE_ID", "Span", "span", "begin", "current_span",
            "enabled", "enable", "disable", "reset", "new_request_id",
-           "unwind_to",
+           "unwind_to", "records", "dropped", "RARE_SPAN_MIN_SECONDS",
            "sample_device_memory", "chrome_trace_payload", "export_trace",
            "flight_recorder_enabled", "enable_flight_recorder",
            "disable_flight_recorder", "rearm_flight_recorder",
@@ -165,13 +190,18 @@ def new_request_id():
 
 class Span:
     """One open traced scope.  Create via :func:`begin`; finish with
-    :meth:`end`.  ``activate=False`` spans do not become the contextvar
-    parent (used for overlapping serving requests)."""
+    :meth:`end`, or use it as a context manager.  ``activate=False``
+    spans do not become the contextvar parent (used for overlapping
+    serving requests).  ``cpu=True`` adds ``cpu_ms``, the opening
+    thread's CPU time inside the span: far below the wall time, the
+    thread was blocked or descheduled; equal to it, Python was at work.
+    Every span is mirrored into the profiler's trace as ``mx:<name>``
+    (module docstring)."""
 
     __slots__ = ("name", "span_id", "parent_id", "tid", "t0", "dur",
-                 "args", "status", "_token")
+                 "args", "status", "_token", "_ann", "_cpu0")
 
-    def __init__(self, name, args=None, activate=True):
+    def __init__(self, name, args=None, activate=True, cpu=False):
         parent = _current.get()
         self.name = name
         self.span_id = "%016x" % next(_ids)
@@ -181,6 +211,12 @@ class Span:
         self.status = "open"
         self.dur = None
         self._token = _current.set(self) if activate else None
+        self._cpu0 = time.thread_time() if cpu else None
+        if _Annotation is not None:
+            self._ann = _Annotation("mx:" + name, **(self.args or {}))
+            self._ann.__enter__()
+        else:
+            self._ann = None
         # t0 before registration: a concurrent exporter snapshotting
         # _active must never see a span without a timestamp
         self.t0 = time.perf_counter()
@@ -202,6 +238,8 @@ class Span:
         if self.args is None:
             self.args = {}
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
         return self
 
     def _record(self, now=None):
@@ -217,11 +255,15 @@ class Span:
         """Close the span and commit it to the ring buffer.  Unlike
         telemetry latency series (success-only), failed spans ARE
         recorded — a postmortem wants exactly those."""
-        global _dropped
         if self.status != "open":
             return self
         self.dur = time.perf_counter() - self.t0
         self.status = "error" if error else "ok"
+        if self._cpu0 is not None:
+            self.set(cpu_ms=1e3 * (time.thread_time() - self._cpu0))
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._token is not None:
             try:
                 _current.reset(self._token)
@@ -230,19 +272,58 @@ class Span:
             self._token = None
         with _lock:
             _active.pop(self.span_id, None)
-            if _buffer.maxlen is not None and \
-                    len(_buffer) == _buffer.maxlen:
-                _dropped += 1
-                _telemetry.TRACE_SPANS_DROPPED.inc()
-            _buffer.append(self._record())
+            _commit(self._record())
         return self
 
+    def __enter__(self):
+        return self
 
-def begin(name, args=None, activate=True):
-    """Open a :class:`Span` (caller must :meth:`Span.end` it).  Prefer
-    the :class:`span` context manager unless the scope crosses loop
-    iterations (e.g. one serving request across upload -> drain)."""
-    return Span(name, args=args, activate=activate)
+    def __exit__(self, exc_type, exc, tb):
+        self.end(error=exc_type is not None)
+
+
+def _commit(rec):
+    """Append one finished record to the ring (caller holds ``_lock``),
+    counting the record it evicts."""
+    global _dropped
+    if len(_buffer) == _buffer.maxlen:
+        _dropped += 1
+        _telemetry.TRACE_SPANS_DROPPED.inc()
+    _buffer.append(rec)
+
+
+def _commit_here(rec):
+    """Commit a record that was made whole on the calling thread."""
+    with _lock:
+        if rec["tid"] not in _thread_names:
+            _thread_names[rec["tid"]] = threading.current_thread().name
+        _commit(rec)
+
+
+def begin(name, args=None, activate=True, cpu=False):
+    """Open a :class:`Span` (caller must :meth:`Span.end` it, or use it
+    in a ``with``).  Recorded whether or not tracing is enabled: a call
+    site that is not one of the always-kept step-level spans asks
+    :func:`enabled` first.  Prefer the :class:`span` context manager
+    for those, unless the scope crosses loop iterations (e.g. one
+    serving request across upload -> drain)."""
+    return Span(name, args=args, activate=activate, cpu=cpu)
+
+
+def _record_past(name, dur, args=None):
+    """Commit a span that ended just now and lasted ``dur`` seconds, on
+    the calling thread, under the innermost open span: for scopes whose
+    length is only known when they end (``gc``, ``compile:*``).  The
+    short ones stay out, which keeps these events rare."""
+    if dur < RARE_SPAN_MIN_SECONDS:
+        return
+    parent = _current.get()
+    _commit_here({"name": name, "span_id": "%016x" % next(_ids),
+                  "parent_id": parent.span_id if parent is not None
+                  else None,
+                  "tid": threading.get_ident(),
+                  "t0": time.perf_counter() - dur, "dur": dur,
+                  "status": "ok", "args": args})
 
 
 def instant(name, args=None):
@@ -251,21 +332,89 @@ def instant(name, args=None):
     background threads (the async metric fetcher, the device
     prefetcher) that have no natural begin/end scope.  No-op when
     tracing is off."""
-    global _dropped
     if not _enabled:
         return
-    tid = threading.get_ident()
-    rec = {"name": name, "span_id": "%016x" % next(_ids),
-           "parent_id": None, "tid": tid, "t0": time.perf_counter(),
-           "dur": 0.0, "status": "instant",
-           "args": dict(args) if args else None}
+    _commit_here({"name": name, "span_id": "%016x" % next(_ids),
+                  "parent_id": None, "tid": threading.get_ident(),
+                  "t0": time.perf_counter(), "dur": 0.0,
+                  "status": "instant",
+                  "args": dict(args) if args else None})
+
+
+def records():
+    """A snapshot of the ring's finished records, oldest first (each a
+    dict: name, span_id, parent_id, tid, t0 and dur in seconds on
+    ``time.perf_counter``'s clock, status, args)."""
     with _lock:
-        if tid not in _thread_names:
-            _thread_names[tid] = threading.current_thread().name
-        if _buffer.maxlen is not None and len(_buffer) == _buffer.maxlen:
-            _dropped += 1
-            _telemetry.TRACE_SPANS_DROPPED.inc()
-        _buffer.append(rec)
+        return list(_buffer)
+
+
+def dropped():
+    """How many records the ring has evicted since the last
+    :func:`reset`."""
+    return _dropped
+
+
+# ---------------------------------------------------------------------------
+# always-kept rare events: garbage collections and compiles
+# ---------------------------------------------------------------------------
+
+#: a collection or a compile phase shorter than this is not recorded in
+#: the ring: gen-0 passes run many times a second, and every first use of
+#: an eager operation reports a trace of some microseconds (thousands in
+#: one set-up), so without it they would evict the loops' spans
+RARE_SPAN_MIN_SECONDS = 1e-3
+_gc_open = None                    # (TraceAnnotation, t0) of the running pass
+
+
+def _on_gc(phase, info):
+    """``gc.callbacks`` hook: every collection is a ``mx:gc`` annotation
+    in a running profiler trace; one that held the interpreter for
+    :data:`RARE_SPAN_MIN_SECONDS` or more is also a ``gc`` span in the
+    ring, on the thread whose allocation set it off."""
+    global _gc_open
+    if phase == "start":
+        ann = None
+        if _Annotation is not None:
+            ann = _Annotation("mx:gc", generation=info["generation"])
+            ann.__enter__()
+        _gc_open = (ann, time.perf_counter())
+    elif _gc_open is not None:
+        (ann, t0), _gc_open = _gc_open, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _record_past("gc", time.perf_counter() - t0,
+                     {"generation": info["generation"],
+                      "collected": info["collected"]})
+
+
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile:lower",
+    # a compile or a load from the persistent cache
+    "/jax/core/compile/backend_compile_duration": "compile:executable",
+}
+
+
+def _on_jax_duration(event, duration_secs, **kw):
+    """``jax.monitoring`` listener: each phase of acquiring a program as
+    a span in the ring (not in the profiler's trace: the phase is over
+    when JAX reports it)."""
+    name = _COMPILE_SPANS.get(event)
+    if name is not None:
+        fun = kw.get("fun_name")
+        _record_past(name, float(duration_secs),
+                     {"fun_name": str(fun)} if fun is not None else None)
+
+
+def _install_hooks():
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    try:
+        import jax.monitoring as _jm
+    except ImportError:
+        return
+    _jm.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def unwind_to(outer, error=True):
@@ -634,6 +783,7 @@ def _bundle_info(reason, exc, extra):
     return info
 
 
+_install_hooks()
 if _config.get("MXNET_TRACE"):
     enable()
 if _config.get("MXNET_FLIGHT_RECORDER"):

@@ -43,6 +43,15 @@ from transformer_lm import TransformerLM  # noqa: E402
 VOCAB, D_MODEL, N_HEADS, N_LAYERS, MAX_LEN = 48, 32, 2, 2, 24
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _telemetry_off_afterwards():
+    """The server tests turn telemetry on (and count on its staying on
+    from one to the next); turn it off when the file is done, so that no
+    later file in the same process inherits it."""
+    yield
+    telemetry.disable()
+
+
 @pytest.fixture(scope="module")
 def lm():
     mx.random.seed(0)
