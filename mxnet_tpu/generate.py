@@ -36,8 +36,8 @@ compilation" line):
   failures to 429/504 exactly like predict failures.
 
 * **Paged KV cache** — :class:`PagedGenerationEngine` replaces the
-  per-slot ring with a fixed-shape page pool
-  ``(layers, pages, heads, page_size, d_head)`` plus host-side page
+  per-slot ring with a fixed-shape, token-major page pool
+  ``(pages * page_size, layers, heads * d_head)`` plus host-side page
   tables (same "host state flips, compiled shape stays" trick): pages
   buy prefix sharing (a shared system prompt prefills ONCE; new
   requests attach to its pages refcounted, copy-on-write by page
@@ -108,6 +108,9 @@ def _decode_statusz():
     for s in _live_snapshot():
         st = s.stats()
         st["occupancy"] = s._engine.occupancy()
+        pool_shape = getattr(s._engine, "pool_shape", None)
+        if pool_shape is not None:         # the paged engine's K/V pools
+            st["pool_shape"] = list(pool_shape)
         if s._shedder is not None:
             st["ttft_burn_rate"] = round(s._shedder.burn, 4)
         out["servers"].append(st)
@@ -695,11 +698,16 @@ def _prefix_page_hashes(token_ids, page_size, limit):
 class PagedGenerationEngine:
     """Paged/block KV-cache generation over a chunk-protocol model.
 
-    Device state is one fixed-shape page pool per K/V —
-    ``(layers, pages, heads, page_size, d_head)``, donated through every
-    dispatch — and each decode slot maps its positions onto pool pages
-    through a host-side page table (page 0 is a write-through "trash"
-    page absorbing padded/invalid positions, so shapes never change).
+    Device state is one fixed-shape page pool per K/V, stored
+    token-major — ``(pages * page_size, layers, heads * d_head)``: row
+    ``page * page_size + offset`` holds one position's K (or V) of
+    every layer and head — and donated through every dispatch.  Both
+    index operations of a dispatch address dimension 0, so XLA gathers
+    whole rows and scatters the chunk's rows in place on the donated
+    buffer; no dispatch copies the pool.  Each decode slot maps its
+    positions onto pool pages through a host-side page table (page 0
+    is a write-through "trash" page absorbing padded/invalid positions,
+    so shapes never change).
     One compiled ``chunk`` function covers all three dispatch shapes:
 
     * **prefill chunk** ``(1, prefill_chunk)`` — prompts stream in
@@ -821,7 +829,13 @@ class PagedGenerationEngine:
         self._mesh = parallel.resolve_mesh(mesh)
         L, H = cfg["n_layers"], cfg["n_heads"]
         dh = cfg["d_model"] // H
-        pool_shape = (L, self._num_pages, H, self._page_size, dh)
+        # token-major: the dimension the page table addresses leads and
+        # a token's (layers, heads * d_head) trail as one contiguous
+        # row.  Heads and d_head are kept as ONE dimension because the
+        # TPU runtime lays an array out by its shape: with a d_head
+        # under 128 lanes minor-most it would make the tokens the
+        # minor-most dimension instead and copy the pool to index it.
+        pool_shape = (self._num_pages * self._page_size, L, H * dh)
         if self._mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -899,20 +913,27 @@ class PagedGenerationEngine:
 
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
                      wpage, woff, lane_keys):
-            """The one paged dispatch: gather each row's pages into a
-            linear (B, H, S, dh) cache view, run the model's
-            chunk_forward, sample EVERY chunk position with its
-            position-derived key, and scatter the chunk's K/V back to
-            the pool at (wpage, woff) — trash page 0 absorbs padded
-            positions.  tokens (B, C); page_table (B, P); wpage/woff
-            flat (B*C,)."""
+            """The one paged dispatch: gather the pool rows of each
+            slot's pages into a linear (B, H, S, dh) cache view per
+            layer, run the model's chunk_forward, sample EVERY chunk
+            position with its position-derived key, and scatter the
+            chunk's K/V rows back to the pool at row
+            ``wpage * page_size + woff`` — trash page 0 absorbs padded
+            positions.  pool_k/pool_v (pages * page_size, L, H*dh);
+            tokens (B, C); page_table (B, P); wpage/woff flat
+            (B*C,)."""
             Bc, C = tokens.shape
 
+            # pool row of every cache position: (B, P) pages -> (B, S)
+            rows = (page_table[:, :, None] * page
+                    + jnp.arange(page, dtype=jnp.int32)).reshape((Bc, S))
+
             def run():
-                gk = jnp.moveaxis(pool_k[:, page_table], 3, 2).reshape(
-                    (L, Bc, H, S, dh))
-                gv = jnp.moveaxis(pool_v[:, page_table], 3, 2).reshape(
-                    (L, Bc, H, S, dh))
+                def view(pool):     # (B, S, L, H*dh) -> (L, B, H, S, dh)
+                    return pool[rows].reshape(
+                        (Bc, S, L, H, dh)).transpose(2, 0, 3, 1, 4)
+
+                gk, gv = view(pool_k), view(pool_v)
                 caches = [(gk[li], gv[li]) for li in range(L)]
                 logits_nd, chunk_caches = net.chunk_forward(
                     tokens, caches, start)
@@ -932,28 +953,36 @@ class PagedGenerationEngine:
                                                  scfg)[0]))(logits, keys)
             k_new = jnp.stack([k for k, _v in chunk_caches])
             v_new = jnp.stack([v for _k, v in chunk_caches])
-            # scatter (advanced indices split by a slice move to the
-            # FRONT of the result): values must arrive (B*C, L, H, dh)
+            # one pool row per chunk position: (B*C, L, H*dh)
             kvals = k_new.astype(cache_dtype).transpose(
-                1, 3, 0, 2, 4).reshape((Bc * C, L, H, dh))
+                1, 3, 0, 2, 4).reshape((Bc * C, L, H * dh))
             vvals = v_new.astype(cache_dtype).transpose(
-                1, 3, 0, 2, 4).reshape((Bc * C, L, H, dh))
-            pool_k = pool_k.at[:, wpage, :, woff, :].set(kvals)
-            pool_v = pool_v.at[:, wpage, :, woff, :].set(vvals)
+                1, 3, 0, 2, 4).reshape((Bc * C, L, H * dh))
+            # leading-dimension scatter, in place on the donated pool;
+            # padded positions collide on the trash page, so the rows
+            # are not unique
+            wrow = wpage * page + woff
+            pool_k = pool_k.at[wrow].set(kvals)
+            pool_v = pool_v.at[wrow].set(vvals)
             return sampled, logits, pool_k, pool_v
 
         self._jit_chunk = jax.jit(chunk_fn, donate_argnums=(1, 2))
+        # the spec and the fingerprint name the pool's layout: an
+        # executable stored for another one is never loaded
+        pool_tag = "tokens%dxL%dxHD%d" % pool_shape
         self._aot_spec = aot_spec or (
-            "lm_decode_paged:slots%dxpages%dxpg%d"
-            % (self._slots, self._num_pages, page))
+            "lm_decode_paged:slots%dxpages%dxpg%d:%s"
+            % (self._slots, self._num_pages, page, pool_tag))
         store = _aot.resolve_aot(aot)
         if store is not None:
             dtag = _dtp.policy_tag(dt_policy)
-            fp = ("dtype=%s;sampling=%s;page=%d;chunk=%d;spec=%d"
-                  % (dtag, scfg.tag, page, self._chunk, self._spec_k))
+            fp = ("dtype=%s;sampling=%s;page=%d;chunk=%d;spec=%d;pool=%s"
+                  % (dtag, scfg.tag, page, self._chunk, self._spec_k,
+                     pool_tag))
             mext = {"dtype_policy": dtag, "sampling": scfg.tag,
                     "page_size": page, "prefill_chunk": self._chunk,
-                    "spec_k": self._spec_k}
+                    "spec_k": self._spec_k,
+                    "pool_layout": pool_tag}
             self._jit_chunk = _aot.AOTFunction(
                 self._jit_chunk, "generate:paged_chunk", store,
                 fingerprint_extra=fp, manifest_kind="generate",
@@ -983,6 +1012,11 @@ class PagedGenerationEngine:
     @property
     def pages_per_slot(self):
         return self._pages_per_slot
+
+    @property
+    def pool_shape(self):
+        """Shape of each of the K and V pools on the device."""
+        return tuple(self._pool_k.shape)
 
     @property
     def prefill_chunk(self):
@@ -1386,19 +1420,28 @@ class PagedGenerationEngine:
 
         if not isinstance(self._jit_chunk, _aot.AOTFunction):
             return [{"label": "generate", "status": "disabled"}]
-        infos = []
-        B, P, C = self._slots, self._pages_per_slot, self._chunk
-        shapes = [(1, C), (B, 1)]
+        return [self._jit_chunk.prewarm(*self._dispatch_args(shape))
+                for shape in self.dispatch_shapes()]
+
+    def dispatch_shapes(self):
+        """The ``(rows, chunk)`` token shapes the one dispatch is
+        compiled for: a prefill chunk, a decode step, and the verify
+        step when speculation is on."""
+        shapes = [(1, self._chunk), (self._slots, 1)]
         if self._spec_k > 0:
-            shapes.append((B, self._spec_k + 1))
-        for (nb, nc) in shapes:
-            infos.append(self._jit_chunk.prewarm(
-                self._params, self._pool_k, self._pool_v,
-                np.zeros((nb, P), np.int32), np.zeros((nb, nc), np.int32),
-                np.zeros(nb, np.int32), np.zeros(nb * nc, np.int32),
-                np.zeros(nb * nc, np.int32),
-                np.zeros((nb, 2), np.uint32)))
-        return infos
+            shapes.append((self._slots, self._spec_k + 1))
+        return shapes
+
+    def _dispatch_args(self, shape):
+        """Arguments of the dispatch at one token shape, all zeros
+        beside the engine's own parameters and pools: what a compile
+        that runs nothing needs."""
+        nb, nc = shape
+        return (self._params, self._pool_k, self._pool_v,
+                np.zeros((nb, self._pages_per_slot), np.int32),
+                np.zeros((nb, nc), np.int32), np.zeros(nb, np.int32),
+                np.zeros(nb * nc, np.int32), np.zeros(nb * nc, np.int32),
+                np.zeros((nb, 2), np.uint32))
 
 
 # ---------------------------------------------------------------------------

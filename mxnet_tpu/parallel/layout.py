@@ -285,25 +285,32 @@ register_layout(Layout("data_parallel", [
 # slots shard over the data axes (each data shard serves its own
 # sequences), heads over tp (each tp shard attends over its own heads,
 # composing with the column-parallel proj_q/k/v below: the K/V a shard
-# caches are exactly the ones its projections produce).  The paged
-# engine's page pool (generate.PagedGenerationEngine) resolves under
-# the SAME rule via the pool_k/pool_v names: its rank-5
-# (layers, pages, heads, page_size, d_head) arrays put the page dim
-# where slots sat — pages shard over the data axes (page ids are
-# host-side bookkeeping, every shard holds the same page's slice of
-# heads), heads over tp exactly like the ring.  An indivisible pages
-# dim (the pool carries a +1 trash page, so it is usually odd)
-# degrades to replicated on those axes while heads stay tp-sharded.
-_KV_CACHE_FSDP = SpecRule("kv_cache", r"(cache|pool)_(k|v)$",
+# caches are exactly the ones its projections produce).
+_KV_CACHE_FSDP = SpecRule("kv_cache", r"cache_(k|v)$",
                           (None, ("dp", "fsdp")), rank=5)
-_KV_CACHE_TP = SpecRule("kv_cache", r"(cache|pool)_(k|v)$",
+_KV_CACHE_TP = SpecRule("kv_cache", r"cache_(k|v)$",
                         (None, ("dp", "fsdp"), "tp"), rank=5)
+# the paged engine's page pool (generate.PagedGenerationEngine) is
+# token-major: rank-3 (pages * page_size, layers, heads * d_head)
+# arrays named pool_k/pool_v, indexed on dimension 0 by the gather and
+# the scatter of every dispatch.  Tokens shard over the data axes (page
+# ids are host-side bookkeeping and index the whole pool, whichever
+# shard holds the row), the heads * d_head dimension over tp — whole
+# heads to a shard, like the ring, since heads are its major factor.
+# A tokens dim the data axes do not divide (the pool carries a +1
+# trash page) degrades to replicated there while heads stay
+# tp-sharded.
+_KV_POOL_FSDP = SpecRule("kv_pool", r"pool_(k|v)$",
+                         (("dp", "fsdp"),), rank=3)
+_KV_POOL_TP = SpecRule("kv_pool", r"pool_(k|v)$",
+                       (("dp", "fsdp"), None, "tp"), rank=3)
 
 register_layout(Layout("fsdp", [
     # ZeRO-3: shard dim 0 of every matrix/conv kernel and the only dim
     # of every vector along fsdp; scalars replicated.  Optimizer state
     # follows its parameter (parallel.train places m/v/mom identically).
     _KV_CACHE_FSDP,
+    _KV_POOL_FSDP,
     SpecRule("matrix_dim0", r".*", ("fsdp",), min_rank=2),
     SpecRule("vector", r".*", ("fsdp",), rank=1),
     SpecRule("scalar", r".*", (), rank=0),
@@ -311,6 +318,7 @@ register_layout(Layout("fsdp", [
 
 register_layout(Layout("fsdp_tp", [
     _KV_CACHE_TP,
+    _KV_POOL_TP,
     # Megatron pairing on the mxnet (out_features, in_features) weight
     # convention: qkv/up projections column-parallel (tp on dim 0), the
     # following out/down projections row-parallel (tp on dim 1), so the

@@ -16,7 +16,13 @@ Tier-1 guards:
 * admission raises the typed `Overloaded` reasons (``slots`` /
   ``pages``) and the paged TokenServer end-to-end output (chunked +
   shared + speculative) matches the ring TokenServer's;
-* the new bench-mode ledger metrics gate in the right direction.
+* the new bench-mode ledger metrics gate in the right direction;
+* the pool is token-major (ISSUE 27): both index operations of the one
+  dispatch address the donated pool's dimension 0 with nothing
+  pool-sized before them, the TPU compiler (no chip: a described
+  v5e) copies no pool and expands no gather into a loop at OPT-1.3B's
+  widths, mixed batches match the ring engine, and a mesh resolves the
+  pool under its own ``kv_pool`` rule.
 
 Engine programs stay tiny (d_model 32, cache 24) for the tier-1
 budget; every paged engine compiles at most three chunk signatures.
@@ -341,3 +347,280 @@ def test_perf_gate_directions_for_paged_metrics():
         "lm_decode_spec_accepted_per_step", "tokens/step")
     assert not perf_gate.higher_is_better(
         "lm_decode_ttft_interference_p99_ms", "ms")
+
+
+# ---------------------------------------------------------------------------
+# the token-major pool: structure of the one dispatch (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+def _dispatch_args(eng, shape):
+    """The engine's own zero arguments for one of its dispatch shapes."""
+    prefill, decode, verify = eng.dispatch_shapes()
+    return eng._dispatch_args(
+        {"prefill": prefill, "decode": decode, "verify": verify}[shape])
+
+
+@pytest.mark.parametrize("shape", ["decode", "prefill", "verify"])
+def test_pool_is_indexed_on_its_leading_dimension(lm, shape):
+    """Both index operations of the dispatch take the donated pool
+    itself — no transpose, copy or reshape of it first — and address its
+    dimension 0; nothing else in the program has the pool's size."""
+    import jax
+
+    eng = generate.PagedGenerationEngine(
+        lm, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
+        spec_k=2, sampling=generate.SamplingConfig(greedy=True))
+    assert eng.pool_shape == (eng.num_pages * eng.page_size, N_LAYERS,
+                              D_MODEL)
+    closed = jax.make_jaxpr(eng._jit_chunk)(*_dispatch_args(eng, shape))
+    (call,) = closed.jaxpr.eqns          # the jitted chunk_fn itself
+    body = call.params["jaxpr"].jaxpr
+    n_params = len(eng._params)
+    pools = body.invars[n_params:n_params + 2]
+    pool_size = int(np.prod(eng.pool_shape))
+
+    gathers = [e for e in body.eqns if e.primitive.name == "gather"
+               and any(e.invars[0] is p for p in pools)]
+    scatters = [e for e in body.eqns if e.primitive.name == "scatter"
+                and any(e.invars[0] is p for p in pools)]
+    assert len(gathers) == 2 and len(scatters) == 2, (gathers, scatters)
+    for e in gathers:
+        dn = e.params["dimension_numbers"]
+        assert tuple(dn.start_index_map) == (0,)
+        assert tuple(dn.collapsed_slice_dims) == (0,)
+        assert tuple(e.params["slice_sizes"]) == (1,) + eng.pool_shape[1:]
+    for e in scatters:
+        dn = e.params["dimension_numbers"]
+        assert tuple(dn.scatter_dims_to_operand_dims) == (0,)
+        assert tuple(dn.inserted_window_dims) == (0,)
+        # padded positions collide on the trash page
+        assert not e.params["unique_indices"]
+    # the pools reach nothing but their gather and their scatter (no
+    # transpose, copy or reshape of a pool comes before either), and
+    # only the scatters' results have the pool's size
+    for e in body.eqns:
+        if any(e is x for x in gathers + scatters):
+            continue
+        assert not any(v is p for v in e.invars for p in pools), e
+        for v in e.outvars:
+            assert int(np.prod(v.aval.shape)) < pool_size, e
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) TPU v5e chip to compile for; the
+    TPU's compiler is loaded here and nowhere at import."""
+    mp = pytest.MonkeyPatch()
+    for k, v in (("TPU_LOG_DIR", "disabled"), ("TPU_SKIP_MDS_QUERY", "1"),
+                 ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                 ("TPU_WORKER_HOSTNAMES", "localhost")):
+        if k not in os.environ:
+            mp.setenv(k, v)
+    try:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        yield SingleDeviceSharding(topo.devices[0])
+    except Exception as e:  # noqa: BLE001 - no compiler, no test
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    finally:
+        mp.undo()
+
+
+@pytest.fixture(scope="module")
+def wide_engine():
+    """The serving cell's engine at OPT-1.3B's widths (d_model 2048, 32
+    heads, page 16, 8 slots of 1024 positions, 513 pages, bf16 cache),
+    cut to 2 layers and a small FFN and vocabulary: the pool keeps its
+    real rows."""
+    net = TransformerLM(vocab_size=256, d_model=2048, n_heads=32,
+                        n_layers=2, d_ff=256, max_len=1024)
+    net.initialize(mx.init.Zero())
+    return generate.PagedGenerationEngine(
+        net, slots=8, cache_len=1024, page_size=16, num_pages=513,
+        prefill_chunk=32, spec_k=0, dtype_policy="bf16_mixed",
+        sampling=generate.SamplingConfig(greedy=True))
+
+
+@pytest.mark.parametrize("shape", ["decode", "prefill"])
+def test_tpu_program_copies_no_pool(v5e_chip, wide_engine, shape):
+    """What the chip's compiler makes of the dispatch (optimized HLO for
+    a described v5e, nothing runs): the pool keeps a row-major layout
+    with tokens outermost, and besides the parameter, the in-place
+    scatter and the result no operation has the pool's size: no copy,
+    no loop that gathers page by page, no buffer of zeros."""
+    import re
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    eng = wide_engine
+    shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
+
+    def struct(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=v5e_chip)
+
+    args = jax.tree_util.tree_map(struct,
+                                  eng._dispatch_args(shapes[shape]))
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = eng._jit_chunk.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    pool = "bf16[%s]" % ",".join(str(d) for d in eng.pool_shape)
+    assert " while(" not in text, "a gather or scatter became a loop"
+    entry = text[text.index("\nENTRY"):]
+    ops = re.findall(r"= %s(\{[^ ]*\})? ([\w\-]+)\(" % re.escape(pool),
+                     entry)
+    assert sorted(op for _layout, op in ops) == \
+        ["fusion", "fusion", "parameter", "parameter"], ops
+    for layout, _op in ops:
+        assert layout.startswith("{2,1,0"), "tokens are not outermost"
+    scatter_fusions = re.findall(
+        r"= %s\S* fusion\(.*op_name=\"jit\(chunk_fn\)/scatter" %
+        re.escape(pool), entry)
+    assert len(scatter_fusions) == 2
+    assert text.count("may-alias") + text.count("must-alias") >= 2, \
+        "the donated pools are not aliased to the results"
+
+
+# ---------------------------------------------------------------------------
+# mixed batches == ring, and the pool's own layout rule under a mesh
+# ---------------------------------------------------------------------------
+
+def _ring_tokens(ring, prompt, n):
+    slot, tok = ring.admit(prompt)
+    out = [tok] + _drain(ring, slot, n - 1)
+    ring.evict(slot, "length")
+    return out
+
+
+def _side_by_side(eng, prompts, steps):
+    """Admit every prompt, decode ``steps`` steps with all of them
+    active, evict: the tokens of each."""
+    slots = [eng.admit(p) for p in prompts]
+    got = [[tok] for _s, tok in slots]
+    for _ in range(steps):
+        out = eng.decode_step()
+        for g, (s, _t) in zip(got, slots):
+            g.extend(out[s])
+    for s, _t in slots:
+        eng.evict(s, "length")
+    return got
+
+
+def _mixed_inside_page(eng):
+    """Three prompts that end inside a page (lengths 5, 7, 10 on pages
+    of 4), decoding side by side."""
+    prompts = [_prompt(5, seed=31), _prompt(7, seed=32),
+               _prompt(10, seed=33)]
+    return prompts, _side_by_side(eng, prompts, 6)
+
+
+def _mixed_evict_reuse(eng):
+    """A slot is evicted mid-flight and the next admission takes its
+    slot and its pages while the neighbour keeps decoding."""
+    a, b, c = _prompt(9, seed=41), _prompt(6, seed=42), _prompt(11, seed=43)
+    (sa, ta), (sb, tb) = eng.admit(a), eng.admit(b)
+    got_a, got_b = [ta], [tb]
+    for _ in range(3):
+        out = eng.decode_step()
+        got_a.extend(out[sa])
+        got_b.extend(out[sb])
+    pages_a = set(int(p) for p in eng._page_table[sa] if p)
+    eng.evict(sa, "eos")
+    sc, tc = eng.admit(c)
+    assert sc == sa, "LIFO slot reuse"
+    assert pages_a & set(int(p) for p in eng._page_table[sc]), \
+        "the next admission must take the evicted slot's pages"
+    got_c = [tc]
+    for _ in range(4):
+        out = eng.decode_step()
+        got_b.extend(out[sb])
+        got_c.extend(out[sc])
+    eng.evict(sb, "length")
+    eng.evict(sc, "length")
+    return [a, b, c], [got_a, got_b, got_c]
+
+
+def _mixed_trash_collisions(eng):
+    """One active slot of three (the idle slots' rows of every decode
+    step collide on the trash page) after a prompt of 3 tokens in a
+    chunk of 8 (five padded positions collide there too); the trash
+    page's rows must never reach a live slot's view."""
+    prompts = [_prompt(3, seed=51)]
+    return prompts, _side_by_side(eng, prompts, 9)
+
+
+@pytest.mark.parametrize("scenario", [
+    _mixed_inside_page, _mixed_evict_reuse, _mixed_trash_collisions],
+    ids=["ends_inside_page", "evict_and_reuse", "trash_collisions"])
+def test_mixed_batch_matches_ring(ring, paged, scenario):
+    prompts, got = scenario(paged)
+    assert paged.pages_in_use() == 0
+    for prompt, toks in zip(prompts, got):
+        assert toks == _ring_tokens(ring, prompt, len(toks))
+
+
+@pytest.mark.parametrize("mesh,spec", [
+    ("tp=2", (None, None, "tp")),
+    ("dp=2,tp=2", ("dp", None, "tp")),
+    ("fsdp=2,tp=2", ("fsdp", None, "tp")),
+    ("dp=2,fsdp=2", (("dp", "fsdp"),))])
+def test_pool_layout_rule_under_mesh(lm, paged, mesh, spec):
+    """The pool resolves under its own ``kv_pool`` rule — tokens over
+    the data axes, heads over tp — and a meshed engine decodes the
+    one-device engine's greedy tokens."""
+    from jax.sharding import PartitionSpec as P
+
+    from mxnet_tpu import parallel
+
+    e = generate.PagedGenerationEngine(
+        lm, slots=3, cache_len=16, page_size=4, prefill_chunk=8,
+        mesh=mesh, sampling=generate.SamplingConfig(greedy=True))
+    res = parallel.layout.get_layout(e.layout_name).resolve(
+        [("pool_k", e.pool_shape), ("pool_v", e.pool_shape)], e._mesh)
+    assert res.rule("pool_k") == res.rule("pool_v") == "kv_pool"
+    assert not res.fallbacks, "52 rows and 32 lanes divide by 2 and 4"
+    assert res.spec("pool_k") == P(*spec)
+    assert e._pool_k.sharding.spec == e._pool_v.sharding.spec == P(*spec)
+    prompts = [_prompt(5, seed=3), _prompt(7, seed=5)]
+    assert _side_by_side(e, prompts, 4) == _side_by_side(paged, prompts, 4)
+
+
+@pytest.mark.parametrize("fault,problem", [
+    (None, None), ("page_size", "no whole number of pages"),
+    ("prefill_chunk", "token block width")])
+def test_prewarm_check_reads_the_token_major_rows(lm, tmp_path, fault,
+                                                  problem):
+    """``tools/prewarm.py --check`` judges a stored paged signature by
+    the pool's token-major leaves: a healthy store passes, a row whose
+    extras disagree with its own recorded shapes is named."""
+    import prewarm
+    from mxnet_tpu import aot
+
+    store = aot.AOTStore(str(tmp_path / "store"))
+    e = generate.PagedGenerationEngine(
+        lm, slots=2, cache_len=16, page_size=4, prefill_chunk=8,
+        aot=store, sampling=generate.SamplingConfig(greedy=True))
+    assert [i["status"] for i in e.prewarm()] == ["compiled", "compiled"]
+    rows, problems = store.manifest_entries()
+    assert problems == [] and len(rows) == 2
+    for row in rows:
+        assert row["label"] == "generate:paged_chunk"
+        assert row["pool_layout"] == "tokens%dxL%dxHD%d" % e.pool_shape
+        assert row["pool_layout"] in row["spec"]
+        if fault is not None:
+            row[fault] = 5
+    found = [m for row in rows for m in prewarm._check_paged_row(row)]
+    if problem is None:
+        assert found == []
+    else:
+        assert found and all(problem in m for m in found), found

@@ -348,8 +348,9 @@ def run_manifest(args):
 def _check_paged_row(e):
     """Shape-consistency problems for one ``generate:paged_chunk``
     manifest row (empty list = healthy).  The paged engine compiles a
-    closed family of signatures — page-pool leaves are rank-5 with the
-    page length at axis 3, and the token block is one of (1, chunk) /
+    closed family of signatures — the two page-pool leaves are rank-3,
+    token-major (pages * page_size, layers, heads * d_head), and the
+    token block is one of (1, chunk) /
     (slots, 1) / (slots, K+1) — so a row whose recorded shapes disagree
     with its own page_size/prefill_chunk/spec_k extras means the store
     was written by a mismatched build and would miss at load."""
@@ -366,15 +367,16 @@ def _check_paged_row(e):
               if isinstance(s, (list, tuple)) and len(s) >= 2
               and isinstance(s[0], (list, tuple))]
     msgs = []
-    pools = [s for s, _d in leaves if len(s) == 5]
-    if len(pools) < 2:
-        msgs.append("%s: no page-pool leaves (rank-5) in the recorded "
-                    "signature" % who)
+    # the model's parameters are rank-1 and rank-2 leaves
+    pools = [s for s, _d in leaves if len(s) == 3]
+    if len(pools) != 2:
+        msgs.append("%s: no pair of token-major page-pool leaves "
+                    "(rank-3) in the recorded signature" % who)
     else:
-        for s in pools[:2]:
-            if s[3] != page:
-                msgs.append("%s: pool page axis %d != page_size %d"
-                            % (who, s[3], page))
+        for s in pools:
+            if s[0] % page:
+                msgs.append("%s: pool of %d rows is no whole number of "
+                            "pages of page_size %d" % (who, s[0], page))
     # the model params are float leaves; the engine's only rank-2
     # int32 leaves are, in flatten order, page_table (slots, P) then
     # the token block (B, C)
