@@ -695,6 +695,49 @@ def _prefix_page_hashes(token_ids, page_size, limit):
     return hashes
 
 
+class BlockTokens(list):
+    """The tokens a committed block emits, with ``fixed_at``, the
+    denoise pass (1..T) at which each was fixed, and ``confidence``,
+    the log-probability the model gave it in that pass."""
+
+    def __init__(self, tokens=(), fixed_at=(), confidence=()):
+        super().__init__(tokens)
+        self.fixed_at = list(fixed_at)
+        self.confidence = list(confidence)
+
+
+class _OpenBlocks:
+    """The schedule of every slot's open block under block-diffusion
+    decoding, on the host, one row a slot.  What a pass does to a block
+    follows from counts alone (``T`` denoise passes that fix
+    ``ceil(masked at block start / T)`` positions each, then a commit),
+    so the host knows every slot's next pass without a result of the
+    last one: the denoise passes launched, the positions still masked
+    after them, the masked positions at the block's start, and for a
+    block no pass has run yet (``fresh``) the tokens the prompt gave
+    it.  The block itself (its tokens, which positions are masked, the
+    pass that fixed each and with what confidence) is state the program
+    carries on the device from pass to pass."""
+
+    def __init__(self, slots, length):
+        self.tokens = np.zeros((slots, length), np.int32)
+        self.fresh = np.zeros(slots, bool)
+        self.given = np.zeros(slots, np.int32)
+        self.passes = np.zeros(slots, np.int32)
+        self.left = np.zeros(slots, np.int32)
+        self.masked_at_start = np.zeros(slots, np.int32)
+
+    def open(self, slot, given=()):
+        """A fresh block in ``slot``: ``given`` tokens lead, the rest
+        is masked."""
+        g, length = len(given), self.tokens.shape[1]
+        self.tokens[slot] = 0
+        self.tokens[slot, :g] = given
+        self.fresh[slot] = True
+        self.given[slot], self.passes[slot] = g, 0
+        self.left[slot] = self.masked_at_start[slot] = length - g
+
+
 class PagedGenerationEngine:
     """Paged/block KV-cache generation over a chunk-protocol model.
 
@@ -715,6 +758,17 @@ class PagedGenerationEngine:
       long admission interleaves with decode steps instead of stalling
       them;
     * **decode** ``(slots, 1)`` — every active slot advances one token;
+      with a model whose ``config`` gives a ``block_length`` B > 1,
+      ``(slots, B)`` — **block-diffusion decoding**: every active slot
+      runs one pass of its open block (``denoise_steps`` T denoise
+      passes, whose K/V rows go to the trash page, then one commit pass
+      that writes them to the slot's pages and emits the block).  The
+      open blocks live on the device and a pass hands them to the next,
+      so :meth:`decode_step` launches a pass before it reads the
+      results of the ones before: ``passes_ahead`` passes stay queued,
+      the device does not wait for the host between two passes, and a
+      block's tokens arrive that many calls after its commit was
+      launched;
     * **verify** ``(slots, spec_k + 1)`` — with n-gram speculation on,
       each step carries the current token plus up to ``spec_k`` drafted
       tokens and verifies them all at once.  Acceptance is exact-match
@@ -740,11 +794,19 @@ class PagedGenerationEngine:
     # prefill chunk per loop tick) when it sees this flag
     incremental = True
 
+    # block-diffusion decoding: the passes launched and not yet read
+    # that a call leaves queued.  One keeps the device busy while the
+    # host delivers, admits and launches; three hold about 70 ms of work
+    # at SDAR-30B-A3B's six layers, which is what the serving machine's
+    # host has been seen to stand still for now and then (100-120 ms,
+    # PERF.md section 2).  Every one delays a burst by a pass.
+    passes_ahead = 3
+
     def __init__(self, net, slots=None, cache_len=None, page_size=None,
                  num_pages=None, prefill_chunk=None, spec_k=None,
                  spec_ngram=None, prefix_share=None, mesh=None,
                  layout=None, dtype_policy=None, aot=None, aot_spec=None,
-                 sampling=None, device=None):
+                 sampling=None, device=None, denoise_steps=None):
         import jax
         import jax.numpy as jnp
 
@@ -812,6 +874,31 @@ class PagedGenerationEngine:
         self._prefix_share = bool(prefix_share)
         self.sampling = sampling if sampling is not None \
             else SamplingConfig()
+        # block-diffusion decoding: the model's mask is block-causal
+        # with this block length (1 = causal, a token a pass)
+        self._block = Bl = int(cfg.get("block_length", 1))
+        if Bl > 1:
+            if self._spec_k or not self.sampling.greedy:
+                raise MXNetError(
+                    "block-diffusion decoding (block_length=%d) is greedy "
+                    "and takes no n-gram speculation" % Bl)
+            if self._page_size % Bl or self._chunk % Bl:
+                raise MXNetError(
+                    "page_size (%d) and prefill_chunk (%d) must be "
+                    "multiples of the model's block_length (%d)"
+                    % (self._page_size, self._chunk, Bl))
+            if cfg.get("mask_token_id") is None:
+                raise MXNetError("model config lacks 'mask_token_id' "
+                                 "(block-diffusion decoding)")
+            self._denoise_steps = int(denoise_steps) if denoise_steps \
+                else Bl
+            if not 1 <= self._denoise_steps <= Bl:
+                raise MXNetError(
+                    "denoise_steps must be in [1, block_length=%d], got %r"
+                    % (Bl, denoise_steps))
+        elif denoise_steps:
+            raise MXNetError("denoise_steps needs a model with "
+                             "block_length > 1")
 
         probe = NDArray(jnp.zeros(
             (1, min(8, cfg["max_len"])), jnp.float32))
@@ -827,15 +914,33 @@ class PagedGenerationEngine:
             if dt_policy is not None else np.dtype(np.float32)
 
         self._mesh = parallel.resolve_mesh(mesh)
-        L, H = cfg["n_layers"], cfg["n_heads"]
-        dh = cfg["d_model"] // H
+        # key/value heads and the head size are the model's to say
+        # (grouped-query attention; a head size that is not d_model /
+        # n_heads)
+        L = cfg["n_layers"]
+        H = int(cfg.get("n_kv_heads", cfg["n_heads"]))
+        dh = int(cfg.get("d_head", cfg["d_model"] // cfg["n_heads"]))
         # token-major: the dimension the page table addresses leads and
         # a token's (layers, heads * d_head) trail as one contiguous
         # row.  Heads and d_head are kept as ONE dimension because the
         # TPU runtime lays an array out by its shape: with a d_head
         # under 128 lanes minor-most it would make the tokens the
         # minor-most dimension instead and copy the pool to index it.
-        pool_shape = (self._num_pages * self._page_size, L, H * dh)
+        # For the same reason the row's second-minor dimension has to
+        # tile without padding (1, 2, 4 or a multiple of 8 sublanes):
+        # with 6 layers of 4 x 128 the runtime puts the layers
+        # outermost and the program copies the pool twice a dispatch
+        # to index it.  Such a row folds the heads into the layers
+        # (one chip; under a mesh heads * d_head stays the dimension
+        # that `tp` shards).
+        def tiles(n):
+            return n in (1, 2, 4) or n % 8 == 0
+
+        row = (L, H * dh)
+        if self._mesh is None and not tiles(L):
+            row = (L * H, dh) if tiles(L * H) and dh % 128 == 0 \
+                else (L * H * dh,)
+        pool_shape = (self._num_pages * self._page_size,) + row
         if self._mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -887,12 +992,41 @@ class PagedGenerationEngine:
         self._spec_accepted = 0
         self._spec_steps = 0
         self._chunks_run = 0
+        # block-diffusion: the schedule of every slot's open block on
+        # the host, the blocks themselves on the device (tokens, which
+        # positions are masked, the pass that fixed each, the confidence
+        # it was fixed with), handed from pass to pass; the pass launched
+        # and not yet read; a slot's count of occupants, by which a pass
+        # launched for the last one is told from the present one's
+        self._blocks = _OpenBlocks(self._slots, Bl) if Bl > 1 else None
+        self._inflight = collections.deque()
+        self._serial = np.zeros(self._slots, np.int64)
+        self.last_pass = None
+        # tokens a slot's request still wants of blocks not yet launched
+        # (admit_incremental's `max_new`), and whether its last block's
+        # commit is launched: no pass is launched for it after that
+        self._budget = np.zeros(self._slots, np.int64)
+        self._drained = np.zeros(self._slots, bool)
+        # where a slot's next block starts by the passes READ (`_pos`
+        # is by the passes launched, up to `passes_ahead` further on)
+        self._read_pos = np.zeros(self._slots, np.int32)
+        if Bl > 1:
+            if self._mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                on_device = NamedSharding(self._mesh, PartitionSpec())
+            else:
+                on_device = self._pool_sharding
+            self._block_state = tuple(
+                jax.device_put(np.zeros((self._slots, Bl), dt), on_device)
+                for dt in (np.int32, bool, np.int32, np.float32))
 
         gluon_params = params
         scfg = self.sampling
         S = self._capacity
         cache_dtype = self._cache_dtype
         page = self._page_size
+        mask_id = int(cfg["mask_token_id"]) if Bl > 1 else None
 
         def _cast_params(tree):
             if dt_policy is None:
@@ -912,7 +1046,7 @@ class PagedGenerationEngine:
             return arr
 
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
-                     wpage, woff, lane_keys):
+                     wpage, woff, lane_keys, block=None):
             """The one paged dispatch: gather the pool rows of each
             slot's pages into a linear (B, H, S, dh) cache view per
             layer, run the model's chunk_forward, sample EVERY chunk
@@ -921,8 +1055,35 @@ class PagedGenerationEngine:
             ``wpage * page_size + woff`` — trash page 0 absorbs padded
             positions.  pool_k/pool_v (pages * page_size, L, H*dh);
             tokens (B, C); page_table (B, P); wpage/woff flat
-            (B*C,)."""
+            (B*C,).  The fifth result is a dict of what only some
+            models give: ``expert_load`` (L, E) from an expert layer,
+            ``block`` and ``masked`` from a pass of block-diffusion
+            decoding.
+
+            ``block`` (a pass of block-diffusion decoding; a prefill
+            chunk gives none) is the open blocks as the last pass left
+            them, (tokens, masked, fixed_at, confidence), each (B, C),
+            and of this pass per slot: ``fresh``, whether its block
+            opens here (``tokens`` then holds what the prompt gave it,
+            ``given`` how many, the rest is masked), ``take``, how many
+            masked positions the pass fixes (0: a commit pass, or no
+            one in the slot) and ``number``, which of the block's
+            passes it is.  The masked positions are fed the mask token;
+            the ``take`` most confident of them (ties to the lower
+            position) are fixed at their best token.  ``extras["block"]``
+            is the blocks after the pass, ``extras["masked"]`` what was
+            masked in it."""
             Bc, C = tokens.shape
+            if block is not None:
+                (b_tok, b_mask, b_at, b_conf), fresh, given, take, \
+                    number = block
+                opens = fresh[:, None]
+                b_tok = jnp.where(opens, tokens, b_tok)
+                b_mask = jnp.where(
+                    opens, jnp.arange(C)[None, :] >= given[:, None], b_mask)
+                b_at = jnp.where(opens, 0, b_at)
+                b_conf = jnp.where(opens, 0.0, b_conf)
+                tokens = jnp.where(b_mask, mask_id, b_tok)
 
             # pool row of every cache position: (B, P) pages -> (B, S)
             rows = (page_table[:, :, None] * page
@@ -935,11 +1096,13 @@ class PagedGenerationEngine:
 
                 gk, gv = view(pool_k), view(pool_v)
                 caches = [(gk[li], gv[li]) for li in range(L)]
-                logits_nd, chunk_caches = net.chunk_forward(
-                    tokens, caches, start)
-                return logits_nd._data, chunk_caches
+                res = net.chunk_forward(tokens, caches, start)
+                # a model may hand back a third item: arrays about the
+                # forward itself (an expert layer's token counts)
+                return res[0]._data, res[1], \
+                    dict(res[2]) if len(res) > 2 else {}
 
-            logits, chunk_caches = _traced(run, params_)
+            logits, chunk_caches, extras = _traced(run, params_)
             logits = _cast_logits(logits)              # (B, C, V) f32
             if scfg.greedy:
                 sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -951,25 +1114,44 @@ class PagedGenerationEngine:
                 sampled = jax.vmap(jax.vmap(
                     lambda lg, kk: sample_logits(lg[None, :], kk,
                                                  scfg)[0]))(logits, keys)
+            if block is not None:
+                # the confidence of each position's best token: its
+                # log-probability under the softmax, in float32
+                lg = logits.astype(jnp.float32)
+                conf = jnp.max(lg, axis=-1) - \
+                    jax.scipy.special.logsumexp(lg, axis=-1)
+                # of each row's masked positions the `take` most
+                # confident are fixed; a stable sort leaves ties to the
+                # lower position
+                order = jnp.argsort(jnp.where(b_mask, -conf, jnp.inf),
+                                    axis=1, stable=True)
+                rank = jnp.argsort(order, axis=1)
+                fix = b_mask & (rank < take[:, None])
+                extras["masked"] = b_mask
+                extras["block"] = (
+                    jnp.where(fix, sampled, b_tok), b_mask & ~fix,
+                    jnp.where(fix, number[:, None], b_at),
+                    jnp.where(fix, conf, b_conf))
             k_new = jnp.stack([k for k, _v in chunk_caches])
             v_new = jnp.stack([v for _k, v in chunk_caches])
-            # one pool row per chunk position: (B*C, L, H*dh)
+            # one pool row per chunk position: (B*C,) + the pool's row
             kvals = k_new.astype(cache_dtype).transpose(
-                1, 3, 0, 2, 4).reshape((Bc * C, L, H * dh))
+                1, 3, 0, 2, 4).reshape((Bc * C,) + row)
             vvals = v_new.astype(cache_dtype).transpose(
-                1, 3, 0, 2, 4).reshape((Bc * C, L, H * dh))
+                1, 3, 0, 2, 4).reshape((Bc * C,) + row)
             # leading-dimension scatter, in place on the donated pool;
             # padded positions collide on the trash page, so the rows
             # are not unique
             wrow = wpage * page + woff
             pool_k = pool_k.at[wrow].set(kvals)
             pool_v = pool_v.at[wrow].set(vvals)
-            return sampled, logits, pool_k, pool_v
+            return sampled, logits, pool_k, pool_v, extras
 
         self._jit_chunk = jax.jit(chunk_fn, donate_argnums=(1, 2))
         # the spec and the fingerprint name the pool's layout: an
         # executable stored for another one is never loaded
-        pool_tag = "tokens%dxL%dxHD%d" % pool_shape
+        pool_tag = "tokens%dxL%dxHD%d" % pool_shape if row == (L, H * dh) \
+            else "tokens" + "x".join(str(d) for d in pool_shape)
         self._aot_spec = aot_spec or (
             "lm_decode_paged:slots%dxpages%dxpg%d:%s"
             % (self._slots, self._num_pages, page, pool_tag))
@@ -1123,6 +1305,10 @@ class PagedGenerationEngine:
         chunk-padded prefill length (advisory; prefix hits shorten the
         actual work)."""
         limit = min(self._capacity, self.model_config["max_len"])
+        Bl = self._block
+        if Bl > 1:
+            # the prompt's tail opens a block that must fit whole
+            limit = Bl * (limit // Bl) - 1
         if length > limit:
             raise MXNetError(
                 "prompt length %d exceeds the paged cache capacity %d "
@@ -1132,8 +1318,12 @@ class PagedGenerationEngine:
         return self._chunk * (-(-length // self._chunk))
 
     def at_capacity(self, slot):
-        return self._pos[slot] >= min(self._capacity,
-                                      self.model_config["max_len"])
+        """No room for the slot's next token (under block-diffusion
+        decoding: for the next whole block after those it has
+        emitted)."""
+        pos = self._read_pos if self._block > 1 else self._pos
+        return pos[slot] + self._block - 1 >= min(
+            self._capacity, self.model_config["max_len"])
 
     # -- page bookkeeping ------------------------------------------------
 
@@ -1186,13 +1376,16 @@ class PagedGenerationEngine:
 
     # -- lifecycle of one sequence ---------------------------------------
 
-    def admit_incremental(self, token_ids):
+    def admit_incremental(self, token_ids, max_new=None):
         """Claim a slot for ``token_ids``: attach any shared prefix
         pages, allocate the remainder of the slot's pages upfront (so
         decode can never starve mid-flight), and queue the un-shared
         prompt tail for chunked prefill.  Returns the slot; the first
         token arrives from the :meth:`prefill_step` that completes the
-        prompt.  Raises :class:`Overloaded` (``slots`` / ``pages``)."""
+        prompt.  ``max_new`` is the most tokens the caller will take:
+        block-diffusion decoding launches its passes ahead of their
+        results, and launches none past the block that covers them.
+        Raises :class:`Overloaded` (``slots`` / ``pages``)."""
         token_ids = np.asarray(token_ids).astype(np.int32).reshape(-1)
         n = token_ids.size
         if n < 1:
@@ -1233,6 +1426,9 @@ class PagedGenerationEngine:
                     % (self.pages_in_use(), self._num_pages - 1))
             fresh.append(pg)
         slot = self._free.popleft()
+        self._budget[slot] = np.iinfo(np.int64).max if max_new is None \
+            else int(max_new)
+        self._drained[slot] = False
         row = self._page_table[slot]
         for i, (_h, pg) in enumerate(attached):
             if self._page_ref[pg] == 0:
@@ -1243,9 +1439,16 @@ class PagedGenerationEngine:
             self._page_ref[pg] += 1
             row[len(attached) + j] = pg
         start = len(attached) * self._page_size
-        self._pending[slot] = {"tokens": token_ids, "filled": start,
-                               "n": n}
+        # block-diffusion: whole blocks of the prompt are prefilled; its
+        # tail opens the first block as given positions
+        Bl = self._block
+        target = n if Bl == 1 else Bl * (n // Bl)
         self._history[slot] = token_ids.tolist()
+        if start < target:
+            self._pending[slot] = {"tokens": token_ids, "filled": start,
+                                   "n": target}
+        else:
+            self._open_first_block(slot, token_ids, target)
         if self.sampling.greedy:
             self._lane_keys[slot] = 0
         else:
@@ -1258,7 +1461,9 @@ class PagedGenerationEngine:
     def prefill_step(self, slot=None):
         """Run ONE prefill chunk (round-robin across pending slots, or
         the given ``slot``).  Returns ``(slot, first_token)`` when that
-        chunk completed its prompt, else None.  The TokenServer calls
+        chunk completed its prompt, else None; under block-diffusion
+        decoding ``(slot, None)``, the first tokens coming of the first
+        block's commit.  The TokenServer calls
         this once per loop tick, interleaving long prefills with decode
         steps; the round-robin keeps a short prompt's TTFT from hiding
         behind a long prompt admitted just before it."""
@@ -1273,7 +1478,8 @@ class PagedGenerationEngine:
         final = filled + count >= n
         with _tracing.begin("engine.prefill", args={
                 "slot": int(slot), "filled": int(filled),
-                "count": int(count), "final": final}):
+                "count": int(count), "final": final,
+                "block": self._block}):
             chunk = np.zeros((1, self._chunk), np.int32)
             chunk[0, :count] = toks[filled:filled + count]
             wpage = np.zeros(self._chunk, np.int32)
@@ -1284,7 +1490,7 @@ class PagedGenerationEngine:
                 wpage[j] = row[p // self._page_size]
                 woff[j] = p % self._page_size
             with _tracing.begin("engine.prefill:launch"):
-                sampled, logits, pk, pv = self._jit_chunk(
+                sampled, logits, pk, pv, _extras = self._jit_chunk(
                     self._params, self._pool_k, self._pool_v,
                     self._page_table[slot:slot + 1].copy(), chunk,
                     np.asarray([filled], np.int32), wpage, woff,
@@ -1296,9 +1502,14 @@ class PagedGenerationEngine:
             if not final:
                 st["filled"] = filled + count
                 return None
+            del self._pending[slot]
+            if self._block > 1:
+                # no token comes of a prompt's last chunk: its tail
+                # opens the first block, which decode_step denoises
+                self._open_first_block(slot, toks, n)
+                return slot, None
             with _tracing.begin("engine.prefill:readback"):
                 tok = int(np.asarray(sampled)[0, count - 1])
-            del self._pending[slot]
             self._pos[slot] = n
             self._cur_tok[slot] = tok
             self._active[slot] = True
@@ -1308,15 +1519,27 @@ class PagedGenerationEngine:
             self._note_occupancy()
         return slot, tok
 
+    def _open_first_block(self, slot, token_ids, prefilled):
+        """Block-diffusion: the prompt's whole blocks are in the cache;
+        its last ``n mod block_length`` tokens open the first block as
+        given positions, the rest of it masked."""
+        self._pos[slot] = self._read_pos[slot] = prefilled
+        self._blocks.open(slot, token_ids[prefilled:])
+        self._active[slot] = True
+        if self._prefix_share:
+            self._register_prefix(slot, token_ids, token_ids.size)
+        self._note_occupancy()
+
     def admit(self, token_ids, slot=None):
         """Synchronous admission (ring-engine drop-in): claim a slot
         and run every prefill chunk back to back.  Returns
         ``(slot, first_token)``."""
         sl = self.admit_incremental(token_ids)
-        while True:
+        while sl in self._pending:
             res = self.prefill_step(slot=sl)
             if res is not None:
                 return res
+        return sl, None
 
     def decode_step(self):
         """One fixed-shape step for every active slot.  Returns
@@ -1326,7 +1549,10 @@ class PagedGenerationEngine:
         drafts leave K/V at positions >= the new ``pos``; those entries
         are masked by ``start`` and overwritten as decode advances."""
         if not self._active.any():
+            self._inflight.clear()    # no one is left to read them for
             return {}
+        if self._block > 1:
+            return self._decode_blocks()
         B, K = self._slots, self._spec_k
         cap = min(self._capacity, self.model_config["max_len"])
         active = [int(b) for b in np.nonzero(self._active)[0]]
@@ -1361,7 +1587,7 @@ class PagedGenerationEngine:
                 table = self._page_table.copy()
                 pos = self._pos.astype(np.int32).copy()
             with _tracing.begin("engine.decode:launch"):
-                sampled, logits, pk, pv = self._jit_chunk(
+                sampled, logits, pk, pv, _extras = self._jit_chunk(
                     self._params, self._pool_k, self._pool_v,
                     table, tokens, pos, wpage, woff, key)
                 self._pool_k, self._pool_v = pk, pv
@@ -1394,6 +1620,138 @@ class PagedGenerationEngine:
         _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
 
+    def _decode_blocks(self):
+        """Launch one pass of every active slot's open block, all in the
+        one ``(slots, block_length)`` program whatever pass each slot is
+        on, then read what the oldest pass still unread gave.
+        A **denoise pass** feeds the block with its masked positions as
+        the mask token against the committed cache, and of the masked
+        positions fixes the ``ceil(masked at block start / T)`` most
+        confident (ties to the lower position) at their best token; its
+        K/V rows go to the trash page.  After ``T`` of them nothing is
+        masked, and a **commit pass** runs the clean block, writes its
+        K/V to the slot's pages, and emits the block.  The blocks stay
+        on the device and which pass a slot is on follows from counts
+        alone, so a pass is queued behind the ones before it without the
+        host having read them: a call leaves ``passes_ahead`` passes
+        unread, and launches none for a slot whose request's last block
+        (``admit_incremental``'s ``max_new``, the cache's end) has its
+        commit launched.  Returns, for the pass read, ``{slot: []}``
+        for a slot whose block stayed open and a :class:`BlockTokens`
+        for one that committed; a slot whose occupant left meanwhile is
+        not in it, and a call with no pass to read yet returns
+        ``{slot: []}`` for every active slot.  ``last_pass`` and
+        ``last_logits`` are of the pass read."""
+        import jax
+
+        Bl, T = self._block, self._denoise_steps
+        cap = min(self._capacity, self.model_config["max_len"])
+        active = [int(b) for b in np.nonzero(self._active)[0]]
+        with _tracing.begin("engine.decode", args={
+                "slots": len(active),
+                "live": int(self._pos[active].sum())}) as step:
+            blk, on = self._blocks, self._active & ~self._drained
+            if on.any():
+                with _tracing.begin("engine.decode:prep"):
+                    # a slot whose T denoise passes are done commits:
+                    # its rows go to its pages, every other row to the
+                    # trash
+                    commit = on & (blk.passes >= T)
+                    run = on & ~commit
+                    take = np.where(run, np.minimum(
+                        -(-blk.masked_at_start // T), blk.left), 0)
+                    # a slot no pass is launched for reads as a block of
+                    # zeros with nothing masked
+                    fresh = blk.fresh | ~on
+                    given = np.where(on, blk.given, Bl).astype(np.int32)
+                    tokens = blk.tokens * (fresh & on)[:, None]
+                    number = blk.passes + 1
+                    p = self._pos[:, None] + np.arange(Bl)
+                    wpage = np.where(commit[:, None], np.take_along_axis(
+                        self._page_table, np.minimum(
+                            p // self._page_size, self._pages_per_slot - 1),
+                        axis=1), 0).astype(np.int32).reshape(-1)
+                    woff = np.where(commit[:, None], p % self._page_size,
+                                    0).astype(np.int32).reshape(-1)
+                    key = self._lane_keys.copy()
+                    table = self._page_table.copy()
+                    pos = self._pos.astype(np.int32).copy()
+                    launched = {
+                        "on": on, "commit": commit, "start": pos,
+                        "pass": number, "given": blk.given.copy(),
+                        "serial": self._serial.copy()}
+                    # the schedule moves on by counts, the pass unread
+                    blk.fresh[on] = False
+                    blk.left -= take
+                    blk.passes[run] += 1
+                    for b in np.nonzero(commit)[0]:
+                        self._budget[b] -= Bl - blk.given[b]
+                        self._pos[b] += Bl
+                        self._drained[b] = self._budget[b] <= 0 \
+                            or self._pos[b] + Bl > cap
+                        blk.open(b)
+                    step.set(denoise=int(run.sum()),
+                             commit=int(commit.sum()))
+                    _telemetry.DECODE_DENOISE_PASSES.inc(int(run.sum()))
+                    _telemetry.DECODE_COMMIT_PASSES.inc(int(commit.sum()))
+                    _telemetry.DECODE_BATCH_TOKENS.observe(len(active))
+                with _tracing.begin("engine.decode:launch"):
+                    _sampled, logits, pk, pv, extras = self._jit_chunk(
+                        self._params, self._pool_k, self._pool_v,
+                        table, tokens, pos, wpage, woff, key,
+                        (self._block_state, fresh, given,
+                         take.astype(np.int32), number))
+                    self._pool_k, self._pool_v = pk, pv
+                    self._block_state = now = extras["block"]
+                    launched["logits"] = logits
+                    launched["read"] = (now[0], now[2], now[3],
+                                        extras["masked"],
+                                        extras.get("expert_load"))
+                    self._inflight.append(launched)
+            else:
+                step.set(denoise=0, commit=0)
+            # with nothing launched (every request's last commit is
+            # under way) the passes in flight are read one a call
+            ahead = self.passes_ahead if on.any() else 0
+            if len(self._inflight) <= ahead:
+                step.set(emitted=0)
+                out = {b: [] for b in active}
+            else:
+                read = self._inflight.popleft()
+                with _tracing.begin("engine.decode:readback"):
+                    # one wait for the small arrays, not one each
+                    toks, at, conf, masked, load = jax.device_get(
+                        read["read"])
+                with _tracing.begin("engine.decode:post"):
+                    # a slot evicted since the launch has another serial
+                    mine = read["on"] & (read["serial"] == self._serial) \
+                        & self._active
+                    out = {int(b): [] for b in np.nonzero(mine)[0]}
+                    emitted_total = 0
+                    for b in np.nonzero(mine & read["commit"])[0]:
+                        g = read["given"][b]
+                        out[int(b)] = emitted = BlockTokens(
+                            toks[b, g:].tolist(), at[b, g:].tolist(),
+                            conf[b, g:].tolist())
+                        emitted_total += len(emitted)
+                        self._history[int(b)].extend(emitted)
+                        self._read_pos[b] = read["start"][b] + Bl
+                    self._last_logits = read["logits"]
+                    self.last_pass = {
+                        "on": read["on"], "start": read["start"],
+                        "pass": read["pass"], "masked": masked}
+                    step.set(emitted=emitted_total)
+                    if load is not None:
+                        step.set(expert_load_max=int(load.max()),
+                                 expert_load_mean=float(load.mean()))
+                    _telemetry.DECODE_BLOCKS_COMMITTED.inc(
+                        int((mine & read["commit"]).sum()))
+                    _telemetry.DECODE_BLOCK_TOKENS.inc(emitted_total)
+                    _telemetry.DECODE_TOKENS.inc(emitted_total)
+                    self._note_occupancy()
+        _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
+        return out
+
     def evict(self, slot, reason):
         """Free ``slot`` (mid-prefill pendings included): drop its
         refcounts, return private pages to the free list, park
@@ -1405,6 +1763,7 @@ class PagedGenerationEngine:
         self._history.pop(slot, None)
         self._active[slot] = False
         self._pos[slot] = 0
+        self._serial[slot] += 1
         self._release_slot_pages(slot)
         # LIFO slot reuse, same reproducibility rationale as the ring
         self._free.appendleft(int(slot))
@@ -1427,7 +1786,7 @@ class PagedGenerationEngine:
         """The ``(rows, chunk)`` token shapes the one dispatch is
         compiled for: a prefill chunk, a decode step, and the verify
         step when speculation is on."""
-        shapes = [(1, self._chunk), (self._slots, 1)]
+        shapes = [(1, self._chunk), (self._slots, self._block)]
         if self._spec_k > 0:
             shapes.append((self._slots, self._spec_k + 1))
         return shapes
@@ -1437,11 +1796,17 @@ class PagedGenerationEngine:
         beside the engine's own parameters and pools: what a compile
         that runs nothing needs."""
         nb, nc = shape
-        return (self._params, self._pool_k, self._pool_v,
+        args = (self._params, self._pool_k, self._pool_v,
                 np.zeros((nb, self._pages_per_slot), np.int32),
                 np.zeros((nb, nc), np.int32), np.zeros(nb, np.int32),
                 np.zeros(nb * nc, np.int32), np.zeros(nb * nc, np.int32),
                 np.zeros((nb, 2), np.uint32))
+        if self._block > 1 and shape == (self._slots, self._block):
+            # a pass of block-diffusion decoding: the open blocks too
+            args += ((self._block_state, np.zeros(nb, bool),
+                      np.zeros(nb, np.int32), np.zeros(nb, np.int32),
+                      np.zeros(nb, np.int32)),)
+        return args
 
 
 # ---------------------------------------------------------------------------
@@ -1451,7 +1816,10 @@ class PagedGenerationEngine:
 class GenerationResult(dict):
     """Resolution payload of one generation request: ``tokens`` (ids,
     prompt excluded), ``finish_reason`` (``eos`` / ``length``),
-    ``ttft_s`` (submit -> first token)."""
+    ``ttft_s`` (submit -> first token); under block-diffusion decoding
+    also ``fixed_at``, the denoise pass (1..T) of its block at which
+    each token was fixed, and ``confidence``, the log-probability the
+    model gave the token in that pass."""
 
     @property
     def tokens(self):
@@ -1469,7 +1837,7 @@ class GenerationResult(dict):
 class _GenRequest:
     __slots__ = ("tokens", "future", "deadline", "t_submit", "max_new",
                  "out", "slot", "ttft", "span", "t_pickup", "prefix_hit",
-                 "on_token")
+                 "on_token", "fixed_at", "confidence")
 
     def __init__(self, tokens, deadline, max_new, span=None,
                  on_token=None):
@@ -1485,6 +1853,8 @@ class _GenRequest:
         self.t_pickup = None       # queue -> prefill pickup time
         self.prefix_hit = None     # prompt tokens served by prefix pages
         self.on_token = on_token   # streaming observer (gateway SSE)
+        self.fixed_at = []         # block-diffusion: pass of each token
+        self.confidence = []       # ... and its log-probability there
 
 
 class TokenServer:
@@ -1712,9 +2082,12 @@ class TokenServer:
 
     def _finish(self, req, reason):
         _telemetry.DECODE_REQUESTS_FINISHED.inc(reason=reason)
-        if req.future._resolve(result=GenerationResult(
-                tokens=list(req.out), finish_reason=reason,
-                ttft_s=req.ttft)):
+        result = GenerationResult(tokens=list(req.out),
+                                  finish_reason=reason, ttft_s=req.ttft)
+        if req.fixed_at:
+            result["fixed_at"] = list(req.fixed_at)
+            result["confidence"] = list(req.confidence)
+        if req.future._resolve(result=result):
             self._emit_event(req, outcome="ok", reason=reason)
 
     def _fail(self, req, exc, stage=None):
@@ -1793,7 +2166,8 @@ class TokenServer:
                 # tick (the TTFT clock keeps running until the chunk
                 # that completes the prompt samples the first token)
                 try:
-                    slot = eng.admit_incremental(req.tokens)
+                    slot = eng.admit_incremental(req.tokens,
+                                                 max_new=req.max_new)
                 except ServingError as e:
                     self._fail(req, e)
                     continue
@@ -1826,9 +2200,13 @@ class TokenServer:
             self._deliver(req, slot, tok)
         return admitted
 
-    def _deliver(self, req, slot, tok):
+    def _deliver(self, req, slot, tok, last=True, fixed=None):
         """Append one generated token and apply the finish/evict
-        rules.  Returns False when the request left its slot."""
+        rules.  Returns False when the request left its slot.  ``last``
+        is false for all but the last token of a burst (speculation, a
+        committed block): the engine has advanced past the whole burst,
+        so the cache's capacity is asked after only at its end.
+        ``fixed`` is a block-diffusion token's (pass, confidence)."""
         eng = self._engine
         if req.future.done():                      # cancelled mid-run
             self._release(slot)
@@ -1843,6 +2221,17 @@ class TokenServer:
             eng.evict(slot, "deadline")
             return False
         req.out.append(tok)
+        if fixed is not None:
+            req.fixed_at.append(fixed[0])
+            req.confidence.append(fixed[1])
+        if req.ttft is None:
+            # block-diffusion: a prompt's last chunk yields no token,
+            # the first ones come of the first block's commit
+            req.ttft = now - req.t_submit
+            _telemetry.DECODE_TTFT_SECONDS.observe(
+                req.ttft, exemplar={"trace_id": _tracing.TRACE_ID,
+                                    "span_id": req.span.span_id}
+                if req.span is not None else None)
         if req.on_token is not None:
             try:
                 req.on_token(tok)
@@ -1855,7 +2244,8 @@ class TokenServer:
             self._release(slot)
             eng.evict(slot, "eos")
             return False
-        if len(req.out) >= req.max_new or eng.at_capacity(slot):
+        if len(req.out) >= req.max_new or \
+                (last and eng.at_capacity(slot)):
             self._finish(req, "length")
             self._release(slot)
             eng.evict(slot, "length")
@@ -1898,6 +2288,8 @@ class TokenServer:
         if req is None:
             eng.evict(slot, "cancelled")
             return
+        if tok is None:
+            return      # block-diffusion: the first block is now open
         req.ttft = time.monotonic() - req.t_submit
         ex = {"trace_id": _tracing.TRACE_ID,
               "span_id": req.span.span_id} \
@@ -1962,12 +2354,17 @@ class TokenServer:
             if req is None:
                 self._engine.evict(slot, "cancelled")
                 continue
-            # paged engines may emit several verified tokens per step;
-            # _deliver's finish rules apply per token (speculative
-            # overshoot past eos/max_new is truncated here, so output
-            # matches non-spec)
-            for t in (tok if isinstance(tok, list) else [tok]):
-                if not self._deliver(req, slot, t):
+            # paged engines may emit several tokens per step (verified
+            # drafts; a committed block, or none while a block is
+            # open); _deliver's finish rules apply per token, so the
+            # overshoot past eos/max_new is truncated here
+            burst = tok if isinstance(tok, list) else [tok]
+            fixed = list(zip(tok.fixed_at, tok.confidence)) \
+                if isinstance(tok, BlockTokens) else None
+            for i, t in enumerate(burst):
+                if not self._deliver(
+                        req, slot, t, last=i == len(burst) - 1,
+                        fixed=fixed[i] if fixed else None):
                     break
 
     # -- lifecycle -------------------------------------------------------

@@ -1,0 +1,4 @@
+"""Language models of the zoo (decoder-only; served by
+``mxnet_tpu.generate.PagedGenerationEngine`` through the chunk
+protocol)."""
+from .moe_decoder import MoEDecoderLM  # noqa: F401

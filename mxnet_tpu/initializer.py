@@ -83,23 +83,20 @@ class Initializer:
     # of one transfer per parameter at init time.
     @staticmethod
     def _set(arr, value):
-        npv = np.asarray(value).astype(np.dtype(arr.dtype)).reshape(arr.shape)
+        npv = np.asarray(value).astype(np.dtype(arr.dtype), copy=False) \
+            .reshape(arr.shape)
         arr._rebind(npv)
 
+    # constants are made in the array's own type: a float64 detour
+    # through ``astype`` costs seconds a billion values (bfloat16 most)
     def _init_zero(self, _, arr):
-        self._set(arr, np.zeros(arr.shape))
+        self._set(arr, np.zeros(arr.shape, np.dtype(arr.dtype)))
 
     def _init_one(self, _, arr):
-        self._set(arr, np.ones(arr.shape))
+        self._set(arr, np.ones(arr.shape, np.dtype(arr.dtype)))
 
-    def _init_bias(self, _, arr):
-        self._set(arr, np.zeros(arr.shape))
-
-    def _init_gamma(self, _, arr):
-        self._set(arr, np.ones(arr.shape))
-
-    def _init_beta(self, _, arr):
-        self._set(arr, np.zeros(arr.shape))
+    _init_bias = _init_beta = _init_zero
+    _init_gamma = _init_one
 
     def _init_weight(self, desc, arr):
         raise NotImplementedError
@@ -111,18 +108,12 @@ class Initializer:
 
 @register
 class Zero(Initializer):
-    def _init_weight(self, _, arr):
-        self._set(arr, np.zeros(arr.shape))
-
-    _init_default = _init_weight
+    _init_weight = _init_default = Initializer._init_zero
 
 
 @register
 class One(Initializer):
-    def _init_weight(self, _, arr):
-        self._set(arr, np.ones(arr.shape))
-
-    _init_default = _init_weight
+    _init_weight = _init_default = Initializer._init_one
 
 
 @register

@@ -18,4 +18,4 @@ from .ring_attention import (ring_attention, ring_attention_sharded,  # noqa: F4
                              local_attention)
 from .pipeline import pipeline_forward, gpipe_loss  # noqa: F401
 from .ulysses import ulysses_attention, ulysses_attention_sharded  # noqa: F401
-from .moe import moe_ffn, moe_ffn_sharded  # noqa: F401
+from .moe import moe_ffn, moe_ffn_sharded, routed_experts  # noqa: F401

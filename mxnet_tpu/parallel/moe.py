@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["moe_ffn", "moe_ffn_sharded"]
+__all__ = ["moe_ffn", "moe_ffn_sharded", "routed_experts"]
 
 
 def _check_top_k(top_k, n_experts):
@@ -159,3 +159,60 @@ def moe_ffn_sharded(mesh, x, gate_w, w_in, w_out, axis_name="ep",
         out_specs=(P(axis_name, None), P()),
         check_vma=False)
     return fn(x, gate_w, w_in, w_out)
+
+
+def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
+                   first=0, norm_topk=True):
+    """The part that the experts held here add to a gated top-k expert
+    layer, with no capacity and no token dropped: what the serving path
+    calls (``gluon.model_zoo.language.MoEDecoderLM``).
+
+    The router is over ALL experts; this caller holds the experts
+    ``[first, first + held)`` (``held`` = the matrices' width over
+    ``d_expert``) and computes their part of the result for
+    the tokens routed to them.  The parts of all holders add up to the
+    whole layer's output (on one chip that holds every expert, the part
+    is the whole).  Shapes are static: every held expert multiplies
+    every token, and a token's weight for an expert it was not routed
+    to is zero, so the one compiled program serves any routing.
+
+      x: (tokens, d_model)
+      router_w: (d_model, n_experts), every expert's column
+      w_gate, w_up: (d_model, held * d_expert), expert by expert
+      w_down: (held * d_expert, d_model)
+    (two dimensions each: the TPU lays a (d_model, held, d_expert) array
+    out in tiles over its last two dimensions, and the program would
+    copy every expert matrix to multiply by it)
+    Returns ``(out, counts)``:
+      out: (tokens, d_model), ``sum_e w_e * down_e(silu(gate_e x) *
+        up_e x)`` over the held experts among each token's ``top_k``
+        (``w`` the router's softmax, over float32, renormalised over
+        the chosen ones with ``norm_topk``)
+      counts: (n_experts,) int32, the tokens routed to each expert of
+        the whole layer in this call.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    n_exp = router_w.shape[-1]
+    _check_top_k(top_k, n_exp)
+    T, F = x.shape[0], int(d_expert)
+    held = w_down.shape[0] // F
+    f32 = jnp.float32
+    logits = jnp.dot(x, router_w, preferred_element_type=f32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = lax.top_k(probs, top_k)                    # (T, k)
+    if norm_topk:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    chosen = top_i[:, :, None] == jnp.arange(n_exp, dtype=top_i.dtype)
+    counts = chosen.sum((0, 1)).astype(jnp.int32)             # (E,)
+    combine = jnp.where(chosen, top_p[:, :, None], 0.0).sum(1)  # (T, E)
+    mine = lax.dynamic_slice_in_dim(combine, first, held, axis=1)
+    # all held experts as two plain matrix products over (held * F)
+    g = jnp.dot(x, w_gate, preferred_element_type=f32)
+    u = jnp.dot(x, w_up, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).reshape((T, held, F)) * mine[:, :, None]
+    out = jnp.dot(h.reshape((T, held * F)).astype(x.dtype), w_down,
+                  preferred_element_type=f32)
+    return out.astype(x.dtype), counts

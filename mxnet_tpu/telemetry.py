@@ -867,11 +867,13 @@ DECODE_TTFT_SECONDS = histogram(
     "TTFT burn-rate shedder).")
 DECODE_TOKENS = counter(
     "mxnet_tpu_decode_tokens_total",
-    "Tokens generated across all decode slots.")
+    "Tokens generated across all decode slots (under block-diffusion "
+    "decoding: tokens of committed blocks, not passes).")
 DECODE_STEP_SECONDS = histogram(
     "mxnet_tpu_decode_step_seconds",
     "Wall time of one fixed-shape decode step (all slots advance one "
-    "token), prep to post: read from the engine.decode span.")
+    "token, or one pass of their open block), prep to post: read from "
+    "the engine.decode span.")
 DECODE_BATCH_TOKENS = histogram(
     "mxnet_tpu_decode_batch_tokens",
     "Active slots per decode step (the continuous-batching batch-size "
@@ -909,6 +911,23 @@ DECODE_SPEC_ACCEPTED = counter(
     "Drafted tokens accepted by exact-match verification (acceptance "
     "rate = this over drafted; each accepted token is one decode "
     "dispatch saved).")
+DECODE_DENOISE_PASSES = counter(
+    "mxnet_tpu_decode_denoise_passes_total",
+    "Block-diffusion decoding: slot-passes that ran a slot's open block "
+    "against the committed cache and fixed its most confident masked "
+    "positions (their K/V rows go to the trash page).")
+DECODE_COMMIT_PASSES = counter(
+    "mxnet_tpu_decode_commit_passes_total",
+    "Block-diffusion decoding: slot-passes that ran a clean block and "
+    "wrote its K/V to the slot's pages (their logits are not used).")
+DECODE_BLOCKS_COMMITTED = counter(
+    "mxnet_tpu_decode_blocks_committed_total",
+    "Block-diffusion decoding: blocks committed to the cache.")
+DECODE_BLOCK_TOKENS = counter(
+    "mxnet_tpu_decode_block_tokens_total",
+    "Block-diffusion decoding: tokens emitted by committed blocks (a "
+    "block's positions less the prompt's given ones; tokens per "
+    "slot-pass = this over denoise + commit passes).")
 
 # device memory (sampled per train step by tracing.sample_device_memory)
 DEVICE_MEMORY_BYTES_IN_USE = gauge(
@@ -1279,6 +1298,11 @@ def statusz():
                                  if drafted else None)(
                 DECODE_SPEC_ACCEPTED.value(),
                 DECODE_SPEC_DRAFTED.value()),
+            # block-diffusion decoding: passes are not tokens
+            "denoise_passes": DECODE_DENOISE_PASSES.value(),
+            "commit_passes": DECODE_COMMIT_PASSES.value(),
+            "blocks_committed": DECODE_BLOCKS_COMMITTED.value(),
+            "block_tokens": DECODE_BLOCK_TOKENS.value(),
         },
         "checkpoint": {
             "async_queue_depth": CHECKPOINT_QUEUE_DEPTH.value(),

@@ -527,7 +527,8 @@ def test_manifest_checks_and_cells_report_the_new_metrics():
     man = manifest.manifest()
     assert manifest.check(man)
     names = [m["name"] for m in man["per_layer"]]
-    assert names[-10:] == list(NEW_METRICS) and len(names) == 27
+    # PR 26's ten follow the 17 of PR 24; PR 28 appended four more
+    assert names[17:27] == list(NEW_METRICS) and len(names) == 31
     train = {m["name"] for m in
              manifest.metrics_of(man, "per_layer", "resnet50_train_b256")}
     serve = {m["name"] for m in

@@ -444,19 +444,73 @@ def wide_engine():
         sampling=generate.SamplingConfig(greedy=True))
 
 
+@pytest.fixture(scope="module")
+def wide_moe_engine():
+    """The block-diffusion serving cell's engine (ISSUE 28) at
+    SDAR-30B-A3B's widths and the cell's sizes: d_model 2048, 32 query
+    and 4 key/value heads of 128, its 6 layers (the pool's rows depend
+    on them), experts of width 768, blocks of 4, 32 slots of 1024
+    positions, page 16, 2049 pages, prefill chunks of 256, bf16 weights
+    and cache; cut to 8 experts (top 2) and a small vocabulary."""
+    from mxnet_tpu.gluon.model_zoo.language import MoEDecoderLM
+
+    net = MoEDecoderLM(
+        vocab_size=256, d_model=2048, n_layers=6, n_heads=32, n_kv_heads=4,
+        d_head=128, n_experts=8, top_k=2, d_expert=768, block_length=4,
+        mask_token_id=255, max_len=1024, dtype="bfloat16")
+    net.initialize(mx.init.Zero())
+    return generate.PagedGenerationEngine(
+        net, slots=32, cache_len=1024, page_size=16, num_pages=2049,
+        prefill_chunk=256, spec_k=0, dtype_policy="bf16_mixed",
+        denoise_steps=2, sampling=generate.SamplingConfig(greedy=True))
+
+
+# the programs' temporaries at those sizes (compiled.memory_analysis()):
+# the gathered views of 32 x 1024 rows and their relayouts (decode), one
+# slot's view and a chunk's expert activations (prefill)
+MOE_TEMP_BYTES = {"decode": 1.4e9, "prefill": 0.5e9}
+
+
+class _time_limit:
+    """Fail, rather than hang the suite, when the compile for the
+    described chip takes longer than ``seconds``."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def __enter__(self):
+        import signal
+
+        def late(_sig, _frame):
+            raise TimeoutError("no compile in %d s" % self.seconds)
+
+        self.was = signal.signal(signal.SIGALRM, late)
+        signal.alarm(self.seconds)
+
+    def __exit__(self, *exc):
+        import signal
+
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, self.was)
+
+
 @pytest.mark.parametrize("shape", ["decode", "prefill"])
-def test_tpu_program_copies_no_pool(v5e_chip, wide_engine, shape):
+@pytest.mark.parametrize("model", ["opt", "moe"])
+def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     """What the chip's compiler makes of the dispatch (optimized HLO for
     a described v5e, nothing runs): the pool keeps a row-major layout
     with tokens outermost, and besides the parameter, the in-place
     scatter and the result no operation has the pool's size: no copy,
-    no loop that gathers page by page, no buffer of zeros."""
+    no loop that gathers page by page, no buffer of zeros.  For the
+    block-diffusion cell's model the temporaries stay under a stated
+    size as well."""
     import re
 
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    eng = wide_engine
+    eng = request.getfixturevalue(
+        "wide_engine" if model == "opt" else "wide_moe_engine")
     shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
 
     def struct(a):
@@ -470,7 +524,9 @@ def test_tpu_program_copies_no_pool(v5e_chip, wide_engine, shape):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        text = eng._jit_chunk.lower(*args).compile().as_text()
+        with _time_limit(300):
+            compiled = eng._jit_chunk.lower(*args).compile()
+        text = compiled.as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
@@ -489,6 +545,13 @@ def test_tpu_program_copies_no_pool(v5e_chip, wide_engine, shape):
     assert len(scatter_fusions) == 2
     assert text.count("may-alias") + text.count("must-alias") >= 2, \
         "the donated pools are not aliased to the results"
+    if model == "moe":
+        assert eng.pool_shape == (2049 * 16, 6 * 4, 128)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < MOE_TEMP_BYTES[shape], temp
+        # no expert matrix is copied to be multiplied
+        assert not re.findall(r"= bf16\[2048,6144\]\S* copy\(", entry)
+        assert not re.findall(r"= bf16\[6144,2048\]\S* copy\(", entry)
 
 
 # ---------------------------------------------------------------------------
