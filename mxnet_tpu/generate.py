@@ -222,6 +222,44 @@ def _parse_buckets(spec, cache_len):
 
 
 # ---------------------------------------------------------------------------
+# the weights an engine holds
+# ---------------------------------------------------------------------------
+
+def _cast_weights(names, tree, policy):
+    """Every parameter of ``tree`` in the dtype ``policy``'s rules give
+    it (``param_cast_dtype``); one already there, one that is not
+    floating and all of them without a policy are the arrays handed in."""
+    if policy is None:
+        return tree
+    return tuple(policy.cast_compute(n, a) for n, a in zip(names, tree))
+
+
+def _hold_weights(names, placed, policy):
+    """The tuple an engine holds and hands to every dispatch: the
+    parameters ``placed`` (on their device, under their sharding) as
+    the dispatch itself casts them (:func:`_cast_weights`), cast here
+    once, on the device, each keeping its sharding.  Serving never
+    updates a weight, so the cast has one result for the engine's life;
+    a program handed arrays at their targets traces with no ``convert``
+    on a weight, and reads no float32 master.  A parameter already at
+    its target is held as the very buffer it is: nothing is copied.
+    Commits the ``engine.weights`` span: ``held_bytes``, ``cast_bytes``
+    (the bytes of the copies made) and ``aliased`` (the parameters kept
+    as handed over)."""
+    import jax
+
+    with _tracing.begin("engine.weights") as sp:
+        held = _cast_weights(names, placed, policy)
+        jax.block_until_ready(held)
+        kept = [h is a for h, a in zip(held, placed)]
+        sp.set(held_bytes=sum(int(h.nbytes) for h in held),
+               cast_bytes=sum(int(h.nbytes) for h, k in zip(held, kept)
+                              if not k),
+               aliased=sum(kept))
+    return held
+
+
+# ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
 
@@ -235,6 +273,16 @@ class GenerationEngine:
     token; :meth:`evict` frees a lane.  All device state (cache) is
     donated through the jit sites, which thread ``aot=`` /
     ``dtype_policy=`` like every other front end.
+
+    The weights are a snapshot taken when the engine is built, held in
+    the dtype the policy computes in: each parameter is cast once, on
+    the device, to what the policy's rules give it (bfloat16 under
+    ``bf16_mixed``, float32 where a rule keeps it), and no program
+    reads a float32 master.  A parameter handed over at its target is
+    held as the buffer it is.  A caller who keeps float32 masters in
+    the network pays for both, the masters and the engine's copy (1.5 x
+    the masters); hand the weights over in the compute dtype to hold
+    them once.
 
     Single-consumer: one thread drives the engine (TokenServer's loop,
     or a bench loop).  Admission control, deadlines, and futures live
@@ -315,7 +363,7 @@ class GenerationEngine:
             self.layout_name = layout_obj.name
             res = layout_obj.resolve(
                 [(p.name, tuple(p.shape)) for p in params], self._mesh)
-            self._params = tuple(
+            placed = tuple(
                 jax.device_put(p.data()._data,
                                NamedSharding(self._mesh, res.spec(p.name)))
                 for p in params)
@@ -327,10 +375,10 @@ class GenerationEngine:
         else:
             self.layout_name = None
             dev = device if device is not None else jax.devices()[0]
-            self._params = tuple(
+            placed = tuple(
                 jax.device_put(p.data()._data, dev) for p in params)
             self._cache_sharding = dev
-        jax.block_until_ready(self._params)
+        self._params = _hold_weights(self._param_names, placed, dt_policy)
         self._cache_k = jax.device_put(
             jnp.zeros(cache_shape, self._cache_dtype),
             self._cache_sharding)
@@ -349,20 +397,15 @@ class GenerationEngine:
         scfg = self.sampling
         vocab = cfg["vocab_size"]
 
-        def _cast_params(tree):
-            if dt_policy is None:
-                return tree
-            return tuple(dt_policy.cast_compute(n, a) for n, a in
-                         zip(self._param_names, tree))
-
         def _traced(fn, params_):
             """Run ``fn`` with the model's parameters swapped to the
             (policy-cast) traced arrays — the shared param-swap trace
             recipe (gluon.block.swapped_params) under the dtype-policy
             scope."""
             with _dtp.scope(dt_policy), \
-                    block_mod.swapped_params(gluon_params,
-                                             _cast_params(params_)):
+                    block_mod.swapped_params(
+                        gluon_params, _cast_weights(
+                            self._param_names, params_, dt_policy)):
                 return fn()
 
         def _cast_logits(arr):
@@ -452,6 +495,11 @@ class GenerationEngine:
     @property
     def cache_len(self):
         return self._cache_len
+
+    @property
+    def param_bytes(self):
+        """Bytes of the weights the engine holds, as it holds them."""
+        return sum(int(a.nbytes) for a in self._params)
 
     @property
     def buckets(self):
@@ -786,6 +834,18 @@ class PagedGenerationEngine:
     refcount drops to zero stay cached (LRU) until pool pressure
     reclaims them.
 
+    **The weights** are a snapshot taken when the engine is built, held
+    in the dtype the policy computes in: each parameter is cast once, on
+    the device and under its sharding, to what the policy's rules give
+    it (bfloat16 under ``bf16_mixed``, float32 where a rule keeps it),
+    so no dispatch reads a float32 master or casts a weight
+    (``param_bytes``; the ``engine.weights`` span says what was cast).
+    A parameter handed over at its target is held as the buffer it is,
+    never copied.  A caller who keeps float32 masters in the network
+    pays for both, the masters and the engine's copy (1.5 x the
+    masters); hand the weights over in the compute dtype to hold them
+    once.
+
     Greedy decode is token-identical to :class:`GenerationEngine` on
     the same model.  Single-consumer, like the ring engine.
     """
@@ -949,7 +1009,7 @@ class PagedGenerationEngine:
             self.layout_name = layout_obj.name
             res = layout_obj.resolve(
                 [(p.name, tuple(p.shape)) for p in params], self._mesh)
-            self._params = tuple(
+            placed = tuple(
                 jax.device_put(p.data()._data,
                                NamedSharding(self._mesh, res.spec(p.name)))
                 for p in params)
@@ -961,10 +1021,10 @@ class PagedGenerationEngine:
         else:
             self.layout_name = None
             dev = device if device is not None else jax.devices()[0]
-            self._params = tuple(
+            placed = tuple(
                 jax.device_put(p.data()._data, dev) for p in params)
             self._pool_sharding = dev
-        jax.block_until_ready(self._params)
+        self._params = _hold_weights(self._param_names, placed, dt_policy)
         self._pool_k = jax.device_put(
             jnp.zeros(pool_shape, self._cache_dtype), self._pool_sharding)
         self._pool_v = jax.device_put(
@@ -1028,16 +1088,11 @@ class PagedGenerationEngine:
         page = self._page_size
         mask_id = int(cfg["mask_token_id"]) if Bl > 1 else None
 
-        def _cast_params(tree):
-            if dt_policy is None:
-                return tree
-            return tuple(dt_policy.cast_compute(n, a) for n, a in
-                         zip(self._param_names, tree))
-
         def _traced(fn, params_):
             with _dtp.scope(dt_policy), \
-                    block_mod.swapped_params(gluon_params,
-                                             _cast_params(params_)):
+                    block_mod.swapped_params(
+                        gluon_params, _cast_weights(
+                            self._param_names, params_, dt_policy)):
                 return fn()
 
         def _cast_logits(arr):
@@ -1199,6 +1254,11 @@ class PagedGenerationEngine:
     def pool_shape(self):
         """Shape of each of the K and V pools on the device."""
         return tuple(self._pool_k.shape)
+
+    @property
+    def param_bytes(self):
+        """Bytes of the weights the engine holds, as it holds them."""
+        return sum(int(a.nbytes) for a in self._params)
 
     @property
     def prefill_chunk(self):

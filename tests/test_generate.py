@@ -310,6 +310,76 @@ def test_engine_tp_mesh_matches_single_device(lm, eng):
 
 
 # ---------------------------------------------------------------------------
+# the weights the ring engine holds (ISSUE 29; both engines side by side
+# in tests/test_paged_decode.py)
+# ---------------------------------------------------------------------------
+
+def _tokens(e, prompt, n):
+    slot, tok = e.admit(prompt)
+    out = [tok] + [e.decode_step()[slot] for _ in range(n)]
+    e.evict(slot, "length")
+    return out
+
+
+def test_ring_engine_weights_are_a_snapshot_in_the_compute_dtype():
+    """The engine holds what the parameters were when it was built, cast
+    once: a later change to the network's float32 masters reaches no
+    dispatch, and the held weights take less than the masters."""
+    from mxnet_tpu import tracing
+
+    mx.random.seed(1)
+    net = TransformerLM(vocab_size=VOCAB, d_model=D_MODEL, n_heads=N_HEADS,
+                        n_layers=N_LAYERS, max_len=MAX_LEN)
+    net.initialize(mx.init.Xavier())
+    net(nd.array(np.zeros((1, 4), np.float32)))
+    e = generate.GenerationEngine(
+        net, slots=2, cache_len=16, buckets=[8], dtype_policy="bf16_mixed",
+        sampling=generate.SamplingConfig(greedy=True))
+    span = [r for r in tracing.records() if r["name"] == "engine.weights"][-1]
+    masters = sum(int(np.prod(p.shape)) * 4
+                  for p in net.collect_params().values())
+    assert e.param_bytes == span["args"]["held_bytes"] < masters
+    assert span["args"]["cast_bytes"] == masters - e.param_bytes > 0
+    prompt = _prompt(5, seed=9)
+    before = _tokens(e, prompt, 4)
+    for p in net.collect_params().values():
+        p.set_data(nd.array(np.zeros(p.shape, np.float32)))
+    assert _tokens(e, prompt, 4) == before
+    assert len(set(before)) > 1, "a model of zeros would pass as well"
+
+
+def test_ring_engine_mesh_prefill_same_on_held_and_master_weights(lm):
+    """dp=2,tp=2 under ``bf16_mixed``: a prefill on the held (cast)
+    weights gives bit for bit what the same program gives on the
+    float32 masters placed under the same shardings."""
+    import jax
+    import jax.numpy as jnp
+
+    e = generate.GenerationEngine(
+        lm, slots=2, cache_len=16, buckets=[8], mesh="dp=2,tp=2",
+        dtype_policy="bf16_mixed",
+        sampling=generate.SamplingConfig(greedy=True))
+    assert {str(a.dtype) for a in e._params} == {"bfloat16", "float32"}
+    masters = tuple(jax.device_put(p.data()._data, a.sharding)
+                    for p, a in zip(lm.collect_params().values(), e._params))
+    tokens = np.zeros((1, 8), np.int32)
+    tokens[0, :5] = _prompt(5, seed=3)
+
+    def prefill(weights):
+        tok, logits, ck, _cv = e._jit_prefill(
+            weights, jnp.copy(e._cache_k), jnp.copy(e._cache_v), tokens,
+            np.int32(5), np.int32(1), jax.random.PRNGKey(0))
+        return int(tok[0]), np.asarray(logits), \
+            np.asarray(ck.astype(np.float32))
+
+    got, want = prefill(e._params), prefill(masters)
+    assert got[0] == want[0]
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
+    assert np.abs(got[2]).max() > 0
+
+
+# ---------------------------------------------------------------------------
 # TokenServer: typed admission / deadlines / eviction / drain
 # ---------------------------------------------------------------------------
 

@@ -47,8 +47,7 @@ from transformer_lm import TransformerLM  # noqa: E402
 VOCAB, D_MODEL, N_HEADS, N_LAYERS, MAX_LEN = 48, 32, 2, 2, 24
 
 
-@pytest.fixture(scope="module")
-def lm():
+def _small_lm():
     mx.random.seed(0)
     net = TransformerLM(vocab_size=VOCAB, d_model=D_MODEL,
                         n_heads=N_HEADS, n_layers=N_LAYERS,
@@ -56,6 +55,11 @@ def lm():
     net.initialize(mx.init.Xavier())
     net(nd.array(np.zeros((1, 4), np.float32)))
     return net
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return _small_lm()
 
 
 @pytest.fixture(scope="module")
@@ -501,7 +505,8 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     a described v5e, nothing runs): the pool keeps a row-major layout
     with tokens outermost, and besides the parameter, the in-place
     scatter and the result no operation has the pool's size: no copy,
-    no loop that gathers page by page, no buffer of zeros.  For the
+    no loop that gathers page by page, no buffer of zeros.  No weight
+    enters as a float32 master to be cast in the program.  For the
     block-diffusion cell's model the temporaries stay under a stated
     size as well."""
     import re
@@ -545,6 +550,27 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     assert len(scatter_fusions) == 2
     assert text.count("may-alias") + text.count("must-alias") >= 2, \
         "the donated pools are not aliased to the results"
+    # the weights enter in the dtypes the policy's rules give them (ISSUE
+    # 29): float32 only where a rule keeps it, and nothing of a weight
+    # matrix's shape is converted to bfloat16 inside the program
+    policy = eng._dtype_policy
+    entered = {int(i): dt for dt, i in re.findall(
+        r"= (\w+)\[[\d,]*\]\S* parameter\((\d+)\)", entry)}
+    matrices = set()
+    for i, (name, a) in enumerate(zip(eng._param_names, eng._params)):
+        want = policy.param_cast_dtype(name, tuple(a.shape))
+        assert entered[i] == {"bfloat16": "bf16", "float32": "f32"}[
+            str(want)], (name, entered[i])
+        if entered[i] == "f32":
+            assert policy.rule_name(name, tuple(a.shape)), name
+        elif a.ndim > 1:
+            matrices.add(tuple(a.shape))
+    rows = int(np.prod(shapes[shape]))
+    matrices.discard((rows, eng.model_config["d_model"]))  # activations'
+    assert len(matrices) >= 4
+    for m in matrices:
+        assert not re.findall(r"= bf16\[%s\]\S* convert\("
+                              % ",".join(str(d) for d in m), text), m
     if model == "moe":
         assert eng.pool_shape == (2049 * 16, 6 * 4, 128)
         temp = compiled.memory_analysis().temp_size_in_bytes
@@ -552,6 +578,220 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
         # no expert matrix is copied to be multiplied
         assert not re.findall(r"= bf16\[2048,6144\]\S* copy\(", entry)
         assert not re.findall(r"= bf16\[6144,2048\]\S* copy\(", entry)
+
+
+# ---------------------------------------------------------------------------
+# the weights an engine holds (ISSUE 29): cast once, when it is built
+# ---------------------------------------------------------------------------
+
+def _build(kind, net, **kw):
+    """A small engine of either kind over ``net``."""
+    if kind == "ring":
+        return generate.GenerationEngine(
+            net, slots=3, cache_len=MAX_LEN, buckets=[8, MAX_LEN],
+            sampling=generate.SamplingConfig(greedy=True), **kw)
+    return generate.PagedGenerationEngine(
+        net, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
+        sampling=generate.SamplingConfig(greedy=True), **kw)
+
+
+def _weights_span(build):
+    """``build()``'s engine and the ``engine.weights`` span it left in
+    the ring."""
+    from mxnet_tpu import tracing
+
+    before = {r["span_id"] for r in tracing.records()}
+    eng = build()
+    spans = [r for r in tracing.records() if r["name"] == "engine.weights"
+             and r["span_id"] not in before]
+    assert len(spans) == 1, spans
+    return eng, spans[0]["args"]
+
+
+def _pointers(arrays):
+    return [a.unsafe_buffer_pointer() for a in arrays]
+
+
+def _on_device(net):
+    """``net`` with every parameter a committed device array, as a
+    caller who made or loaded the weights on the device hands them
+    over."""
+    import jax
+
+    from mxnet_tpu.ndarray import NDArray
+
+    for p in net.collect_params().values():
+        p.set_data(NDArray(jax.device_put(p.data()._data,
+                                          jax.devices()[0])))
+    return net
+
+
+@pytest.fixture(scope="module")
+def lm_on_device():
+    return _on_device(_small_lm())
+
+
+@pytest.mark.parametrize("kind", ["paged", "ring"])
+def test_engine_holds_weights_in_the_rules_dtypes(lm_on_device, kind):
+    """Under ``bf16_mixed`` with float32 parameters every held array has
+    the dtype the policy's rules give that parameter (norms and the head
+    stay float32), the span says what was cast, and the network keeps
+    its float32 masters: the engine's copy is a snapshot."""
+    from mxnet_tpu import dtype_policy
+
+    lm = lm_on_device
+    policy = dtype_policy.get_policy("bf16_mixed")
+    eng, span = _weights_span(lambda: _build(kind, lm,
+                                             dtype_policy="bf16_mixed"))
+    params = list(lm.collect_params().values())
+    assert [p.name for p in params] == eng._param_names
+    want = [policy.param_cast_dtype(p.name, tuple(p.shape)) for p in params]
+    assert [np.dtype(a.dtype) for a in eng._params] == want
+    assert {str(d) for d in want} == {"bfloat16", "float32"}
+    assert np.dtype(eng._params[-1].dtype) == np.float32, "the head's rule"
+    assert all(str(p.data()._data.dtype) == "float32" for p in params)
+    cast = [a for a, p in zip(eng._params, params)
+            if a.dtype != p.data()._data.dtype]
+    assert set(span) == {"held_bytes", "cast_bytes", "aliased"}
+    assert span["cast_bytes"] == sum(a.nbytes for a in cast) > 0
+    assert span["aliased"] == len(params) - len(cast)
+    assert span["held_bytes"] == eng.param_bytes == \
+        sum(a.nbytes for a in eng._params)
+    # the parameters the rules keep are the network's own buffers
+    kept = [(a, p.data()._data) for a, p in zip(eng._params, params)
+            if a.dtype == p.data()._data.dtype]
+    assert _pointers(a for a, _m in kept) == _pointers(m for _a, m in kept)
+
+
+@pytest.mark.parametrize("kind", ["paged", "ring"])
+def test_engine_without_a_policy_casts_nothing(lm_on_device, kind):
+    lm = lm_on_device
+    eng, span = _weights_span(lambda: _build(kind, lm, dtype_policy="f32"))
+    masters = [p.data()._data for p in lm.collect_params().values()]
+    assert _pointers(eng._params) == _pointers(masters)
+    assert span["cast_bytes"] == 0 and span["aliased"] == len(masters)
+    assert span["held_bytes"] == eng.param_bytes == \
+        sum(m.nbytes for m in masters)
+
+
+def test_weights_handed_over_at_their_targets_are_the_same_buffers():
+    """A model stored in bfloat16 with float32 norms and head (the
+    block-diffusion cell's, small) is held as the very buffers it has:
+    no second copy of the weights."""
+    from mxnet_tpu.gluon.model_zoo.language import MoEDecoderLM
+
+    net = MoEDecoderLM(64, 32, 2, 4, 2, 16, 4, 2, 16, block_length=4,
+                       mask_token_id=63, max_len=32, dtype="bfloat16")
+    net.initialize(mx.init.Normal(0.02))
+    _on_device(net)
+    eng, span = _weights_span(lambda: generate.PagedGenerationEngine(
+        net, slots=2, cache_len=32, page_size=8, prefill_chunk=8, spec_k=0,
+        dtype_policy="bf16_mixed", denoise_steps=2))
+    masters = [p.data()._data for p in net.collect_params().values()]
+    assert {str(m.dtype) for m in masters} == {"bfloat16", "float32"}
+    assert _pointers(eng._params) == _pointers(masters)
+    assert span == {"held_bytes": sum(m.nbytes for m in masters),
+                    "cast_bytes": 0, "aliased": len(masters)}
+
+
+@pytest.mark.parametrize("kind", ["paged", "ring"])
+def test_held_weights_keep_their_sharding(lm, kind):
+    """Under a mesh the cast copy lies where the layout put the master."""
+    from jax.sharding import NamedSharding
+
+    from mxnet_tpu import parallel
+
+    e = _build(kind, lm, mesh="dp=2,tp=2", dtype_policy="bf16_mixed")
+    params = list(lm.collect_params().values())
+    res = parallel.layout.get_layout(e.layout_name).resolve(
+        [(p.name, tuple(p.shape)) for p in params], e._mesh)
+    sharded = 0
+    for p, a in zip(params, e._params):
+        want = NamedSharding(e._mesh, res.spec(p.name))
+        assert a.sharding.is_equivalent_to(want, a.ndim), p.name
+        sharded += any(ax is not None for ax in res.spec(p.name))
+    assert sharded, "no parameter of the layout is sharded"
+    assert {str(a.dtype) for a in e._params} == {"bfloat16", "float32"}
+
+
+def _weight_converts(jitted, args, n_params):
+    """The ``convert_element_type`` equations of ``jitted``'s program
+    whose operand is one of its first ``n_params`` arguments."""
+    import jax
+
+    closed = jax.make_jaxpr(jitted)(*args)
+    (call,) = closed.jaxpr.eqns
+    body = call.params["jaxpr"].jaxpr
+    weights = body.invars[:n_params]
+    return [e for e in body.eqns if e.primitive.name == "convert_element_type"
+            and any(v is w for v in e.invars for w in weights)]
+
+
+def _ring_dispatch_args(eng, shape, params):
+    import jax
+    import jax.numpy as jnp
+
+    ck, cv = jnp.copy(eng._cache_k), jnp.copy(eng._cache_v)
+    key = jax.random.PRNGKey(0)
+    if shape == "prefill":
+        tokens = np.zeros((1, 8), np.int32)
+        tokens[0, :5] = _prompt(5, seed=7)
+        return eng._jit_prefill, (params, ck, cv, tokens, np.int32(5),
+                                  np.int32(1), key)
+    return eng._jit_decode, (params, ck, cv, np.arange(3, dtype=np.int32),
+                             np.array([4, 2, 0], np.int32), key)
+
+
+def _paged_dispatch_args(eng, shape, params):
+    """A dispatch with live page tables, tokens and write rows, on
+    copies of the pools (the dispatch donates them)."""
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(11)
+    nb, nc = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))[shape]
+    table = 1 + np.arange(nb * eng.pages_per_slot, dtype=np.int32).reshape(
+        (nb, eng.pages_per_slot))
+    start = rs.randint(1, 8, nb).astype(np.int32)
+    pos = start[:, None] + np.arange(nc, dtype=np.int32)
+    wpage = np.take_along_axis(table, pos // eng.page_size, 1).reshape(-1)
+    woff = (pos % eng.page_size).reshape(-1).astype(np.int32)
+    pool = rs.standard_normal(eng.pool_shape).astype(np.float32)
+    return eng._jit_chunk, (
+        params, jnp.asarray(pool, eng.cache_dtype),
+        jnp.asarray(pool[::-1], eng.cache_dtype), table,
+        rs.randint(0, VOCAB, (nb, nc)).astype(np.int32), start, wpage, woff,
+        np.zeros((nb, 2), np.uint32))
+
+
+@pytest.mark.parametrize("shape", ["prefill", "decode"])
+@pytest.mark.parametrize("kind", ["paged", "ring"])
+def test_dispatch_casts_no_weight_and_computes_the_same(lm, kind, shape):
+    """The program traced on the held weights has no ``convert`` of a
+    parameter; called with the float32 masters it is the old program,
+    which casts every one the rules do not keep, and the two give
+    bitwise equal logits, tokens and caches: the rounding to bfloat16
+    moved from every program to the constructor, and nothing else."""
+    import jax
+
+    eng = _build(kind, lm, dtype_policy="bf16_mixed")
+    masters = tuple(jax.device_put(p.data()._data)
+                    for p in lm.collect_params().values())
+    make = _ring_dispatch_args if kind == "ring" else _paged_dispatch_args
+    n = len(masters)
+    jitted, held_args = make(eng, shape, eng._params)
+    _jitted, master_args = make(eng, shape, masters)
+    assert _weight_converts(jitted, held_args, n) == []
+    n_cast = sum(str(a.dtype) == "bfloat16" for a in eng._params)
+    assert len(_weight_converts(jitted, master_args, n)) == n_cast > 0
+    got, want = jitted(*held_args), jitted(*master_args)
+    flat_got, tree_got = jax.tree_util.tree_flatten(got)
+    flat_want, tree_want = jax.tree_util.tree_flatten(want)
+    assert tree_got == tree_want
+    for g, w in zip(flat_got, flat_want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g.astype(np.float32)),
+                                      np.asarray(w.astype(np.float32)))
+    assert float(np.abs(np.asarray(got[1])).max()) > 0, "logits all zero"
 
 
 # ---------------------------------------------------------------------------
