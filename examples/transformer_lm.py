@@ -77,14 +77,11 @@ class DecoderBlock(gluon.HybridBlock):
         return a.reshape((B, H, T, dh)).transpose(
             (0, 2, 1, 3)).reshape((B, T, H * dh))
 
-    def _attend_capture(self, F, x):
-        """Causal MHA over the full sequence; also returns this layer's
-        K/V heads as (B, H, T, dh) — the cache the prefill half of the
-        generation engine (mxnet_tpu/generate.py) seeds from.  The op
-        sequence is EXACTLY the train-path attention so prefill logits
-        match training/full-context forward bit-for-bit."""
-        B, T, D = x.shape
-        H, dh = self._n_heads, self._d_head
+    def _attend(self, F, x):
+        """Causal MHA over the full sequence (the train path and the
+        full re-forward the serving tests hold ``forward_chunk`` to)."""
+        B, T, _D = x.shape
+        dh = self._d_head
         q = self._split_heads(self.proj_q(x))
         k = self._split_heads(self.proj_k(x))
         v = self._split_heads(self.proj_v(x))
@@ -96,25 +93,11 @@ class DecoderBlock(gluon.HybridBlock):
                          F.ones_like(scores) * -1e30)
         att = F.softmax(scores, axis=-1)
         out = F.batch_dot(att, v)  # (B*H, T, dh)
-        out = self._merge_heads(out, B, T)
-        kv_shape = (B, H, T, dh)
-        return (self.attn_out(out), k.reshape(kv_shape),
-                v.reshape(kv_shape))
-
-    def _attend(self, F, x):
-        out, _k, _v = self._attend_capture(F, x)
-        return out
+        return self.attn_out(self._merge_heads(out, B, T))
 
     def hybrid_forward(self, F, x):
         x = x + self._attend(F, self.ln1(x))
         return x + self.ffn_down(self.ffn_up(self.ln2(x)))
-
-    def forward_prefill(self, F, x):
-        """One block's full-sequence forward that also hands back K/V
-        for the cache: identical math to ``hybrid_forward``."""
-        a, k, v = self._attend_capture(F, self.ln1(x))
-        x = x + a
-        return x + self.ffn_down(self.ffn_up(self.ln2(x))), k, v
 
     def forward_chunk(self, F, x, k_cache, v_cache, cache_mask,
                       causal_mask):
@@ -158,47 +141,6 @@ class DecoderBlock(gluon.HybridBlock):
         x = x + self.attn_out(out)
         return (x + self.ffn_down(self.ffn_up(self.ln2(x))),
                 k_c, v_c)
-
-    def forward_decode(self, F, x, k_cache, v_cache, write_mask,
-                       valid_mask):
-        """One block's single-token decode against the ring KV cache.
-
-        ``x`` is the (B, 1, D) input NDArray; ``k_cache``/``v_cache``
-        are RAW jax arrays (B, H, S, dh); ``write_mask`` (B, 1, S, 1)
-        selects each sequence's ring slot for this token's K/V;
-        ``valid_mask`` (B*H, 1, S) marks cache slots holding real
-        entries.  Returns (x_out, new_k_cache, new_v_cache).  The
-        projection/LN/FFN submodules are the SAME children the train
-        path runs, so decode logits track the full-context forward."""
-        import jax.numpy as jnp
-
-        from mxnet_tpu.ndarray import NDArray
-
-        B, _one, D = x.shape
-        H, dh = self._n_heads, self._d_head
-        S = k_cache.shape[2]
-        h = self.ln1(x)
-        q = self._split_heads(self.proj_q(h))          # (B*H, 1, dh)
-        k_t = self._split_heads(self.proj_k(h))._data.reshape(
-            (B, H, 1, dh))
-        v_t = self._split_heads(self.proj_v(h))._data.reshape(
-            (B, H, 1, dh))
-        # ring write via a boolean select: the masked lanes keep the
-        # cache value EXACTLY (no arithmetic), the selected slot takes
-        # this token's K/V — donation-friendly, fuses into one update
-        k_cache = jnp.where(write_mask, k_t, k_cache)
-        v_cache = jnp.where(write_mask, v_t, v_cache)
-        kc = NDArray(k_cache.reshape((B * H, S, dh)))
-        vc = NDArray(v_cache.reshape((B * H, S, dh)))
-        scores = F.batch_dot(q, kc, transpose_b=True) * (dh ** -0.5)
-        scores = F.where(NDArray(valid_mask), scores,
-                         F.ones_like(scores) * -1e30)
-        att = F.softmax(scores, axis=-1)
-        out = F.batch_dot(att, vc)                     # (B*H, 1, dh)
-        out = self._merge_heads(out, B, 1)
-        x = x + self.attn_out(out)
-        return (x + self.ffn_down(self.ffn_up(self.ln2(x))),
-                k_cache, v_cache)
 
 
 class TransformerLM(gluon.HybridBlock):
@@ -263,79 +205,12 @@ class TransformerLM(gluon.HybridBlock):
 
     # -- generation protocol (mxnet_tpu/generate.py) ---------------------
     #
-    # prefill_forward / decode_forward are the cache-aware inference
-    # halves of hybrid_forward: any model exposing them (plus .config
-    # with vocab_size/d_model/n_heads/n_layers/max_len) plugs into
-    # generate.GenerationEngine.  Both are called under the gluon trace
-    # machinery with parameters swapped in, exactly like
+    # chunk_forward is the cache-aware inference form of hybrid_forward:
+    # any model exposing it (plus .config with vocab_size / d_model /
+    # n_heads / n_layers / max_len) plugs into
+    # generate.PagedGenerationEngine.  It is called under the gluon
+    # trace machinery with parameters swapped in, exactly like
     # serving.Predictor.from_block's traced forward.
-
-    def prefill_forward(self, tokens):
-        """Full-sequence forward that also returns every layer's K/V.
-
-        ``tokens`` is a (B, T) NDArray of token ids.  Returns
-        ``(logits NDArray (B, T, V), caches)`` where ``caches`` is one
-        ``(k, v)`` pair of raw (B, H, T, dh) jax arrays per layer —
-        positions 0..T-1 of the decode ring.  Logits are identical to
-        ``hybrid_forward`` by construction (same children, same op
-        sequence)."""
-        from mxnet_tpu import ndarray as F
-
-        B, T = tokens.shape
-        if T > self._cfg["max_len"]:
-            raise ValueError("prefill length %d > max_len %d"
-                             % (T, self._cfg["max_len"]))
-        pos = F.arange(T)
-        x = F.broadcast_add(self.embed(tokens),
-                            self.pos_embed(pos).reshape(
-                                (1, T, self._cfg["d_model"])))
-        caches = []
-        for blk in self._blocks:
-            x, k, v = blk.forward_prefill(F, x)
-            caches.append((k._data, v._data))
-        return self.head(self.ln_f(x)), caches
-
-    def decode_forward(self, tokens, caches, pos):
-        """One autoregressive step against the ring KV cache.
-
-        ``tokens`` raw (B,) int32 — the token EMITTED at position
-        ``pos`` (raw (B,) int32) per sequence; ``caches`` a list of
-        per-layer ``(k, v)`` raw jax arrays (B, H, S, dh).  Writes each
-        sequence's K/V into ring slot ``pos % S``, attends over the
-        ``min(pos+1, S)`` filled slots, and returns
-        ``(logits NDArray (B, V), new_caches)``."""
-        import jax.numpy as jnp
-
-        from mxnet_tpu import ndarray as F
-        from mxnet_tpu.ndarray import NDArray
-
-        B = tokens.shape[0]
-        H = self._cfg["n_heads"]
-        D = self._cfg["d_model"]
-        S = caches[0][0].shape[2]
-        max_len = self._cfg["max_len"]
-        pos = pos.astype(jnp.int32)
-        tok_nd = NDArray(tokens.reshape((B, 1)))
-        # position row for the incoming token (clamped: the engine
-        # evicts at max_len, the clamp keeps a late step in-bounds)
-        pos_clip = jnp.clip(pos, 0, max_len - 1)
-        x = self.embed(tok_nd) + self.pos_embed(
-            NDArray(pos_clip)).reshape((B, 1, D))
-        slot_idx = jnp.arange(S, dtype=jnp.int32)
-        write_mask = (slot_idx[None, :] == (pos % S)[:, None]) \
-            .reshape((B, 1, S, 1))
-        count = jnp.minimum(pos + 1, S)
-        valid = slot_idx[None, :] < count[:, None]          # (B, S)
-        valid_bh = jnp.broadcast_to(
-            valid.reshape((B, 1, 1, S)), (B, H, 1, S)).reshape(
-                (B * H, 1, S))
-        new_caches = []
-        for blk, (kc, vc) in zip(self._blocks, caches):
-            x, kc, vc = blk.forward_decode(F, x, kc, vc, write_mask,
-                                           valid_bh)
-            new_caches.append((kc, vc))
-        logits = self.head(self.ln_f(x))                    # (B, 1, V)
-        return logits.reshape((B, self._cfg["vocab_size"])), new_caches
 
     def chunk_forward(self, tokens, caches, start):
         """C positions per sequence against a linear KV cache — the one

@@ -433,20 +433,14 @@ FLAGS = {
         "headers cannot grow per-tenant state without bound"),
     "MXNET_DECODE_SLOTS": (
         "8", _pint, "honored",
-        "generate.GenerationEngine default decode batch slots: the "
-        "fixed-shape continuous-batching width of the compiled decode "
-        "step (one KV-cache lane per slot)"),
+        "generate.PagedGenerationEngine default decode batch slots: "
+        "the fixed-shape continuous-batching width of the compiled "
+        "decode step (one page-table row per slot)"),
     "MXNET_DECODE_CACHE_LEN": (
         "256", _pint, "honored",
-        "default KV-cache ring length per slot (positions kept per "
-        "sequence; capped at the model's max_len).  Generation past "
-        "the ring attends over a sliding window"),
-    "MXNET_DECODE_BUCKETS": (
-        "32,64,128,256", str, "honored",
-        "comma list of prefill length buckets: a prompt pads up to "
-        "the smallest bucket >= its length, so prefill compiles one "
-        "executable per bucket (each a distinct AOT manifest row "
-        "tools/prewarm.py can warm) instead of one per prompt length"),
+        "default KV-cache positions per slot (prompt + generated, "
+        "rounded up to whole pages; capped at the model's max_len).  "
+        "A sequence that fills them finishes with reason 'length'"),
     "MXNET_DECODE_QUEUE": (
         "64", _pint, "honored",
         "generate.TokenServer admission-queue depth: a full queue "
@@ -460,12 +454,6 @@ FLAGS = {
         "128", _pint, "honored",
         "default cap on generated tokens per request (finish_reason "
         "'length'); per-submit max_new_tokens= overrides"),
-    "MXNET_DECODE_PAGED": (
-        "0", _pint, "honored",
-        "tools default engine selection (bench_decode/prewarm): 1 "
-        "builds the paged engine (generate.PagedGenerationEngine: page "
-        "pool + prefix sharing + chunked prefill) instead of the "
-        "per-slot KV ring; library callers pick the class directly"),
     "MXNET_DECODE_PAGE_SIZE": (
         "16", _pint, "honored",
         "positions per KV page in the paged engine's pool; a slot "

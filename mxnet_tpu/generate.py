@@ -1,4 +1,4 @@
-"""LM generation engine: KV-cache decode with a prefill/decode split
+"""LM generation engine: paged KV-cache decode with chunked prefill
 and continuous-batching token serving.
 
 The training half of the LM stack (``examples/transformer_lm.py`` +
@@ -9,20 +9,32 @@ cache pays O(1).  This module is the inference half, built the way the
 TPU path rewards (fixed-shape compiled executables, PAPERS.md "full
 compilation" line):
 
-* **KV cache as donated device state** — one ring-buffer lane per
-  decode slot, ``(layers, slots, heads, ring, d_head)`` stacked arrays
-  donated into every prefill/decode dispatch so the cache updates in
-  place; cache dtype follows the ``dtype_policy=`` compute dtype
-  (bf16 under ``bf16_mixed``), and with a mesh the lanes shard by the
-  ``kv_cache`` spec rule of the PR 9 layouts (slots over dp/fsdp,
-  heads over tp — tp serving composes with the training mesh).
-* **Prefill/decode split** — prefill runs the model's full-sequence
-  forward at *bucketed* lengths (``MXNET_DECODE_BUCKETS``: one
-  compiled executable per bucket, each a distinct AOT manifest row
-  ``tools/prewarm.py`` can warm), seeding the admitted sequence's
-  cache lane and sampling its first token (the TTFT token).  Decode is
-  one fixed-shape token step over ALL slots — admission and eviction
-  change host-side masks, never the compiled program.
+* **The KV cache is a page pool, donated device state** —
+  :class:`PagedGenerationEngine` holds one fixed-shape, token-major
+  pool per K and V, ``(pages * page_size, layers, heads * d_head)``,
+  donated into every dispatch so it updates in place; host-side page
+  tables map each decode slot's positions onto pool pages, so
+  admission and eviction flip host state and never the compiled
+  program.  The pool's dtype follows the ``dtype_policy=`` compute
+  dtype (bf16 under ``bf16_mixed``), and with a mesh the pool shards
+  by the ``kv_pool`` spec rule of the layouts (``heads * d_head`` over
+  tp, tokens over the data axes where they divide them — tp serving
+  composes with the training mesh).
+* **One compiled dispatch, three shapes** — a prefill chunk
+  ``(1, prefill_chunk)``, a decode step ``(slots, 1)`` (``(slots,
+  block_length)`` under block-diffusion decoding) and, with n-gram
+  speculation on, a verify step ``(slots, spec_k + 1)``, each a
+  distinct AOT manifest row ``tools/prewarm.py`` can warm.  Long
+  prompts stream in fixed-size chunks interleaved with decode steps, so
+  admission never freezes active slots; the chunk that completes a
+  prompt samples its first token (the TTFT token).
+* **What pages buy** — prefix sharing (a shared system prompt prefills
+  ONCE; new requests attach to its pages refcounted, copy-on-write by
+  page alignment) and n-gram self-speculative decoding (draft K tokens
+  from a suffix match over the sequence's own history, verify all of
+  them in ONE fixed-shape dispatch; exact-match acceptance over the
+  position-keyed sampler keeps spec output bit-identical to
+  non-speculative sampling).
 * **Sampling under the PRNG discipline** — greedy / top-k / top-p
   fused into the compiled step; sampling keys come from
   ``mxnet_tpu.random.next_key()``, so ``mx.random.seed(n)`` makes a
@@ -32,34 +44,20 @@ compilation" line):
   taxonomy as ``serving_async`` (:class:`Overloaded` at admission,
   :class:`DeadlineExceeded` tagged ``stage="prefill"`` vs
   ``stage="decode"``, burn-rate shedding over the TTFT histogram,
-  drained ``close()``), so the future HTTP front end maps decode
-  failures to 429/504 exactly like predict failures.
+  drained ``close()``), so the HTTP front end maps decode failures to
+  429/504 exactly like predict failures.
 
-* **Paged KV cache** — :class:`PagedGenerationEngine` replaces the
-  per-slot ring with a fixed-shape, token-major page pool
-  ``(pages * page_size, layers, heads * d_head)`` plus host-side page
-  tables (same "host state flips, compiled shape stays" trick): pages
-  buy prefix sharing (a shared system prompt prefills ONCE; new
-  requests attach to its pages refcounted, copy-on-write by page
-  alignment), chunked prefill (long prompts stream in fixed-size
-  chunks interleaved with decode steps so admission never freezes
-  active lanes), and n-gram self-speculative decoding (draft K tokens
-  from a suffix match over the sequence's own history, verify all of
-  them in ONE fixed-shape dispatch; exact-match acceptance over the
-  position-keyed sampler keeps spec output bit-identical to
-  non-speculative sampling).
-
-Model protocol: any net exposing ``prefill_forward(tokens)`` /
-``decode_forward(tokens, caches, pos)`` (see
-``examples/transformer_lm.py``) plus a ``config`` dict with
+The model protocol: a served net implements ``chunk_forward(tokens,
+caches, start)`` — C positions a sequence against a linear view of the
+cached ones; one entry point covers prefill chunks, decode and the
+verify step (``examples/transformer_lm.py``,
+``gluon.model_zoo.language.MoEDecoderLM``) — and a ``config`` dict with
 ``vocab_size`` / ``d_model`` / ``n_heads`` / ``n_layers`` / ``max_len``
-plugs in; the paged engine instead drives the single
-``chunk_forward(tokens, caches, start)`` entry point (one compiled
-family covers prefill chunks, decode, and the verify step).
+(``n_kv_heads`` / ``d_head`` where they are not the defaults;
+``block_length`` and ``mask_token_id`` for block-diffusion decoding).
 Benchmarks: ``tools/bench_decode.py`` (tokens/s/user, TTFT p50/p99,
-the >=3x KV-cache-vs-reforward acceptance number, plus the paged /
-prefix-share / chunked-prefill / speculative modes); docs:
-``docs/lm_serving.md``.
+the KV-cache-vs-reforward ratio, plus the prefix-share /
+chunked-prefill / speculative modes); docs: ``docs/lm_serving.md``.
 """
 from __future__ import annotations
 
@@ -80,8 +78,8 @@ from .serving_async import (Cancelled, DeadlineExceeded, Overloaded,
                             ReplicaFailed, ServingError, ServingFuture,
                             BurnRateShedder)
 
-__all__ = ["SamplingConfig", "GenerationEngine",
-           "PagedGenerationEngine", "TokenServer", "GenerationResult",
+__all__ = ["SamplingConfig", "PagedGenerationEngine", "TokenServer",
+           "GenerationResult",
            "sample_logits", "ServingError", "Overloaded",
            "DeadlineExceeded", "Cancelled"]
 
@@ -108,9 +106,7 @@ def _decode_statusz():
     for s in _live_snapshot():
         st = s.stats()
         st["occupancy"] = s._engine.occupancy()
-        pool_shape = getattr(s._engine, "pool_shape", None)
-        if pool_shape is not None:         # the paged engine's K/V pools
-            st["pool_shape"] = list(pool_shape)
+        st["pool_shape"] = list(s._engine.pool_shape)
         if s._shedder is not None:
             st["ttft_burn_rate"] = round(s._shedder.burn, 4)
         out["servers"].append(st)
@@ -207,20 +203,6 @@ def sample_logits(logits, key, cfg):
     return jax.random.categorical(key, logits).astype(jnp.int32)
 
 
-def _parse_buckets(spec, cache_len):
-    """``MXNET_DECODE_BUCKETS``/buckets= -> sorted unique lengths
-    capped at ``cache_len`` (always containing cache_len so every
-    admissible prompt has a bucket)."""
-    if spec is None:
-        spec = _config.get("MXNET_DECODE_BUCKETS")
-    if isinstance(spec, str):
-        vals = [int(s) for s in spec.split(",") if s.strip()]
-    else:
-        vals = [int(v) for v in spec]
-    vals = sorted({v for v in vals if 0 < v <= cache_len} | {cache_len})
-    return vals
-
-
 # ---------------------------------------------------------------------------
 # the weights an engine holds
 # ---------------------------------------------------------------------------
@@ -261,451 +243,6 @@ def _hold_weights(names, placed, policy):
 
 # ---------------------------------------------------------------------------
 # the engine
-# ---------------------------------------------------------------------------
-
-class GenerationEngine:
-    """Fixed-shape KV-cache generation over a decode-protocol model.
-
-    ``slots`` decode lanes share one compiled token step; each lane
-    owns a ``cache_len``-position KV ring.  :meth:`admit` prefills a
-    prompt into a free lane (bucketed lengths) and returns its first
-    sampled token; :meth:`decode_step` advances every active lane one
-    token; :meth:`evict` frees a lane.  All device state (cache) is
-    donated through the jit sites, which thread ``aot=`` /
-    ``dtype_policy=`` like every other front end.
-
-    The weights are a snapshot taken when the engine is built, held in
-    the dtype the policy computes in: each parameter is cast once, on
-    the device, to what the policy's rules give it (bfloat16 under
-    ``bf16_mixed``, float32 where a rule keeps it), and no program
-    reads a float32 master.  A parameter handed over at its target is
-    held as the buffer it is.  A caller who keeps float32 masters in
-    the network pays for both, the masters and the engine's copy (1.5 x
-    the masters); hand the weights over in the compute dtype to hold
-    them once.
-
-    Single-consumer: one thread drives the engine (TokenServer's loop,
-    or a bench loop).  Admission control, deadlines, and futures live
-    in :class:`TokenServer`.
-    """
-
-    def __init__(self, net, slots=None, cache_len=None, buckets=None,
-                 mesh=None, layout=None, dtype_policy=None, aot=None,
-                 aot_spec=None, sampling=None, device=None):
-        import jax
-        import jax.numpy as jnp
-
-        from . import aot as _aot
-        from . import dtype_policy as _dtp
-        from . import autograd
-        from . import parallel
-        from .gluon import block as block_mod
-        from .ndarray.ndarray import NDArray
-
-        for attr in ("prefill_forward", "decode_forward", "config"):
-            if not hasattr(net, attr):
-                raise MXNetError(
-                    "GenerationEngine needs a model implementing the "
-                    "decode protocol (prefill_forward / decode_forward "
-                    "/ config — see examples/transformer_lm.py); %s "
-                    "lacks %r" % (type(net).__name__, attr))
-        cfg = dict(net.config)
-        for k in ("vocab_size", "d_model", "n_heads", "n_layers",
-                  "max_len"):
-            if k not in cfg:
-                raise MXNetError("model config lacks %r (decode "
-                                 "protocol)" % k)
-        self.model_config = cfg
-        if slots is None:
-            slots = _config.get("MXNET_DECODE_SLOTS")
-        self._slots = int(slots)
-        if self._slots < 1:
-            raise MXNetError("slots must be >= 1, got %r" % (slots,))
-        if cache_len is None:
-            cache_len = min(_config.get("MXNET_DECODE_CACHE_LEN"),
-                            cfg["max_len"])
-        self._cache_len = int(min(cache_len, cfg["max_len"]))
-        if self._cache_len < 1:
-            raise MXNetError("cache_len must be >= 1, got %r"
-                             % (cache_len,))
-        self._buckets = _parse_buckets(buckets, self._cache_len)
-        self.sampling = sampling if sampling is not None \
-            else SamplingConfig()
-
-        # finish deferred parameter init (abstract eval — no compile)
-        probe = NDArray(jnp.zeros(
-            (1, min(8, cfg["max_len"])), jnp.float32))
-        with autograd.pause():
-            block_mod._abstract_eval_forward(net, [probe])
-        self._net = net
-        params = list(net.collect_params().values())
-        self._param_names = [p.name for p in params]
-        dt_policy = _dtp.resolve_policy(dtype_policy)
-        self._dtype_policy = dt_policy
-        _dtp.note_policy(dt_policy, "generate")
-        self._cache_dtype = np.dtype(dt_policy.compute_dtype) \
-            if dt_policy is not None else np.dtype(np.float32)
-
-        # placement: params committed once (Predictor discipline); with
-        # a mesh both params and cache lanes take their layout specs —
-        # the kv_cache rule shards slots over the data axes and heads
-        # over tp, so tensor-parallel serving composes with the PR 9
-        # training mesh
-        self._mesh = parallel.resolve_mesh(mesh)
-        L, H = cfg["n_layers"], cfg["n_heads"]
-        dh = cfg["d_model"] // H
-        cache_shape = (L, self._slots, H, self._cache_len, dh)
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding
-
-            layout_obj = parallel.layout.resolve_layout(layout,
-                                                        self._mesh)
-            self.layout_name = layout_obj.name
-            res = layout_obj.resolve(
-                [(p.name, tuple(p.shape)) for p in params], self._mesh)
-            placed = tuple(
-                jax.device_put(p.data()._data,
-                               NamedSharding(self._mesh, res.spec(p.name)))
-                for p in params)
-            cres = layout_obj.resolve(
-                [("cache_k", cache_shape), ("cache_v", cache_shape)],
-                self._mesh)
-            self._cache_sharding = NamedSharding(self._mesh,
-                                                 cres.spec("cache_k"))
-        else:
-            self.layout_name = None
-            dev = device if device is not None else jax.devices()[0]
-            placed = tuple(
-                jax.device_put(p.data()._data, dev) for p in params)
-            self._cache_sharding = dev
-        self._params = _hold_weights(self._param_names, placed, dt_policy)
-        self._cache_k = jax.device_put(
-            jnp.zeros(cache_shape, self._cache_dtype),
-            self._cache_sharding)
-        self._cache_v = jax.device_put(
-            jnp.zeros(cache_shape, self._cache_dtype),
-            self._cache_sharding)
-
-        # host-side lane state (the continuous-batching control plane)
-        self._pos = np.zeros(self._slots, np.int32)
-        self._active = np.zeros(self._slots, bool)
-        self._cur_tok = np.zeros(self._slots, np.int32)
-        self._free = collections.deque(range(self._slots))
-        self._zero_key = jax.random.PRNGKey(0)
-
-        gluon_params = params
-        scfg = self.sampling
-        vocab = cfg["vocab_size"]
-
-        def _traced(fn, params_):
-            """Run ``fn`` with the model's parameters swapped to the
-            (policy-cast) traced arrays — the shared param-swap trace
-            recipe (gluon.block.swapped_params) under the dtype-policy
-            scope."""
-            with _dtp.scope(dt_policy), \
-                    block_mod.swapped_params(
-                        gluon_params, _cast_weights(
-                            self._param_names, params_, dt_policy)):
-                return fn()
-
-        def _cast_logits(arr):
-            if dt_policy is not None:
-                return dt_policy.cast_output(arr)
-            return arr
-
-        S, B = self._cache_len, self._slots
-        cache_dtype = self._cache_dtype
-
-        def prefill_fn(params_, cache_k, cache_v, tokens, n_valid, slot,
-                       key):
-            """tokens (1, Tb) int32; writes the sequence's K/V into
-            ring lane ``slot`` (positions 0..Tb-1), samples the first
-            generated token from the last VALID position's logits."""
-            from jax import lax
-
-            def run():
-                logits_nd, caches = net.prefill_forward(NDArray(tokens))
-                return logits_nd._data, [(k, v) for k, v in caches]
-
-            logits, caches = _traced(run, params_)
-            last = lax.dynamic_slice(
-                logits, (0, jnp.maximum(n_valid - 1, 0), 0),
-                (1, 1, vocab)).reshape((1, vocab))
-            last = _cast_logits(last)
-            next_tok = sample_logits(last, key, scfg)
-            for li, (k, v) in enumerate(caches):
-                kpad = jnp.zeros((1, H, S, dh), cache_dtype)
-                kpad = lax.dynamic_update_slice(
-                    kpad, k.astype(cache_dtype), (0, 0, 0, 0))
-                vpad = jnp.zeros((1, H, S, dh), cache_dtype)
-                vpad = lax.dynamic_update_slice(
-                    vpad, v.astype(cache_dtype), (0, 0, 0, 0))
-                cache_k = lax.dynamic_update_slice(
-                    cache_k, kpad.reshape((1, 1, H, S, dh)),
-                    (li, slot, 0, 0, 0))
-                cache_v = lax.dynamic_update_slice(
-                    cache_v, vpad.reshape((1, 1, H, S, dh)),
-                    (li, slot, 0, 0, 0))
-            return next_tok, last, cache_k, cache_v
-
-        def decode_fn(params_, cache_k, cache_v, tokens, pos, key):
-            """One token step over all ``slots`` lanes (fixed shape)."""
-            def run():
-                caches = [(cache_k[li], cache_v[li]) for li in range(L)]
-                logits_nd, new = net.decode_forward(tokens, caches, pos)
-                return logits_nd._data, new
-
-            logits, new = _traced(run, params_)
-            logits = _cast_logits(logits)
-            next_tok = sample_logits(logits, key, scfg)
-            new_k = jnp.stack([k for k, _v in new])
-            new_v = jnp.stack([v for _k, v in new])
-            return (next_tok, logits, new_k.astype(cache_dtype),
-                    new_v.astype(cache_dtype))
-
-        # jit sites: cache donated (in-place ring update), threaded
-        # through aot=/dtype_policy= like every other front end.  Each
-        # prefill BUCKET is a distinct signature -> its own AOT
-        # manifest row; so is each (slots, cache_len) decode shape.
-        self._jit_prefill = jax.jit(prefill_fn, donate_argnums=(1, 2))
-        self._jit_decode = jax.jit(decode_fn, donate_argnums=(1, 2))
-        self._aot_spec = aot_spec or ("lm_decode:slots%dxlen%d"
-                                      % (B, S))
-        store = _aot.resolve_aot(aot)
-        if store is not None:
-            dtag = _dtp.policy_tag(dt_policy)
-            fp = "dtype=%s;sampling=%s" % (dtag, scfg.tag)
-            mext = {"dtype_policy": dtag, "sampling": scfg.tag}
-            self._jit_prefill = _aot.AOTFunction(
-                self._jit_prefill, "generate:prefill", store,
-                fingerprint_extra=fp, manifest_kind="generate",
-                manifest_spec=self._aot_spec, manifest_extra=mext)
-            self._jit_decode = _aot.AOTFunction(
-                self._jit_decode, "generate:decode", store,
-                fingerprint_extra=fp, manifest_kind="generate",
-                manifest_spec=self._aot_spec, manifest_extra=mext)
-        self._H, self._dh, self._L = H, dh, L
-
-    # -- introspection ---------------------------------------------------
-
-    @property
-    def slots(self):
-        return self._slots
-
-    @property
-    def cache_len(self):
-        return self._cache_len
-
-    @property
-    def param_bytes(self):
-        """Bytes of the weights the engine holds, as it holds them."""
-        return sum(int(a.nbytes) for a in self._params)
-
-    @property
-    def buckets(self):
-        """Prefill length buckets (sorted; one compiled program each)."""
-        return list(self._buckets)
-
-    @property
-    def dtype_policy_tag(self):
-        from . import dtype_policy as _dtp
-
-        return _dtp.policy_tag(self._dtype_policy)
-
-    @property
-    def cache_dtype(self):
-        return self._cache_dtype
-
-    @property
-    def mesh_shape(self):
-        from . import parallel
-
-        return parallel.mesh_shape(self._mesh)
-
-    def active_slots(self):
-        return [int(i) for i in np.nonzero(self._active)[0]]
-
-    def free_slots(self):
-        return len(self._free)
-
-    def position(self, slot):
-        """Tokens resident for ``slot`` (prompt + generated so far)."""
-        return int(self._pos[slot])
-
-    @property
-    def last_logits(self):
-        """f32 logits of the most recent prefill ((1, V), the admitted
-        sequence's last valid position) or decode step ((slots, V)) —
-        already computed by the dispatch, fetched here for tests and
-        logprob-surfacing callers."""
-        out = getattr(self, "_last_logits", None)
-        return None if out is None else np.asarray(out)
-
-    def occupancy(self):
-        """Cache occupancy snapshot: active lanes, resident tokens vs
-        ring capacity (the serving-dashboard gauges)."""
-        active = int(self._active.sum())
-        tokens = int(np.minimum(self._pos[self._active],
-                                self._cache_len).sum()) if active else 0
-        cap = self._slots * self._cache_len
-        return {"active_slots": active, "slots": self._slots,
-                "cache_tokens": tokens, "cache_capacity": cap,
-                "occupancy": tokens / cap if cap else 0.0}
-
-    def _note_occupancy(self):
-        occ = self.occupancy()
-        _telemetry.DECODE_ACTIVE_SLOTS.set(occ["active_slots"])
-        _telemetry.DECODE_CACHE_TOKENS.set(occ["cache_tokens"])
-
-    def bucket_for(self, length):
-        """Smallest prefill bucket >= ``length`` (raises when the
-        prompt exceeds every bucket)."""
-        for b in self._buckets:
-            if length <= b:
-                return b
-        raise MXNetError(
-            "prompt length %d exceeds the largest prefill bucket %d "
-            "(cache_len=%d; shorten the prompt or build the engine "
-            "with a longer cache)" % (length, self._buckets[-1],
-                                      self._cache_len))
-
-    def _next_key(self):
-        if self.sampling.greedy:
-            # greedy consumes nothing from the framework stream — the
-            # constant key keeps the compiled signature stable
-            return self._zero_key
-        from . import random as _random
-
-        return _random.next_key()
-
-    # -- lifecycle of one sequence ---------------------------------------
-
-    def admit(self, token_ids, slot=None):
-        """Prefill ``token_ids`` into a free lane.  Returns
-        ``(slot, first_token)`` — the first generated token (the TTFT
-        token), sampled inside the prefill dispatch.  Raises
-        :class:`Overloaded` (reason ``slots``) when no lane is free."""
-        import jax
-
-        token_ids = np.asarray(token_ids).astype(np.int32).reshape(-1)
-        n = token_ids.size
-        if n < 1:
-            raise MXNetError("admit needs at least one prompt token")
-        bucket = self.bucket_for(n)
-        if slot is None:
-            if not self._free:
-                raise Overloaded("slots", "all %d decode slots busy"
-                                 % self._slots)
-            slot = self._free.popleft()
-        else:
-            self._free.remove(slot)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = token_ids
-        key = self._next_key()
-        try:
-            next_tok, _logits, ck, cv = self._jit_prefill(
-                self._params, self._cache_k, self._cache_v, padded,
-                np.int32(n), np.int32(slot), key)
-        except Exception:
-            # donation makes the old cache unusable on failure; the
-            # lane goes back to the pool and the engine stays usable
-            # only if the cache arrays survived (non-donating fallback)
-            self._free.appendleft(slot)
-            raise
-        self._cache_k, self._cache_v = ck, cv
-        self._last_logits = _logits
-        tok = int(jax.device_get(next_tok)[0])
-        self._pos[slot] = n
-        self._cur_tok[slot] = tok
-        self._active[slot] = True
-        self._note_occupancy()
-        return slot, tok
-
-    def decode_step(self):
-        """One token for every active lane.  Returns ``{slot: token}``
-        (empty when nothing is active).  Inactive lanes compute
-        alongside (fixed shape) but their output is discarded."""
-        if not self._active.any():
-            return {}
-        # the step's phases as always-kept spans (docs/observability.md
-        # "Spans of the hot loops"); DECODE_STEP_SECONDS reads the span
-        with _tracing.begin("engine.decode", args={
-                "slots": int(self._active.sum()),
-                "live": int(self._pos[self._active].sum())}) as step:
-            with _tracing.begin("engine.decode:prep"):
-                key = self._next_key()
-                cur_tok, pos = self._cur_tok.copy(), self._pos.copy()
-            with _tracing.begin("engine.decode:launch"):
-                next_tok, _logits, ck, cv = self._jit_decode(
-                    self._params, self._cache_k, self._cache_v,
-                    cur_tok, pos, key)
-                self._cache_k, self._cache_v = ck, cv
-                self._last_logits = _logits
-            with _tracing.begin("engine.decode:readback"):
-                toks = np.asarray(next_tok)
-            with _tracing.begin("engine.decode:post"):
-                out = {}
-                for slot in np.nonzero(self._active)[0]:
-                    slot = int(slot)
-                    tok = int(toks[slot])
-                    out[slot] = tok
-                    self._cur_tok[slot] = tok
-                    self._pos[slot] += 1
-                _telemetry.DECODE_TOKENS.inc(len(out))
-                _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
-                self._note_occupancy()
-        _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
-        return out
-
-    def evict(self, slot, reason):
-        """Free lane ``slot`` (reason: ``eos`` / ``deadline`` /
-        ``length`` / ``cancelled`` / ``drain``).  The lane's ring is
-        overwritten by the next admit — no device work."""
-        if not self._active[slot]:
-            return
-        self._active[slot] = False
-        self._pos[slot] = 0
-        # LIFO reuse: the same request sequence lands on the same
-        # lanes run after run, which keeps SAMPLED generation
-        # reproducible under mx.random.seed (categorical splits its
-        # key per lane row)
-        self._free.appendleft(int(slot))
-        _telemetry.DECODE_EVICTIONS.inc(reason=reason)
-        self._note_occupancy()
-
-    def at_capacity(self, slot):
-        """True when ``slot`` exhausted the model's positions (the
-        ``length`` eviction the server applies): the ring slides past
-        ``cache_len``, but learned positions end at ``max_len``."""
-        return self._pos[slot] >= self.model_config["max_len"]
-
-    def prewarm(self):
-        """Compile — or load from the AOT store — the decode step and
-        every prefill bucket without generating (donation-safe: AOT
-        prewarm never executes).  Returns acquisition info dicts like
-        ``Predictor.prewarm``."""
-        from . import aot as _aot
-
-        infos = []
-        key = self._zero_key
-        if isinstance(self._jit_decode, _aot.AOTFunction):
-            infos.append(self._jit_decode.prewarm(
-                self._params, self._cache_k, self._cache_v,
-                np.zeros(self._slots, np.int32),
-                np.zeros(self._slots, np.int32), key))
-        for b in self._buckets:
-            if isinstance(self._jit_prefill, _aot.AOTFunction):
-                infos.append(self._jit_prefill.prewarm(
-                    self._params, self._cache_k, self._cache_v,
-                    np.zeros((1, b), np.int32), np.int32(1),
-                    np.int32(0), key))
-        if not infos:
-            infos.append({"label": "generate", "status": "disabled"})
-        return infos
-
-
-# ---------------------------------------------------------------------------
-# paged engine
 # ---------------------------------------------------------------------------
 
 def _ngram_draft(history, ngram, k):
@@ -846,13 +383,10 @@ class PagedGenerationEngine:
     masters); hand the weights over in the compute dtype to hold them
     once.
 
-    Greedy decode is token-identical to :class:`GenerationEngine` on
-    the same model.  Single-consumer, like the ring engine.
+    Single-consumer: one thread drives the engine (TokenServer's loop,
+    or a bench loop).  Admission control, deadlines, and futures live
+    in :class:`TokenServer`.
     """
-
-    # TokenServer switches to incremental admission (admit, then one
-    # prefill chunk per loop tick) when it sees this flag
-    incremental = True
 
     # block-diffusion decoding: the passes launched and not yet read
     # that a call leaves queued.  One keeps the device busy while the
@@ -1360,10 +894,9 @@ class PagedGenerationEngine:
         _telemetry.DECODE_PAGES_IN_USE.set(occ["pages_in_use"])
 
     def bucket_for(self, length):
-        """Admissibility check mirroring the ring engine's API: raises
-        when ``length`` exceeds a slot's page capacity, else returns the
-        chunk-padded prefill length (advisory; prefix hits shorten the
-        actual work)."""
+        """Admissibility check: raises when ``length`` exceeds a slot's
+        page capacity, else returns the chunk-padded prefill length
+        (advisory; prefix hits shorten the actual work)."""
         limit = min(self._capacity, self.model_config["max_len"])
         Bl = self._block
         if Bl > 1:
@@ -1590,10 +1123,10 @@ class PagedGenerationEngine:
             self._register_prefix(slot, token_ids, token_ids.size)
         self._note_occupancy()
 
-    def admit(self, token_ids, slot=None):
-        """Synchronous admission (ring-engine drop-in): claim a slot
-        and run every prefill chunk back to back.  Returns
-        ``(slot, first_token)``."""
+    def admit(self, token_ids):
+        """Synchronous admission, for a caller that drives the engine
+        by hand: claim a slot and run every prefill chunk back to back.
+        Returns ``(slot, first_token)``."""
         sl = self.admit_incremental(token_ids)
         while sl in self._pending:
             res = self.prefill_step(slot=sl)
@@ -1825,7 +1358,8 @@ class PagedGenerationEngine:
         self._pos[slot] = 0
         self._serial[slot] += 1
         self._release_slot_pages(slot)
-        # LIFO slot reuse, same reproducibility rationale as the ring
+        # LIFO reuse: the same request sequence lands on the same
+        # slots run after run
         self._free.appendleft(int(slot))
         _telemetry.DECODE_EVICTIONS.inc(reason=reason)
         self._note_occupancy()
@@ -1919,13 +1453,20 @@ class _GenRequest:
 
 class TokenServer:
     """Continuous-batching token front end over one
-    :class:`GenerationEngine`.
+    :class:`PagedGenerationEngine`.
 
     ``submit`` admits a prompt through a bounded queue and returns a
     :class:`ServingFuture` resolving to a :class:`GenerationResult`.
-    A background loop admits queued prompts into free decode slots
-    (prefill), steps every active slot one token per iteration, and
-    evicts on EOS, deadline, length cap, or cancellation.  The typed
+    A background loop admits queued prompts into free decode slots,
+    runs one prefill chunk and one decode step of every active slot a
+    tick, and evicts on EOS, deadline, length cap, or cancellation.
+    Of the engine it calls ``bucket_for`` (is the prompt admissible),
+    ``free_slots``, ``admit_incremental`` (claim a slot and its pages),
+    ``prefill_step`` (one chunk; ``(slot, first_token)`` when it ends a
+    prompt), ``decode_step`` (``{slot: [tokens]}``, a list a slot in
+    every mode), ``at_capacity``, ``evict`` and ``occupancy``, and reads
+    ``sampling.eos_id``, ``last_prefix_hit_tokens`` and ``pool_shape``
+    (for ``/statusz``).  The typed
     degradation contract is the serving_async taxonomy applied
     per-token:
 
@@ -1946,10 +1487,6 @@ class TokenServer:
                  shed_burn_threshold=2.0, shed_window_s=30.0,
                  shed_hist=None):
         self._engine = engine
-        # paged engines admit incrementally: the loop streams one
-        # prefill chunk per tick between decode steps instead of
-        # running the whole prompt inside admission
-        self._incremental = bool(getattr(engine, "incremental", False))
         if queue_depth is None:
             queue_depth = _config.get("MXNET_DECODE_QUEUE")
         self._depth = int(queue_depth)
@@ -2221,43 +1758,24 @@ class TokenServer:
                 if req.span is not None else None
             _telemetry.DECODE_QUEUE_WAIT_SECONDS.observe(
                 t_pick - req.t_submit, exemplar=ex)
-            if self._incremental:
-                # claim the slot + pages only; chunks run one per loop
-                # tick (the TTFT clock keeps running until the chunk
-                # that completes the prompt samples the first token)
-                try:
-                    slot = eng.admit_incremental(req.tokens,
-                                                 max_new=req.max_new)
-                except ServingError as e:
-                    self._fail(req, e)
-                    continue
-                except Exception as e:
-                    self._fail(req, ReplicaFailed(
-                        "prefill admission failed: %s" % (e,), cause=e))
-                    continue
-                req.slot = slot
-                req.prefix_hit = getattr(
-                    eng, "last_prefix_hit_tokens", None) or None
-                with self._cond:
-                    self._by_slot[slot] = req
-                admitted += 1
-                continue
+            # claim the slot + pages only; chunks run one per loop
+            # tick (the TTFT clock keeps running until the chunk
+            # that completes the prompt samples the first token)
             try:
-                slot, tok = eng.admit(req.tokens)
+                slot = eng.admit_incremental(req.tokens,
+                                             max_new=req.max_new)
             except ServingError as e:
                 self._fail(req, e)
                 continue
             except Exception as e:
                 self._fail(req, ReplicaFailed(
-                    "prefill dispatch failed: %s" % (e,), cause=e))
+                    "prefill admission failed: %s" % (e,), cause=e))
                 continue
             req.slot = slot
-            req.ttft = time.monotonic() - req.t_submit
-            _telemetry.DECODE_TTFT_SECONDS.observe(req.ttft, exemplar=ex)
+            req.prefix_hit = eng.last_prefix_hit_tokens or None
             with self._cond:
                 self._by_slot[slot] = req
             admitted += 1
-            self._deliver(req, slot, tok)
         return admitted
 
     def _deliver(self, req, slot, tok, last=True, fixed=None):
@@ -2318,11 +1836,11 @@ class TokenServer:
             self._cond.notify_all()
 
     def _prefill_tick(self):
-        """One chunked-prefill step (incremental engines): evict
-        cancelled/expired mid-prefill requests first — no point
-        streaming chunks for a dead request — then run ONE chunk; when
-        it completes a prompt, the sampled first token starts the
-        request's delivery (TTFT observed here)."""
+        """One chunked-prefill step: evict cancelled/expired
+        mid-prefill requests first — no point streaming chunks for a
+        dead request — then run ONE chunk; when it completes a prompt,
+        the sampled first token starts the request's delivery (TTFT
+        observed here)."""
         eng = self._engine
         with self._cond:
             stale = [(s, r) for s, r in self._by_slot.items()
@@ -2396,31 +1914,28 @@ class TokenServer:
         with _tracing.begin("serve.admit") as sp:
             self._sweep_queue()
             sp.set(admitted=self._admissions())
-        if self._incremental:
-            self._prefill_tick()
+        self._prefill_tick()
         toks = self._engine.decode_step()
         if toks:
             with _tracing.begin("serve.deliver", args={
-                    "tokens": sum(len(t) if isinstance(t, list) else 1
-                                  for t in toks.values())}):
+                    "tokens": sum(len(t) for t in toks.values())}):
                 self._deliver_step(toks)
         if self._shedder is not None:
             self._shedder.update()
 
     def _deliver_step(self, toks):
-        for slot, tok in toks.items():
+        for slot, burst in toks.items():
             with self._cond:
                 req = self._by_slot.get(slot)
             if req is None:
                 self._engine.evict(slot, "cancelled")
                 continue
-            # paged engines may emit several tokens per step (verified
-            # drafts; a committed block, or none while a block is
-            # open); _deliver's finish rules apply per token, so the
-            # overshoot past eos/max_new is truncated here
-            burst = tok if isinstance(tok, list) else [tok]
-            fixed = list(zip(tok.fixed_at, tok.confidence)) \
-                if isinstance(tok, BlockTokens) else None
+            # a step may emit several tokens a slot (verified drafts; a
+            # committed block, or none while a block is open);
+            # _deliver's finish rules apply per token, so the overshoot
+            # past eos/max_new is truncated here
+            fixed = list(zip(burst.fixed_at, burst.confidence)) \
+                if isinstance(burst, BlockTokens) else None
             for i, t in enumerate(burst):
                 if not self._deliver(
                         req, slot, t, last=i == len(burst) - 1,

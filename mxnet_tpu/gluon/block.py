@@ -69,7 +69,7 @@ def swapped_params(params, arrays, training=False):
     NDArray trace state, pins autograd ``training``, and restores
     everything on exit.  The one param-swap recipe shared by the traced
     front-ends (``serving.Predictor.from_block``,
-    ``generate.GenerationEngine``, ``tools/bench_decode.py``).  Holds
+    ``generate.PagedGenerationEngine``, ``tools/bench_decode.py``).  Holds
     :data:`_param_swap_lock` for the whole window."""
     from .. import autograd
 

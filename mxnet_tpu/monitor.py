@@ -85,7 +85,7 @@ class TelemetryHeartbeat:
             ttft99 = t.DECODE_TTFT_SECONDS.quantile(0.99)
             parts.append("ttft_p99_ms %.1f" % ((ttft99 or 0.0) * 1e3))
             parts.append("slots %d" % int(t.DECODE_ACTIVE_SLOTS.value()))
-            # paged-engine levers (omitted while the ring engine runs):
+            # the page pool's levers (each omitted while it reads 0):
             # page-pool fill, prefix-cache hit rate, and the share of
             # drafted tokens the verify step accepted
             pages = int(t.DECODE_PAGES_IN_USE.value())

@@ -280,23 +280,16 @@ register_layout(Layout("data_parallel", [
     SpecRule("replicated", r".*", ()),
 ]))
 
-# decode KV-cache lanes (generate.GenerationEngine): rank-5
-# (layers, slots, heads, ring, d_head) arrays named cache_k/cache_v —
-# slots shard over the data axes (each data shard serves its own
-# sequences), heads over tp (each tp shard attends over its own heads,
-# composing with the column-parallel proj_q/k/v below: the K/V a shard
-# caches are exactly the ones its projections produce).
-_KV_CACHE_FSDP = SpecRule("kv_cache", r"cache_(k|v)$",
-                          (None, ("dp", "fsdp")), rank=5)
-_KV_CACHE_TP = SpecRule("kv_cache", r"cache_(k|v)$",
-                        (None, ("dp", "fsdp"), "tp"), rank=5)
-# the paged engine's page pool (generate.PagedGenerationEngine) is
+# the decode engine's page pool (generate.PagedGenerationEngine) is
 # token-major: rank-3 (pages * page_size, layers, heads * d_head)
 # arrays named pool_k/pool_v, indexed on dimension 0 by the gather and
 # the scatter of every dispatch.  Tokens shard over the data axes (page
 # ids are host-side bookkeeping and index the whole pool, whichever
 # shard holds the row), the heads * d_head dimension over tp — whole
-# heads to a shard, like the ring, since heads are its major factor.
+# heads to a shard, since heads are its major factor: each tp shard
+# attends over its own heads, composing with the column-parallel
+# proj_q/k/v below (the K/V a shard caches are exactly the ones its
+# projections produce).
 # A tokens dim the data axes do not divide (the pool carries a +1
 # trash page) degrades to replicated there while heads stay
 # tp-sharded.
@@ -309,7 +302,6 @@ register_layout(Layout("fsdp", [
     # ZeRO-3: shard dim 0 of every matrix/conv kernel and the only dim
     # of every vector along fsdp; scalars replicated.  Optimizer state
     # follows its parameter (parallel.train places m/v/mom identically).
-    _KV_CACHE_FSDP,
     _KV_POOL_FSDP,
     SpecRule("matrix_dim0", r".*", ("fsdp",), min_rank=2),
     SpecRule("vector", r".*", ("fsdp",), rank=1),
@@ -317,7 +309,6 @@ register_layout(Layout("fsdp", [
 ]))
 
 register_layout(Layout("fsdp_tp", [
-    _KV_CACHE_TP,
     _KV_POOL_TP,
     # Megatron pairing on the mxnet (out_features, in_features) weight
     # convention: qkv/up projections column-parallel (tp on dim 0), the

@@ -838,16 +838,16 @@ SERVING_WARM_POOL_SPARES = gauge(
     "mxnet_tpu_serving_warm_pool_spares",
     "Pre-built spare replicas available to heal the next ejection.")
 
-# LM generation / decode tier (generate.GenerationEngine + TokenServer;
+# LM generation / decode tier (generate.PagedGenerationEngine + TokenServer;
 # see docs/lm_serving.md) — scraped through the PR 12 /metrics endpoint
 # so the serving dashboards see the decode tier next to predict
 DECODE_ACTIVE_SLOTS = gauge(
     "mxnet_tpu_decode_active_slots",
-    "Decode slots (KV-cache lanes) currently generating a sequence.")
+    "Decode slots currently generating a sequence.")
 DECODE_CACHE_TOKENS = gauge(
     "mxnet_tpu_decode_cache_tokens",
-    "Tokens resident across all active KV-cache lanes (occupancy = "
-    "this over slots x cache_len; GenerationEngine.occupancy()).")
+    "Tokens resident across all active decode slots (occupancy = "
+    "this over slots x cache_len; PagedGenerationEngine.occupancy()).")
 DECODE_EVICTIONS = counter(
     "mxnet_tpu_decode_evictions_total",
     "Sequences evicted from their decode slot, by reason (eos = "

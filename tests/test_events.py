@@ -282,8 +282,8 @@ def eng():
                        n_layers=2, max_len=24)
     lm.initialize(mx.init.Xavier())
     lm(nd.array(np.zeros((1, 4), np.float32)))
-    return generate.GenerationEngine(
-        lm, slots=2, cache_len=24, buckets=[8, 24],
+    return generate.PagedGenerationEngine(
+        lm, slots=2, cache_len=24, page_size=4, prefill_chunk=8,
         sampling=generate.SamplingConfig(greedy=True))
 
 

@@ -52,8 +52,8 @@ def lm():
 
 @pytest.fixture(scope="module")
 def eng(lm):
-    return generate.GenerationEngine(
-        lm, slots=3, cache_len=MAX_LEN, buckets=[8, MAX_LEN],
+    return generate.PagedGenerationEngine(
+        lm, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
         sampling=generate.SamplingConfig(greedy=True))
 
 

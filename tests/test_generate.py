@@ -1,25 +1,29 @@
 """LM generation engine (mxnet_tpu/generate.py): KV-cache decode
 correctness, sampling determinism, and continuous-batching serving.
 
-Tier-1 guards for the ISSUE 13 tentpole:
-* prefill logits are EXACTLY the full-context forward (same children,
-  same op sequence), and KV-cache decode logits match the full-context
-  forward to dtype rounding across f32 and bf16_mixed — prefill N then
-  decode 1 ≡ forward N+1;
+Tier-1 guards:
+* the model protocol: ``chunk_forward`` against a linear cache gives
+  the full-context forward's logits in float32, however the sequence is
+  cut into chunks (one chunk, chunks of 3, a token at a time), for both
+  served models (``TransformerLM`` and the zoo's ``MoEDecoderLM``);
+* the engine's greedy tokens are those of the full re-forward (the
+  independent reference every engine test is held to), on one device,
+  under a dp=2,tp=2 mesh, and to bf16 rounding under ``bf16_mixed``;
 * greedy decode is deterministic, and sampling decode is reproducible
   under the framework PRNG discipline (``mx.random.seed``);
+* a slot's cache ends a sequence: ``cache_len - prompt + 1`` tokens,
+  finish reason ``length``;
+* ``decode_step`` returns a list of tokens a slot in every mode (plain,
+  speculative, block-diffusion), which is what ``TokenServer`` relies
+  on;
 * the TokenServer applies the serving_async typed-error taxonomy
   per-token: Overloaded at admission, DeadlineExceeded tagged
   ``prefill`` vs ``decode`` (driven via ``testing/faults`` latency
-  injection), eviction counters by reason, drained close();
-* the KV-cache lanes resolve to the fsdp_tp layout's kv_cache rule
-  (slots over data axes, heads over tp) and a tp-meshed engine decodes
-  the same greedy tokens as the single-device one.
+  injection), eviction counters by reason, drained close().
 
-Kept lean for the tier-1 budget (suite runs ~680 s of the 870 s kill
-window): one module-scoped model + engine serves most tests, the
-engine programs are tiny (d_model 32), and the continuous-batching
-soak is marked ``slow``.
+Kept lean for the tier-1 budget: one module-scoped model + engine
+serves most tests, the engine programs are tiny (d_model 32), and the
+continuous-batching soak is marked ``slow``.
 """
 import os
 import sys
@@ -31,6 +35,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import generate, nd, telemetry
 from mxnet_tpu.base import MXNetError
+from mxnet_tpu.gluon.model_zoo.language import MoEDecoderLM
 from mxnet_tpu.testing import faults
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -65,11 +70,38 @@ def lm():
     return net
 
 
+def _moe(block_length=1, mask_token_id=None):
+    """The zoo's sparse GQA/RoPE decoder, tiny: 4 query and 2 key/value
+    heads of 8, 4 experts top-2 of width 16."""
+    mx.random.seed(0)
+    net = MoEDecoderLM(VOCAB, D_MODEL, N_LAYERS, 4, 2, 8, 4, 2, 16,
+                       block_length=block_length,
+                       mask_token_id=mask_token_id, max_len=MAX_LEN)
+    net.initialize(mx.init.Xavier())
+    return net
+
+
+@pytest.fixture(scope="module")
+def moe():
+    return _moe()
+
+
+@pytest.fixture(scope="module")
+def models(lm, moe):
+    return {"transformer_lm": lm, "moe_decoder_lm": moe}
+
+
+def _engine(net, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
+            sampling=None, **kw):
+    return generate.PagedGenerationEngine(
+        net, slots=slots, cache_len=cache_len, page_size=page_size,
+        prefill_chunk=prefill_chunk,
+        sampling=sampling or generate.SamplingConfig(greedy=True), **kw)
+
+
 @pytest.fixture(scope="module")
 def eng(lm):
-    return generate.GenerationEngine(
-        lm, slots=3, cache_len=MAX_LEN, buckets=[8, MAX_LEN],
-        sampling=generate.SamplingConfig(greedy=True))
+    return _engine(lm)
 
 
 def _prompt(n=5, seed=0):
@@ -77,83 +109,96 @@ def _prompt(n=5, seed=0):
         .astype(np.int32)
 
 
-def _full_logits(lm, token_ids):
+def _full_logits(net, token_ids):
     """Reference: full-context forward over the whole sequence."""
     toks = nd.array(np.asarray(token_ids, np.float32)[None])
-    return np.asarray(lm(toks)._data)[0]
+    return np.asarray(net(toks)._data)[0]
+
+
+def _greedy_reference(net, prompt, n):
+    """``n`` greedy tokens by full re-forward, float32: the reference
+    the engine is held to, independent of any cache.  Every forward
+    runs at the one shape (1, MAX_LEN), zeros after the sequence: under
+    a causal mask they reach no position before them."""
+    seq = np.zeros(MAX_LEN, np.int32)
+    seq[:len(prompt)] = prompt
+    out = []
+    for at in range(len(prompt), len(prompt) + n):
+        out.append(int(_full_logits(net, seq)[at - 1].argmax()))
+        if at < MAX_LEN:
+            seq[at] = out[-1]
+    return out
+
+
+def _generate(e, prompt, n):
+    """``n`` tokens of ``prompt`` from the engine driven by hand."""
+    slot, tok = e.admit(prompt)
+    out = [tok]
+    while len(out) < n:
+        out.extend(e.decode_step()[slot])
+    e.evict(slot, "length")
+    return out[:n]
 
 
 # ---------------------------------------------------------------------------
-# decode correctness: prefill N + decode 1 == forward N+1
+# the model protocol: chunk_forward == the full forward, however cut
 # ---------------------------------------------------------------------------
 
-def test_prefill_logits_bitmatch_full_forward(lm):
-    prompt = _prompt(6)
-    ref = _full_logits(lm, prompt)
-    logits_nd, caches = lm.prefill_forward(
-        nd.array(prompt[None].astype(np.float32)))
-    got = np.asarray(logits_nd._data)[0]
-    np.testing.assert_array_equal(got, ref)
-    assert len(caches) == N_LAYERS
-    assert caches[0][0].shape == (1, N_HEADS, 6, D_MODEL // N_HEADS)
-
-
-def test_decode_logits_match_full_forward_f32(lm):
-    """Eager-level: seed a ring from prefill, decode the next tokens,
-    compare every step's logits against one full-context forward."""
+@pytest.mark.parametrize("chunk", [9, 3, 1],
+                         ids=["one_chunk", "chunks_of_3", "token_at_a_time"])
+@pytest.mark.parametrize("model", ["transformer_lm", "moe_decoder_lm"])
+def test_chunk_forward_matches_full_forward_f32(models, model, chunk):
+    """Eager, no engine: feed 9 tokens through ``chunk_forward`` in
+    chunks against a linear cache that holds what the chunks before
+    wrote, and compare every position's logits with one full-context
+    forward."""
     import jax.numpy as jnp
 
-    prompt = _prompt(5)
-    seq = list(prompt)
-    # continue the sequence greedily for 6 steps to build a reference
-    full = _full_logits(lm, seq)
-    nxt = int(full[-1].argmax())
-    _pl, caches = lm.prefill_forward(
-        nd.array(np.asarray(seq, np.float32)[None]))
+    net = models[model]
+    cfg = net.config
+    seq = _prompt(9, seed=2)
+    ref = _full_logits(net, seq)
+    H = cfg.get("n_kv_heads", cfg["n_heads"])
+    dh = cfg.get("d_head", cfg["d_model"] // cfg["n_heads"])
     S = 16
-    ring = []
-    for k, v in caches:
-        kpad = jnp.zeros((1, N_HEADS, S, D_MODEL // N_HEADS), k.dtype)
-        ring.append((kpad.at[:, :, :len(seq)].set(k),
-                     jnp.zeros_like(kpad).at[:, :, :len(seq)].set(v)))
-    for _step in range(6):
-        seq.append(nxt)
-        pos = jnp.full((1,), len(seq) - 1, jnp.int32)
-        logits_nd, ring = lm.decode_forward(
-            jnp.asarray([nxt], jnp.int32), ring, pos)
-        got = np.asarray(logits_nd._data)[0]
-        ref = _full_logits(lm, seq)[-1]
-        np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-5)
-        nxt = int(got.argmax())
-        assert nxt == int(ref.argmax())
+    caches = [(jnp.zeros((1, H, S, dh), jnp.float32),) * 2
+              for _ in range(cfg["n_layers"])]
+    for start in range(0, len(seq), chunk):
+        res = net.chunk_forward(
+            jnp.asarray(seq[None, start:start + chunk]), caches,
+            jnp.asarray([start], jnp.int32))
+        got = np.asarray(res[0]._data)[0]
+        np.testing.assert_allclose(got, ref[start:start + chunk],
+                                   atol=2e-5, rtol=1e-5)
+        assert (got.argmax(-1) == ref[start:start + chunk].argmax(-1)).all()
+        assert len(res[1]) == cfg["n_layers"]
+        assert res[1][0][0].shape == (1, H, chunk, dh)
+        caches = [(k.at[:, :, start:start + chunk].set(kc),
+                   v.at[:, :, start:start + chunk].set(vc))
+                  for (k, v), (kc, vc) in zip(caches, res[1])]
 
 
-def test_engine_greedy_decode_matches_full_forward(lm, eng):
+# ---------------------------------------------------------------------------
+# decode correctness: the engine's greedy tokens == full re-forward
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("model", ["transformer_lm", "moe_decoder_lm"])
+def test_engine_greedy_decode_matches_full_forward(models, eng, model):
     """Engine-level (jitted): greedy generation equals full-context
     greedy re-forward, token for token."""
+    net = models[model]
+    e = eng if model == "transformer_lm" else _engine(net, slots=2)
     prompt = _prompt(5, seed=3)
-    slot, tok = eng.admit(prompt)
-    toks = [tok]
-    for _ in range(6):
-        toks.append(eng.decode_step()[slot])
-    eng.evict(slot, "length")
-    seq = list(prompt)
-    ref = []
-    for _ in range(7):
-        nxt = int(_full_logits(lm, seq)[-1].argmax())
-        ref.append(nxt)
-        seq.append(nxt)
-    assert toks == ref
+    assert _generate(e, prompt, 7) == _greedy_reference(net, prompt, 7)
 
 
 def test_engine_decode_matches_bf16_mixed(lm):
     """bf16_mixed engine: decode-step logits track the SAME policy's
-    prefill (== full-context forward under that policy) to bf16
-    rounding; cache dtype follows the policy compute dtype."""
-    e = generate.GenerationEngine(
-        lm, slots=2, cache_len=16, buckets=[16],
-        dtype_policy="bf16_mixed",
-        sampling=generate.SamplingConfig(greedy=True))
+    one-chunk prefill of the sequence so far (the full-context forward
+    under that policy) to bf16 rounding; cache dtype follows the policy
+    compute dtype."""
+    e = _engine(lm, slots=2, cache_len=16, prefill_chunk=16,
+                prefix_share=False, dtype_policy="bf16_mixed")
     assert e.cache_dtype == np.dtype("bfloat16")
     assert e.dtype_policy_tag == "bf16_mixed"
     prompt = _prompt(5, seed=4)
@@ -161,16 +206,16 @@ def test_engine_decode_matches_bf16_mixed(lm):
     seq = list(prompt) + [tok]
     for _ in range(4):
         step_toks = e.decode_step()
-        got = e.last_logits[slot]
-        # reference: prefill of the full sequence so far on the OTHER
-        # lane — prefill is exactly the full-context forward under the
-        # same policy/params (head stays f32 per the norm/head rules)
-        ref_slot, _rt = e.admit(np.asarray(seq, np.int32)[:16])
-        ref = e.last_logits[0]
+        got = e.last_logits[slot, 0]
+        # reference: prefill of the full sequence so far in the OTHER
+        # slot, one chunk from an empty cache (head stays f32 per the
+        # norm/head rules)
+        ref_slot, _rt = e.admit(np.asarray(seq, np.int32))
+        ref = e.last_logits[0, len(seq) - 1]
         e.evict(ref_slot, "length")
         np.testing.assert_allclose(got, ref, atol=0.12, rtol=0.05)
         assert int(got.argmax()) == int(ref.argmax())
-        seq.append(step_toks[slot])
+        seq.extend(step_toks[slot])
 
 
 # ---------------------------------------------------------------------------
@@ -178,24 +223,14 @@ def test_engine_decode_matches_bf16_mixed(lm):
 # ---------------------------------------------------------------------------
 
 def test_greedy_deterministic_and_sampling_reproducible(lm):
-    e = generate.GenerationEngine(
-        lm, slots=2, cache_len=16, buckets=[8],
-        sampling=generate.SamplingConfig(greedy=False, top_k=8,
-                                         temperature=0.9))
+    e = _engine(lm, slots=2, cache_len=16,
+                sampling=generate.SamplingConfig(greedy=False, top_k=8,
+                                                 temperature=0.9))
     prompt = _prompt(4, seed=5)
-
-    def run():
-        slot, tok = e.admit(prompt)
-        out = [tok]
-        for _ in range(5):
-            out.append(e.decode_step()[slot])
-        e.evict(slot, "length")
-        return out
-
     mx.random.seed(7)
-    a = run()
+    a = _generate(e, prompt, 6)
     mx.random.seed(7)
-    b = run()
+    b = _generate(e, prompt, 6)
     assert a == b, "sampled decode must be reproducible under seed"
     assert all(0 <= t < VOCAB for t in a)
 
@@ -216,7 +251,7 @@ def test_sample_logits_top_k_top_p():
 
 
 # ---------------------------------------------------------------------------
-# engine admission / ring
+# engine admission / a slot's capacity
 # ---------------------------------------------------------------------------
 
 def test_engine_slot_exhaustion_and_reuse(eng):
@@ -229,14 +264,14 @@ def test_engine_slot_exhaustion_and_reuse(eng):
     assert ei.value.reason == "slots"
     eng.evict(slots[1], "eos")
     slot, _tok = eng.admit(_prompt(4, seed=9))
-    assert slot == slots[1], "evicted lane must be reused"
+    assert slot == slots[1], "evicted slot must be reused"
     for s in slots:
         eng.evict(s, "length")
     assert eng.free_slots() == eng.slots
 
 
 def test_engine_prompt_too_long_and_occupancy(eng):
-    with pytest.raises(MXNetError, match="prefill bucket"):
+    with pytest.raises(MXNetError, match="paged cache capacity"):
         eng.admit(np.zeros(MAX_LEN + 1, np.int32))
     occ = eng.occupancy()
     assert occ["active_slots"] == 0 and occ["cache_tokens"] == 0
@@ -248,135 +283,94 @@ def test_engine_prompt_too_long_and_occupancy(eng):
     eng.evict(slot, "length")
 
 
-def test_ring_wraparound_past_cache_len(lm):
-    """cache_len < max_len: generation slides the attention window
-    through the ring without shape churn or failure."""
-    e = generate.GenerationEngine(
-        lm, slots=1, cache_len=8, buckets=[8],
-        sampling=generate.SamplingConfig(greedy=True))
-    slot, tok = e.admit(_prompt(6, seed=6))
+@pytest.fixture(scope="module")
+def short_cache(lm):
+    """One slot of 8 positions under a model of 24."""
+    return _engine(lm, slots=1, cache_len=8, prefill_chunk=4)
+
+
+def test_cache_shorter_than_max_len_ends_the_sequence(lm, short_cache):
+    """cache_len < max_len: a slot holds ``cache_len`` positions and no
+    window slides.  A prompt of 6 in a cache of 8 yields the token its
+    prefill samples and one for each of the two positions left, the
+    full re-forward's tokens, and is then at capacity."""
+    e = short_cache
+    assert e.cache_len == 8 < MAX_LEN
+    prompt = _prompt(6, seed=6)
+    slot, tok = e.admit(prompt)
     produced = [tok]
-    # decode well past the ring (6 prompt + 10 > 8) up to max_len
     while not e.at_capacity(slot):
-        produced.append(e.decode_step()[slot])
-    # one token per position 6..23, plus the final step's sample
-    # (produced at capacity, never fed back)
-    assert len(produced) == MAX_LEN - 6 + 1
-    assert all(0 <= t < VOCAB for t in produced)
+        produced.extend(e.decode_step()[slot])
+    assert e.position(slot) == e.cache_len
     e.evict(slot, "length")
+    assert len(produced) == e.cache_len - len(prompt) + 1
+    assert produced == _greedy_reference(lm, prompt, len(produced))
+
+
+@pytest.mark.parametrize("n_prompt", [6, 8],
+                         ids=["two_positions_left", "prompt_fills_it"])
+def test_server_finishes_by_length_at_the_cache_end(lm, short_cache,
+                                                    n_prompt):
+    """Through ``TokenServer`` such a sequence resolves with
+    ``finish_reason`` ``length`` well under ``max_new_tokens``; a prompt
+    that fills the cache yields the one token its prefill samples."""
+    prompt = _prompt(n_prompt, seed=6)
+    with generate.TokenServer(short_cache, max_new_tokens=64) as srv:
+        r = srv.generate(prompt, timeout=60)
+    assert r.finish_reason == "length"
+    assert r.tokens == _greedy_reference(lm, prompt, 8 - n_prompt + 1)
+    assert short_cache.free_slots() == 1
+
+
+def test_engine_refuses_a_model_without_the_protocol(lm):
+    """One protocol: a net without ``chunk_forward`` (or ``config``) is
+    named as such when the engine is built, not at its first trace."""
+    class NoProtocol:
+        config = lm.config
+
+    with pytest.raises(MXNetError, match="chunk_forward / config"):
+        generate.PagedGenerationEngine(NoProtocol())
+
+
+@pytest.mark.parametrize("mode", ["plain", "speculative", "block"])
+def test_decode_step_returns_a_list_a_slot(lm, mode):
+    """What ``TokenServer`` relies on: in every mode ``decode_step``
+    maps each active slot to a list (one token; the verified drafts and
+    one; none while a block is open, then a committed block)."""
+    if mode == "block":
+        e = _engine(_moe(block_length=4, mask_token_id=VOCAB - 1), slots=2,
+                    prefill_chunk=8, spec_k=0, denoise_steps=2)
+    else:
+        e = _engine(lm, slots=2, spec_k=2 if mode == "speculative" else 0,
+                    spec_ngram=2)
+    prompt = np.tile(_prompt(3, seed=7), 3)[:8].astype(np.int32)
+    slot, _tok = e.admit(prompt)
+    bursts = []
+    for _ in range(8):
+        out = e.decode_step()
+        assert set(out) == {slot}
+        assert isinstance(out[slot], list), type(out[slot])
+        bursts.append(len(out[slot]))
+    e.evict(slot, "length")
+    assert sum(bursts) > 0
+    if mode == "plain":
+        assert bursts == [1] * 8
+    elif mode == "block":
+        assert set(bursts) == {0, 4}, bursts
 
 
 # ---------------------------------------------------------------------------
-# KV-cache sharding layout + tp-meshed engine
+# a tp-meshed engine
 # ---------------------------------------------------------------------------
 
-def test_kv_cache_layout_rule():
-    from mxnet_tpu import parallel
-    from mxnet_tpu.parallel import layout as playout
-
-    mesh = parallel.resolve_mesh("dp=2,fsdp=2,tp=2")
-    shape = (N_LAYERS, 4, 2, 16, 16)   # (L, slots, H, S, dh)
-    res = playout.get_layout("fsdp_tp").resolve(
-        [("cache_k", shape), ("cache_v", shape)], mesh)
-    assert res.rule("cache_k") == "kv_cache"
-    spec = res.spec("cache_k")
-    # slots over the data axes, heads over tp, ring/d_head unsharded
-    assert tuple(spec) == (None, ("dp", "fsdp"), "tp")
-    res2 = playout.get_layout("fsdp").resolve(
-        [("cache_k", shape)], parallel.resolve_mesh("fsdp=2"))
-    assert res2.rule("cache_k") == "kv_cache"
-
-
-def test_engine_tp_mesh_matches_single_device(lm, eng):
-    """tp serving composes with the PR 9 mesh: a dp=2,tp=2 engine
-    produces the same greedy tokens as the single-device engine."""
-    e = generate.GenerationEngine(
-        lm, slots=2, cache_len=16, buckets=[8], mesh="dp=2,tp=2",
-        sampling=generate.SamplingConfig(greedy=True))
+def test_engine_tp_mesh_matches_full_forward(lm):
+    """tp serving composes with the training mesh: a dp=2,tp=2 engine
+    produces the greedy tokens of the full re-forward."""
+    e = _engine(lm, slots=2, cache_len=16, mesh="dp=2,tp=2")
     assert e.layout_name == "fsdp_tp"
     assert e.mesh_shape == {"dp": 2, "tp": 2}
     prompt = _prompt(5, seed=3)
-    slot, tok = e.admit(prompt)
-    toks = [tok]
-    for _ in range(4):
-        toks.append(e.decode_step()[slot])
-    e.evict(slot, "length")
-    ref_slot, ref_tok = eng.admit(prompt)
-    ref = [ref_tok]
-    for _ in range(4):
-        ref.append(eng.decode_step()[ref_slot])
-    eng.evict(ref_slot, "length")
-    assert toks == ref
-
-
-# ---------------------------------------------------------------------------
-# the weights the ring engine holds (ISSUE 29; both engines side by side
-# in tests/test_paged_decode.py)
-# ---------------------------------------------------------------------------
-
-def _tokens(e, prompt, n):
-    slot, tok = e.admit(prompt)
-    out = [tok] + [e.decode_step()[slot] for _ in range(n)]
-    e.evict(slot, "length")
-    return out
-
-
-def test_ring_engine_weights_are_a_snapshot_in_the_compute_dtype():
-    """The engine holds what the parameters were when it was built, cast
-    once: a later change to the network's float32 masters reaches no
-    dispatch, and the held weights take less than the masters."""
-    from mxnet_tpu import tracing
-
-    mx.random.seed(1)
-    net = TransformerLM(vocab_size=VOCAB, d_model=D_MODEL, n_heads=N_HEADS,
-                        n_layers=N_LAYERS, max_len=MAX_LEN)
-    net.initialize(mx.init.Xavier())
-    net(nd.array(np.zeros((1, 4), np.float32)))
-    e = generate.GenerationEngine(
-        net, slots=2, cache_len=16, buckets=[8], dtype_policy="bf16_mixed",
-        sampling=generate.SamplingConfig(greedy=True))
-    span = [r for r in tracing.records() if r["name"] == "engine.weights"][-1]
-    masters = sum(int(np.prod(p.shape)) * 4
-                  for p in net.collect_params().values())
-    assert e.param_bytes == span["args"]["held_bytes"] < masters
-    assert span["args"]["cast_bytes"] == masters - e.param_bytes > 0
-    prompt = _prompt(5, seed=9)
-    before = _tokens(e, prompt, 4)
-    for p in net.collect_params().values():
-        p.set_data(nd.array(np.zeros(p.shape, np.float32)))
-    assert _tokens(e, prompt, 4) == before
-    assert len(set(before)) > 1, "a model of zeros would pass as well"
-
-
-def test_ring_engine_mesh_prefill_same_on_held_and_master_weights(lm):
-    """dp=2,tp=2 under ``bf16_mixed``: a prefill on the held (cast)
-    weights gives bit for bit what the same program gives on the
-    float32 masters placed under the same shardings."""
-    import jax
-    import jax.numpy as jnp
-
-    e = generate.GenerationEngine(
-        lm, slots=2, cache_len=16, buckets=[8], mesh="dp=2,tp=2",
-        dtype_policy="bf16_mixed",
-        sampling=generate.SamplingConfig(greedy=True))
-    assert {str(a.dtype) for a in e._params} == {"bfloat16", "float32"}
-    masters = tuple(jax.device_put(p.data()._data, a.sharding)
-                    for p, a in zip(lm.collect_params().values(), e._params))
-    tokens = np.zeros((1, 8), np.int32)
-    tokens[0, :5] = _prompt(5, seed=3)
-
-    def prefill(weights):
-        tok, logits, ck, _cv = e._jit_prefill(
-            weights, jnp.copy(e._cache_k), jnp.copy(e._cache_v), tokens,
-            np.int32(5), np.int32(1), jax.random.PRNGKey(0))
-        return int(tok[0]), np.asarray(logits), \
-            np.asarray(ck.astype(np.float32))
-
-    got, want = prefill(e._params), prefill(masters)
-    assert got[0] == want[0]
-    np.testing.assert_array_equal(got[1], want[1])
-    np.testing.assert_array_equal(got[2], want[2])
-    assert np.abs(got[2]).max() > 0
+    assert _generate(e, prompt, 5) == _greedy_reference(lm, prompt, 5)
 
 
 # ---------------------------------------------------------------------------
@@ -409,6 +403,19 @@ def test_server_generates_and_finishes_by_reason(lm, eng):
     finally:
         srv.close()
     assert eng.free_slots() == eng.slots
+
+
+def test_statusz_names_the_pool_of_a_live_server(eng):
+    """The ``decode`` subsystem of ``/statusz`` reads every live
+    server's engine without asking what kind it is: its pool's shape
+    and the page counters of its occupancy."""
+    with generate.TokenServer(eng, queue_depth=4) as srv:
+        (st,) = [s for s in generate._decode_statusz()["servers"]
+                 if s["pool_shape"] == list(eng.pool_shape)
+                 and not s["closed"]]
+        assert st["free_slots"] == eng.slots
+        assert st["occupancy"]["pages_total"] == eng.num_pages - 1
+        assert srv.stats()["closed"] is False
 
 
 def test_server_overload_queue_and_shutdown(lm, eng):
@@ -503,9 +510,7 @@ def test_server_cancel_and_drain(lm, eng):
 def test_server_continuous_batching_soak(lm):
     """Churn: more requests than slots x few, mixed lengths/deadlines,
     every future resolves, no slot/queue leaks."""
-    e = generate.GenerationEngine(
-        lm, slots=3, cache_len=16, buckets=[8],
-        sampling=generate.SamplingConfig(greedy=True))
+    e = _engine(lm, cache_len=16)
     srv = generate.TokenServer(e, queue_depth=32, max_new_tokens=6)
     rng = np.random.RandomState(0)
     futs = []

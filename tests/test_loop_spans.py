@@ -162,24 +162,6 @@ def test_server_tick_tree_is_kept_with_tracing_off(untraced, engine):
     assert not any(r["name"] == "decode.request" for r in recs)
 
 
-def test_ring_engine_decode_step_has_the_same_tree(untraced):
-    mx.random.seed(0)
-    net = TransformerLM(vocab_size=48, d_model=32, n_heads=2, n_layers=1,
-                        max_len=16)
-    net.initialize(mx.init.Xavier())
-    net(nd.array(np.zeros((1, 4), np.float32)))
-    eng = generate.GenerationEngine(
-        net, slots=2, cache_len=16, buckets=[8],
-        sampling=generate.SamplingConfig(greedy=True))
-    eng.admit(np.asarray([1, 2, 3], np.int32))
-    tracing.reset()
-    assert len(eng.decode_step()) == 1
-    recs = tracing.records()
-    dec = [r for r in recs if r["name"] == "engine.decode"]
-    assert len(dec) == 1 and dec[0]["args"] == {"slots": 1, "live": 3}
-    assert [k["name"] for k in _kids(recs, dec[0])] == DECODE_PHASES
-
-
 def test_decode_step_histogram_reads_the_span(untraced, engine):
     from mxnet_tpu import telemetry
 

@@ -1,9 +1,11 @@
-"""Paged KV-cache decode (ISSUE 16 tentpole): the block-pool engine's
-correctness contract against the ring engine, plus each serving lever.
+"""Paged KV-cache decode (ISSUE 16 tentpole): the page-pool engine's
+correctness contract against the full re-forward, plus each serving
+lever.
 
 Tier-1 guards:
-* paged greedy decode is TOKEN-IDENTICAL to the ring engine — on one
-  device (f32) AND under a dp=2,tp=2 mesh (the pool resolves through
+* paged greedy decode is TOKEN-IDENTICAL to greedy decoding by full
+  re-forward (float32, no cache) on one device, and a dp=2,tp=2 mesh
+  decodes the one-device engine's tokens (the pool resolves through
   the layout registry's `pool_k|v` rule);
 * chunked prefill produces the same tokens and decode logits as a
   single-chunk (monolithic) prefill of the same prompt;
@@ -14,15 +16,15 @@ Tier-1 guards:
   refcount-0 pages in the retained LRU on eviction, re-attaches them,
   and reclaims them under pool pressure;
 * admission raises the typed `Overloaded` reasons (``slots`` /
-  ``pages``) and the paged TokenServer end-to-end output (chunked +
-  shared + speculative) matches the ring TokenServer's;
+  ``pages``) and the TokenServer's end-to-end output (chunked +
+  shared + speculative) matches the full re-forward's;
 * the new bench-mode ledger metrics gate in the right direction;
 * the pool is token-major (ISSUE 27): both index operations of the one
   dispatch address the donated pool's dimension 0 with nothing
   pool-sized before them, the TPU compiler (no chip: a described
   v5e) copies no pool and expands no gather into a loop at OPT-1.3B's
-  widths, mixed batches match the ring engine, and a mesh resolves the
-  pool under its own ``kv_pool`` rule.
+  widths, mixed batches match the full re-forward, and a mesh resolves
+  the pool under its own ``kv_pool`` rule.
 
 Engine programs stay tiny (d_model 32, cache 24) for the tier-1
 budget; every paged engine compiles at most three chunk signatures.
@@ -63,13 +65,6 @@ def lm():
 
 
 @pytest.fixture(scope="module")
-def ring(lm):
-    return generate.GenerationEngine(
-        lm, slots=3, cache_len=MAX_LEN, buckets=[8, MAX_LEN],
-        sampling=generate.SamplingConfig(greedy=True))
-
-
-@pytest.fixture(scope="module")
 def paged(lm):
     return generate.PagedGenerationEngine(
         lm, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
@@ -82,33 +77,44 @@ def _prompt(n=5, seed=0):
 
 
 def _drain(eng, slot, steps):
-    """``steps`` decode ticks for one slot, flattening the paged
-    engine's per-step token lists."""
+    """``steps`` decode ticks for one slot, its per-step token lists
+    flattened."""
     out = []
     for _ in range(steps):
-        got = eng.decode_step()[slot]
-        out.extend(got if isinstance(got, list) else [got])
+        out.extend(eng.decode_step()[slot])
+    return out
+
+
+def _greedy_reference(lm, prompt, n):
+    """``n`` greedy tokens by full re-forward (``lm(tokens)``, argmax
+    at the last position, float32): the reference independent of any
+    cache.  Every forward runs at the one shape (1, MAX_LEN), zeros
+    after the sequence: under the causal mask they reach no position
+    before them."""
+    seq = np.zeros(MAX_LEN, np.float32)
+    seq[:len(prompt)] = prompt
+    out = []
+    for at in range(len(prompt), len(prompt) + n):
+        logits = np.asarray(lm(nd.array(seq[None]))._data)[0]
+        out.append(int(logits[at - 1].argmax()))
+        if at < MAX_LEN:
+            seq[at] = out[-1]
     return out
 
 
 # ---------------------------------------------------------------------------
-# paged == ring, single device and meshed
+# paged == full re-forward on one device; meshed == one device
 # ---------------------------------------------------------------------------
 
-def test_paged_greedy_matches_ring(ring, paged):
+def test_paged_greedy_matches_full_forward(lm, paged):
     """The tentpole's correctness bar: same prompt, same greedy
     tokens, token for token — the page-table gather/scatter is
-    semantically the ring cache."""
+    semantically a linear cache of the sequence."""
     prompt = _prompt(9, seed=3)
-    r_slot, r_tok = ring.admit(prompt)
-    ref = [r_tok] + _drain(ring, r_slot, 8)
-    ring.evict(r_slot, "length")
     p_slot, p_tok = paged.admit(prompt)
-    got = [p_tok]
-    while len(got) < len(ref):
-        got.extend(_drain(paged, p_slot, 1))
+    got = [p_tok] + _drain(paged, p_slot, 8)
     paged.evict(p_slot, "length")
-    assert got == ref
+    assert got == _greedy_reference(lm, prompt, 9)
 
 
 def test_paged_mesh_matches_single_device(lm, paged):
@@ -300,27 +306,21 @@ def test_paged_overloaded_slots(lm):
 
 
 # ---------------------------------------------------------------------------
-# TokenServer end to end: every lever on == ring output
+# TokenServer end to end: every lever on == full re-forward
 # ---------------------------------------------------------------------------
 
-def test_server_paged_levers_match_ring(lm, ring):
-    """The integration bar: a paged TokenServer with chunked prefill,
-    prefix sharing, AND speculation serves the same greedy tokens as
-    the ring TokenServer, prompt for prompt."""
+def test_server_paged_levers_match_full_forward(lm):
+    """The integration bar: a TokenServer with chunked prefill, prefix
+    sharing, AND speculation serves the greedy tokens of the full
+    re-forward, prompt for prompt."""
     paged_eng = generate.PagedGenerationEngine(
         lm, slots=2, cache_len=MAX_LEN, page_size=4, prefill_chunk=3,
         spec_k=2, spec_ngram=2, prefix_share=True,
         sampling=generate.SamplingConfig(greedy=True))
     prompts = [_prompt(9, seed=8), _prompt(5, seed=9),
                _prompt(9, seed=8)]   # the repeat exercises the hit path
-    ref, got = [], []
-    srv = generate.TokenServer(ring, max_new_tokens=6)
-    try:
-        for p in prompts:
-            ref.append(srv.generate(p, max_new_tokens=6,
-                                    timeout=60).tokens)
-    finally:
-        srv.close()
+    ref = [_greedy_reference(lm, p, 6) for p in prompts]
+    got = []
     srv = generate.TokenServer(paged_eng, max_new_tokens=6)
     try:
         for p in prompts:
@@ -342,7 +342,7 @@ def test_perf_gate_directions_for_paged_metrics():
     import perf_gate
 
     assert perf_gate.higher_is_better(
-        "lm_decode_paged_tokens_per_sec_per_user", "tokens/sec/user")
+        "lm_decode_tokens_per_sec_per_user", "tokens/sec/user")
     assert perf_gate.higher_is_better(
         "lm_decode_prefix_share_tokens_per_sec", "tokens/sec")
     assert perf_gate.higher_is_better(
@@ -584,12 +584,8 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
 # the weights an engine holds (ISSUE 29): cast once, when it is built
 # ---------------------------------------------------------------------------
 
-def _build(kind, net, **kw):
-    """A small engine of either kind over ``net``."""
-    if kind == "ring":
-        return generate.GenerationEngine(
-            net, slots=3, cache_len=MAX_LEN, buckets=[8, MAX_LEN],
-            sampling=generate.SamplingConfig(greedy=True), **kw)
+def _build(net, **kw):
+    """A small engine over ``net``."""
     return generate.PagedGenerationEngine(
         net, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
         sampling=generate.SamplingConfig(greedy=True), **kw)
@@ -631,8 +627,7 @@ def lm_on_device():
     return _on_device(_small_lm())
 
 
-@pytest.mark.parametrize("kind", ["paged", "ring"])
-def test_engine_holds_weights_in_the_rules_dtypes(lm_on_device, kind):
+def test_engine_holds_weights_in_the_rules_dtypes(lm_on_device):
     """Under ``bf16_mixed`` with float32 parameters every held array has
     the dtype the policy's rules give that parameter (norms and the head
     stay float32), the span says what was cast, and the network keeps
@@ -641,7 +636,7 @@ def test_engine_holds_weights_in_the_rules_dtypes(lm_on_device, kind):
 
     lm = lm_on_device
     policy = dtype_policy.get_policy("bf16_mixed")
-    eng, span = _weights_span(lambda: _build(kind, lm,
+    eng, span = _weights_span(lambda: _build(lm,
                                              dtype_policy="bf16_mixed"))
     params = list(lm.collect_params().values())
     assert [p.name for p in params] == eng._param_names
@@ -663,10 +658,9 @@ def test_engine_holds_weights_in_the_rules_dtypes(lm_on_device, kind):
     assert _pointers(a for a, _m in kept) == _pointers(m for _a, m in kept)
 
 
-@pytest.mark.parametrize("kind", ["paged", "ring"])
-def test_engine_without_a_policy_casts_nothing(lm_on_device, kind):
+def test_engine_without_a_policy_casts_nothing(lm_on_device):
     lm = lm_on_device
-    eng, span = _weights_span(lambda: _build(kind, lm, dtype_policy="f32"))
+    eng, span = _weights_span(lambda: _build(lm, dtype_policy="f32"))
     masters = [p.data()._data for p in lm.collect_params().values()]
     assert _pointers(eng._params) == _pointers(masters)
     assert span["cast_bytes"] == 0 and span["aliased"] == len(masters)
@@ -694,14 +688,13 @@ def test_weights_handed_over_at_their_targets_are_the_same_buffers():
                     "cast_bytes": 0, "aliased": len(masters)}
 
 
-@pytest.mark.parametrize("kind", ["paged", "ring"])
-def test_held_weights_keep_their_sharding(lm, kind):
+def test_held_weights_keep_their_sharding(lm):
     """Under a mesh the cast copy lies where the layout put the master."""
     from jax.sharding import NamedSharding
 
     from mxnet_tpu import parallel
 
-    e = _build(kind, lm, mesh="dp=2,tp=2", dtype_policy="bf16_mixed")
+    e = _build(lm, mesh="dp=2,tp=2", dtype_policy="bf16_mixed")
     params = list(lm.collect_params().values())
     res = parallel.layout.get_layout(e.layout_name).resolve(
         [(p.name, tuple(p.shape)) for p in params], e._mesh)
@@ -727,21 +720,6 @@ def _weight_converts(jitted, args, n_params):
             and any(v is w for v in e.invars for w in weights)]
 
 
-def _ring_dispatch_args(eng, shape, params):
-    import jax
-    import jax.numpy as jnp
-
-    ck, cv = jnp.copy(eng._cache_k), jnp.copy(eng._cache_v)
-    key = jax.random.PRNGKey(0)
-    if shape == "prefill":
-        tokens = np.zeros((1, 8), np.int32)
-        tokens[0, :5] = _prompt(5, seed=7)
-        return eng._jit_prefill, (params, ck, cv, tokens, np.int32(5),
-                                  np.int32(1), key)
-    return eng._jit_decode, (params, ck, cv, np.arange(3, dtype=np.int32),
-                             np.array([4, 2, 0], np.int32), key)
-
-
 def _paged_dispatch_args(eng, shape, params):
     """A dispatch with live page tables, tokens and write rows, on
     copies of the pools (the dispatch donates them)."""
@@ -764,8 +742,7 @@ def _paged_dispatch_args(eng, shape, params):
 
 
 @pytest.mark.parametrize("shape", ["prefill", "decode"])
-@pytest.mark.parametrize("kind", ["paged", "ring"])
-def test_dispatch_casts_no_weight_and_computes_the_same(lm, kind, shape):
+def test_dispatch_casts_no_weight_and_computes_the_same(lm, shape):
     """The program traced on the held weights has no ``convert`` of a
     parameter; called with the float32 masters it is the old program,
     which casts every one the rules do not keep, and the two give
@@ -773,13 +750,12 @@ def test_dispatch_casts_no_weight_and_computes_the_same(lm, kind, shape):
     moved from every program to the constructor, and nothing else."""
     import jax
 
-    eng = _build(kind, lm, dtype_policy="bf16_mixed")
+    eng = _build(lm, dtype_policy="bf16_mixed")
     masters = tuple(jax.device_put(p.data()._data)
                     for p in lm.collect_params().values())
-    make = _ring_dispatch_args if kind == "ring" else _paged_dispatch_args
     n = len(masters)
-    jitted, held_args = make(eng, shape, eng._params)
-    _jitted, master_args = make(eng, shape, masters)
+    jitted, held_args = _paged_dispatch_args(eng, shape, eng._params)
+    _jitted, master_args = _paged_dispatch_args(eng, shape, masters)
     assert _weight_converts(jitted, held_args, n) == []
     n_cast = sum(str(a.dtype) == "bfloat16" for a in eng._params)
     assert len(_weight_converts(jitted, master_args, n)) == n_cast > 0
@@ -795,15 +771,9 @@ def test_dispatch_casts_no_weight_and_computes_the_same(lm, kind, shape):
 
 
 # ---------------------------------------------------------------------------
-# mixed batches == ring, and the pool's own layout rule under a mesh
+# mixed batches == full re-forward, and the pool's own layout rule under
+# a mesh
 # ---------------------------------------------------------------------------
-
-def _ring_tokens(ring, prompt, n):
-    slot, tok = ring.admit(prompt)
-    out = [tok] + _drain(ring, slot, n - 1)
-    ring.evict(slot, "length")
-    return out
-
 
 def _side_by_side(eng, prompts, steps):
     """Admit every prompt, decode ``steps`` steps with all of them
@@ -865,11 +835,11 @@ def _mixed_trash_collisions(eng):
 @pytest.mark.parametrize("scenario", [
     _mixed_inside_page, _mixed_evict_reuse, _mixed_trash_collisions],
     ids=["ends_inside_page", "evict_and_reuse", "trash_collisions"])
-def test_mixed_batch_matches_ring(ring, paged, scenario):
+def test_mixed_batch_matches_full_forward(lm, paged, scenario):
     prompts, got = scenario(paged)
     assert paged.pages_in_use() == 0
     for prompt, toks in zip(prompts, got):
-        assert toks == _ring_tokens(ring, prompt, len(toks))
+        assert toks == _greedy_reference(lm, prompt, len(toks))
 
 
 @pytest.mark.parametrize("mesh,spec", [

@@ -238,8 +238,8 @@ def _records_bench_io():
 
 
 def _records_bench_decode():
-    # every bench_decode mode: the ring bench plus the four paged-lever
-    # modes (--paged / --prefix-share / --chunked-prefill / --spec),
+    # every bench_decode mode: the default two-phase bench plus the
+    # three lever modes (--prefix-share / --chunked-prefill / --spec),
     # each with its own canned result and headline metric
     import bench_decode
 
@@ -247,7 +247,7 @@ def _records_bench_decode():
     for mode, canned in sorted(bench_decode.CANNED_MODE_RESULTS.items()):
         recs += bench_decode.ledger_records(canned)
     metrics = {r["metric"] for r in recs}
-    assert {"lm_decode_paged_tokens_per_sec_per_user",
+    assert {"lm_decode_tokens_per_sec_per_user",
             "lm_decode_prefix_share_tokens_per_sec",
             "lm_decode_prefix_hit_rate",
             "lm_decode_ttft_interference_p99_ms",

@@ -138,7 +138,7 @@ def profile_decode(args):
     a few steps under the unified trace and return its hot-op ranking
     plus the SMALL-BATCH, cache-length-keyed operand shapes decode
     actually runs — token-step GEMMs are (slots x d_model)-thin and
-    the attention softmax·V chain is keyed by the ring length, shapes
+    the attention softmax·V chain is keyed by the cache length, shapes
     the train-profile corpus never sees."""
     import tempfile
 
@@ -153,7 +153,7 @@ def profile_decode(args):
     log("profiling KV-cache decode engine (%d steps)"
         % args.decode_steps)
     lm, cfg = bench_decode.build_lm(max_len=args.decode_cache_len)
-    eng = generate.GenerationEngine(
+    eng = generate.PagedGenerationEngine(
         lm, slots=args.decode_slots, cache_len=args.decode_cache_len,
         dtype_policy=args.dtype_policy)
     import numpy as np
@@ -164,7 +164,7 @@ def profile_decode(args):
     out = None
     for _ in range(max(1, args.decode_steps)):
         out = eng.decode_step()
-    jax.block_until_ready(eng._cache_k)
+    jax.block_until_ready(eng._pool_k)
     del out
     path = os.path.join(tempfile.mkdtemp(prefix="mxnet_tpu_decode_"),
                         "decode_trace.json")
@@ -173,7 +173,7 @@ def profile_decode(args):
     B, D, V = eng.slots, cfg["d_model"], cfg["vocab"]
     H, S = cfg["n_heads"], eng.cache_len
     # decode's dominant GEMM operand shapes: the (slots x D) token-step
-    # projections/FFN/head, and the (slots*heads x ring) attention
+    # projections/FFN/head, and the (slots*heads x cache_len) attention
     # score/value rows the softmax·V fusion would act on
     shapes = [(B, D), (B, 4 * D), (B, V), (B * H, S)]
     meta = {"model": {k: cfg[k] for k in ("vocab", "d_model", "n_heads",
@@ -369,7 +369,7 @@ def main(argv=None):
     p.add_argument("--decode-slots", type=int, default=8,
                    help="--decode: engine decode slots (default 8)")
     p.add_argument("--decode-cache-len", type=int, default=128,
-                   help="--decode: KV ring length profiled (default "
+                   help="--decode: KV cache length profiled (default "
                         "128)")
     p.add_argument("--patterns", help="comma list (default: all "
                                       "registered)")

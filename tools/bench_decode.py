@@ -13,30 +13,29 @@ the algorithm, not eager dispatch overhead):
 
 1. **Baseline**: one fixed-shape full-context forward per generated
    token (compiled once at ``--ctx``), greedy next-token on the host.
-2. **Engine**: ``GenerationEngine`` + ``TokenServer`` serving
-   ``--users`` concurrent prompts with the KV-cache decode step; plus
-   a single-user pass for the apples-to-apples per-sequence rate.
+2. **Engine**: ``PagedGenerationEngine`` + ``TokenServer`` serving
+   ``--users`` concurrent prompts with the KV-cache decode step
+   (prefix sharing and drafting off, so the number moves only with the
+   page pool's mechanics); plus a single-user pass for the
+   apples-to-apples per-sequence rate.
 
 Emits TWO ``BENCH {json}`` records through the perf ledger (the
 ``lm_decode`` record kind): ``lm_decode_tokens_per_sec_per_user``
 (tokens/sec/user, higher-better) and ``lm_decode_ttft_p99_ms`` (ms,
 LOWER-better — ``tools/perf_gate.py`` gates latency units upward).
-``cache_speedup`` carries the acceptance number: aggregate KV-cache
-tokens/s over the re-forward baseline (>= 3x on CPU at ctx 256).
+``cache_speedup`` is aggregate KV-cache tokens/s over the re-forward
+baseline.
 
-    # CPU smoke (the committed numbers):
+    # CPU smoke:
     python tools/bench_decode.py
 
     # real chip:
     python tools/bench_decode.py --users 16 --ctx 512
 
-Paged-engine modes (ISSUE 16) measure each serving lever behind its
+The lever modes (ISSUE 16) measure each serving lever behind its
 own perf-ledger metric so ``tools/perf_gate.py`` can gate them
 independently:
 
-* ``--paged`` — the default two-phase bench on the
-  :class:`PagedGenerationEngine` (block KV pool, sharing/spec off):
-  ``lm_decode_paged_tokens_per_sec_per_user``.
 * ``--prefix-share`` — N users behind ONE system prompt, aggregate
   tokens/s with copy-on-write prefix sharing vs the same engine with
   sharing disabled: ``lm_decode_prefix_share_tokens_per_sec`` (up) and
@@ -77,35 +76,21 @@ def log(msg):
 # test_generate.py and tests/test_perf_observatory.py import THIS so
 # the two guards can never drift apart)
 CANNED_RESULT = {
-    "metric": "lm_decode_tokens_per_sec_per_user", "value": 225.1,
-    "unit": "tokens/sec/user", "tokens_per_sec": 1801.0,
-    "tokens_per_sec_single_user": 246.9,
-    "baseline_tokens_per_sec": 163.1, "cache_speedup": 11.0,
-    "ttft_ms": {"p50": 10.3, "p99": 19.8}, "cache_occupancy": 0.24,
-    "batch_tokens_mean": 8.0, "users": 8, "slots": 8, "cache_len": 256,
-    "buckets": [32, 64, 128, 256], "ctx": 256, "prompt_len": 16,
-    "gen_tokens": 48, "sampling": "greedy", "dtype_policy": "f32",
-    "mesh_shape": {}, "layout": None, "devices": 1,
-}
-
-
-# per-mode canned results: same contract as CANNED_RESULT — the
-# schema guard feeds each through ledger_records so a field rename in
-# run_* shows up as a tier-1 failure, not a silently-reshaped record
-CANNED_PAGED_RESULT = {
-    "metric": "lm_decode_paged_tokens_per_sec_per_user", "value": 733.4,
+    "metric": "lm_decode_tokens_per_sec_per_user", "value": 733.4,
     "unit": "tokens/sec/user", "tokens_per_sec": 5866.9,
     "tokens_per_sec_single_user": 1163.0,
     "baseline_tokens_per_sec": 199.0, "cache_speedup": 29.5,
     "ttft_ms": {"p50": 8.9, "p99": 15.7}, "cache_occupancy": 0.23,
     "batch_tokens_mean": 7.0, "users": 8, "slots": 8, "cache_len": 256,
-    "buckets": None, "page_size": 16, "num_pages": 129,
-    "pages_in_use_peak": 128, "prefill_chunk": 32, "ctx": 256,
-    "prompt_len": 16, "gen_tokens": 48, "sampling": "greedy",
-    "dtype_policy": "f32", "mesh_shape": {}, "layout": None,
-    "devices": 1,
+    "page_size": 16, "num_pages": 129, "pages_in_use_peak": 128,
+    "prefill_chunk": 32, "ctx": 256, "prompt_len": 16,
+    "gen_tokens": 48, "sampling": "greedy", "dtype_policy": "f32",
+    "mesh_shape": {}, "layout": None, "devices": 1,
 }
 
+# per-mode canned results: same contract as CANNED_RESULT — the
+# schema guard feeds each through ledger_records so a field rename in
+# run_* shows up as a tier-1 failure, not a silently-reshaped record
 CANNED_PREFIX_SHARE_RESULT = {
     "metric": "lm_decode_prefix_share_tokens_per_sec", "value": 18774.9,
     "unit": "tokens/sec", "noshare_tokens_per_sec": 16223.5,
@@ -139,8 +124,7 @@ CANNED_SPEC_RESULT = {
 
 # mode name -> canned result (tests iterate this to guard every mode)
 CANNED_MODE_RESULTS = {
-    "ring": CANNED_RESULT,
-    "paged": CANNED_PAGED_RESULT,
+    "default": CANNED_RESULT,
     "prefix_share": CANNED_PREFIX_SHARE_RESULT,
     "chunked_prefill": CANNED_CHUNKED_PREFILL_RESULT,
     "spec": CANNED_SPEC_RESULT,
@@ -247,11 +231,10 @@ def run_baseline(lm, ctx, prompt, gen_tokens):
 
 def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
         dtype_policy=None, mesh=None, layout=None, trace_out=None,
-        baseline=True, paged=None, page_size=None, prefill_chunk=None,
-        **model_kw):
+        baseline=True, page_size=None, prefill_chunk=None, **model_kw):
     import jax
 
-    from mxnet_tpu import config, generate, telemetry, tracing
+    from mxnet_tpu import generate, telemetry, tracing
 
     telemetry.enable()
     if trace_out:
@@ -273,28 +256,17 @@ def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
     if dtype_policy is None:
         dtype_policy = os.environ.get("BENCH_DTYPE_POLICY") or \
             ("bf16_mixed" if cfg["on_tpu"] else None)
-    if paged is None:
-        paged = bool(config.get("MXNET_DECODE_PAGED"))
-    if paged:
-        # the isolated paged-layout measurement: sharing and drafting
-        # off so the number moves only with the page pool mechanics
-        eng = generate.PagedGenerationEngine(
-            lm, slots=slots, cache_len=ctx, page_size=page_size,
-            prefill_chunk=prefill_chunk, spec_k=0, prefix_share=False,
-            mesh=mesh, layout=layout, dtype_policy=dtype_policy,
-            sampling=generate.SamplingConfig(greedy=True))
-        log("engine: paged slots=%d cache_len=%d page=%d pages=%d "
-            "chunk=%d dtype=%s mesh=%s"
-            % (eng.slots, eng.cache_len, eng.page_size, eng.num_pages,
-               eng.prefill_chunk, eng.dtype_policy_tag, eng.mesh_shape))
-    else:
-        eng = generate.GenerationEngine(
-            lm, slots=slots, cache_len=ctx, mesh=mesh, layout=layout,
-            dtype_policy=dtype_policy,
-            sampling=generate.SamplingConfig(greedy=True))
-        log("engine: slots=%d cache_len=%d buckets=%s dtype=%s mesh=%s"
-            % (eng.slots, eng.cache_len, eng.buckets,
-               eng.dtype_policy_tag, eng.mesh_shape))
+    # the isolated measurement of the page pool: sharing and drafting
+    # off so the number moves only with the pool's mechanics
+    eng = generate.PagedGenerationEngine(
+        lm, slots=slots, cache_len=ctx, page_size=page_size,
+        prefill_chunk=prefill_chunk, spec_k=0, prefix_share=False,
+        mesh=mesh, layout=layout, dtype_policy=dtype_policy,
+        sampling=generate.SamplingConfig(greedy=True))
+    log("engine: slots=%d cache_len=%d page=%d pages=%d chunk=%d "
+        "dtype=%s mesh=%s"
+        % (eng.slots, eng.cache_len, eng.page_size, eng.num_pages,
+           eng.prefill_chunk, eng.dtype_policy_tag, eng.mesh_shape))
 
     baseline_tps = None
     if baseline:
@@ -302,8 +274,8 @@ def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
 
     srv = generate.TokenServer(eng, queue_depth=max(users, 4),
                                max_new_tokens=gen_tokens)
-    # warmup: one short request compiles the prompt's prefill bucket +
-    # the decode step (or loads them from the AOT store)
+    # warmup: one short request compiles the prefill chunk + the
+    # decode step (or loads them from the AOT store)
     srv.generate(prompt, max_new_tokens=2, timeout=600)
     telemetry.reset()
 
@@ -327,7 +299,7 @@ def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
     while not all(f.done() for f in futs):
         occ = eng.occupancy()
         occ_peak = max(occ_peak, occ["occupancy"])
-        pages_peak = max(pages_peak, occ.get("pages_in_use", 0))
+        pages_peak = max(pages_peak, occ["pages_in_use"])
         time.sleep(0.002)
     results = [f.result(timeout=600) for f in futs]
     dt = time.perf_counter() - t0
@@ -346,8 +318,7 @@ def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
         % (users, total, dt, agg_tps, per_user, p50, p99))
 
     result = {
-        "metric": "lm_decode_paged_tokens_per_sec_per_user" if paged
-        else "lm_decode_tokens_per_sec_per_user",
+        "metric": "lm_decode_tokens_per_sec_per_user",
         "value": round(per_user, 2),
         "unit": "tokens/sec/user",
         "tokens_per_sec": round(agg_tps, 2),
@@ -363,7 +334,10 @@ def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
         "users": users,
         "slots": eng.slots,
         "cache_len": eng.cache_len,
-        "buckets": getattr(eng, "buckets", None),
+        "page_size": eng.page_size,
+        "num_pages": eng.num_pages,
+        "pages_in_use_peak": pages_peak,
+        "prefill_chunk": eng.prefill_chunk,
         "ctx": ctx,
         "prompt_len": prompt_len,
         "gen_tokens": gen_tokens,
@@ -373,10 +347,6 @@ def run(users=None, slots=None, ctx=256, prompt_len=16, gen_tokens=None,
         "layout": eng.layout_name,
         "devices": len(jax.devices()),
     }
-    if paged:
-        result.update(page_size=eng.page_size, num_pages=eng.num_pages,
-                      pages_in_use_peak=pages_peak,
-                      prefill_chunk=eng.prefill_chunk)
     if baseline_tps:
         log("cache speedup vs re-forward @ ctx %d: %.2fx (aggregate), "
             "%.2fx (single user)" % (ctx, agg_tps / baseline_tps,
@@ -638,9 +608,9 @@ def main(argv=None):
                    help="decode slots / KV-cache lanes (default 8 CPU, "
                         "16 TPU)")
     p.add_argument("--ctx", type=int, default=256,
-                   help="context window: cache ring length AND the "
+                   help="context window: a slot's cache length AND the "
                         "baseline's fixed re-forward shape (default "
-                        "256 — the acceptance shape)")
+                        "256)")
     p.add_argument("--prompt-len", type=int, default=None,
                    help="prompt length (default 16; --spec 24, "
                         "--chunked-prefill's short prompt 8)")
@@ -665,10 +635,6 @@ def main(argv=None):
     p.add_argument("--n-heads", type=int, default=None)
     p.add_argument("--n-layers", type=int, default=None)
     mode = p.add_mutually_exclusive_group()
-    mode.add_argument("--paged", action="store_true",
-                      help="run the two-phase bench on the paged "
-                           "engine (sharing/spec off); also the "
-                           "default when MXNET_DECODE_PAGED=1")
     mode.add_argument("--prefix-share", action="store_true",
                       help="N users behind one system prompt: "
                            "aggregate tokens/s, sharing on vs off")
@@ -681,10 +647,10 @@ def main(argv=None):
                            "tokens per verify step + speedup vs "
                            "drafting off")
     p.add_argument("--page-size", type=int, default=None,
-                   help="paged modes: positions per KV page (default "
+                   help="positions per KV page (default "
                         "MXNET_DECODE_PAGE_SIZE)")
     p.add_argument("--prefill-chunk", type=int, default=None,
-                   help="paged modes: prefill chunk length (default "
+                   help="prefill chunk length (default "
                         "MXNET_DECODE_PREFILL_CHUNK)")
     p.add_argument("--system-len", type=int, default=112,
                    help="--prefix-share: shared system-prompt length")
@@ -719,8 +685,7 @@ def main(argv=None):
                      prompt_len=a.prompt_len or 16,
                      gen_tokens=a.gen_tokens,
                      trace_out=a.trace_out,
-                     baseline=not a.no_baseline,
-                     paged=a.paged or None, page_size=a.page_size,
+                     baseline=not a.no_baseline, page_size=a.page_size,
                      prefill_chunk=a.prefill_chunk, **common)
     from mxnet_tpu import perf_ledger
 

@@ -185,11 +185,10 @@ def _resnet50_serving_int8(store, batch=None, dtype_policy=None):
         yield info
 
 
-@model("lm_decode", "transformer-LM generation tier: the ring engine's "
-                    "decode step plus every prefill length bucket, AND "
-                    "the paged engine's chunk family (prefill chunk, "
-                    "decode, speculative verify) — one manifest row "
-                    "per signature; warms everything a decode replica "
+@model("lm_decode", "transformer-LM generation tier: the decode "
+                    "engine's chunk family (prefill chunk, decode, "
+                    "speculative verify) — one manifest row per "
+                    "signature; warms everything a decode replica "
                     "needs at spawn")
 def _lm_decode(store, batch=None, dtype_policy=None):
     import mxnet_tpu as mx
@@ -208,22 +207,15 @@ def _lm_decode(store, batch=None, dtype_policy=None):
     lm = TransformerLM(vocab_size=256, d_model=64, n_heads=4,
                        n_layers=2, max_len=64)
     lm.initialize(mx.init.Xavier())
-    eng = generate.GenerationEngine(
-        lm, slots=slots, cache_len=64, buckets=[16, 32, 64],
-        aot=store, aot_spec="lm_decode", dtype_policy=dtype_policy,
-        sampling=generate.SamplingConfig(greedy=True))
-    for info in eng.prewarm():
-        yield info
-    # the paged replica's three chunk-family signatures: a (1, chunk)
+    # the replica's three chunk-family signatures: a (1, chunk)
     # prefill chunk, the (slots, 1) decode step, and the (slots, K+1)
-    # speculative verify — same model, same spec name, so a manifest
-    # replay rebuilds both engines from this one entry point
-    paged = generate.PagedGenerationEngine(
+    # speculative verify
+    eng = generate.PagedGenerationEngine(
         lm, slots=slots, cache_len=64, page_size=16, prefill_chunk=16,
         spec_k=2, aot=store, aot_spec="lm_decode",
         dtype_policy=dtype_policy,
         sampling=generate.SamplingConfig(greedy=True))
-    for info in paged.prewarm():
+    for info in eng.prewarm():
         yield info
 
 
