@@ -99,46 +99,31 @@ class DecoderBlock(gluon.HybridBlock):
         x = x + self._attend(F, self.ln1(x))
         return x + self.ffn_down(self.ffn_up(self.ln2(x)))
 
-    def forward_chunk(self, F, x, k_cache, v_cache, cache_mask,
-                      causal_mask):
-        """One block's C-position chunk forward against a linear KV
-        cache view — the shared attention shape behind chunked prefill,
+    def forward_chunk(self, F, x, k_rows, v_rows, start):
+        """One block's C-position chunk forward against its layer's
+        cached rows — the shared attention shape behind chunked prefill,
         paged decode (C=1), and the speculative verify step (C=K+1).
 
-        ``x`` is the (B, C, D) chunk input NDArray; ``k_cache`` /
-        ``v_cache`` are RAW jax arrays (B*H, S, dh) holding the already
-        cached positions (this chunk's K/V is NOT in them);
-        ``cache_mask`` (B*H, C, S) marks cache positions a chunk query
-        may attend (pos < its sequence's start); ``causal_mask``
-        (1, C, C) is the within-chunk causal triangle.  Returns
-        ``(x_out, k_chunk, v_chunk)`` with the chunk K/V as raw
-        (B*H, C, dh) arrays for the caller to write into its pool.
-        The projection/LN/FFN submodules are the SAME children the
-        train path runs, so chunk logits track the full-context
-        forward."""
-        import jax
-        import jax.numpy as jnp
-
-        B, C, _D = x.shape
-        H, dh = self._n_heads, self._d_head
-        h = self.ln1(x)
-        q = self._split_heads(self.proj_q(h))._data    # (B*H, C, dh)
-        k_c = self._split_heads(self.proj_k(h))._data
-        v_c = self._split_heads(self.proj_v(h))._data
-        scale = dh ** -0.5
-        s_cache = jnp.matmul(q, jnp.swapaxes(k_cache, 1, 2)) * scale
-        s_chunk = jnp.matmul(q, jnp.swapaxes(k_c, 1, 2)) * scale
-        neg = jnp.asarray(-1e30, s_cache.dtype)
-        s_cache = jnp.where(cache_mask, s_cache, neg)
-        s_chunk = jnp.where(causal_mask, s_chunk, neg)
-        scores = jnp.concatenate([s_cache, s_chunk], axis=-1)
-        att = jax.nn.softmax(scores, axis=-1)
-        v_full = jnp.concatenate([v_cache, v_c], axis=1)
-        out = jnp.matmul(att, v_full)                  # (B*H, C, dh)
+        ``x`` is the (B, C, D) chunk input NDArray; ``k_rows`` /
+        ``v_rows`` are RAW jax arrays (B, S, D), one row a cached
+        position with every head side by side, as the engine's page
+        pool holds them (this chunk's K/V is NOT in them); ``start``
+        raw (B,) int32: a chunk query attends the cached positions
+        below its sequence's start and the chunk's own up to itself
+        (``ops.attention_rows.chunk_attention_rows``, which reads the
+        rows as they lie).  Returns ``(x_out, k_chunk, v_chunk)`` with
+        the chunk K/V as raw (B, C, D) rows for the caller to write
+        into its pool.  The projection/LN/FFN submodules are the SAME
+        children the train path runs, so chunk logits track the
+        full-context forward."""
         from mxnet_tpu.ndarray import NDArray
+        from mxnet_tpu.ops.attention_rows import chunk_attention_rows
 
-        out = self._merge_heads(NDArray(out), B, C)
-        x = x + self.attn_out(out)
+        h = self.ln1(x)
+        k_c, v_c = self.proj_k(h)._data, self.proj_v(h)._data
+        out = chunk_attention_rows(self.proj_q(h)._data, k_c, v_c,
+                                   k_rows, v_rows, start, self._n_heads)
+        x = x + self.attn_out(NDArray(out))
         return (x + self.ffn_down(self.ffn_up(self.ln2(x))),
                 k_c, v_c)
 
@@ -172,7 +157,9 @@ class TransformerLM(gluon.HybridBlock):
 
     @property
     def config(self):
-        return dict(self._cfg)
+        # cache_rows: chunk_forward takes a layer's cache as rows,
+        # (B, S, heads * d_head), and returns the chunk's K/V as rows
+        return dict(self._cfg, cache_rows=True)
 
     def flops_per_token(self, seq_len=None):
         """Train FLOPs/token: the standard 6N dense term plus — when
@@ -207,10 +194,10 @@ class TransformerLM(gluon.HybridBlock):
     #
     # chunk_forward is the cache-aware inference form of hybrid_forward:
     # any model exposing it (plus .config with vocab_size / d_model /
-    # n_heads / n_layers / max_len) plugs into
-    # generate.PagedGenerationEngine.  It is called under the gluon
-    # trace machinery with parameters swapped in, exactly like
-    # serving.Predictor.from_block's traced forward.
+    # n_heads / n_layers / max_len, and cache_rows for the form its
+    # caches take) plugs into generate.PagedGenerationEngine.  It is
+    # called under the gluon trace machinery with parameters swapped
+    # in, exactly like serving.Predictor.from_block's traced forward.
 
     def chunk_forward(self, tokens, caches, start):
         """C positions per sequence against a linear KV cache — the one
@@ -219,14 +206,16 @@ class TransformerLM(gluon.HybridBlock):
 
         ``tokens`` raw (B, C) int32 — the tokens occupying positions
         ``start_b .. start_b+C-1`` of each sequence; ``caches`` one
-        ``(k, v)`` pair of raw (B, H, S, dh) jax arrays per layer
-        holding the already cached positions 0..start_b-1 (a gathered
-        page view in the paged engine); ``start`` raw (B,) int32.
+        ``(k, v)`` pair of raw (B, S, D) jax arrays per layer, one row
+        a position with every head side by side (``config`` says
+        ``cache_rows``), holding the already cached positions
+        0..start_b-1 (a layer's gathered pages in the paged engine);
+        ``start`` raw (B,) int32.
         Position j of the chunk attends cache positions ``s < start_b``
         plus chunk positions ``j' <= j`` — exactly the causal window the
         full forward gives it.  Returns ``(logits NDArray (B, C, V),
         chunk_caches)`` where ``chunk_caches`` is one ``(k, v)`` pair of
-        raw (B, H, C, dh) arrays per layer for the caller to write back
+        raw (B, C, D) rows per layer for the caller to write back
         (positions past a sequence's real length just produce values the
         caller routes to its trash page)."""
         import jax.numpy as jnp
@@ -235,32 +224,16 @@ class TransformerLM(gluon.HybridBlock):
         from mxnet_tpu.ndarray import NDArray
 
         B, C = tokens.shape
-        H = self._cfg["n_heads"]
         D = self._cfg["d_model"]
-        dh = D // H
-        S = caches[0][0].shape[2]
-        max_len = self._cfg["max_len"]
         start = start.astype(jnp.int32)
-        tok_nd = NDArray(tokens)
         pos_ids = jnp.clip(start[:, None] + jnp.arange(C, dtype=jnp.int32),
-                           0, max_len - 1)                  # (B, C)
-        x = self.embed(tok_nd) + self.pos_embed(
+                           0, self._cfg["max_len"] - 1)     # (B, C)
+        x = self.embed(NDArray(tokens)) + self.pos_embed(
             NDArray(pos_ids)).reshape((B, C, D))
-        s_idx = jnp.arange(S, dtype=jnp.int32)
-        cache_valid = s_idx[None, :] < start[:, None]       # (B, S)
-        cache_mask = jnp.broadcast_to(
-            cache_valid.reshape((B, 1, 1, S)), (B, H, C, S)).reshape(
-                (B * H, C, S))
-        c_idx = jnp.arange(C, dtype=jnp.int32)
-        causal_mask = (c_idx[:, None] >= c_idx[None, :]).reshape(
-            (1, C, C))
         chunk_caches = []
-        for blk, (kc, vc) in zip(self._blocks, caches):
-            x, k_c, v_c = blk.forward_chunk(
-                F, x, kc.reshape((B * H, S, dh)),
-                vc.reshape((B * H, S, dh)), cache_mask, causal_mask)
-            chunk_caches.append((k_c.reshape((B, H, C, dh)),
-                                 v_c.reshape((B, H, C, dh))))
+        for blk, (k_rows, v_rows) in zip(self._blocks, caches):
+            x, k_c, v_c = blk.forward_chunk(F, x, k_rows, v_rows, start)
+            chunk_caches.append((k_c, v_c))
         return self.head(self.ln_f(x)), chunk_caches
 
 

@@ -10,15 +10,18 @@ TPU path rewards (fixed-shape compiled executables, PAPERS.md "full
 compilation" line):
 
 * **The KV cache is a page pool, donated device state** —
-  :class:`PagedGenerationEngine` holds one fixed-shape, token-major
-  pool per K and V, ``(pages * page_size, layers, heads * d_head)``,
+  :class:`PagedGenerationEngine` holds one fixed-shape pool per K and
+  V, indexed on its leading dimension (one row a (layer, token),
+  ``(layers * pages * page_size, heads * d_head)``, for a model that
+  takes its caches as rows; else token-major, ``(pages * page_size,
+  layers, heads * d_head)``),
   donated into every dispatch so it updates in place; host-side page
   tables map each decode slot's positions onto pool pages, so
   admission and eviction flip host state and never the compiled
   program.  The pool's dtype follows the ``dtype_policy=`` compute
   dtype (bf16 under ``bf16_mixed``), and with a mesh the pool shards
   by the ``kv_pool`` spec rule of the layouts (``heads * d_head`` over
-  tp, tokens over the data axes where they divide them — tp serving
+  tp, rows over the data axes where they divide them — tp serving
   composes with the training mesh).
 * **One compiled dispatch, three shapes** — a prefill chunk
   ``(1, prefill_chunk)``, a decode step ``(slots, 1)`` (``(slots,
@@ -54,7 +57,9 @@ verify step (``examples/transformer_lm.py``,
 ``gluon.model_zoo.language.MoEDecoderLM``) — and a ``config`` dict with
 ``vocab_size`` / ``d_model`` / ``n_heads`` / ``n_layers`` / ``max_len``
 (``n_kv_heads`` / ``d_head`` where they are not the defaults;
-``block_length`` and ``mask_token_id`` for block-diffusion decoding).
+``block_length`` and ``mask_token_id`` for block-diffusion decoding;
+``cache_rows`` where it takes a layer's cache as rows, ``(B, S, heads *
+d_head)``, and not as a head-split view, ``(B, heads, S, d_head)``).
 Benchmarks: ``tools/bench_decode.py`` (tokens/s/user, TTFT p50/p99,
 the KV-cache-vs-reforward ratio, plus the prefix-share /
 chunked-prefill / speculative modes); docs: ``docs/lm_serving.md``.
@@ -326,16 +331,26 @@ class _OpenBlocks:
 class PagedGenerationEngine:
     """Paged/block KV-cache generation over a chunk-protocol model.
 
-    Device state is one fixed-shape page pool per K/V, stored
-    token-major — ``(pages * page_size, layers, heads * d_head)``: row
-    ``page * page_size + offset`` holds one position's K (or V) of
-    every layer and head — and donated through every dispatch.  Both
-    index operations of a dispatch address dimension 0, so XLA gathers
-    whole rows and scatters the chunk's rows in place on the donated
-    buffer; no dispatch copies the pool.  Each decode slot maps its
-    positions onto pool pages through a host-side page table (page 0
-    is a write-through "trash" page absorbing padded/invalid positions,
-    so shapes never change).
+    Device state is one fixed-shape page pool per K/V, donated through
+    every dispatch, in the one of two forms the model's protocol picks.
+    A model whose ``config`` says ``cache_rows`` takes each layer's
+    cache as rows, and the pool is two-dimensional, ``(layers * pages *
+    page_size, heads * d_head)``: row ``layer * tokens + page *
+    page_size + offset`` holds one position's K (or V) of one layer,
+    every head side by side.  A dispatch gathers a layer's rows at a
+    time, ``(slots, cache_len, heads * d_head)``, and the model attends
+    them as they lie (``ops.attention_rows``): nothing the size of all
+    layers' caches is ever built, sliced or re-tiled.  Any other model
+    gets the token-major pool, ``(pages * page_size, layers, heads *
+    d_head)``: row ``page * page_size + offset`` holds one position's
+    K (or V) of every layer and head, gathered whole into a view that
+    is split by layer and head for the model.  Either way both index
+    operations of a dispatch address dimension 0, so XLA gathers whole
+    rows and scatters the chunk's rows in place on the donated buffer;
+    no dispatch copies the pool.  Each decode slot maps its positions
+    onto pool pages through a host-side page table (page 0 is a
+    write-through "trash" page absorbing padded/invalid positions, so
+    shapes never change).
     One compiled ``chunk`` function covers all three dispatch shapes:
 
     * **prefill chunk** ``(1, prefill_chunk)`` — prompts stream in
@@ -514,27 +529,41 @@ class PagedGenerationEngine:
         L = cfg["n_layers"]
         H = int(cfg.get("n_kv_heads", cfg["n_heads"]))
         dh = int(cfg.get("d_head", cfg["d_model"] // cfg["n_heads"]))
-        # token-major: the dimension the page table addresses leads and
-        # a token's (layers, heads * d_head) trail as one contiguous
-        # row.  Heads and d_head are kept as ONE dimension because the
-        # TPU runtime lays an array out by its shape: with a d_head
-        # under 128 lanes minor-most it would make the tokens the
-        # minor-most dimension instead and copy the pool to index it.
-        # For the same reason the row's second-minor dimension has to
-        # tile without padding (1, 2, 4 or a multiple of 8 sublanes):
-        # with 6 layers of 4 x 128 the runtime puts the layers
-        # outermost and the program copies the pool twice a dispatch
-        # to index it.  Such a row folds the heads into the layers
-        # (one chip; under a mesh heads * d_head stays the dimension
-        # that `tp` shards).
-        def tiles(n):
-            return n in (1, 2, 4) or n % 8 == 0
+        n_tokens = self._num_pages * self._page_size
+        # what the model's protocol declares picks the pool's rows
+        self._cache_rows = bool(cfg.get("cache_rows"))
+        if self._cache_rows:
+            # one row a (layer, token): the layer is part of the row
+            # index, so a layer's cache is a gather of whole rows,
+            # (slots * cache_len, heads * d_head), with nothing to slice
+            # or re-tile out of it, and the model's attention reads the
+            # rows as they lie (ops.attention_rows).  Both index
+            # operations address dimension 0 and heads * d_head is the
+            # one minor dimension (the one `tp` shards under a mesh)
+            row = (H * dh,)
+            pool_shape = (L * n_tokens,) + row
+        else:
+            # token-major: the dimension the page table addresses leads
+            # and a token's (layers, heads * d_head) trail as one
+            # contiguous row.  Heads and d_head are kept as ONE dimension
+            # because the TPU runtime lays an array out by its shape:
+            # with a d_head under 128 lanes minor-most it would make the
+            # tokens the minor-most dimension instead and copy the pool
+            # to index it.  For the same reason the row's second-minor
+            # dimension has to tile without padding (1, 2, 4 or a
+            # multiple of 8 sublanes): with 6 layers of 4 x 128 the
+            # runtime puts the layers outermost and the program copies
+            # the pool twice a dispatch to index it.  Such a row folds
+            # the heads into the layers (one chip; under a mesh
+            # heads * d_head stays the dimension that `tp` shards).
+            def tiles(n):
+                return n in (1, 2, 4) or n % 8 == 0
 
-        row = (L, H * dh)
-        if self._mesh is None and not tiles(L):
-            row = (L * H, dh) if tiles(L * H) and dh % 128 == 0 \
-                else (L * H * dh,)
-        pool_shape = (self._num_pages * self._page_size,) + row
+            row = (L, H * dh)
+            if self._mesh is None and not tiles(L):
+                row = (L * H, dh) if tiles(L * H) and dh % 128 == 0 \
+                    else (L * H * dh,)
+            pool_shape = (n_tokens,) + row
         if self._mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -559,10 +588,15 @@ class PagedGenerationEngine:
                 jax.device_put(p.data()._data, dev) for p in params)
             self._pool_sharding = dev
         self._params = _hold_weights(self._param_names, placed, dt_policy)
-        self._pool_k = jax.device_put(
-            jnp.zeros(pool_shape, self._cache_dtype), self._pool_sharding)
-        self._pool_v = jax.device_put(
-            jnp.zeros(pool_shape, self._cache_dtype), self._pool_sharding)
+        with _tracing.begin("engine.pool") as sp:
+            self._pool_k = jax.device_put(
+                jnp.zeros(pool_shape, self._cache_dtype),
+                self._pool_sharding)
+            self._pool_v = jax.device_put(
+                jnp.zeros(pool_shape, self._cache_dtype),
+                self._pool_sharding)
+            sp.set(shape=list(pool_shape), cache_rows=self._cache_rows,
+                   bytes=2 * int(self._pool_k.nbytes))
 
         # host control plane: page tables + slot state + the prefix map
         P = self._pages_per_slot
@@ -620,6 +654,7 @@ class PagedGenerationEngine:
         S = self._capacity
         cache_dtype = self._cache_dtype
         page = self._page_size
+        cache_rows = self._cache_rows
         mask_id = int(cfg["mask_token_id"]) if Bl > 1 else None
 
         def _traced(fn, params_):
@@ -634,20 +669,42 @@ class PagedGenerationEngine:
                 return dt_policy.cast_output(arr)
             return arr
 
+        # a page of whole sublane tiles (8 rows of 32 bits: 16 of
+        # bfloat16) lets the pool of rows be seen as (layers * pages,
+        # page_size, H*dh) at no cost, the TPU's tiled layout of both
+        # being the same bytes, and a layer gathered page by page: a
+        # fifth of the time of the same bytes gathered row by row
+        # (PERF.md, PR 32).  Any other page size would make that view a
+        # copy of the pool, and gathers rows
+        n_pages = self._num_pages
+        by_page = page % (8 * max(1, 4 // cache_dtype.itemsize)) == 0
+
+        def layer_rows(pool, li, page_table, rows):
+            """Layer ``li``'s cached rows of every slot, (B, S, H*dh).
+            Every gather takes its own view of the pool: with one view
+            shared by a pool's 24 gathers the chip's compiler schedules
+            the same operations a tenth slower (7.44 against 6.70 ms a
+            decode program; PERF.md, PR 32)."""
+            if not by_page:
+                return pool[li * n_tokens + rows]
+            return pool.reshape((L * n_pages, page, H * dh))[
+                li * n_pages + page_table].reshape(rows.shape + (H * dh,))
+
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
                      wpage, woff, lane_keys, block=None):
             """The one paged dispatch: gather the pool rows of each
-            slot's pages into a linear (B, H, S, dh) cache view per
-            layer, run the model's chunk_forward, sample EVERY chunk
-            position with its position-derived key, and scatter the
-            chunk's K/V rows back to the pool at row
-            ``wpage * page_size + woff`` — trash page 0 absorbs padded
-            positions.  pool_k/pool_v (pages * page_size, L, H*dh);
-            tokens (B, C); page_table (B, P); wpage/woff flat
-            (B*C,).  The fifth result is a dict of what only some
-            models give: ``expert_load`` (L, E) from an expert layer,
-            ``block`` and ``masked`` from a pass of block-diffusion
-            decoding.
+            slot's pages into each layer's linear cache (a view
+            (B, H, S, dh) a layer of the token-major pool; for a model
+            that takes rows, (B, S, H*dh), a layer at a time), run the
+            model's chunk_forward, sample EVERY chunk position with its
+            position-derived key, and scatter the chunk's K/V rows back
+            to the pool at token ``wpage * page_size + woff`` — trash
+            page 0 absorbs padded positions.  pool_k/pool_v as
+            ``pool_shape``; tokens (B, C); page_table (B, P);
+            wpage/woff flat (B*C,).  The fifth result is a dict of what
+            only some models give: ``expert_load`` (L, E) from an expert
+            layer, ``block`` and ``masked`` from a pass of
+            block-diffusion decoding.
 
             ``block`` (a pass of block-diffusion decoding; a prefill
             chunk gives none) is the open blocks as the last pass left
@@ -674,17 +731,22 @@ class PagedGenerationEngine:
                 b_conf = jnp.where(opens, 0.0, b_conf)
                 tokens = jnp.where(b_mask, mask_id, b_tok)
 
-            # pool row of every cache position: (B, P) pages -> (B, S)
+            # pool token of every cache position: (B, P) pages -> (B, S)
             rows = (page_table[:, :, None] * page
                     + jnp.arange(page, dtype=jnp.int32)).reshape((Bc, S))
 
             def run():
-                def view(pool):     # (B, S, L, H*dh) -> (L, B, H, S, dh)
-                    return pool[rows].reshape(
-                        (Bc, S, L, H, dh)).transpose(2, 0, 3, 1, 4)
+                if cache_rows:
+                    caches = [(layer_rows(pool_k, li, page_table, rows),
+                               layer_rows(pool_v, li, page_table, rows))
+                              for li in range(L)]
+                else:
+                    def view(pool):  # (B, S, L, H*dh) -> (L, B, H, S, dh)
+                        return pool[rows].reshape(
+                            (Bc, S, L, H, dh)).transpose(2, 0, 3, 1, 4)
 
-                gk, gv = view(pool_k), view(pool_v)
-                caches = [(gk[li], gv[li]) for li in range(L)]
+                    gk, gv = view(pool_k), view(pool_v)
+                    caches = [(gk[li], gv[li]) for li in range(L)]
                 res = net.chunk_forward(tokens, caches, start)
                 # a model may hand back a third item: arrays about the
                 # forward itself (an expert layer's token counts)
@@ -723,15 +785,22 @@ class PagedGenerationEngine:
                     jnp.where(fix, conf, b_conf))
             k_new = jnp.stack([k for k, _v in chunk_caches])
             v_new = jnp.stack([v for _k, v in chunk_caches])
-            # one pool row per chunk position: (B*C,) + the pool's row
-            kvals = k_new.astype(cache_dtype).transpose(
-                1, 3, 0, 2, 4).reshape((Bc * C,) + row)
-            vvals = v_new.astype(cache_dtype).transpose(
-                1, 3, 0, 2, 4).reshape((Bc * C,) + row)
             # leading-dimension scatter, in place on the donated pool;
             # padded positions collide on the trash page, so the rows
             # are not unique
-            wrow = wpage * page + woff
+            if cache_rows:
+                # (L, B, C, H*dh): one pool row a (layer, chunk position)
+                kvals = k_new.astype(cache_dtype).reshape((-1,) + row)
+                vvals = v_new.astype(cache_dtype).reshape((-1,) + row)
+                wrow = (jnp.arange(L, dtype=jnp.int32)[:, None] * n_tokens
+                        + (wpage * page + woff)[None, :]).reshape(-1)
+            else:
+                # (L, B, H, C, dh): one pool row a chunk position
+                kvals = k_new.astype(cache_dtype).transpose(
+                    1, 3, 0, 2, 4).reshape((Bc * C,) + row)
+                vvals = v_new.astype(cache_dtype).transpose(
+                    1, 3, 0, 2, 4).reshape((Bc * C,) + row)
+                wrow = wpage * page + woff
             pool_k = pool_k.at[wrow].set(kvals)
             pool_v = pool_v.at[wrow].set(vvals)
             return sampled, logits, pool_k, pool_v, extras
@@ -739,8 +808,12 @@ class PagedGenerationEngine:
         self._jit_chunk = jax.jit(chunk_fn, donate_argnums=(1, 2))
         # the spec and the fingerprint name the pool's layout: an
         # executable stored for another one is never loaded
-        pool_tag = "tokens%dxL%dxHD%d" % pool_shape if row == (L, H * dh) \
-            else "tokens" + "x".join(str(d) for d in pool_shape)
+        if self._cache_rows:     # one row a (layer, token)
+            pool_tag = "L%dxtokens%dxHD%d" % (L, n_tokens, H * dh)
+        elif row == (L, H * dh):  # token-major, a token's layers a row
+            pool_tag = "tokens%dxL%dxHD%d" % pool_shape
+        else:                     # token-major, heads folded into layers
+            pool_tag = "tokens" + "x".join(str(d) for d in pool_shape)
         self._aot_spec = aot_spec or (
             "lm_decode_paged:slots%dxpages%dxpg%d:%s"
             % (self._slots, self._num_pages, page, pool_tag))
@@ -1072,7 +1145,8 @@ class PagedGenerationEngine:
         with _tracing.begin("engine.prefill", args={
                 "slot": int(slot), "filled": int(filled),
                 "count": int(count), "final": final,
-                "block": self._block}):
+                "block": self._block,
+                "attn": self._attends_in(self._chunk)}):
             chunk = np.zeros((1, self._chunk), np.int32)
             chunk[0, :count] = toks[filled:filled + count]
             wpage = np.zeros(self._chunk, np.int32)
@@ -1154,7 +1228,8 @@ class PagedGenerationEngine:
         # "Spans of the hot loops"); DECODE_STEP_SECONDS reads the span
         with _tracing.begin("engine.decode", args={
                 "slots": len(active),
-                "live": int(self._pos[active].sum())}) as step:
+                "live": int(self._pos[active].sum()),
+                "attn": self._attends_in(C)}) as step:
             with _tracing.begin("engine.decode:prep"):
                 tokens = np.zeros((B, C), np.int32)
                 drafts = {}
@@ -1242,7 +1317,8 @@ class PagedGenerationEngine:
         active = [int(b) for b in np.nonzero(self._active)[0]]
         with _tracing.begin("engine.decode", args={
                 "slots": len(active),
-                "live": int(self._pos[active].sum())}) as step:
+                "live": int(self._pos[active].sum()),
+                "attn": self._attends_in(Bl)}) as step:
             blk, on = self._blocks, self._active & ~self._drained
             if on.any():
                 with _tracing.begin("engine.decode:prep"):
@@ -1384,6 +1460,19 @@ class PagedGenerationEngine:
         if self._spec_k > 0:
             shapes.append((self._slots, self._spec_k + 1))
         return shapes
+
+    def _attends_in(self, chunk):
+        """The form the program of a dispatch of ``chunk`` positions a
+        slot attends in (the ``attn`` of the step spans): ``"rows"``
+        where the model reads its cache rows as they lie, ``"heads"``
+        where it splits them by head.  A model that takes rows attends
+        through ``ops.attention_rows``, whose rule goes by the query
+        rows a slot; a head-split view is attended head by head."""
+        if not self._cache_rows:
+            return "heads"
+        from .ops.attention_rows import attends_in
+
+        return attends_in(chunk, self.model_config["n_heads"])
 
     def _dispatch_args(self, shape):
         """Arguments of the dispatch at one token shape, all zeros

@@ -1,0 +1,157 @@
+"""Attention of a chunk of queries against K/V rows as the paged pool
+holds them: one row a position, every head side by side,
+``(B, S, kv_heads * d_head)``.
+
+The decode engine (``generate.PagedGenerationEngine``) gathers such rows
+a layer at a time for a model whose ``config`` says ``cache_rows``.  A
+head size under the TPU's 128 lanes must never become the minor-most
+dimension of anything as large as the cache: splitting the rows into
+``(B, heads, S, d_head)`` makes the compiler re-tile all of them, every
+layer, every step.  So for the decode and verify shapes (few query rows
+a slot) the rows are read as they lie:
+
+* the queries are expanded **block-diagonally**: row ``(c, h)`` of
+  ``(B, C * heads, kv_heads * d_head)`` holds head ``h``'s ``d_head``
+  values at the lanes of its key/value head and zeros elsewhere;
+* the scores are ``q_bd @ k_rows^T``, contracted over the full row (the
+  zeros add exactly 0), the output ``p @ v_rows``, of which each head
+  keeps its own lanes.
+
+Both are plain matrix products; the arithmetic is ``kv_heads`` times the
+head-split products', which the MXU has to spare while the cache's
+bytes set the pace.  With many query rows a slot (a prefill chunk) the
+arithmetic outgrows the bytes saved, and the head-split products are
+the faster, on rows transposed whole to ``(B, kv_heads * d_head, S)``
+(lane-dense on both sides) so that the heads split off a major
+dimension: :func:`attends_in` says which form a dispatch's shape gets,
+and nothing else decides it.
+
+Either way the operands stay in the dtype they arrive in, the products
+accumulate in float32, and the softmax over the cached and the chunk's
+own positions together is float32.
+"""
+from __future__ import annotations
+
+__all__ = ["chunk_attention_rows", "attends_in"]
+
+# query rows a slot (chunk positions x query heads) up to which the
+# block-diagonal products beat the head-split ones.  Timed on a TPU v5e
+# at OPT-1.3B's widths, 8 slots of 1024 positions (PERF.md, PR 32): a
+# decode step (32 rows a slot) 14.8 against 26.5 ms a program, a verify
+# step of 4 tokens (128) 16.2 against 21.6, a prefill chunk of 32 (1024)
+# 7.3 against 4.8
+BLOCK_DIAGONAL_MAX_QUERY_ROWS = 256
+
+
+def attends_in(chunk, n_heads):
+    """The form :func:`chunk_attention_rows` attends in for ``chunk``
+    query positions a slot of ``n_heads`` query heads: ``"rows"`` (the
+    block-diagonal products on the rows as they lie) or ``"heads"`` (the
+    head-split products)."""
+    return "rows" if chunk * n_heads <= BLOCK_DIAGONAL_MAX_QUERY_ROWS \
+        else "heads"
+
+
+def _softmax_pair(s_cache, s_chunk, cache_ok, chunk_ok, dtype):
+    """The softmax over the cached and the chunk's own positions
+    together, float32, of scores given apart: ``s_cache`` (..., Q, S)
+    and ``s_chunk`` (..., Q, C) with the positions a query may attend
+    (``cache_ok``, ``chunk_ok``, broadcastable).  Returns the two parts
+    of the probabilities in ``dtype``.  Every query attends at least
+    itself, so the shared maximum is finite and a masked score's
+    ``exp`` is exactly 0."""
+    import jax.numpy as jnp
+
+    neg = jnp.float32(-1e30)
+    s_cache = jnp.where(cache_ok, s_cache, neg)
+    s_chunk = jnp.where(chunk_ok, s_chunk, neg)
+    m = jnp.maximum(s_cache.max(-1, keepdims=True),
+                    s_chunk.max(-1, keepdims=True))
+    e_cache, e_chunk = jnp.exp(s_cache - m), jnp.exp(s_chunk - m)
+    denom = e_cache.sum(-1, keepdims=True) + e_chunk.sum(-1, keepdims=True)
+    return (e_cache / denom).astype(dtype), (e_chunk / denom).astype(dtype)
+
+
+def _dot(a, b, dims):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
+                         n_heads, n_kv_heads=None, scale=None):
+    """Causal attention of a chunk against its sequence's cached rows.
+
+    ``q`` (B, C, n_heads * d_head): the chunk's queries, at positions
+    ``start_b .. start_b + C - 1``; ``k_chunk`` / ``v_chunk``
+    (B, C, n_kv_heads * d_head): its own keys and values (not in the
+    cache yet); ``k_rows`` / ``v_rows`` (B, S, n_kv_heads * d_head): the
+    cache, of which positions ``s < start_b`` are attended and the rest
+    (unwritten, stale or the trash page's) are not; ``start`` (B,)
+    int32.  Query head ``h`` reads key/value head ``h // (n_heads /
+    n_kv_heads)``.  Chunk position ``c`` attends every cached position
+    below ``start_b`` and chunk positions ``c' <= c``.  Returns
+    (B, C, n_heads * d_head) in ``q``'s dtype.
+    """
+    import jax.numpy as jnp
+
+    B, C, _ = q.shape
+    S = k_rows.shape[1]
+    H = int(n_heads)
+    Hkv = int(n_kv_heads) if n_kv_heads else H
+    G, dh = H // Hkv, q.shape[2] // H
+    if scale is None:
+        scale = dh ** -0.5
+    cache_ok = (jnp.arange(S, dtype=jnp.int32)[None, :]
+                < start.astype(jnp.int32)[:, None])             # (B, S)
+    c_idx = jnp.arange(C, dtype=jnp.int32)
+    causal = c_idx[:, None] >= c_idx[None, :]                   # (C, C')
+
+    if attends_in(C, H) == "rows":
+        # row (c, h) = head h's values at the lanes of its key/value
+        # head, zeros at the others
+        own = (jnp.arange(H)[:, None] // G
+               == jnp.arange(Hkv)[None, :])                     # (H, Hkv)
+        q_bd = jnp.where(own[None, None, :, :, None],
+                         q.reshape((B, C, H, 1, dh)),
+                         jnp.zeros((), q.dtype)).reshape(
+                             (B, C * H, Hkv * dh))
+        rows_t = (((2,), (2,)), ((0,), (0,)))   # contract the full row
+        s_cache = _dot(q_bd, k_rows, rows_t) * scale            # (B, CH, S)
+        s_chunk = _dot(q_bd, k_chunk, rows_t) * scale           # (B, CH, C)
+        p_cache, p_chunk = _softmax_pair(
+            s_cache, s_chunk, cache_ok[:, None, :],
+            jnp.repeat(causal, H, axis=0)[None], k_rows.dtype)
+        over_s = (((2,), (1,)), ((0,), (0,)))
+        o_bd = _dot(p_cache, v_rows, over_s) + _dot(p_chunk, v_chunk,
+                                                    over_s)
+        # every head keeps the lanes of its key/value head: summed over
+        # the key/value heads the rows belong to, lanes (kv', d) hold
+        # head (kv', g)
+        o_bd = o_bd.reshape((B, C, Hkv, G, Hkv, dh))
+        mine = jnp.eye(Hkv, dtype=bool)[None, None, :, None, :, None]
+        out = jnp.where(mine, o_bd, 0.0).sum(axis=2)     # (B, C, G, Hkv, dh)
+        out = out.transpose((0, 1, 3, 2, 4))
+    else:
+        def heads(a):       # (B, T, Hkv * dh) -> (B, Hkv, dh, T)
+            # positions minor-most: the rows are transposed whole, lane
+            # dense on both sides, and the heads split off a major
+            # dimension, which moves nothing
+            return jnp.swapaxes(a, 1, 2).reshape((B, Hkv, dh, a.shape[1]))
+
+        # the G query heads of a key/value head side by side: row (g, c)
+        qh = q.reshape((B, C, Hkv, G, dh)).transpose(
+            (0, 2, 3, 1, 4)).reshape((B, Hkv, G * C, dh))
+        over_d = (((3,), (2,)), ((0, 1), (0, 1)))
+        s_cache = _dot(qh, heads(k_rows), over_d) * scale   # (B,Hkv,GC,S)
+        s_chunk = _dot(qh, heads(k_chunk), over_d) * scale
+        p_cache, p_chunk = _softmax_pair(
+            s_cache, s_chunk, cache_ok[:, None, None, :],
+            jnp.tile(causal, (G, 1))[None, None], k_rows.dtype)
+        over_s = (((3,), (3,)), ((0, 1), (0, 1)))
+        out = _dot(p_cache, heads(v_rows), over_s) + _dot(
+            p_chunk, heads(v_chunk), over_s)             # (B, Hkv, GC, dh)
+        out = out.reshape((B, Hkv, G, C, dh)).transpose((0, 3, 1, 2, 4))
+    return out.reshape((B, C, H * dh)).astype(q.dtype)

@@ -280,21 +280,24 @@ register_layout(Layout("data_parallel", [
     SpecRule("replicated", r".*", ()),
 ]))
 
-# the decode engine's page pool (generate.PagedGenerationEngine) is
-# token-major: rank-3 (pages * page_size, layers, heads * d_head)
+# the decode engine's page pools (generate.PagedGenerationEngine),
 # arrays named pool_k/pool_v, indexed on dimension 0 by the gather and
-# the scatter of every dispatch.  Tokens shard over the data axes (page
-# ids are host-side bookkeeping and index the whole pool, whichever
-# shard holds the row), the heads * d_head dimension over tp — whole
-# heads to a shard, since heads are its major factor: each tp shard
-# attends over its own heads, composing with the column-parallel
-# proj_q/k/v below (the K/V a shard caches are exactly the ones its
-# projections produce).
-# A tokens dim the data axes do not divide (the pool carries a +1
-# trash page) degrades to replicated there while heads stay
-# tp-sharded.
+# the scatter of every dispatch, in the one of two forms the model's
+# protocol picks: rank-2 rows, (layers * pages * page_size,
+# heads * d_head), one row a (layer, token), for a model that takes its
+# caches as rows (``cache_rows`` in its config); else rank-3 and
+# token-major, (pages * page_size, layers, heads * d_head).  Either
+# way the rows shard over the data axes (page ids are host-side
+# bookkeeping and index the whole pool, whichever shard holds the row)
+# and the heads * d_head dimension, the last, over tp — whole heads to
+# a shard, since heads are its major factor: each tp shard holds the
+# K/V its column-parallel proj_k/v below produce.
+# A row count the data axes do not divide (the pool carries a +1 trash
+# page) degrades to replicated there while heads stay tp-sharded.
 _KV_POOL_FSDP = SpecRule("kv_pool", r"pool_(k|v)$",
-                         (("dp", "fsdp"),), rank=3)
+                         (("dp", "fsdp"),), min_rank=2)
+_KV_POOL_TP_ROWS = SpecRule("kv_pool", r"pool_(k|v)$",
+                            (("dp", "fsdp"), "tp"), rank=2)
 _KV_POOL_TP = SpecRule("kv_pool", r"pool_(k|v)$",
                        (("dp", "fsdp"), None, "tp"), rank=3)
 
@@ -309,6 +312,7 @@ register_layout(Layout("fsdp", [
 ]))
 
 register_layout(Layout("fsdp_tp", [
+    _KV_POOL_TP_ROWS,
     _KV_POOL_TP,
     # Megatron pairing on the mxnet (out_features, in_features) weight
     # convention: qkv/up projections column-parallel (tp on dim 0), the

@@ -19,12 +19,14 @@ Tier-1 guards:
   ``pages``) and the TokenServer's end-to-end output (chunked +
   shared + speculative) matches the full re-forward's;
 * the new bench-mode ledger metrics gate in the right direction;
-* the pool is token-major (ISSUE 27): both index operations of the one
-  dispatch address the donated pool's dimension 0 with nothing
-  pool-sized before them, the TPU compiler (no chip: a described
-  v5e) copies no pool and expands no gather into a loop at OPT-1.3B's
-  widths, mixed batches match the full re-forward, and a mesh resolves
-  the pool under its own ``kv_pool`` rule.
+* the pool is indexed on its leading dimension (ISSUE 27), and for a
+  model that takes its caches as rows it holds one row a (layer, token)
+  (ISSUE 32): both index operations of the one dispatch address the
+  donated pool's dimension 0 with nothing pool-sized before them, the
+  TPU compiler (no chip: a described v5e) copies no pool, builds no
+  view of all layers' caches and expands no gather into a loop at
+  OPT-1.3B's widths, mixed batches match the full re-forward, and a
+  mesh resolves the pool under its own ``kv_pool`` rule.
 
 Engine programs stay tiny (d_model 32, cache 24) for the tier-1
 budget; every paged engine compiles at most three chunk signatures.
@@ -115,6 +117,23 @@ def test_paged_greedy_matches_full_forward(lm, paged):
     got = [p_tok] + _drain(paged, p_slot, 8)
     paged.evict(p_slot, "length")
     assert got == _greedy_reference(lm, prompt, 9)
+
+
+@pytest.mark.parametrize("mesh", [None, "dp=2,tp=2"],
+                         ids=["one_device", "dp2_tp2"])
+def test_pages_gathered_whole_match_full_forward(lm, mesh):
+    """Pages of 8 float32 rows are whole sublane tiles, so a layer is
+    gathered page by page from the pool seen as (layers * pages, page,
+    heads * d_head) (ISSUE 32): two slots side by side, prompts that
+    end inside a page and idle rows on the trash page, decode the full
+    re-forward's greedy tokens, on one device and under a mesh."""
+    e = generate.PagedGenerationEngine(
+        lm, slots=3, cache_len=MAX_LEN, page_size=8, prefill_chunk=8,
+        mesh=mesh, sampling=generate.SamplingConfig(greedy=True))
+    prompts = [_prompt(5, seed=61), _prompt(11, seed=62)]
+    got = _side_by_side(e, prompts, 7)
+    for prompt, toks in zip(prompts, got):
+        assert toks == _greedy_reference(lm, prompt, len(toks))
 
 
 def test_paged_mesh_matches_single_device(lm, paged):
@@ -364,48 +383,72 @@ def _dispatch_args(eng, shape):
         {"prefill": prefill, "decode": decode, "verify": verify}[shape])
 
 
+@pytest.mark.parametrize("page_size", [4, 8], ids=["by_row", "by_page"])
 @pytest.mark.parametrize("shape", ["decode", "prefill", "verify"])
-def test_pool_is_indexed_on_its_leading_dimension(lm, shape):
+def test_pool_is_indexed_on_its_leading_dimension(lm, shape, page_size):
     """Both index operations of the dispatch take the donated pool
-    itself — no transpose, copy or reshape of it first — and address its
-    dimension 0; nothing else in the program has the pool's size."""
+    itself — no transpose or copy of it first — and address its
+    dimension 0: a gather a layer of whole rows (of whole pages, from
+    the pool seen page by page, where a page is a whole number of
+    sublane tiles: 8 rows of float32), one scatter of the chunk's rows
+    of every layer; nothing else in the program has the pool's size."""
     import jax
 
     eng = generate.PagedGenerationEngine(
-        lm, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
-        spec_k=2, sampling=generate.SamplingConfig(greedy=True))
-    assert eng.pool_shape == (eng.num_pages * eng.page_size, N_LAYERS,
+        lm, slots=3, cache_len=MAX_LEN, page_size=page_size,
+        prefill_chunk=8, spec_k=2,
+        sampling=generate.SamplingConfig(greedy=True))
+    # one row a (layer, token): TransformerLM's config says cache_rows
+    assert eng.pool_shape == (N_LAYERS * eng.num_pages * page_size,
                               D_MODEL)
-    closed = jax.make_jaxpr(eng._jit_chunk)(*_dispatch_args(eng, shape))
+    args = _dispatch_args(eng, shape)
+    closed = jax.make_jaxpr(eng._jit_chunk)(*args)
     (call,) = closed.jaxpr.eqns          # the jitted chunk_fn itself
     body = call.params["jaxpr"].jaxpr
     n_params = len(eng._params)
-    pools = body.invars[n_params:n_params + 2]
+    pools = list(body.invars[n_params:n_params + 2])
     pool_size = int(np.prod(eng.pool_shape))
+    by_page = page_size == 8
+    # the pool seen page by page: the same bytes under the TPU's tiling
+    views = [e for e in body.eqns if e.primitive.name == "reshape"
+             and any(e.invars[0] is p for p in pools)]
+    assert len(views) == (2 * N_LAYERS if by_page else 0)
+    for e in views:
+        assert e.outvars[0].aval.shape == (
+            N_LAYERS * eng.num_pages, page_size, D_MODEL)
+    sources = pools + [e.outvars[0] for e in views]
 
     gathers = [e for e in body.eqns if e.primitive.name == "gather"
-               and any(e.invars[0] is p for p in pools)]
+               and any(e.invars[0] is p for p in sources)]
     scatters = [e for e in body.eqns if e.primitive.name == "scatter"
                 and any(e.invars[0] is p for p in pools)]
-    assert len(gathers) == 2 and len(scatters) == 2, (gathers, scatters)
+    assert len(gathers) == 2 * N_LAYERS and len(scatters) == 2, \
+        (gathers, scatters)
+    nb, nc = args[4].shape
     for e in gathers:
         dn = e.params["dimension_numbers"]
         assert tuple(dn.start_index_map) == (0,)
         assert tuple(dn.collapsed_slice_dims) == (0,)
-        assert tuple(e.params["slice_sizes"]) == (1,) + eng.pool_shape[1:]
+        assert tuple(e.params["slice_sizes"]) == (
+            (1, page_size, D_MODEL) if by_page else (1, D_MODEL))
+        # a layer's rows of every slot, as the model takes them
+        assert int(np.prod(e.outvars[0].aval.shape)) == \
+            nb * eng.cache_len * D_MODEL
     for e in scatters:
         dn = e.params["dimension_numbers"]
         assert tuple(dn.scatter_dims_to_operand_dims) == (0,)
         assert tuple(dn.inserted_window_dims) == (0,)
+        # a row a (layer, chunk position)
+        assert e.invars[2].aval.shape == (N_LAYERS * nb * nc, D_MODEL)
         # padded positions collide on the trash page
         assert not e.params["unique_indices"]
-    # the pools reach nothing but their gather and their scatter (no
-    # transpose, copy or reshape of a pool comes before either), and
-    # only the scatters' results have the pool's size
+    # the pools reach nothing but their gathers and their scatter (no
+    # transpose or copy of a pool comes before either), and only the
+    # views' and the scatters' results have the pool's size
     for e in body.eqns:
-        if any(e is x for x in gathers + scatters):
+        if any(e is x for x in views + gathers + scatters):
             continue
-        assert not any(v is p for v in e.invars for p in pools), e
+        assert not any(v is p for v in e.invars for p in sources), e
         for v in e.outvars:
             assert int(np.prod(v.aval.shape)) < pool_size, e
 
@@ -437,10 +480,12 @@ def v5e_chip():
 def wide_engine():
     """The serving cell's engine at OPT-1.3B's widths (d_model 2048, 32
     heads, page 16, 8 slots of 1024 positions, 513 pages, bf16 cache),
-    cut to 2 layers and a small FFN and vocabulary: the pool keeps its
-    real rows."""
+    cut to 6 layers and a small FFN and vocabulary: the pool keeps its
+    real rows, and at 0.2 GB is past what the compiler would move into
+    the chip's fast memory whole (as it does a pool of 2 layers, which
+    the cell's 24 are as far from)."""
     net = TransformerLM(vocab_size=256, d_model=2048, n_heads=32,
-                        n_layers=2, d_ff=256, max_len=1024)
+                        n_layers=6, d_ff=256, max_len=1024)
     net.initialize(mx.init.Zero())
     return generate.PagedGenerationEngine(
         net, slots=8, cache_len=1024, page_size=16, num_pages=513,
@@ -473,6 +518,10 @@ def wide_moe_engine():
 # the gathered views of 32 x 1024 rows and their relayouts (decode), one
 # slot's view and a chunk's expert activations (prefill)
 MOE_TEMP_BYTES = {"decode": 1.4e9, "prefill": 0.5e9}
+# the OPT cell's (ISSUE 32): no view of all layers' caches is built, so
+# at the cell's 24 layers the decode program's are 0.03 GB (2.4 GB with
+# the view; a quarter of either at the fixture's 6 layers)
+OPT_TEMP_BYTES = {"decode": 0.5e9, "prefill": 0.5e9}
 
 
 class _time_limit:
@@ -503,12 +552,14 @@ class _time_limit:
 def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     """What the chip's compiler makes of the dispatch (optimized HLO for
     a described v5e, nothing runs): the pool keeps a row-major layout
-    with tokens outermost, and besides the parameter, the in-place
+    with its rows outermost, and besides the parameter, the in-place
     scatter and the result no operation has the pool's size: no copy,
     no loop that gathers page by page, no buffer of zeros.  No weight
-    enters as a float32 master to be cast in the program.  For the
-    block-diffusion cell's model the temporaries stay under a stated
-    size as well."""
+    enters as a float32 master to be cast in the program.  The
+    temporaries stay under a stated size.  For the OPT cell's model,
+    whose pool holds a row a (layer, token), nothing in the entry is as
+    large as a view of all layers' caches either: a layer's rows are
+    gathered and attended, and the next layer's after them."""
     import re
 
     import jax
@@ -542,8 +593,10 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
                      entry)
     assert sorted(op for _layout, op in ops) == \
         ["fusion", "fusion", "parameter", "parameter"], ops
+    major_first = "{" + ",".join(
+        str(d) for d in reversed(range(len(eng.pool_shape))))
     for layout, _op in ops:
-        assert layout.startswith("{2,1,0"), "tokens are not outermost"
+        assert layout.startswith(major_first), "rows are not outermost"
     scatter_fusions = re.findall(
         r"= %s\S* fusion\(.*op_name=\"jit\(chunk_fn\)/scatter" %
         re.escape(pool), entry)
@@ -571,9 +624,26 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     for m in matrices:
         assert not re.findall(r"= bf16\[%s\]\S* convert\("
                               % ",".join(str(d) for d in m), text), m
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if model == "opt":
+        assert eng.pool_shape == (6 * 513 * 16, 2048)
+        assert temp < OPT_TEMP_BYTES[shape], temp
+        # all layers' caches of the dispatch's slots: the view the
+        # program used to build, re-tile twice and slice
+        view = shapes[shape][0] * eng.cache_len * 6 * 2048
+        large = []
+        for line in entry.splitlines():
+            m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([\w\-]+)\(", line)
+            # (the entry's result is a tuple that names the pools; a
+            # bitcast, the pool seen page by page, moves nothing)
+            if m and m.group(2) not in ("tuple", "bitcast") and any(
+                    np.prod([int(d) for d in dims.split(",")]) >= view
+                    for dims in re.findall(r"\[([\d,]+)\]", m.group(1))):
+                large.append(m.group(2))
+        assert sorted(large) == ["fusion", "fusion", "parameter",
+                                 "parameter"], large
     if model == "moe":
         assert eng.pool_shape == (2049 * 16, 6 * 4, 128)
-        temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < MOE_TEMP_BYTES[shape], temp
         # no expert matrix is copied to be multiplied
         assert not re.findall(r"= bf16\[2048,6144\]\S* copy\(", entry)
@@ -589,6 +659,17 @@ def _build(net, **kw):
     return generate.PagedGenerationEngine(
         net, slots=3, cache_len=MAX_LEN, page_size=4, prefill_chunk=8,
         sampling=generate.SamplingConfig(greedy=True), **kw)
+
+
+def _moe_lm(block_length=1, **kw):
+    """The zoo's decoder, small: it takes head-split views."""
+    from mxnet_tpu.gluon.model_zoo.language import MoEDecoderLM
+
+    net = MoEDecoderLM(64, 32, 2, 4, 2, 16, 4, 2, 16,
+                       block_length=block_length, mask_token_id=63,
+                       max_len=32, **kw)
+    net.initialize(mx.init.Normal(0.02))
+    return net
 
 
 def _weights_span(build):
@@ -672,12 +753,7 @@ def test_weights_handed_over_at_their_targets_are_the_same_buffers():
     """A model stored in bfloat16 with float32 norms and head (the
     block-diffusion cell's, small) is held as the very buffers it has:
     no second copy of the weights."""
-    from mxnet_tpu.gluon.model_zoo.language import MoEDecoderLM
-
-    net = MoEDecoderLM(64, 32, 2, 4, 2, 16, 4, 2, 16, block_length=4,
-                       mask_token_id=63, max_len=32, dtype="bfloat16")
-    net.initialize(mx.init.Normal(0.02))
-    _on_device(net)
+    net = _on_device(_moe_lm(block_length=4, dtype="bfloat16"))
     eng, span = _weights_span(lambda: generate.PagedGenerationEngine(
         net, slots=2, cache_len=32, page_size=8, prefill_chunk=8, spec_k=0,
         dtype_policy="bf16_mixed", denoise_steps=2))
@@ -705,6 +781,51 @@ def test_held_weights_keep_their_sharding(lm):
         sharded += any(ax is not None for ax in res.spec(p.name))
     assert sharded, "no parameter of the layout is sharded"
     assert {str(a.dtype) for a in e._params} == {"bfloat16", "float32"}
+
+
+@pytest.mark.parametrize("model", ["rows", "views", "views_blocks"])
+def test_spans_name_the_attention_form_and_the_pool(lm, model,
+                                                    monkeypatch):
+    """The counter that says the mechanism engaged (ISSUE 32): every
+    ``engine.decode`` / ``engine.prefill`` span carries ``attn``, the
+    form the launched program attends in by the engine's rule for that
+    model and dispatch shape, and the ``engine.pool`` span written once
+    when the engine is built carries the pool's shape."""
+    from mxnet_tpu import tracing
+    from mxnet_tpu.ops import attention_rows
+
+    # two heads: a decode step (2 query rows a slot) and a verify step
+    # (6) read the rows as they lie, a prefill chunk of 8 (16) does not
+    monkeypatch.setattr(attention_rows, "BLOCK_DIAGONAL_MAX_QUERY_ROWS", 8)
+    before = {r["span_id"] for r in tracing.records()}
+    if model == "rows":
+        eng = _build(lm, spec_k=2)
+        want = {"engine.prefill": "heads", "engine.decode": "rows"}
+        shape = (N_LAYERS * eng.num_pages * 4, D_MODEL)
+        assert [eng._attends_in(c) for _b, c in eng.dispatch_shapes()] \
+            == ["heads", "rows", "rows"]
+    else:
+        blocks = 4 if model == "views_blocks" else 1
+        eng = generate.PagedGenerationEngine(
+            _moe_lm(blocks), slots=2, cache_len=32, page_size=8,
+            prefill_chunk=8, spec_k=0, denoise_steps=2 if blocks > 1
+            else None)
+        want = {"engine.prefill": "heads", "engine.decode": "heads"}
+        shape = (eng.num_pages * 8, 2, 2 * 16)
+    # two prefill chunks of 8 (whole blocks only, under block decoding)
+    slot, _tok = eng.admit(_prompt(11 if model == "rows" else 19, seed=7))
+    for _ in range(3):
+        eng.decode_step()
+    eng.evict(slot, "length")
+    mine = [r for r in tracing.records() if r["span_id"] not in before]
+    for name, form in want.items():
+        spans = [r for r in mine if r["name"] == name]
+        assert len(spans) >= 2, name
+        assert [r["args"]["attn"] for r in spans] == [form] * len(spans)
+    (pool,) = [r for r in mine if r["name"] == "engine.pool"]
+    assert tuple(pool["args"]["shape"]) == eng.pool_shape == shape
+    assert pool["args"]["cache_rows"] == (model == "rows")
+    assert pool["args"]["bytes"] == 2 * int(np.prod(shape)) * 4
 
 
 def _weight_converts(jitted, args, n_params):
@@ -843,14 +964,15 @@ def test_mixed_batch_matches_full_forward(lm, paged, scenario):
 
 
 @pytest.mark.parametrize("mesh,spec", [
-    ("tp=2", (None, None, "tp")),
-    ("dp=2,tp=2", ("dp", None, "tp")),
-    ("fsdp=2,tp=2", ("fsdp", None, "tp")),
+    ("tp=2", (None, "tp")),
+    ("dp=2,tp=2", ("dp", "tp")),
+    ("fsdp=2,tp=2", ("fsdp", "tp")),
     ("dp=2,fsdp=2", (("dp", "fsdp"),))])
 def test_pool_layout_rule_under_mesh(lm, paged, mesh, spec):
-    """The pool resolves under its own ``kv_pool`` rule — tokens over
-    the data axes, heads over tp — and a meshed engine decodes the
-    one-device engine's greedy tokens."""
+    """The pool of rows (rank 2: a row a (layer, token)) resolves under
+    its own ``kv_pool`` rule — rows over the data axes, heads over tp —
+    and a meshed engine decodes the one-device engine's greedy
+    tokens."""
     from jax.sharding import PartitionSpec as P
 
     from mxnet_tpu import parallel
@@ -861,7 +983,8 @@ def test_pool_layout_rule_under_mesh(lm, paged, mesh, spec):
     res = parallel.layout.get_layout(e.layout_name).resolve(
         [("pool_k", e.pool_shape), ("pool_v", e.pool_shape)], e._mesh)
     assert res.rule("pool_k") == res.rule("pool_v") == "kv_pool"
-    assert not res.fallbacks, "52 rows and 32 lanes divide by 2 and 4"
+    assert e.pool_shape == (N_LAYERS * 52, D_MODEL)
+    assert not res.fallbacks, "104 rows and 32 lanes divide by 2 and 4"
     assert res.spec("pool_k") == P(*spec)
     assert e._pool_k.sharding.spec == e._pool_v.sharding.spec == P(*spec)
     prompts = [_prompt(5, seed=3), _prompt(7, seed=5)]
@@ -874,8 +997,9 @@ def test_pool_layout_rule_under_mesh(lm, paged, mesh, spec):
 def test_prewarm_check_reads_the_token_major_rows(lm, tmp_path, fault,
                                                   problem):
     """``tools/prewarm.py --check`` judges a stored paged signature by
-    the pool's token-major leaves: a healthy store passes, a row whose
-    extras disagree with its own recorded shapes is named."""
+    the pool's leaves, here rows of one (layer, token) each: a healthy
+    store passes, a row whose extras disagree with its own recorded
+    shapes is named."""
     import prewarm
     from mxnet_tpu import aot
 
@@ -888,7 +1012,8 @@ def test_prewarm_check_reads_the_token_major_rows(lm, tmp_path, fault,
     assert problems == [] and len(rows) == 2
     for row in rows:
         assert row["label"] == "generate:paged_chunk"
-        assert row["pool_layout"] == "tokens%dxL%dxHD%d" % e.pool_shape
+        assert row["pool_layout"] == "L%dxtokens%dxHD%d" % (
+            N_LAYERS, e.num_pages * e.page_size, D_MODEL)
         assert row["pool_layout"] in row["spec"]
         if fault is not None:
             row[fault] = 5

@@ -340,9 +340,10 @@ def run_manifest(args):
 def _check_paged_row(e):
     """Shape-consistency problems for one ``generate:paged_chunk``
     manifest row (empty list = healthy).  The paged engine compiles a
-    closed family of signatures — the two page-pool leaves are rank-3,
-    token-major (pages * page_size, layers, heads * d_head), and the
-    token block is one of (1, chunk) /
+    closed family of signatures — the two page-pool leaves, which come
+    right before the page table, have one row a (layer, token) (rank 2)
+    or a token (token-major, rank 3), so a whole number of pages of
+    rows either way, and the token block is one of (1, chunk) /
     (slots, 1) / (slots, K+1) — so a row whose recorded shapes disagree
     with its own page_size/prefill_chunk/spec_k extras means the store
     was written by a mismatched build and would miss at load."""
@@ -359,11 +360,15 @@ def _check_paged_row(e):
               if isinstance(s, (list, tuple)) and len(s) >= 2
               and isinstance(s[0], (list, tuple))]
     msgs = []
-    # the model's parameters are rank-1 and rank-2 leaves
-    pools = [s for s, _d in leaves if len(s) == 3]
-    if len(pools) != 2:
-        msgs.append("%s: no pair of token-major page-pool leaves "
-                    "(rank-3) in the recorded signature" % who)
+    # in flatten order: the model's parameters, the two pools, then the
+    # page table, the first rank-2 int32 leaf
+    table = next((i for i, (s, d) in enumerate(leaves)
+                  if len(s) == 2 and d == "int32"), 0)
+    pools = [s for s, d in leaves[max(table - 2, 0):table]
+             if len(s) >= 2 and d != "int32"]
+    if len(pools) != 2 or pools[0] != pools[1]:
+        msgs.append("%s: no pair of page-pool leaves before the page "
+                    "table in the recorded signature" % who)
     else:
         for s in pools:
             if s[0] % page:
