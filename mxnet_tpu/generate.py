@@ -59,7 +59,12 @@ verify step (``examples/transformer_lm.py``,
 (``n_kv_heads`` / ``d_head`` where they are not the defaults;
 ``block_length`` and ``mask_token_id`` for block-diffusion decoding;
 ``cache_rows`` where it takes a layer's cache as rows, ``(B, S, heads *
-d_head)``, and not as a head-split view, ``(B, heads, S, d_head)``).
+d_head)``, and not as a head-split view, ``(B, heads, S, d_head)``).  A
+model whose layers do not all cache K and V a head
+(``gluon.model_zoo.language.HybridDecoderLM``) says what each keeps in
+``layer_caches``: paged rows of a width, in one pool, or arrays a
+slot (recurrent state); its ``chunk_forward`` takes a fourth
+argument, the positions of each row that count.
 Benchmarks: ``tools/bench_decode.py`` (tokens/s/user, TTFT p50/p99,
 the KV-cache-vs-reforward ratio, plus the prefix-share /
 chunked-prefill / speculative modes); docs: ``docs/lm_serving.md``.
@@ -331,8 +336,11 @@ class _OpenBlocks:
 class PagedGenerationEngine:
     """Paged/block KV-cache generation over a chunk-protocol model.
 
-    Device state is one fixed-shape page pool per K/V, donated through
-    every dispatch, in the one of two forms the model's protocol picks.
+    Device state is what the model's protocol declares, donated through
+    every dispatch: for a model of attention layers alone one
+    fixed-shape page pool per K/V, in one of two forms; for a model that
+    says a layer at a time what it caches (``layer_caches``), one pool
+    of paged rows and arrays of per-slot state side by side (below).
     A model whose ``config`` says ``cache_rows`` takes each layer's
     cache as rows, and the pool is two-dimensional, ``(layers * pages *
     page_size, heads * d_head)``: row ``layer * tokens + page *
@@ -376,6 +384,27 @@ class PagedGenerationEngine:
       ``fold_in(lane_key, position)``), so accepted output is
       bit-identical to what non-speculative sampling would have
       produced — distribution preservation by construction.
+
+    **Layers that keep other things.**  ``config["layer_caches"]`` has
+    one entry a layer.  ``{"rows": width}``: one row a token of that
+    many values (a latent that all heads share), paged like K and V but
+    in ONE pool and no second one (one width a model), ``(layers that
+    keep rows * pages * page_size, width padded to whole tiles of 128
+    lanes)``; the model is handed a layer's gathered rows as they lie
+    and returns the chunk's.  ``{"state": [(shape, dtype), ...]}``:
+    arrays a SLOT (a recurrent layer's state), held as ``(slots,) +
+    shape`` in a tuple of their own, handed to every dispatch donated
+    like the pools and taken back from it; :meth:`cached` reads a
+    slot's caches back for a check.  A ``(slots, 1)`` decode step hands the model every slot's
+    row and takes every row back, the model leaving alone the rows of
+    slots no one is in (``valid`` 0); a ``(1, C)`` prefill chunk reads
+    and writes its own slot's row only, and the chunk that starts a
+    sequence reads it as zeros, so admission costs no dispatch and
+    eviction clears nothing.  State cannot be cut at a page boundary,
+    so a model with any is not offered prefix attachment (asked for, it
+    is turned off with a warning and no page is registered), and
+    ``spec_k > 0`` or a ``block_length`` over 1 raise: a rejected draft
+    or a re-run block would have to be rolled back out of it.
 
     **Prefix sharing** is page-aligned copy-on-write: full prompt pages
     are content-hashed (chained, so identity implies identical prefix)
@@ -532,7 +561,76 @@ class PagedGenerationEngine:
         n_tokens = self._num_pages * self._page_size
         # what the model's protocol declares picks the pool's rows
         self._cache_rows = bool(cfg.get("cache_rows"))
-        if self._cache_rows:
+        # a model whose layers do not all cache K and V a head says, a
+        # layer, what it caches: {"rows": width}, paged rows of that
+        # many values a token in ONE pool (no second one), or
+        # {"state": [(shape, dtype), ...]}, arrays a slot (a dtype of
+        # None is the cache's)
+        declared = cfg.get("layer_caches")
+        self._declared = declared is not None
+        self._state_layers = state_layers = [
+            li for li, kind in enumerate(declared or ()) if "state" in kind]
+        if self._declared:
+            if len(declared) != L or any(
+                    set(kind) not in ({"rows"}, {"state"})
+                    for kind in declared):
+                raise MXNetError(
+                    "model config's layer_caches must give each of the "
+                    "%d layers {'rows': width} or {'state': [(shape, "
+                    "dtype), ...]}, got %r" % (L, declared))
+            if self._mesh is not None:
+                raise MXNetError(
+                    "a model that declares its layers' caches is served "
+                    "on one device: the layouts have no rule yet for "
+                    "pools of latent rows or per-slot state")
+            if state_layers:
+                # state cannot be cut at a page boundary, copied by
+                # attaching pages or rolled back past a rejected draft
+                if self._spec_k:
+                    raise MXNetError(
+                        "spec_k=%d: speculation needs to roll a rejected "
+                        "draft back, which per-slot recurrent state "
+                        "(layers %s) cannot; build the engine with "
+                        "spec_k=0" % (self._spec_k, state_layers))
+                if Bl > 1:
+                    raise MXNetError(
+                        "block-diffusion decoding runs a block several "
+                        "times; a model with per-slot recurrent state "
+                        "cannot")
+                if self._prefix_share:
+                    _logger.warning(
+                        "prefix sharing is not offered to a model with "
+                        "per-slot recurrent state (layers %s): a prefix's "
+                        "pages do not hold what those layers kept of it; "
+                        "every prompt prefills whole", state_layers)
+                    self._prefix_share = False
+            # ONE pool of rows, of one width (a second width waits for
+            # a model that has one): row `k * tokens + page * page_size
+            # + offset` holds one position of the k-th layer that keeps
+            # rows, gathered a layer at a time like a model's that
+            # takes rows.  A row is whole tiles of 128 lanes, the
+            # model's values first and zeros after them: the TPU pads a
+            # row to that in memory whatever its shape says, and given
+            # a minor dimension that is no multiple of 128 (a latent
+            # row's 576) its runtime lays the TOKENS out minor-most and
+            # the program copies the pool twice a dispatch to index it.
+            # The model is handed the rows as they lie, zeros included
+            row_layers = [li for li, kind in enumerate(declared)
+                          if "rows" in kind]
+            widths = {int(declared[li]["rows"]) for li in row_layers}
+            if len(widths) > 1:
+                raise MXNetError(
+                    "layer_caches declares rows of several widths (%s); "
+                    "the engine keeps one pool of one width"
+                    % sorted(widths))
+            pool_shape = (len(row_layers) * n_tokens,
+                          -(-max(widths, default=0) // 128) * 128)
+            state_specs = [
+                ((self._slots,) + tuple(int(d) for d in shape),
+                 np.dtype(dt) if dt is not None else self._cache_dtype)
+                for li in state_layers
+                for shape, dt in declared[li]["state"]]
+        elif self._cache_rows:
             # one row a (layer, token): the layer is part of the row
             # index, so a layer's cache is a gather of whole rows,
             # (slots * cache_len, heads * d_head), with nothing to slice
@@ -588,15 +686,29 @@ class PagedGenerationEngine:
                 jax.device_put(p.data()._data, dev) for p in params)
             self._pool_sharding = dev
         self._params = _hold_weights(self._param_names, placed, dt_policy)
+        # what every dispatch is handed donated: the K and the V pool
+        # or, for a model that declares its layers' caches, its one pool
+        # of rows (no second one) and the arrays of per-slot state
+        self._pool_v, self._state = None, ()
         with _tracing.begin("engine.pool") as sp:
-            self._pool_k = jax.device_put(
-                jnp.zeros(pool_shape, self._cache_dtype),
-                self._pool_sharding)
-            self._pool_v = jax.device_put(
-                jnp.zeros(pool_shape, self._cache_dtype),
-                self._pool_sharding)
-            sp.set(shape=list(pool_shape), cache_rows=self._cache_rows,
-                   bytes=2 * int(self._pool_k.nbytes))
+            def zeros(shape, dtype):
+                return jax.device_put(jnp.zeros(shape, dtype),
+                                      self._pool_sharding)
+
+            self._pool_k = zeros(pool_shape, self._cache_dtype)
+            if self._declared:
+                self._state = tuple(zeros(sh, dt)
+                                    for sh, dt in state_specs)
+                state_bytes = sum(int(a.nbytes) for a in self._state)
+                sp.set(shape=list(pool_shape), cache_rows=True,
+                       bytes=int(self._pool_k.nbytes) + state_bytes,
+                       latent_rows_bytes=int(self._pool_k.nbytes),
+                       state_bytes=state_bytes)
+            else:
+                self._pool_v = zeros(pool_shape, self._cache_dtype)
+                sp.set(shape=list(pool_shape),
+                       cache_rows=self._cache_rows,
+                       bytes=2 * int(self._pool_k.nbytes))
 
         # host control plane: page tables + slot state + the prefix map
         P = self._pages_per_slot
@@ -690,8 +802,65 @@ class PagedGenerationEngine:
             return pool.reshape((L * n_pages, page, H * dh))[
                 li * n_pages + page_table].reshape(rows.shape + (H * dh,))
 
+        def read_declared(pool, state, page_table, rows, lanes):
+            """What every layer of a model that declares its caches
+            kept: a layer's rows of every slot of the dispatch, (B, S,
+            the pool's lanes), gathered like a layer of the pool of
+            rows; a layer's state, each array's rows ``slot_ids`` of it
+            (the arrays themselves where the dispatch is over all
+            slots), zeros for a slot whose sequence starts here."""
+            slot_ids, fresh, _valid = lanes
+            over_all = rows.shape[0] == self._slots
+            caches, at = [], 0
+            for li, kind in enumerate(declared):
+                if "rows" in kind:
+                    k = row_layers.index(li)
+                    if by_page:
+                        got = pool.reshape(
+                            (len(row_layers) * n_pages, page, -1))[
+                            k * n_pages + page_table].reshape(
+                                rows.shape + (-1,))
+                    else:
+                        got = pool[k * n_tokens + rows]
+                    caches.append(got)
+                    continue
+                mine = []
+                for _spec in kind["state"]:
+                    a = state[at] if over_all else state[at][slot_ids]
+                    mine.append(jnp.where(
+                        fresh.reshape((-1,) + (1,) * (a.ndim - 1)),
+                        jnp.zeros((), a.dtype), a))
+                    at += 1
+                caches.append(tuple(mine))
+            return caches
+
+        def write_declared(pool, state, kept, wpage, woff, lanes):
+            """The chunk's rows scattered to the pool, in place, and the
+            state of the dispatch's slots put back."""
+            slot_ids = lanes[0]
+            over_all = slot_ids.shape[0] == self._slots
+            state, at = list(state), 0
+            if row_layers:
+                vals = jnp.stack([kept[li] for li in row_layers])
+                wrow = (jnp.arange(len(row_layers),
+                                   dtype=jnp.int32)[:, None]
+                        * n_tokens + (wpage * page + woff)[None, :]
+                        ).reshape(-1)
+                vals = vals.astype(cache_dtype).reshape(
+                    (-1, vals.shape[-1]))
+                pool = pool.at[wrow].set(jnp.pad(vals, (
+                    (0, 0), (0, pool.shape[1] - vals.shape[1]))))
+            for li in state_layers:
+                for new in kept[li]:
+                    new = new.astype(state[at].dtype)
+                    state[at] = new if over_all \
+                        else state[at].at[slot_ids].set(new)
+                    at += 1
+            return pool, tuple(state)
+
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
-                     wpage, woff, lane_keys, block=None):
+                     wpage, woff, lane_keys, block=None, lanes=None,
+                     state=()):
             """The one paged dispatch: gather the pool rows of each
             slot's pages into each layer's linear cache (a view
             (B, H, S, dh) a layer of the token-major pool; for a model
@@ -718,7 +887,18 @@ class PagedGenerationEngine:
             the ``take`` most confident of them (ties to the lower
             position) are fixed at their best token.  ``extras["block"]``
             is the blocks after the pass, ``extras["masked"]`` what was
-            masked in it."""
+            masked in it.
+
+            For a model that declares its layers' caches ``pool_k`` is
+            its one pool of rows, ``pool_v`` nothing, and ``state`` the
+            tuple of its per-slot state arrays, donated like the pools
+            and handed back as ``extras["state"]``;
+            ``lanes`` = (``slot_ids`` (B,) the slot of each row of the
+            dispatch, ``fresh`` (B,) whether its sequence starts here
+            (its state reads as zeros: admission costs no dispatch of
+            its own), ``valid`` (B,) how many of its C positions count:
+            0 for a slot no one is in, whose state the model leaves as
+            it was)."""
             Bc, C = tokens.shape
             if block is not None:
                 (b_tok, b_mask, b_at, b_conf), fresh, given, take, \
@@ -736,6 +916,11 @@ class PagedGenerationEngine:
                     + jnp.arange(page, dtype=jnp.int32)).reshape((Bc, S))
 
             def run():
+                if lanes is not None:
+                    caches = read_declared(pool_k, state, page_table,
+                                           rows, lanes)
+                    res = net.chunk_forward(tokens, caches, start, lanes[2])
+                    return res[0]._data, res[1], dict(res[2])
                 if cache_rows:
                     caches = [(layer_rows(pool_k, li, page_table, rows),
                                layer_rows(pool_v, li, page_table, rows))
@@ -783,6 +968,10 @@ class PagedGenerationEngine:
                     jnp.where(fix, sampled, b_tok), b_mask & ~fix,
                     jnp.where(fix, number[:, None], b_at),
                     jnp.where(fix, conf, b_conf))
+            if lanes is not None:
+                pool_k, extras["state"] = write_declared(
+                    pool_k, state, chunk_caches, wpage, woff, lanes)
+                return sampled, logits, pool_k, pool_v, extras
             k_new = jnp.stack([k for k, _v in chunk_caches])
             v_new = jnp.stack([v for _k, v in chunk_caches])
             # leading-dimension scatter, in place on the donated pool;
@@ -805,10 +994,14 @@ class PagedGenerationEngine:
             pool_v = pool_v.at[wrow].set(vvals)
             return sampled, logits, pool_k, pool_v, extras
 
-        self._jit_chunk = jax.jit(chunk_fn, donate_argnums=(1, 2))
+        self._jit_chunk = jax.jit(chunk_fn, donate_argnums=(1, 2, 11))
         # the spec and the fingerprint name the pool's layout: an
         # executable stored for another one is never loaded
-        if self._cache_rows:     # one row a (layer, token)
+        if self._declared:       # one pool of rows, state a slot
+            pool_tag = "rows%dx%d:state%s" % (
+                pool_shape + ("+".join("x".join(str(d) for d in sh)
+                                       for sh, _dt in state_specs),))
+        elif self._cache_rows:   # one row a (layer, token)
             pool_tag = "L%dxtokens%dxHD%d" % (L, n_tokens, H * dh)
         elif row == (L, H * dh):  # token-major, a token's layers a row
             pool_tag = "tokens%dxL%dxHD%d" % pool_shape
@@ -859,7 +1052,9 @@ class PagedGenerationEngine:
 
     @property
     def pool_shape(self):
-        """Shape of each of the K and V pools on the device."""
+        """Shape of each of the K and V pools on the device (for a
+        model that declares its layers' caches: of its one pool of
+        rows)."""
         return tuple(self._pool_k.shape)
 
     @property
@@ -903,6 +1098,45 @@ class PagedGenerationEngine:
 
     def position(self, slot):
         return int(self._pos[slot])
+
+    def cached(self, slots):
+        """What the caches hold of the sequences in ``slots``, read on
+        the host (a check's reading, never the serving loop's: the
+        whole pool of rows and every state array cross to the host,
+        once, and nothing is compiled): for a model that declares its
+        layers' caches, a slot ``{"position": the positions cached,
+        "tokens": the ids at them, "layers": a layer its rows
+        (position, width) or the tuple of its state arrays}``, float32.
+        For the engine's one thread, between two dispatches."""
+        if not self._declared:
+            raise MXNetError("cached() reads the caches of a model that "
+                             "declares them (config['layer_caches'])")
+        pool = np.asarray(self._pool_k)
+        state = [np.asarray(a) for a in self._state]
+        n_tokens = self._num_pages * self._page_size
+        out = []
+        for slot in slots:
+            st = self._pending.get(slot)
+            n = int(st["filled"] if st is not None else self._pos[slot])
+            at = np.arange(n)
+            tok = self._page_table[slot, at // self._page_size] \
+                * self._page_size + at % self._page_size
+            layers, rows_seen, state_seen = [], 0, 0
+            for kind in self.model_config["layer_caches"]:
+                if "rows" in kind:
+                    layers.append(pool[rows_seen * n_tokens + tok][
+                        :, :int(kind["rows"])].astype(np.float32))
+                    rows_seen += 1
+                else:
+                    mine = state[state_seen:state_seen
+                                 + len(kind["state"])]
+                    state_seen += len(mine)
+                    layers.append(tuple(a[slot].astype(np.float32)
+                                        for a in mine))
+            out.append({"position": n,
+                        "tokens": list(self._history[slot][:n]),
+                        "layers": layers})
+        return out
 
     @property
     def last_logits(self):
@@ -1146,7 +1380,7 @@ class PagedGenerationEngine:
                 "slot": int(slot), "filled": int(filled),
                 "count": int(count), "final": final,
                 "block": self._block,
-                "attn": self._attends_in(self._chunk)}):
+                "attn": self._attends_in(self._chunk)}) as step:
             chunk = np.zeros((1, self._chunk), np.int32)
             chunk[0, :count] = toks[filled:filled + count]
             wpage = np.zeros(self._chunk, np.int32)
@@ -1156,14 +1390,22 @@ class PagedGenerationEngine:
                 p = filled + j
                 wpage[j] = row[p // self._page_size]
                 woff[j] = p % self._page_size
+            lanes = None
+            if self._declared:
+                # the slot's own row of every state array, read as zeros
+                # by the chunk that starts its sequence
+                fresh = filled == 0
+                lanes = (np.asarray([slot], np.int32), np.asarray([fresh]),
+                         np.asarray([count], np.int32))
+                if self._state_layers:
+                    step.set(state_slots=1)
+                    if fresh:
+                        _telemetry.DECODE_STATE_RESETS.inc()
             with _tracing.begin("engine.prefill:launch"):
-                sampled, logits, pk, pv, _extras = self._jit_chunk(
-                    self._params, self._pool_k, self._pool_v,
+                sampled, self._last_logits, _extras = self._dispatch(
                     self._page_table[slot:slot + 1].copy(), chunk,
                     np.asarray([filled], np.int32), wpage, woff,
-                    self._lane_keys[slot:slot + 1].copy())
-                self._pool_k, self._pool_v = pk, pv
-                self._last_logits = logits
+                    self._lane_keys[slot:slot + 1].copy(), lanes=lanes)
             self._chunks_run += 1
             _telemetry.DECODE_PREFILL_CHUNKS.inc()
             if not final:
@@ -1220,6 +1462,8 @@ class PagedGenerationEngine:
             return {}
         if self._block > 1:
             return self._decode_blocks()
+        import jax
+
         B, K = self._slots, self._spec_k
         cap = min(self._capacity, self.model_config["max_len"])
         active = [int(b) for b in np.nonzero(self._active)[0]]
@@ -1254,14 +1498,26 @@ class PagedGenerationEngine:
                 key = self._lane_keys.copy()
                 table = self._page_table.copy()
                 pos = self._pos.astype(np.int32).copy()
+                lanes = None
+                if self._declared:
+                    # every slot's row of the state; only those someone
+                    # is in count a position, the others stay as they are
+                    lanes = (np.arange(B, dtype=np.int32),
+                             np.zeros(B, bool),
+                             self._active.astype(np.int32))
+                    if self._state_layers:
+                        step.set(state_slots=len(active))
             with _tracing.begin("engine.decode:launch"):
-                sampled, logits, pk, pv, _extras = self._jit_chunk(
-                    self._params, self._pool_k, self._pool_v,
-                    table, tokens, pos, wpage, woff, key)
-                self._pool_k, self._pool_v = pk, pv
-                self._last_logits = logits
+                sampled, self._last_logits, extras = self._dispatch(
+                    table, tokens, pos, wpage, woff, key, lanes=lanes)
+            load = extras.get("expert_load")
             with _tracing.begin("engine.decode:readback"):
-                sampled = np.asarray(sampled)
+                if load is None:
+                    sampled = np.asarray(sampled)
+                else:       # one wait for the small arrays, not one each
+                    sampled, load = jax.device_get((sampled, load))
+            if load is not None:
+                self._note_expert_load(step, load)
             with _tracing.begin("engine.decode:post"):
                 out = {}
                 emitted_total = 0
@@ -1365,12 +1621,10 @@ class PagedGenerationEngine:
                     _telemetry.DECODE_COMMIT_PASSES.inc(int(commit.sum()))
                     _telemetry.DECODE_BATCH_TOKENS.observe(len(active))
                 with _tracing.begin("engine.decode:launch"):
-                    _sampled, logits, pk, pv, extras = self._jit_chunk(
-                        self._params, self._pool_k, self._pool_v,
+                    _sampled, logits, extras = self._dispatch(
                         table, tokens, pos, wpage, woff, key,
-                        (self._block_state, fresh, given,
-                         take.astype(np.int32), number))
-                    self._pool_k, self._pool_v = pk, pv
+                        block=(self._block_state, fresh, given,
+                               take.astype(np.int32), number))
                     self._block_state = now = extras["block"]
                     launched["logits"] = logits
                     launched["read"] = (now[0], now[2], now[3],
@@ -1411,8 +1665,7 @@ class PagedGenerationEngine:
                         "pass": read["pass"], "masked": masked}
                     step.set(emitted=emitted_total)
                     if load is not None:
-                        step.set(expert_load_max=int(load.max()),
-                                 expert_load_mean=float(load.mean()))
+                        self._note_expert_load(step, load)
                     _telemetry.DECODE_BLOCKS_COMMITTED.inc(
                         int((mine & read["commit"]).sum()))
                     _telemetry.DECODE_BLOCK_TOKENS.inc(emitted_total)
@@ -1420,6 +1673,40 @@ class PagedGenerationEngine:
                     self._note_occupancy()
         _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
+
+    def _dispatch(self, page_table, tokens, start, wpage, woff, keys,
+                  block=None, lanes=None):
+        """Launch the one program on the engine's donated pools (and,
+        for a model that declares its layers' caches, its per-slot
+        state), keep what it hands back in their place, and return
+        ``(sampled, logits, extras)``."""
+        more = (block,) if block is not None else ()
+        if self._declared:
+            more = (block, lanes, self._state)
+        sampled, logits, self._pool_k, self._pool_v, extras = \
+            self._jit_chunk(self._params, self._pool_k, self._pool_v,
+                            page_table, tokens, start, wpage, woff, keys,
+                            *more)
+        self._state = extras.pop("state", ())
+        return sampled, logits, extras
+
+    def _note_expert_load(self, step, load):
+        """An expert model's routing in the program a step read, on its
+        span: ``load`` (expert layers, experts) counts the rows routed
+        to each expert of the whole layer (padded and idle rows too).
+        ``expert_rows_all`` is every chosen (row, expert) pair,
+        ``expert_rows_held`` those that fell on the experts held here
+        (a model that says ``experts_held``; all of them otherwise),
+        ``experts_held_touched`` the (layer, held expert) pairs at least
+        one row fell on: the experts whose weights the step needed."""
+        first, held = self.model_config.get("experts_held",
+                                            (0, load.shape[1]))
+        mine = load[:, first:first + held]
+        step.set(expert_load_max=int(load.max()),
+                 expert_load_mean=float(load.mean()),
+                 expert_rows_held=int(mine.sum()),
+                 expert_rows_all=int(load.sum()),
+                 experts_held_touched=int((mine > 0).sum()))
 
     def evict(self, slot, reason):
         """Free ``slot`` (mid-prefill pendings included): drop its
@@ -1467,7 +1754,11 @@ class PagedGenerationEngine:
         where the model reads its cache rows as they lie, ``"heads"``
         where it splits them by head.  A model that takes rows attends
         through ``ops.attention_rows``, whose rule goes by the query
-        rows a slot; a head-split view is attended head by head."""
+        rows a slot; a head-split view is attended head by head; a
+        model that declares its layers' caches reads its rows as they
+        lie in every shape."""
+        if self._declared:      # handed rows as they lie, nothing else
+            return "rows"
         if not self._cache_rows:
             return "heads"
         from .ops.attention_rows import attends_in
@@ -1489,6 +1780,9 @@ class PagedGenerationEngine:
             args += ((self._block_state, np.zeros(nb, bool),
                       np.zeros(nb, np.int32), np.zeros(nb, np.int32),
                       np.zeros(nb, np.int32)),)
+        if self._declared:
+            args += (None, (np.zeros(nb, np.int32), np.zeros(nb, bool),
+                            np.zeros(nb, np.int32)), self._state)
         return args
 
 

@@ -2,3 +2,4 @@
 ``mxnet_tpu.generate.PagedGenerationEngine`` through the chunk
 protocol)."""
 from .moe_decoder import MoEDecoderLM  # noqa: F401
+from .hybrid_decoder import HybridDecoderLM  # noqa: F401

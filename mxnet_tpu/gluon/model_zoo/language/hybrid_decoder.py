@@ -1,0 +1,411 @@
+"""A hybrid decoder-only language model: most layers mix tokens by a
+linear-attention recurrence (Kimi Delta Attention, arXiv:2510.26692),
+every few by softmax attention over a cached latent (multi-head latent
+attention, DeepSeek-V2, arXiv:2405.04434, section 2.1), and the
+feed-forward of a layer is a dense gated-SiLU MLP or a sparse expert
+layer with a sigmoid, group-limited router and a shared expert
+(DeepSeek-V3, arXiv:2412.19437, section 2.1.2): the ``bailing_hybrid``
+block.
+
+    h = x + Mix_l(RMSNorm(x));  y = h + FFN_l(RMSNorm(h))
+
+``mixers[l]`` is ``"kda"`` or ``"mla"`` and ``ffns[l]`` ``"dense"`` or
+``"moe"``; no bias anywhere, an untied output head.
+
+**KDA** (``n_heads`` heads of ``d_k`` key and ``d_v`` value channels):
+``q, k, v = SiLU(conv(W_qkv x))``, a causal depthwise convolution of
+``conv_kernel`` taps a channel; ``q = l2norm(q) / sqrt(d_k)``, ``k =
+l2norm(k)`` a head; a log-decay a key channel ``g = lower_bound *
+sigmoid(exp(A_log_h) * (W_f x + dt_bias))``; ``beta = sigmoid(W_b x)``
+a head; the state ``S`` (d_k, d_v) a head, float32, moved by the gated
+delta rule (``ops.gated_delta``: its one-token form for a decode step,
+its chunkwise form for a chunk); ``out = W_o (RMSNorm_head(o) *
+sigmoid(W_g x))`` with one gate a head.  No positions.  What a KDA
+layer carries from one dispatch to the next is per sequence, not per
+token: ``S`` and the last ``conv_kernel - 1`` inputs of the convolution.
+
+**MLA** (no query latent): ``q = W_q x`` as heads of ``(d_nope |
+d_rope)``; ``[c | k_r] = W_dkv x`` (``d_latent | d_rope``), ``c~ =
+RMSNorm(c)``; ``[k_nope | v]_h = W_ukv c~``; rotary positions
+(interleaved pairs) on ``q_r`` and on the one ``k_r`` all heads share;
+``softmax((q_nope . k_nope + q_r . k_r) / sqrt(d_nope + d_rope)) v``,
+causal; the same head-wise sigmoid gate; ``W_o``.  **Cached a token:
+the row ``[c~ | rope(k_r)]``, ``d_latent + d_rope`` values, and nothing
+else.**  The up-projection is absorbed: ``q_nope W_uk`` (a query in
+the latent space) beside ``rope(q_r)`` attends the cached rows as they
+lie and the result goes through ``W_uv``, so no key or value of a
+cached position is ever rebuilt, in a decode step or in a chunk (on
+the chip a chunk of 512 against 9216 rows takes 46.0 ms absorbed and
+48.0 with the rows expanded to per-head keys and values: PERF.md, PR
+33).
+
+**The expert layer** is ``parallel.moe.routed_experts`` with
+``sigmoid_group_select``; told which experts it holds
+(``experts_held``) it routes over all of them and computes its own
+experts' part.  The shared expert is the block's own: every token
+takes it, whoever holds which routed expert.
+
+The model speaks the chunk protocol of ``mxnet_tpu.generate`` and
+declares, a layer, what it caches (``config["layer_caches"]``): a KDA
+layer per-slot state (``S`` and the convolution's tail), an MLA layer
+paged rows of ``d_latent + d_rope`` values.  Parameters are registered
+in one flat list, layer by layer; every matrix is stored ``(out, in)``,
+the routed experts side by side as ``MoEDecoderLM`` stores them.
+``dtype`` is the type the embedding and the matrices are stored in;
+norm weights, ``A_log``, ``dt_bias``, the router's selection bias and
+the output head are always float32.
+"""
+from __future__ import annotations
+
+from ...block import HybridBlock
+from .moe_decoder import _rms
+
+__all__ = ["HybridDecoderLM"]
+
+
+def _rope_pairs(x, pos, theta):
+    """Rotary positions over interleaved pairs ``(x[2i], x[2i+1])``.
+    x (B, C, ..., d) float32, pos (B, C) int32."""
+    import jax.numpy as jnp
+
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, :, None] * inv           # (B, C, d/2)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x0, x1 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def _mm(a, w):
+    """a (..., in) x w (out, in), in the weight's dtype."""
+    import jax.numpy as jnp
+
+    return jnp.dot(a.astype(w.dtype), w.T)
+
+
+def _gated_mlp(x, wg, wu, wd):
+    import jax
+
+    return _mm(jax.nn.silu(_mm(x, wg)) * _mm(x, wu), wd)
+
+
+class HybridDecoderLM(HybridBlock):
+    """Token ids (batch, seq) -> logits (batch, seq, vocab).
+
+    ``mixers`` / ``ffns`` give every layer's kind.  ``experts_held`` =
+    ``(first, count)`` makes this instance hold only those routed
+    experts of every expert layer (the chip's share of an
+    expert-parallel deployment); the shared expert is always held.
+    """
+
+    def __init__(self, vocab_size, d_model, mixers, ffns, n_heads,
+                 d_k, d_v, conv_kernel, kda_lower_bound,
+                 d_nope, d_rope, d_latent, d_ff, n_experts, top_k,
+                 d_expert, n_group, topk_group, routed_scaling,
+                 norm_topk=True, max_len=262144, rope_theta=6e6,
+                 rms_eps=1e-6, experts_held=None, dtype="float32",
+                 **kwargs):
+        super().__init__(**kwargs)
+        mixers, ffns = list(mixers), list(ffns)
+        if len(mixers) != len(ffns) or not mixers:
+            raise ValueError("mixers and ffns give one kind a layer each")
+        for kinds, known in ((mixers, ("kda", "mla")),
+                             (ffns, ("dense", "moe"))):
+            if set(kinds) - set(known):
+                raise ValueError("layer kinds %r are not of %r"
+                                 % (kinds, known))
+        first, held = experts_held if experts_held is not None \
+            else (0, n_experts)
+        if not (0 <= first and held >= 1 and first + held <= n_experts):
+            raise ValueError("experts_held %r outside [0, %d)"
+                             % (experts_held, n_experts))
+        if n_experts % n_group:
+            raise ValueError("n_experts (%d) must divide by n_group (%d)"
+                             % (n_experts, n_group))
+        self._mixers, self._ffns = mixers, ffns
+        self._first = int(first)
+        self._theta, self._eps = float(rope_theta), float(rms_eps)
+        self._lower = float(kda_lower_bound)
+        self._route = dict(n_group=int(n_group), topk_group=int(topk_group),
+                           scaling=float(routed_scaling),
+                           norm_topk=bool(norm_topk))
+        D, H, F, K = d_model, n_heads, d_expert, int(conv_kernel)
+        wide = H * (2 * d_k + d_v)       # q, k, v channels of a KDA layer
+        self._sizes = dict(H=H, dk=d_k, dv=d_v, K=K, wide=wide,
+                           dn=d_nope, dr=d_rope, dl=d_latent)
+        # what a layer keeps between dispatches: the engine allocates
+        # it (generate.PagedGenerationEngine, "layer_caches")
+        caches = [{"state": [((H, d_k, d_v), "float32"),
+                             ((K - 1, wide), None)]} if m == "kda"
+                  else {"rows": d_latent + d_rope} for m in mixers]
+        self._cfg = dict(
+            vocab_size=vocab_size, d_model=D, n_heads=H,
+            n_layers=len(mixers), max_len=max_len, n_experts=n_experts,
+            top_k=top_k, d_expert=F, experts_held=(int(first), int(held)),
+            layer_caches=caches)
+
+        def get(name, shape, stored=dtype):
+            return self.params.get(name, shape=shape, dtype=stored)
+
+        with self.name_scope():
+            self._embed = get("embed_weight", (vocab_size, D))
+            self._layers = []
+            for i, (mix, ffn) in enumerate(zip(mixers, ffns)):
+                h = "h%d_" % i
+                norm_mix = get(h + "attn_norm_gamma", (D,), "float32")
+                if mix == "kda":
+                    mixer = [
+                        get(h + "proj_qkv_weight", (wide, D)),
+                        get(h + "conv_weight", (K, wide)),
+                        get(h + "decay_weight", (H * d_k, D)),
+                        get(h + "decay_a_log", (H,), "float32"),
+                        get(h + "decay_dt_bias", (H * d_k,), "float32"),
+                        get(h + "beta_weight", (H, D)),
+                        get(h + "gate_weight", (H, D)),
+                        get(h + "o_norm_gamma", (d_v,), "float32"),
+                        get(h + "attn_out_weight", (D, H * d_v))]
+                else:
+                    mixer = [
+                        get(h + "proj_q_weight", (H * (d_nope + d_rope), D)),
+                        get(h + "kv_down_weight", (d_latent + d_rope, D)),
+                        get(h + "kv_norm_gamma", (d_latent,), "float32"),
+                        get(h + "kv_up_weight",
+                            (H * (d_nope + d_v), d_latent)),
+                        get(h + "gate_weight", (H, D)),
+                        get(h + "attn_out_weight", (D, H * d_v))]
+                norm_ffn = get(h + "ffn_norm_gamma", (D,), "float32")
+                if ffn == "dense":
+                    feed = [get(h + "ffn_gate_weight", (d_ff, D)),
+                            get(h + "ffn_up_weight", (d_ff, D)),
+                            get(h + "ffn_down_weight", (D, d_ff))]
+                else:
+                    feed = [
+                        get(h + "router_weight", (n_experts, D)),
+                        get(h + "router_bias", (n_experts,), "float32"),
+                        get(h + "experts_gate_weight", (D, held * F)),
+                        get(h + "experts_up_weight", (D, held * F)),
+                        get(h + "experts_down_weight", (held * F, D)),
+                        get(h + "shared_gate_weight", (F, D)),
+                        get(h + "shared_up_weight", (F, D)),
+                        get(h + "shared_down_weight", (D, F))]
+                self._layers.append((norm_mix, mixer, norm_ffn, feed))
+            self._final = get("final_norm_gamma", (D,), "float32")
+            self._head = get("head_weight", (vocab_size, D), "float32")
+
+    @property
+    def config(self):
+        return dict(self._cfg)
+
+    # -- the mixers --------------------------------------------------------
+
+    def _kda(self, n, p, cache, valid):
+        """The KDA mixer on normed states ``n`` (B, C, D).  ``cache``
+        is ``(S (B, H, dk, dv) float32, tail (B, K-1, wide))``;
+        ``valid`` (B,) the leading positions of each row that count.
+        Returns (out (B, C, D), (S, tail) after the valid positions)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ....ops.gated_delta import gated_delta_chunk, gated_delta_step
+
+        wqkv, wc, wf, a_log, dt_bias, wb, wgate, g_o, wo = p
+        z = self._sizes
+        H, dk, dv, K = z["H"], z["dk"], z["dv"], z["K"]
+        B, C, _D = n.shape
+        state, tail = cache
+        f32 = jnp.float32
+        x = _mm(n, wqkv)                                    # (B, C, wide)
+        seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+        # the tail after this dispatch: the last K-1 inputs that count
+        # (with valid = 0 the tail it came with)
+        keep = valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        tail = jnp.take_along_axis(seen, keep[:, :, None], axis=1)
+        y = sum(seen[:, j:j + C].astype(f32) * wc[j].astype(f32)
+                for j in range(K))
+        y = jax.nn.silu(y)
+        q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
+        q = q.reshape((B, C, H, dk))
+        k = k.reshape((B, C, H, dk))
+        v = v.reshape((B, C, H, dv))
+
+        def l2(a):
+            return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True)
+                                     + 1e-6)
+
+        q, k = l2(q) * dk ** -0.5, l2(k)
+        gate_in = _mm(n, wf).astype(f32).reshape((B, C, H, dk)) \
+            + dt_bias.reshape((H, dk))
+        g = self._lower * jax.nn.sigmoid(
+            jnp.exp(a_log)[:, None] * gate_in)              # in [lower, 0]
+        beta = jax.nn.sigmoid(_mm(n, wb).astype(f32))       # (B, C, H)
+        if C == 1:
+            live = valid > 0
+            o, state = gated_delta_step(
+                q[:, 0], k[:, 0], v[:, 0],
+                jnp.where(live[:, None, None], g[:, 0], 0.0),
+                jnp.where(live[:, None], beta[:, 0], 0.0), state)
+            o = o[:, None]
+        else:
+            o, state = gated_delta_chunk(q, k, v, g, beta, state, valid)
+        o = _rms(o, g_o, self._eps) \
+            * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
+        return _mm(o.reshape((B, C, H * dv)), wo), (state, tail)
+
+    def _mla(self, n, p, rows, start, pos):
+        """The MLA mixer on normed states ``n`` (B, C, D) at positions
+        ``pos`` (B, C).  ``rows`` (B, S, >= dl + dr) are the cached
+        positions' ``[c~ | rope(k_r)]`` (those under ``start`` count),
+        with whatever zero lanes the pool keeps after them, or None.
+        Returns (out (B, C, D), the chunk's rows (B, C, dl+dr))."""
+        import jax
+        import jax.numpy as jnp
+
+        from ....ops.attention_rows import _softmax_pair
+
+        wq, wdkv, g_kv, wukv, wgate, wo = p
+        z = self._sizes
+        H, dn, dr, dl, dv = z["H"], z["dn"], z["dr"], z["dl"], z["dv"]
+        B, C, _D = n.shape
+        f32, act = jnp.float32, wq.dtype
+        q = _mm(n, wq).reshape((B, C, H, dn + dr))
+        q_nope = q[..., :dn]
+        q_r = _rope_pairs(q[..., dn:].astype(f32), pos, self._theta) \
+            .astype(act)
+        down = _mm(n, wdkv)                                 # (B, C, dl+dr)
+        c = _rms(down[..., :dl], g_kv, self._eps).astype(act)
+        k_r = _rope_pairs(down[..., dl:].astype(f32), pos, self._theta) \
+            .astype(act)
+        new = jnp.concatenate([c, k_r], axis=-1)            # (B, C, dl+dr)
+        up = wukv.reshape((H, dn + dv, dl))
+        w_uk, w_uv = up[:, :dn], up[:, dn:]                 # (H, dn|dv, dl)
+        scale = (dn + dr) ** -0.5
+        causal = jnp.tril(jnp.ones((C, C), bool))[None, None]
+        cached = rows is not None
+        if cached:
+            S = rows.shape[1]
+            rows = rows.astype(act)
+            cache_ok = (jnp.arange(S, dtype=jnp.int32)[None, :]
+                        < start[:, None])[:, None, None, :]
+
+        def dot(spec, a, b):
+            return jnp.einsum(spec, a, b, preferred_element_type=f32)
+
+        # absorbed: the queries go to the latent space and attend the
+        # rows as they lie; the row is contracted whole and read whole
+        # (the lanes after the latent are dropped from the result), so
+        # no slice of the cache is ever copied
+        q_lat = dot("bchd,hdl->bchl", q_nope, w_uk).astype(act)
+        q_cat = jnp.concatenate([q_lat, q_r], axis=-1)      # (B,C,H,dl+dr)
+        s_new = dot("bchw,bsw->bhcs", q_cat, new) * scale
+        if cached:
+            # (zeros under the pool's zero lanes)
+            q_old = jnp.pad(q_cat, [(0, 0)] * 3 + [
+                (0, rows.shape[-1] - dl - dr)])
+            s_old = dot("bchw,bsw->bhcs", q_old, rows) * scale
+            p_old, p_new = _softmax_pair(s_old, s_new, cache_ok, causal, act)
+            ctx = dot("bhcs,bsw->bchw", p_old, rows)[..., :dl] \
+                + dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
+        else:
+            p_new = jax.nn.softmax(
+                jnp.where(causal, s_new, -1e30), -1).astype(act)
+            ctx = dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
+        o = dot("bchl,hdl->bchd", ctx.astype(act), w_uv)
+        o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
+        return _mm(o.reshape((B, C, H * dv)), wo), new
+
+    def _moe(self, m, p):
+        """The expert layer on normed states ``m`` (N, D): (the held
+        routed experts' part + the shared expert, counts (E,))."""
+        from ....parallel.moe import routed_experts, sigmoid_group_select
+
+        wr, bias, wg, wu, wd, sg, su, sd = p
+        c = self._cfg
+        y, counts = routed_experts(
+            m, wr.T, wg, wu, wd, c["top_k"], c["d_expert"],
+            first=self._first,
+            select=sigmoid_group_select(bias, **self._route))
+        return y + _gated_mlp(m, sg, su, sd).astype(y.dtype), counts
+
+    # -- the one forward ---------------------------------------------------
+
+    def _run(self, tokens, caches, start, valid):
+        """tokens (B, C) int; caches a list with, a layer, its state
+        ``(S, tail)`` (KDA) or its cached rows (B, S, dl+dr) (MLA), or
+        None (a whole sequence from nothing); start, valid (B,) int32.
+        Returns (logits raw (B, C, V), a layer's new state or the
+        chunk's new rows, expert load (expert layers, E) int32)."""
+        import jax.numpy as jnp
+
+        z = self._sizes
+        B, C = tokens.shape
+        pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
+        x = jnp.take(self._embed.data()._data, tokens, axis=0)
+        act = x.dtype
+        new, loads = [], []
+        def raw(params):
+            return [q.data()._data for q in params]
+
+        for li, (mix, ffn) in enumerate(zip(self._mixers, self._ffns)):
+            norm_mix, mixer, norm_ffn, feed = self._layers[li]
+            n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
+            if mix == "kda":
+                cache = caches[li] if caches is not None else (
+                    jnp.zeros((B, z["H"], z["dk"], z["dv"]), jnp.float32),
+                    jnp.zeros((B, z["K"] - 1, z["wide"]), act))
+                out, kept = self._kda(n, raw(mixer), cache, valid)
+            else:
+                out, kept = self._mla(
+                    n, raw(mixer),
+                    caches[li] if caches is not None else None, start, pos)
+            new.append(kept)
+            x = x + out.astype(act)
+            m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
+            if ffn == "dense":
+                y = _gated_mlp(m, *raw(feed))
+            else:
+                y, counts = self._moe(m.reshape((B * C, -1)), raw(feed))
+                y = y.reshape((B, C, -1))
+                loads.append(counts)
+            x = x + y.astype(act)
+        head = self._head.data()._data
+        h = _rms(x, self._final.data()._data, self._eps).astype(head.dtype)
+        load = jnp.stack(loads) if loads else None
+        return jnp.dot(h, head.T), new, load
+
+    def hybrid_forward(self, F, tokens, **_registered):
+        import jax.numpy as jnp
+
+        from ....ndarray import NDArray
+
+        ids = tokens._data.astype(jnp.int32)
+        B, T = ids.shape
+        logits, _new, _load = self._run(
+            ids, None, jnp.zeros((B,), jnp.int32),
+            jnp.full((B,), T, jnp.int32))
+        return NDArray(logits)
+
+    def chunk_forward(self, tokens, caches, start, valid):
+        """C positions a sequence against what each layer cached (the
+        chunk protocol of ``generate.PagedGenerationEngine`` for a model
+        that declares ``layer_caches``): ``tokens`` raw (B, C) int32 at
+        positions ``start_b ..``, of which the first ``valid_b`` count
+        (the rest is padding; 0 for a row no one is in).  ``caches[l]``
+        is, for a layer with state, the tuple of its arrays ``(B, ...)``
+        as the sequence left them, and for a layer with paged rows the
+        rows of positions ``< start_b``, (B, S, lanes) with ``lanes``
+        the declared width padded with zeros to whole tiles of 128.
+        Returns
+        ``(logits NDArray (B, C, V), a list with, a layer, the state
+        after the valid positions or the chunk's rows (B, C, width),
+        {"expert_load": (expert layers, experts) int32})``."""
+        import jax.numpy as jnp
+
+        from ....ndarray import NDArray
+
+        logits, new, load = self._run(
+            tokens.astype(jnp.int32), caches, start.astype(jnp.int32),
+            valid.astype(jnp.int32))
+        extras = {} if load is None else {"expert_load": load}
+        return NDArray(logits), new, extras

@@ -25,7 +25,8 @@ from __future__ import annotations
 
 import functools
 
-__all__ = ["moe_ffn", "moe_ffn_sharded", "routed_experts"]
+__all__ = ["moe_ffn", "moe_ffn_sharded", "routed_experts",
+           "sigmoid_group_select"]
 
 
 def _check_top_k(top_k, n_experts):
@@ -161,8 +162,46 @@ def moe_ffn_sharded(mesh, x, gate_w, w_in, w_out, axis_name="ep",
     return fn(x, gate_w, w_in, w_out)
 
 
+def sigmoid_group_select(bias, n_group, topk_group, scaling=1.0,
+                         norm_topk=True):
+    """The selection rule of a sigmoid, group-limited router with a
+    selection bias (DeepSeek-V3, arXiv:2412.19437, section 2.1.2,
+    ``noaux_tc``), for :func:`routed_experts`' ``select``.
+
+    A token's score for an expert is ``s = sigmoid(logit)``.  Who is
+    chosen goes by ``s + bias`` (``bias`` (n_experts,) float32, kept
+    out of the weights): the experts lie in ``n_group`` groups side by
+    side, a group's score is the sum of its two highest ``s + bias``,
+    the ``topk_group`` best groups are kept, and the ``top_k`` highest
+    ``s + bias`` inside them are the token's experts.  What each adds
+    is weighted by its ``s`` alone, over the sum of the chosen ones'
+    ``s`` with ``norm_topk``, times ``scaling``."""
+    def select(logits, top_k):
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        T, E = logits.shape
+        score = jax.nn.sigmoid(logits)
+        biased = score + bias.astype(score.dtype)
+        by_group = biased.reshape((T, n_group, E // n_group))
+        group = lax.top_k(by_group, 2)[0].sum(-1)          # (T, groups)
+        kept = lax.top_k(group, topk_group)[1]             # (T, kept)
+        open_ = (kept[:, :, None] == jnp.arange(
+            n_group, dtype=kept.dtype)).any(1)             # (T, groups)
+        biased = jnp.where(jnp.repeat(open_, E // n_group, axis=1),
+                           biased, -jnp.inf)
+        top_i = lax.top_k(biased, top_k)[1]
+        top_w = jnp.take_along_axis(score, top_i, axis=1)
+        if norm_topk:
+            top_w = top_w / top_w.sum(-1, keepdims=True)
+        return top_w * scaling, top_i
+
+    return select
+
+
 def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
-                   first=0, norm_topk=True):
+                   first=0, norm_topk=True, select=None):
     """The part that the experts held here add to a gated top-k expert
     layer, with no capacity and no token dropped: what the serving path
     calls (``gluon.model_zoo.language.MoEDecoderLM``).
@@ -186,8 +225,12 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
     Returns ``(out, counts)``:
       out: (tokens, d_model), ``sum_e w_e * down_e(silu(gate_e x) *
         up_e x)`` over the held experts among each token's ``top_k``
-        (``w`` the router's softmax, over float32, renormalised over
-        the chosen ones with ``norm_topk``)
+        (who is chosen, and with what ``w``, is ``select``'s to say:
+        ``select(logits (tokens, n_experts) float32, top_k)`` ->
+        ``(w, index)``, each (tokens, top_k), as
+        :func:`sigmoid_group_select` makes one; without it the ``top_k``
+        highest of the router's softmax, over float32, renormalised
+        over the chosen ones with ``norm_topk``)
       counts: (n_experts,) int32, the tokens routed to each expert of
         the whole layer in this call.
     """
@@ -201,10 +244,13 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
     held = w_down.shape[0] // F
     f32 = jnp.float32
     logits = jnp.dot(x, router_w, preferred_element_type=f32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = lax.top_k(probs, top_k)                    # (T, k)
-    if norm_topk:
-        top_p = top_p / top_p.sum(-1, keepdims=True)
+    if select is not None:
+        top_p, top_i = select(logits, top_k)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = lax.top_k(probs, top_k)                # (T, k)
+        if norm_topk:
+            top_p = top_p / top_p.sum(-1, keepdims=True)
     chosen = top_i[:, :, None] == jnp.arange(n_exp, dtype=top_i.dtype)
     counts = chosen.sum((0, 1)).astype(jnp.int32)             # (E,)
     combine = jnp.where(chosen, top_p[:, :, None], 0.0).sum(1)  # (T, E)

@@ -911,6 +911,11 @@ DECODE_SPEC_ACCEPTED = counter(
     "Drafted tokens accepted by exact-match verification (acceptance "
     "rate = this over drafted; each accepted token is one decode "
     "dispatch saved).")
+DECODE_STATE_RESETS = counter(
+    "mxnet_tpu_decode_state_resets_total",
+    "Sequences whose per-slot recurrent state was started from zero (in "
+    "the program of their first prefill chunk): one an admission to an "
+    "engine whose model keeps such state.")
 DECODE_DENOISE_PASSES = counter(
     "mxnet_tpu_decode_denoise_passes_total",
     "Block-diffusion decoding: slot-passes that ran a slot's open block "
@@ -1303,6 +1308,8 @@ def statusz():
             "commit_passes": DECODE_COMMIT_PASSES.value(),
             "blocks_committed": DECODE_BLOCKS_COMMITTED.value(),
             "block_tokens": DECODE_BLOCK_TOKENS.value(),
+            # sequences started on an engine with per-slot state
+            "state_resets": DECODE_STATE_RESETS.value(),
         },
         "checkpoint": {
             "async_queue_depth": CHECKPOINT_QUEUE_DEPTH.value(),

@@ -37,8 +37,8 @@ NEW_METRICS = {
 def test_manifest_gains_one_configuration_and_one_cell():
     man = manifest.manifest()
     assert manifest.check(man)
-    assert [c["name"] for c in man["configs"]][-1] == CONFIG
-    assert [w["name"] for w in man["workloads"]][-1] == CELL
+    assert [c["name"] for c in man["configs"]][2] == CONFIG
+    assert [w["name"] for w in man["workloads"]][2] == CELL
     cell = manifest.workload(man, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (CONFIG, "serve_blockgen", 1)
@@ -50,8 +50,9 @@ def test_manifest_gains_one_configuration_and_one_cell():
     assert {"serve_output_tok_s", "setup_s"} <= e2e
     layer = {m["name"]: m for m in manifest.metrics_of(man, "per_layer", CELL)}
     for name, (unit, where) in NEW_METRICS.items():
+        # (a later expert model's cell may follow on the routing metric)
         assert (layer[name]["unit"], layer[name]["layer"],
-                layer[name]["moves"], layer[name]["workloads"]) == \
+                layer[name]["moves"], layer[name]["workloads"][:1]) == \
             (unit, where, "serve_output_tok_s", [CELL])
     # its lists of bytes count full-width K and V: not this model's
     assert "decode_hbm_roofline" not in layer

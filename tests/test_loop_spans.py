@@ -509,8 +509,8 @@ def test_manifest_checks_and_cells_report_the_new_metrics():
     man = manifest.manifest()
     assert manifest.check(man)
     names = [m["name"] for m in man["per_layer"]]
-    # PR 26's ten follow the 17 of PR 24; PR 28 appended four more
-    assert names[17:27] == list(NEW_METRICS) and len(names) == 31
+    # PR 26's ten follow the 17 of PR 24; PRs 28 and 33 appended four each
+    assert names[17:27] == list(NEW_METRICS) and len(names) == 35
     train = {m["name"] for m in
              manifest.metrics_of(man, "per_layer", "resnet50_train_b256")}
     serve = {m["name"] for m in

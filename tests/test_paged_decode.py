@@ -78,6 +78,14 @@ def _prompt(n=5, seed=0):
         .astype(np.int32)
 
 
+def test_cached_is_for_models_that_declare_their_caches(paged):
+    """``cached`` reads per-layer caches off a model that declares them
+    (``tests/test_hybrid_decoder.py``); a model of K and V layers alone
+    is told so."""
+    with pytest.raises(mx.MXNetError, match="layer_caches"):
+        paged.cached([0])
+
+
 def _drain(eng, slot, steps):
     """``steps`` decode ticks for one slot, its per-step token lists
     flattened."""
@@ -514,6 +522,84 @@ def wide_moe_engine():
         denoise_steps=2, sampling=generate.SamplingConfig(greedy=True))
 
 
+@pytest.fixture(scope="module")
+def wide_hybrid_engine():
+    """The hybrid serving cell's engine (ISSUE 33) at Ling-3.0-flash's
+    widths and the cell's sizes: d_model 2560, 32 heads of 128, a latent
+    of 512 + 64, 32 slots of 9216 positions, page 16, 18433 pages,
+    prefill chunks of 512, bf16 weights and cache; cut to one KDA layer
+    (per-slot state) with a dense feed-forward and one MLA layer (latent
+    pages) with an expert layer of 8 experts of which 2 are held, and a
+    small vocabulary."""
+    from mxnet_tpu.gluon.model_zoo.language import HybridDecoderLM
+
+    net = HybridDecoderLM(
+        vocab_size=256, d_model=2560, mixers=["kda", "mla"],
+        ffns=["dense", "moe"], n_heads=32, d_k=128, d_v=128, conv_kernel=4,
+        kda_lower_bound=-5, d_nope=128, d_rope=64, d_latent=512, d_ff=256,
+        n_experts=8, top_k=2, d_expert=768, n_group=4, topk_group=2,
+        routed_scaling=2.5, max_len=9216, experts_held=(0, 2),
+        dtype="bfloat16")
+    net.initialize(mx.init.Zero())
+    return generate.PagedGenerationEngine(
+        net, slots=32, cache_len=9216, page_size=16, num_pages=18433,
+        prefill_chunk=512, spec_k=0, prefix_share=False,
+        dtype_policy="bf16_mixed",
+        sampling=generate.SamplingConfig(greedy=True))
+
+
+# the optimized HLO of the two accepted serving cells' programs at the
+# fixtures' sizes, as commit 686993b (the parent of ISSUE 33, which
+# changed the cache protocol) compiled them under this JAX: sha256 of
+# the text with the tables of source lines and every `metadata={...}`
+# taken out.  A change that means to alter neither program leaves them;
+# one that means to re-bases them from a tree whose cells were measured.
+HLO_JAX = "0.9.0"
+HLO_SHA256 = {
+    ("opt", "decode"):
+        "b17b1c0056fb5bef84bb147d42be94eb4bb1d74b145483c06df8c18307abcaa7",
+    ("opt", "prefill"):
+        "78c914d33623a7f9f7a783b7521c7a1c5273ab4a0688605444e5d80505690a46",
+    ("moe", "decode"):
+        "0001978615a5e52c0b6491c3e915bd40cc58b5d627a0c43449ba37cc6abc0d65",
+    ("moe", "prefill"):
+        "e1d304f1f32f08e43a0014230add5d99954208fb4a2b2219c196b15a22ffbd67",
+}
+
+
+def _program_sha256(text):
+    import hashlib
+    import re
+
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    lines = text.split("\n")
+    first = next(i for i, line in enumerate(lines)
+                 if line.rstrip().endswith("{") and "(" in line)
+    return hashlib.sha256("\n".join(lines[first:]).encode()).hexdigest()
+
+
+def _compile_for(chip, eng, shape):
+    """The dispatch of ``shape`` compiled for the described chip (nothing
+    runs; a compile for a described chip is written to the persistent
+    cache but cannot be read back without one, so the cache is off)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def struct(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip)
+
+    args = jax.tree_util.tree_map(struct, eng._dispatch_args(shape))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with _time_limit(300):
+            return eng._jit_chunk.lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 # the programs' temporaries at those sizes (compiled.memory_analysis()):
 # the gathered views of 32 x 1024 rows and their relayouts (decode), one
 # slot's view and a chunk's expert activations (prefill)
@@ -548,7 +634,7 @@ class _time_limit:
 
 
 @pytest.mark.parametrize("shape", ["decode", "prefill"])
-@pytest.mark.parametrize("model", ["opt", "moe"])
+@pytest.mark.parametrize("model", ["opt", "moe", "hybrid"])
 def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     """What the chip's compiler makes of the dispatch (optimized HLO for
     a described v5e, nothing runs): the pool keeps a row-major layout
@@ -563,29 +649,19 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     import re
 
     import jax
-    from jax.experimental.compilation_cache import compilation_cache
 
+    if model == "hybrid":      # latent pages and per-slot state (ISSUE 33)
+        return _hybrid_program_copies_nothing(
+            v5e_chip, request.getfixturevalue("wide_hybrid_engine"), shape)
     eng = request.getfixturevalue(
         "wide_engine" if model == "opt" else "wide_moe_engine")
     shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
-
-    def struct(a):
-        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=v5e_chip)
-
-    args = jax.tree_util.tree_map(struct,
-                                  eng._dispatch_args(shapes[shape]))
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without one
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        with _time_limit(300):
-            compiled = eng._jit_chunk.lower(*args).compile()
-        text = compiled.as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    compiled = _compile_for(v5e_chip, eng, shapes[shape])
+    text = compiled.as_text()
+    # the cache protocol of ISSUE 33 (a model may declare its layers'
+    # caches) left these two models' programs as they were
+    if jax.__version__ == HLO_JAX:
+        assert _program_sha256(text) == HLO_SHA256[model, shape]
     pool = "bf16[%s]" % ",".join(str(d) for d in eng.pool_shape)
     assert " while(" not in text, "a gather or scatter became a loop"
     entry = text[text.index("\nENTRY"):]
@@ -648,6 +724,59 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
         # no expert matrix is copied to be multiplied
         assert not re.findall(r"= bf16\[2048,6144\]\S* copy\(", entry)
         assert not re.findall(r"= bf16\[6144,2048\]\S* copy\(", entry)
+
+
+def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):
+    """The hybrid model's two programs for a described v5e (ISSUE 33):
+    the one pool of latent rows (576 values in 640 lanes) keeps its rows
+    outermost and is a parameter and the in-place scatter, nothing else;
+    each layer's recurrent state ``S`` (32 slots x 32 x 128 x 128
+    float32) is a parameter and the fusion that makes the new one, never
+    copied in HBM (the decode program brings it to fast memory, `S(1)`,
+    which is no copy of it); pool and state are all aliased to the
+    results.  The decode program attends in the latent space: nothing
+    has the size of the slots' cached rows expanded to 32 heads, and no
+    gather or scatter became a loop.  (The convolution's tails, 2.4 MB,
+    are re-tiled; they are not asserted on.)"""
+    import re
+
+    eng = wide_hybrid_engine
+    assert eng.pool_shape == (18433 * 16, 640)
+    assert [tuple(a.shape) for a in eng._state] == [
+        (32, 32, 128, 128), (32, 3, 3 * 32 * 128)]
+    shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
+    compiled = _compile_for(v5e_chip, eng, shapes[shape])
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    pool_ops = re.findall(r"= bf16\[294928,640\](\{[^ ]*\})? ([\w\-]+)\(",
+                          entry)
+    assert sorted(op for _l, op in pool_ops) == ["fusion", "parameter"]
+    assert all(layout.startswith("{1,0") for layout, _op in pool_ops)
+    assert re.findall(r"= bf16\[294928,640\]\S* fusion\(.*"
+                      r"op_name=\"jit\(chunk_fn\)/scatter", entry)
+    state_ops = [op for op in re.findall(
+        r"= f32\[32,32,128,128\]\S* ([\w\-]+)\(", entry)
+        if op not in ("get-tuple-element", "bitcast")]
+    assert "parameter" in state_ops and "copy" not in state_ops
+    # an asynchronous copy of it moves it between HBM and fast memory:
+    # of its two ends exactly one is in `S(1)`
+    for ends in re.findall(r"= \((f32\[32,32,128,128\]\S*), "
+                           r"(f32\[32,32,128,128\]\S*), \S+ copy-start\(",
+                           entry):
+        assert sum("S(1)" in end for end in ends) == 1, ends
+    # the pool and the two state arrays, donated in place
+    assert text.count("may-alias") + text.count("must-alias") >= 3
+    if shape == "decode":
+        assert " while(" not in text, "a gather or scatter became a loop"
+        # 32 slots x 9216 rows x 32 heads x (128 | 128): what expanding
+        # the cache would build
+        expanded = 32 * 9216 * 32 * 128
+        for dims in re.findall(r"= \w+\[([\d,]+)\]", entry):
+            assert np.prod([int(d) for d in dims.split(",")]) < expanded
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    else:
+        # the chunkwise delta rule's scan over 8 sub-chunks, and no other
+        assert text.count(" while(") == 1
 
 
 # ---------------------------------------------------------------------------
