@@ -365,8 +365,17 @@ class PagedGenerationEngine:
       fixed-size chunks (:meth:`prefill_step`, one chunk per call) so a
       long admission interleaves with decode steps instead of stalling
       them;
-    * **decode** ``(slots, 1)`` — every active slot advances one token;
-      with a model whose ``config`` gives a ``block_length`` B > 1,
+    * **decode** ``(slots, 1)`` — every active slot advances one token.
+      The token a step samples is the next step's input ON THE DEVICE
+      (a prompt's first token, of its last chunk, too), and positions,
+      write pages and budgets advance by counts, so :meth:`decode_step`
+      launches a step before it reads the one before: ``steps_ahead``
+      steps stay queued and the host's delivery, admission and launches
+      run beside the device's work, not between two of its programs.
+      What only the data says (an EOS id, a cancellation) is found when
+      the token is read; the steps launched for that slot meanwhile are
+      thrown away (``mxnet_tpu_decode_steps_wasted_total``).
+      With a model whose ``config`` gives a ``block_length`` B > 1,
       ``(slots, B)`` — **block-diffusion decoding**: every active slot
       runs one pass of its open block (``denoise_steps`` T denoise
       passes, whose K/V rows go to the trash page, then one commit pass
@@ -379,7 +388,9 @@ class PagedGenerationEngine:
       launched;
     * **verify** ``(slots, spec_k + 1)`` — with n-gram speculation on,
       each step carries the current token plus up to ``spec_k`` drafted
-      tokens and verifies them all at once.  Acceptance is exact-match
+      tokens and verifies them all at once (drafts come of the host's
+      history, so this step is read before the next is launched).
+      Acceptance is exact-match
       against the position-keyed sampler (each position's key is
       ``fold_in(lane_key, position)``), so accepted output is
       bit-identical to what non-speculative sampling would have
@@ -439,6 +450,11 @@ class PagedGenerationEngine:
     # host has been seen to stand still for now and then (100-120 ms,
     # PERF.md section 2).  Every one delays a burst by a pass.
     passes_ahead = 3
+    # token-at-a-time decoding: the same for its steps.  One hides the
+    # host's 3.5 ms a tick behind a step of 6.7 ms (OPT-1.3B) or 19 ms
+    # (Ling-3.0-flash); each further one holds a finished request's slot
+    # and every token a tick longer (PERF.md section 6, PR 34)
+    steps_ahead = 1
 
     def __init__(self, net, slots=None, cache_len=None, page_size=None,
                  num_pages=None, prefill_chunk=None, spec_k=None,
@@ -735,31 +751,50 @@ class PagedGenerationEngine:
         # block-diffusion: the schedule of every slot's open block on
         # the host, the blocks themselves on the device (tokens, which
         # positions are masked, the pass that fixed each, the confidence
-        # it was fixed with), handed from pass to pass; the pass launched
-        # and not yet read; a slot's count of occupants, by which a pass
-        # launched for the last one is told from the present one's
+        # it was fixed with), handed from pass to pass
         self._blocks = _OpenBlocks(self._slots, Bl) if Bl > 1 else None
+        # the launches (passes; steps of token-at-a-time decoding) not
+        # yet read; a slot's count of occupants, by which a launch for
+        # the last one is told from the present one's
         self._inflight = collections.deque()
         self._serial = np.zeros(self._slots, np.int64)
         self.last_pass = None
-        # tokens a slot's request still wants of blocks not yet launched
-        # (admit_incremental's `max_new`), and whether its last block's
-        # commit is launched: no pass is launched for it after that
+        # tokens a slot's request still wants of launches not yet made
+        # (admit_incremental's `max_new`), and whether its last one is
+        # made: nothing is launched for it after that
         self._budget = np.zeros(self._slots, np.int64)
         self._drained = np.zeros(self._slots, bool)
-        # where a slot's next block starts by the passes READ (`_pos`
-        # is by the passes launched, up to `passes_ahead` further on)
+        # a slot's position by the launches READ (`_pos` is by those
+        # made, up to `passes_ahead` or `steps_ahead` further on)
         self._read_pos = np.zeros(self._slots, np.int32)
-        if Bl > 1:
-            if self._mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
+        if self._mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
 
-                on_device = NamedSharding(self._mesh, PartitionSpec())
-            else:
-                on_device = self._pool_sharding
+            on_device = NamedSharding(self._mesh, PartitionSpec())
+        else:
+            on_device = self._pool_sharding
+        if Bl > 1:
             self._block_state = tuple(
                 jax.device_put(np.zeros((self._slots, Bl), dt), on_device)
                 for dt in (np.int32, bool, np.int32, np.float32))
+        # token-at-a-time decoding without speculation feeds a step's
+        # tokens to the next on the device: `_feed` is the last step's
+        # `sampled`, with the first token of every prompt completed
+        # since put in its slot's row; `_firsts` are those first tokens,
+        # still on the device, for the next launch's record to carry to
+        # the host.  (Speculation drafts from the tokens read, so its
+        # step is read before the next is launched.)
+        self._feeds = Bl == 1 and not self._spec_k
+        if self._feeds:
+            self._feed = jax.device_put(
+                np.zeros((self._slots, 1), np.int32), on_device)
+            self._firsts = []
+            self._feed_first = jax.jit(
+                lambda feed, sampled, at, slot:
+                feed.at[slot, 0].set(sampled[0, at]))
+            # a row no step is launched for feeds id 0, as it always did
+            self._feed_on = jax.jit(
+                lambda feed, on: jnp.where(on[:, None], feed, 0))
 
         gluon_params = params
         scfg = self.sampling
@@ -1107,10 +1142,14 @@ class PagedGenerationEngine:
         layers' caches, a slot ``{"position": the positions cached,
         "tokens": the ids at them, "layers": a layer its rows
         (position, width) or the tuple of its state arrays}``, float32.
-        For the engine's one thread, between two dispatches."""
+        The caches are as far as the launches MADE have brought them,
+        so the ids of the launches still in flight are read too (and
+        stay in flight: :meth:`decode_step` hands them out as it would
+        have).  For the engine's one thread, between two dispatches."""
         if not self._declared:
             raise MXNetError("cached() reads the caches of a model that "
                              "declares them (config['layer_caches'])")
+        flight = self._tokens_in_flight()
         pool = np.asarray(self._pool_k)
         state = [np.asarray(a) for a in self._state]
         n_tokens = self._num_pages * self._page_size
@@ -1133,13 +1172,41 @@ class PagedGenerationEngine:
                     state_seen += len(mine)
                     layers.append(tuple(a[slot].astype(np.float32)
                                         for a in mine))
-            out.append({"position": n,
-                        "tokens": list(self._history[slot][:n]),
+            ids = self._history[slot] + flight.get(int(slot), [])
+            out.append({"position": n, "tokens": ids[:n],
                         "layers": layers})
+        return out
+
+    def _tokens_in_flight(self):
+        """``{slot: ids}`` that the launches not yet read give their
+        present occupants, in launch order, read without taking a launch
+        off the queue: with ``_history`` they are the ids that ``_pos``
+        counts."""
+        out = {}
+        for rec in self._inflight:
+            mine = (rec["serial"] == self._serial) & self._active
+            if self._block > 1:
+                toks = np.asarray(rec["read"][0])
+                for b in np.nonzero(mine & rec["on"] & rec["commit"])[0]:
+                    out.setdefault(int(b), []).extend(
+                        toks[b, rec["given"][b]:].tolist())
+                continue
+            for slot, first, at in rec["firsts"]:
+                if mine[slot]:
+                    out.setdefault(slot, []).append(
+                        int(np.asarray(first)[0, at]))
+            if rec["sampled"] is not None:
+                toks = np.asarray(rec["sampled"])
+                for b in np.nonzero(mine & rec["on"])[0]:
+                    out.setdefault(int(b), []).append(int(toks[b, 0]))
         return out
 
     @property
     def last_logits(self):
+        """The logits of the last launch READ, on the host: of the step
+        (the pass) a :meth:`decode_step` handed over, or of a chunk whose
+        token was read (:meth:`admit`; a speculating or block-decoding
+        engine's :meth:`prefill_step`)."""
         out = getattr(self, "_last_logits", None)
         return None if out is None else np.asarray(out)
 
@@ -1221,8 +1288,7 @@ class PagedGenerationEngine:
         """No room for the slot's next token (under block-diffusion
         decoding: for the next whole block after those it has
         emitted)."""
-        pos = self._read_pos if self._block > 1 else self._pos
-        return pos[slot] + self._block - 1 >= min(
+        return self._read_pos[slot] + self._block - 1 >= min(
             self._capacity, self.model_config["max_len"])
 
     # -- page bookkeeping ------------------------------------------------
@@ -1283,8 +1349,9 @@ class PagedGenerationEngine:
         prompt tail for chunked prefill.  Returns the slot; the first
         token arrives from the :meth:`prefill_step` that completes the
         prompt.  ``max_new`` is the most tokens the caller will take:
-        block-diffusion decoding launches its passes ahead of their
-        results, and launches none past the block that covers them.
+        decode steps (and block-diffusion decoding's passes) are
+        launched ahead of their results, and none past the token (the
+        block) that covers them.
         Raises :class:`Overloaded` (``slots`` / ``pages``)."""
         token_ids = np.asarray(token_ids).astype(np.int32).reshape(-1)
         n = token_ids.size
@@ -1360,13 +1427,21 @@ class PagedGenerationEngine:
 
     def prefill_step(self, slot=None):
         """Run ONE prefill chunk (round-robin across pending slots, or
-        the given ``slot``).  Returns ``(slot, first_token)`` when that
-        chunk completed its prompt, else None; under block-diffusion
-        decoding ``(slot, None)``, the first tokens coming of the first
-        block's commit.  The TokenServer calls
+        the given ``slot``).  Returns ``(slot, None)`` when that chunk
+        completed its prompt, else None: the prompt's first token stays
+        on the device, where the slot's first decode step takes it, and
+        comes to the host in :meth:`decode_step`'s result (under
+        block-diffusion decoding: of the first block's commit).  With
+        speculation, whose drafts need it on the host, it is read here:
+        ``(slot, first_token)``.  The TokenServer calls
         this once per loop tick, interleaving long prefills with decode
         steps; the round-robin keeps a short prompt's TTFT from hiding
         behind a long prompt admitted just before it."""
+        return self._prefill_chunk(slot, read=not self._feeds)
+
+    def _prefill_chunk(self, slot, read):
+        """:meth:`prefill_step`; with ``read`` a prompt's first token is
+        waited for and returned, wherever else it goes."""
         if not self._pending:
             return None
         if slot is None:
@@ -1402,10 +1477,12 @@ class PagedGenerationEngine:
                     if fresh:
                         _telemetry.DECODE_STATE_RESETS.inc()
             with _tracing.begin("engine.prefill:launch"):
-                sampled, self._last_logits, _extras = self._dispatch(
+                sampled, logits, _extras = self._dispatch(
                     self._page_table[slot:slot + 1].copy(), chunk,
                     np.asarray([filled], np.int32), wpage, woff,
                     self._lane_keys[slot:slot + 1].copy(), lanes=lanes)
+            if read:    # (`last_logits` are of a launch that was read)
+                self._last_logits = logits
             self._chunks_run += 1
             _telemetry.DECODE_PREFILL_CHUNKS.inc()
             if not final:
@@ -1417,12 +1494,26 @@ class PagedGenerationEngine:
                 # opens the first block, which decode_step denoises
                 self._open_first_block(slot, toks, n)
                 return slot, None
-            with _tracing.begin("engine.prefill:readback"):
-                tok = int(np.asarray(sampled)[0, count - 1])
-            self._pos[slot] = n
-            self._cur_tok[slot] = tok
+            # the first token is one of the request's `max_new`; a step
+            # is launched for the slot if it wants more and has room
+            self._pos[slot] = self._read_pos[slot] = n
+            self._budget[slot] -= 1
+            self._drained[slot] = self._budget[slot] <= 0 or n >= min(
+                self._capacity, self.model_config["max_len"])
             self._active[slot] = True
-            self._history[slot].append(tok)
+            tok = None
+            if self._feeds:
+                self._feed = self._feed_first(
+                    self._feed, sampled, np.int32(count - 1),
+                    np.int32(slot))
+                if not read:
+                    self._firsts.append((int(slot), sampled, count - 1))
+                    step.set(fed=1)
+            if read:
+                with _tracing.begin("engine.prefill:readback"):
+                    tok = int(np.asarray(sampled)[0, count - 1])
+                self._cur_tok[slot] = tok
+                self._history[slot].append(tok)
             if self._prefix_share:
                 self._register_prefix(slot, toks, n)
             self._note_occupancy()
@@ -1439,21 +1530,34 @@ class PagedGenerationEngine:
             self._register_prefix(slot, token_ids, token_ids.size)
         self._note_occupancy()
 
-    def admit(self, token_ids):
+    def admit(self, token_ids, max_new=None):
         """Synchronous admission, for a caller that drives the engine
-        by hand: claim a slot and run every prefill chunk back to back.
-        Returns ``(slot, first_token)``."""
-        sl = self.admit_incremental(token_ids)
+        by hand: claim a slot, run every prefill chunk back to back and
+        read what the last one gave.  Returns ``(slot, first_token)``
+        (under block-diffusion decoding ``(slot, None)``)."""
+        sl = self.admit_incremental(token_ids, max_new=max_new)
         while sl in self._pending:
-            res = self.prefill_step(slot=sl)
+            res = self._prefill_chunk(sl, read=True)
             if res is not None:
                 return res
         return sl, None
 
     def decode_step(self):
-        """One fixed-shape step for every active slot.  Returns
-        ``{slot: [tokens...]}`` — one token per slot without
-        speculation, up to ``spec_k + 1`` with it (drafted tokens that
+        """One fixed-shape step for every active slot: launch it, then
+        read the oldest launch still unread, leaving ``steps_ahead``
+        queued (``passes_ahead`` passes under block-diffusion decoding,
+        :meth:`_decode_blocks`).  Returns ``{slot: [tokens...]}`` of the
+        launch READ: one token a slot it stepped, led by the prompt's
+        first token where the slot's prompt completed just before that
+        launch; ``[]`` for a slot that launch was not for (its occupant
+        came since), and for every active slot when nothing is read yet
+        (by hand: the first ``steps_ahead`` calls).  No step is
+        launched for a slot whose request has all the tokens it wants
+        (``admit_incremental``'s ``max_new``) or no room under way;
+        with nothing to launch a call reads one launch, and
+        :meth:`drain` reads them all.  ``last_logits`` are of the step
+        read.  With speculation a call launches AND reads its own step:
+        up to ``spec_k + 1`` tokens a slot (drafted tokens that
         verified, plus the one token sampling always yields).  Rejected
         drafts leave K/V at positions >= the new ``pos``; those entries
         are masked by ``start`` and overwritten as decode advances."""
@@ -1462,31 +1566,134 @@ class PagedGenerationEngine:
             return {}
         if self._block > 1:
             return self._decode_blocks()
-        import jax
-
-        B, K = self._slots, self._spec_k
+        if self._spec_k:
+            return self._decode_verify()
+        B = self._slots
         cap = min(self._capacity, self.model_config["max_len"])
         active = [int(b) for b in np.nonzero(self._active)[0]]
-        C = K + 1 if K > 0 else 1
+        on = self._active & ~self._drained
+        stepped = int(on.sum())
         # the step's phases as always-kept spans (docs/observability.md
         # "Spans of the hot loops"); DECODE_STEP_SECONDS reads the span
         with _tracing.begin("engine.decode", args={
+                "slots": stepped, "live": int(self._pos[on].sum()),
+                "fed": stepped, "attn": self._attends_in(1)}) as step:
+            logits, extras = None, {}
+            if stepped:
+                with _tracing.begin("engine.decode:prep"):
+                    pos = np.where(on, self._pos, 0).astype(np.int32)
+                    wpage = np.where(on, self._page_table[
+                        np.arange(B), np.minimum(
+                            pos // self._page_size,
+                            self._pages_per_slot - 1)], 0).astype(np.int32)
+                    woff = np.where(on, pos % self._page_size,
+                                    0).astype(np.int32)
+                    key = self._lane_keys.copy()
+                    table = self._page_table.copy()
+                    lanes = None
+                    if self._declared:
+                        # every slot's row of the state; only those a
+                        # step is launched for count a position, the
+                        # others stay as they are
+                        lanes = (np.arange(B, dtype=np.int32),
+                                 np.zeros(B, bool), on.astype(np.int32))
+                        if self._state_layers:
+                            step.set(state_slots=stepped)
+                with _tracing.begin("engine.decode:launch"):
+                    tokens = self._feed if on.all() \
+                        else self._feed_on(self._feed, on)
+                    self._feed, logits, extras = self._dispatch(
+                        table, tokens, pos, wpage, woff, key, lanes=lanes)
+                # positions and budgets move on by counts, the step unread
+                self._pos[on] += 1
+                self._budget[on] -= 1
+                self._drained |= on & ((self._budget <= 0)
+                                       | (self._pos >= cap))
+            if stepped or self._firsts:
+                # (first tokens alone where their requests want no more)
+                self._inflight.append({
+                    "on": on, "serial": self._serial.copy(),
+                    "sampled": self._feed if stepped else None,
+                    "logits": logits, "load": extras.get("expert_load"),
+                    "firsts": self._firsts})
+                self._firsts = []
+            ahead = self.steps_ahead if stepped else 0
+            if len(self._inflight) > ahead:
+                out = self._read_step(step)
+            else:
+                out = {b: [] for b in active}
+            step.set(unread=len(self._inflight))
+        _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
+        return out
+
+    def _read_step(self, step):
+        """Take the oldest step still unread off the queue and wait for
+        it: ``{slot: [tokens]}``, for every active slot the tokens that
+        the step (and the first tokens it carries) gave its present
+        occupant."""
+        import jax
+
+        read = self._inflight.popleft()
+        with _tracing.begin("engine.decode:readback"):
+            # one wait for the small arrays, not one each
+            sampled, load, firsts = jax.device_get((
+                read["sampled"], read["load"],
+                [first for _slot, first, _at in read["firsts"]]))
+        with _tracing.begin("engine.decode:post"):
+            # a slot evicted since the launch has another serial
+            mine = (read["serial"] == self._serial) & self._active
+            out = {int(b): [] for b in np.nonzero(self._active)[0]}
+            for (slot, _first, at), got in zip(read["firsts"], firsts):
+                if mine[slot]:
+                    out[slot].append(int(got[0, at]))
+            for b in np.nonzero(mine & read["on"])[0]:
+                out[int(b)].append(int(sampled[b, 0]))
+                self._read_pos[b] += 1
+            for b, toks in out.items():
+                self._history[b].extend(toks)
+            if read["logits"] is not None:
+                self._last_logits = read["logits"]
+            if load is not None:
+                self._note_expert_load(step, load)
+            _telemetry.DECODE_TOKENS.inc(sum(len(t) for t in out.values()))
+            _telemetry.DECODE_BATCH_TOKENS.observe(int(read["on"].sum()))
+            self._note_occupancy()
+        return out
+
+    def drain(self):
+        """Read every launch still in flight, launching nothing: a list,
+        oldest first, of what :meth:`decode_step` would have returned
+        for each.  For a caller that drives the engine by hand and wants
+        the tokens of its last calls."""
+        outs = []
+        while self._inflight:
+            with _tracing.begin("engine.drain") as step:
+                outs.append(self._read_pass(step) if self._block > 1
+                            else self._read_step(step))
+        return outs
+
+    def _decode_verify(self):
+        """The step under n-gram speculation, ``(slots, spec_k + 1)``:
+        the current token and the drafts of every active slot, launched
+        and read in one call."""
+        B, K = self._slots, self._spec_k
+        cap = min(self._capacity, self.model_config["max_len"])
+        active = [int(b) for b in np.nonzero(self._active)[0]]
+        C = K + 1
+        with _tracing.begin("engine.decode", args={
                 "slots": len(active),
-                "live": int(self._pos[active].sum()),
+                "live": int(self._pos[active].sum()), "fed": 0,
                 "attn": self._attends_in(C)}) as step:
             with _tracing.begin("engine.decode:prep"):
                 tokens = np.zeros((B, C), np.int32)
                 drafts = {}
                 for b in active:
                     tokens[b, 0] = self._cur_tok[b]
-                    if K > 0:
-                        room = cap - 1 - int(self._pos[b])
-                        d = _ngram_draft(self._history[b], self._spec_ngram,
-                                         min(K, room)) if room > 0 else []
-                        drafts[b] = d
-                        tokens[b, 1:1 + len(d)] = d
-                    else:
-                        drafts[b] = []
+                    room = cap - 1 - int(self._pos[b])
+                    d = _ngram_draft(self._history[b], self._spec_ngram,
+                                     min(K, room)) if room > 0 else []
+                    drafts[b] = d
+                    tokens[b, 1:1 + len(d)] = d
                 wpage = np.zeros(B * C, np.int32)
                 woff = np.zeros(B * C, np.int32)
                 for b in active:
@@ -1498,26 +1705,11 @@ class PagedGenerationEngine:
                 key = self._lane_keys.copy()
                 table = self._page_table.copy()
                 pos = self._pos.astype(np.int32).copy()
-                lanes = None
-                if self._declared:
-                    # every slot's row of the state; only those someone
-                    # is in count a position, the others stay as they are
-                    lanes = (np.arange(B, dtype=np.int32),
-                             np.zeros(B, bool),
-                             self._active.astype(np.int32))
-                    if self._state_layers:
-                        step.set(state_slots=len(active))
             with _tracing.begin("engine.decode:launch"):
-                sampled, self._last_logits, extras = self._dispatch(
-                    table, tokens, pos, wpage, woff, key, lanes=lanes)
-            load = extras.get("expert_load")
+                sampled, self._last_logits, _extras = self._dispatch(
+                    table, tokens, pos, wpage, woff, key)
             with _tracing.begin("engine.decode:readback"):
-                if load is None:
-                    sampled = np.asarray(sampled)
-                else:       # one wait for the small arrays, not one each
-                    sampled, load = jax.device_get((sampled, load))
-            if load is not None:
-                self._note_expert_load(step, load)
+                sampled = np.asarray(sampled)
             with _tracing.begin("engine.decode:post"):
                 out = {}
                 emitted_total = 0
@@ -1537,10 +1729,12 @@ class PagedGenerationEngine:
                     emitted_total += len(emitted)
                     self._cur_tok[b] = emitted[-1]
                     self._pos[b] += len(emitted)
+                    self._read_pos[b] = self._pos[b]
                     self._history[b].extend(emitted)
                 _telemetry.DECODE_TOKENS.inc(emitted_total)
                 _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
                 self._note_occupancy()
+            step.set(unread=0)
         _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
 
@@ -1566,8 +1760,6 @@ class PagedGenerationEngine:
         not in it, and a call with no pass to read yet returns
         ``{slot: []}`` for every active slot.  ``last_pass`` and
         ``last_logits`` are of the pass read."""
-        import jax
-
         Bl, T = self._block, self._denoise_steps
         cap = min(self._capacity, self.model_config["max_len"])
         active = [int(b) for b in np.nonzero(self._active)[0]]
@@ -1640,38 +1832,45 @@ class PagedGenerationEngine:
                 step.set(emitted=0)
                 out = {b: [] for b in active}
             else:
-                read = self._inflight.popleft()
-                with _tracing.begin("engine.decode:readback"):
-                    # one wait for the small arrays, not one each
-                    toks, at, conf, masked, load = jax.device_get(
-                        read["read"])
-                with _tracing.begin("engine.decode:post"):
-                    # a slot evicted since the launch has another serial
-                    mine = read["on"] & (read["serial"] == self._serial) \
-                        & self._active
-                    out = {int(b): [] for b in np.nonzero(mine)[0]}
-                    emitted_total = 0
-                    for b in np.nonzero(mine & read["commit"])[0]:
-                        g = read["given"][b]
-                        out[int(b)] = emitted = BlockTokens(
-                            toks[b, g:].tolist(), at[b, g:].tolist(),
-                            conf[b, g:].tolist())
-                        emitted_total += len(emitted)
-                        self._history[int(b)].extend(emitted)
-                        self._read_pos[b] = read["start"][b] + Bl
-                    self._last_logits = read["logits"]
-                    self.last_pass = {
-                        "on": read["on"], "start": read["start"],
-                        "pass": read["pass"], "masked": masked}
-                    step.set(emitted=emitted_total)
-                    if load is not None:
-                        self._note_expert_load(step, load)
-                    _telemetry.DECODE_BLOCKS_COMMITTED.inc(
-                        int((mine & read["commit"]).sum()))
-                    _telemetry.DECODE_BLOCK_TOKENS.inc(emitted_total)
-                    _telemetry.DECODE_TOKENS.inc(emitted_total)
-                    self._note_occupancy()
+                out = self._read_pass(step)
         _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
+        return out
+
+    def _read_pass(self, step):
+        """Take the oldest pass still unread off the queue and wait for
+        it (:meth:`_decode_blocks` says what it returns)."""
+        import jax
+
+        read = self._inflight.popleft()
+        with _tracing.begin("engine.decode:readback"):
+            # one wait for the small arrays, not one each
+            toks, at, conf, masked, load = jax.device_get(read["read"])
+        with _tracing.begin("engine.decode:post"):
+            # a slot evicted since the launch has another serial
+            mine = read["on"] & (read["serial"] == self._serial) \
+                & self._active
+            out = {int(b): [] for b in np.nonzero(mine)[0]}
+            emitted_total = 0
+            for b in np.nonzero(mine & read["commit"])[0]:
+                g = read["given"][b]
+                out[int(b)] = emitted = BlockTokens(
+                    toks[b, g:].tolist(), at[b, g:].tolist(),
+                    conf[b, g:].tolist())
+                emitted_total += len(emitted)
+                self._history[int(b)].extend(emitted)
+                self._read_pos[b] = read["start"][b] + self._block
+            self._last_logits = read["logits"]
+            self.last_pass = {
+                "on": read["on"], "start": read["start"],
+                "pass": read["pass"], "masked": masked}
+            step.set(emitted=emitted_total)
+            if load is not None:
+                self._note_expert_load(step, load)
+            _telemetry.DECODE_BLOCKS_COMMITTED.inc(
+                int((mine & read["commit"]).sum()))
+            _telemetry.DECODE_BLOCK_TOKENS.inc(emitted_total)
+            _telemetry.DECODE_TOKENS.inc(emitted_total)
+            self._note_occupancy()
         return out
 
     def _dispatch(self, page_table, tokens, start, wpage, woff, keys,
@@ -1715,6 +1914,14 @@ class PagedGenerationEngine:
         pending = slot in self._pending
         if not pending and not self._active[slot]:
             return
+        # what was launched for its occupant and is not read yet is
+        # thrown away when it is (its serial has moved on by then)
+        wasted = sum(1 for rec in self._inflight if rec["on"][slot]
+                     and rec["serial"][slot] == self._serial[slot])
+        if wasted:
+            _telemetry.DECODE_STEPS_WASTED.inc(wasted, reason=reason)
+        if self._feeds:
+            self._firsts = [f for f in self._firsts if f[0] != slot]
         self._pending.pop(slot, None)
         self._history.pop(slot, None)
         self._active[slot] = False
@@ -1770,9 +1977,13 @@ class PagedGenerationEngine:
         beside the engine's own parameters and pools: what a compile
         that runs nothing needs."""
         nb, nc = shape
+        # a token-at-a-time step is fed the last one's result, an array
+        # on the device, and is compiled (and stored) for that
+        fed = self._feeds and shape == (self._slots, 1)
         args = (self._params, self._pool_k, self._pool_v,
                 np.zeros((nb, self._pages_per_slot), np.int32),
-                np.zeros((nb, nc), np.int32), np.zeros(nb, np.int32),
+                self._feed if fed else np.zeros((nb, nc), np.int32),
+                np.zeros(nb, np.int32),
                 np.zeros(nb * nc, np.int32), np.zeros(nb * nc, np.int32),
                 np.zeros((nb, 2), np.uint32))
         if self._block > 1 and shape == (self._slots, self._block):
@@ -1845,9 +2056,11 @@ class TokenServer:
     tick, and evicts on EOS, deadline, length cap, or cancellation.
     Of the engine it calls ``bucket_for`` (is the prompt admissible),
     ``free_slots``, ``admit_incremental`` (claim a slot and its pages),
-    ``prefill_step`` (one chunk; ``(slot, first_token)`` when it ends a
-    prompt), ``decode_step`` (``{slot: [tokens]}``, a list a slot in
-    every mode), ``at_capacity``, ``evict`` and ``occupancy``, and reads
+    ``prefill_step`` (one chunk; ``(slot, first_token or None)`` when
+    it ends a prompt), ``decode_step`` (``{slot: [tokens]}``, a list a
+    slot in every mode, of a step launched a call or more before: the
+    first token of a request comes this way too), ``at_capacity``,
+    ``evict`` and ``occupancy``, and reads
     ``sampling.eos_id``, ``last_prefix_hit_tokens`` and ``pool_shape``
     (for ``/statusz``).  The typed
     degradation contract is the serving_async taxonomy applied
@@ -2186,8 +2399,9 @@ class TokenServer:
             req.fixed_at.append(fixed[0])
             req.confidence.append(fixed[1])
         if req.ttft is None:
-            # block-diffusion: a prompt's last chunk yields no token,
-            # the first ones come of the first block's commit
+            # a prompt's last chunk hands no token over (only a
+            # speculating engine reads it there): the first one comes of
+            # a decode step, and is stamped as it is read
             req.ttft = now - req.t_submit
             _telemetry.DECODE_TTFT_SECONDS.observe(
                 req.ttft, exemplar={"trace_id": _tracing.TRACE_ID,
@@ -2250,7 +2464,11 @@ class TokenServer:
             eng.evict(slot, "cancelled")
             return
         if tok is None:
-            return      # block-diffusion: the first block is now open
+            # the first token stays on the device for the slot's first
+            # step and is delivered, and the TTFT stamped, when a decode
+            # step hands it over (block-diffusion: the first block is
+            # now open)
+            return
         req.ttft = time.monotonic() - req.t_submit
         ex = {"trace_id": _tracing.TRACE_ID,
               "span_id": req.span.span_id} \

@@ -869,6 +869,14 @@ DECODE_TOKENS = counter(
     "mxnet_tpu_decode_tokens_total",
     "Tokens generated across all decode slots (under block-diffusion "
     "decoding: tokens of committed blocks, not passes).")
+DECODE_STEPS_WASTED = counter(
+    "mxnet_tpu_decode_steps_wasted_total",
+    "Decode launches (steps; passes of block-diffusion decoding) made "
+    "for a slot ahead of their results and thrown away unread because "
+    "its occupant left meanwhile, by the eviction's reason (eos, "
+    "cancelled, deadline, drain; a request that ends by its length "
+    "wastes none: no step is launched past its last token).",
+    ("reason",))
 DECODE_STEP_SECONDS = histogram(
     "mxnet_tpu_decode_step_seconds",
     "Wall time of one fixed-shape decode step (all slots advance one "

@@ -131,13 +131,25 @@ def _greedy_reference(net, prompt, n):
 
 
 def _generate(e, prompt, n):
-    """``n`` tokens of ``prompt`` from the engine driven by hand."""
+    """``n`` tokens of ``prompt`` from the engine driven by hand (a
+    call of ``decode_step`` hands over the tokens of a step launched a
+    call before; what is still in flight at the end is thrown away)."""
     slot, tok = e.admit(prompt)
     out = [tok]
     while len(out) < n:
         out.extend(e.decode_step()[slot])
     e.evict(slot, "length")
     return out[:n]
+
+
+def _step_and_read(e):
+    """One step launched and read at once, by hand: ``decode_step``,
+    then ``drain`` for what it left in flight."""
+    out = e.decode_step()
+    for more in e.drain():
+        for slot, toks in more.items():
+            out[slot] = out.get(slot, []) + toks
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -212,8 +224,8 @@ def test_engine_decode_matches_bf16_mixed(lm):
     slot, tok = e.admit(prompt)
     seq = list(prompt) + [tok]
     for _ in range(4):
-        step_toks = e.decode_step()
-        got = e.last_logits[slot, 0]
+        step_toks = _step_and_read(e)
+        got = e.last_logits[slot, 0]      # of the step read
         # reference: prefill of the full sequence so far in the OTHER
         # slot, one chunk from an empty cache (head stays f32 per the
         # norm/head rules)
@@ -360,8 +372,8 @@ def test_decode_step_returns_a_list_a_slot(lm, mode):
         bursts.append(len(out[slot]))
     e.evict(slot, "length")
     assert sum(bursts) > 0
-    if mode == "plain":
-        assert bursts == [1] * 8
+    if mode == "plain":       # the first call has nothing to read yet
+        assert bursts == [0] * e.steps_ahead + [1] * (8 - e.steps_ahead)
     elif mode == "block":
         assert set(bursts) == {0, 4}, bursts
 

@@ -57,10 +57,12 @@ def test_manifest_gains_one_configuration_and_one_cell():
                                 "vocab_size"]
     assert entry["source"] == SOURCE
     # the tail is the cell's metric: `serve_output_tok_s` spread by 0.9 %
-    # over six runs on the chip (machine stalls of 100-170 ms in a loop
-    # that reads every step before it launches the next; PERF.md), where
-    # a new cell is admitted under 0.5 %, so the cell does not report it,
-    # nor the per-layer metrics that move it
+    # over six runs on the chip when the cell was added (machine stalls
+    # of 100-170 ms in a loop that read every step before it launched
+    # the next; PERF.md), where a new cell is admitted under 0.5 %, so
+    # the cell does not report it, nor the per-layer metrics that move
+    # it.  Steps are launched ahead since PR 34 and the rate repeats; a
+    # `benchmark` PR may list the cell under it
     e2e = {m["name"] for m in manifest.metrics_of(man, "end_to_end", CELL)}
     assert e2e == {"serve_itl_p95_ms", "setup_s"}
     layer = {m["name"]: m for m in manifest.metrics_of(man, "per_layer", CELL)}

@@ -73,6 +73,17 @@ def engine(model):
     return _engine(model[0])
 
 
+def _step(eng):
+    """One decode step launched and read at once, by hand: a call of
+    ``decode_step`` hands over the tokens of a step launched a call
+    before, ``drain`` reads what is still in flight."""
+    out = eng.decode_step()
+    for more in eng.drain():
+        for slot, toks in more.items():
+            out[slot] = out.get(slot, []) + toks
+    return out
+
+
 def _ids(cfg, n, seed):
     return np.random.default_rng(seed).integers(
         0, cfg["vocab_size"], n).astype(np.int32)
@@ -127,8 +138,8 @@ def test_chunked_prefill_then_decode_matches_reference(cfg, model, engine):
         seqs.append(list(p) + [tok])
         slots.append(slot)
     for _ in range(12):
-        out = eng.decode_step()
-        step = eng.last_logits
+        out = _step(eng)
+        step = eng.last_logits             # of the step read
         for sl, seq, lg in zip(slots, seqs, logits):
             lg.append(step[sl, 0])
             seq.extend(out[sl])
@@ -406,22 +417,26 @@ def test_state_life_cycle(cfg, model):
         np.testing.assert_array_equal(x[idle], y[idle])
     assert any(np.abs(x[a] - y[a]).max() > 0
                for x, y in zip(after, stepped))
-    # the slot's next occupant starts from zero
+    # the slot's next occupant starts from zero (a's step is still in
+    # flight: its token is thrown away with its occupant)
+    assert len(eng._inflight) == eng.steps_ahead
     eng.evict(a, "length")
     eng.evict(b, "length")
     prompt = _ids(cfg, 33, 8)
     again, tok = eng.admit(prompt)
     assert again == b                      # LIFO: the slot b left dirty
-    reused = [eng.decode_step()[again][0] for _ in range(6)]
+    reused = [_step(eng)[again][0] for _ in range(6)]
     fresh_eng = _engine(net, slots=1)
     slot, tok_f = fresh_eng.admit(prompt)
-    fresh = [fresh_eng.decode_step()[slot][0] for _ in range(6)]
+    fresh = [_step(fresh_eng)[slot][0] for _ in range(6)]
     assert (tok, reused) == (tok_f, fresh)
 
 
 def test_cached_reads_what_the_reference_keeps(cfg, model):
     """``PagedGenerationEngine.cached``: after prompts of 50 and 9
-    tokens in chunks of 24 and five decode steps, a slot's caches (every
+    tokens in chunks of 24 and five decode steps, the last of them
+    still in flight (its ids are read for the answer and stay for
+    ``decode_step`` to hand out), a slot's caches (every
     KDA layer's ``S`` and convolution tail, the MLA layer's latent rows
     as they lie in its pages) are what the plain reference's ``caches``
     keeps of the same ids, a token a step.  A slot mid-prefill reads as
@@ -434,6 +449,7 @@ def test_cached_reads_what_the_reference_keeps(cfg, model):
              ((50, 21), (9, 22))]
     for _ in range(5):
         eng.decode_step()
+    assert len(eng._inflight) == eng.steps_ahead
     late = eng.admit_incremental(_ids(cfg, 70, 23))
     eng.prefill_step(slot=late)
     kept = jax.jit(lambda params, toks, n: ref.caches(cfg, params, toks, n))
@@ -454,6 +470,55 @@ def test_cached_reads_what_the_reference_keeps(cfg, model):
                 assert mine.shape == (n, cfg["kv_lora_rank"]
                                       + cfg["qk_rope_head_dim"])
                 assert np.abs(mine - theirs[0, :n]).max() < TOL
+    # the step in flight is still there to be read, with its tokens
+    assert len(eng._inflight) == eng.steps_ahead
+    (last,) = eng.drain()
+    assert sorted(last) == sorted(slots) and all(
+        len(t) == 1 for t in last.values())
+
+
+@pytest.mark.parametrize("depth", [1, 3])
+def test_launched_ahead_leaves_the_caches_of_read_then_launch(cfg, model,
+                                                             depth):
+    """Steps launched ahead of their results (ISSUE 34; ``depth`` left
+    unread a call) give the tokens, and leave every layer's state and
+    the latent rows, that reading each step before the next launch
+    (depth 0) gives: two prompts, the second joining after three steps
+    with its first token fed on the device, nine steps in all, and
+    ``cached`` asked with ``depth`` steps still in flight."""
+    net, _arrays = model
+
+    def run(ahead):
+        eng = _engine(net)
+        eng.steps_ahead = ahead
+        slots, got = [], {}
+        for call in range(9):
+            if call in (0, 3):
+                slot = eng.admit_incremental(_ids(cfg, 31 + call, 40 + call))
+                while eng.pending_prefill():
+                    eng.prefill_step()
+                slots.append(slot)
+                got[slot] = []
+            for slot, toks in eng.decode_step().items():
+                got[slot].extend(toks)
+        assert len(eng._inflight) == ahead
+        snaps = eng.cached(slots)
+        assert len(eng._inflight) == ahead
+        for more in eng.drain():
+            for slot, toks in more.items():
+                got[slot].extend(toks)
+        return [got[s] for s in slots], snaps
+
+    want_toks, want = run(0)
+    got_toks, got = run(depth)
+    assert got_toks == want_toks and [len(t) for t in got_toks] == [10, 7]
+    for mine, theirs, n in zip(got, want, (31 + 9, 34 + 6)):
+        assert mine["position"] == theirs["position"] == n
+        assert mine["tokens"] == theirs["tokens"] and len(mine["tokens"]) == n
+        for a, b in zip(mine["layers"], theirs["layers"]):
+            for x, y in zip(a if isinstance(a, tuple) else (a,),
+                            b if isinstance(b, tuple) else (b,)):
+                np.testing.assert_array_equal(x, y)
 
 
 def test_the_two_refusals(model, caplog):
@@ -493,6 +558,7 @@ def test_spans_and_counter_of_state_and_experts(cfg, model):
     for n, seed in ((30, 1), (9, 2)):
         eng.admit(_ids(cfg, n, seed))
     eng.decode_step()
+    eng.decode_step()           # reads the first step, and its routing
     if not was_on:
         telemetry.disable()
     recs = tracing.records()[t0:]

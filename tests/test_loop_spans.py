@@ -137,15 +137,55 @@ def test_server_tick_tree_is_kept_with_tracing_off(untraced, engine):
             assert ids[r["parent_id"]]["name"] == "engine.decode"
         elif r["name"].startswith("engine.prefill:"):
             assert ids[r["parent_id"]]["name"] == "engine.prefill"
-    # every decode step has its four phases, in order
-    for dec in (r for r in recs if r["name"] == "engine.decode"):
-        assert [k["name"] for k in _kids(recs, dec)] == DECODE_PHASES
-        assert dec["args"]["slots"] >= 1 and dec["args"]["live"] >= 1
-    # a prompt's last chunk reads its token back, an earlier one does not
-    for pre in (r for r in recs if r["name"] == "engine.prefill"):
-        kids = [k["name"] for k in _kids(recs, pre)]
-        assert kids == ["engine.prefill:launch"] + (
-            ["engine.prefill:readback"] if pre["args"]["final"] else [])
+    # a decode call launches a step (prep, launch), then reads the
+    # oldest step still unread (readback, post) and leaves `steps_ahead`
+    # queued: the first call has nothing to read yet, the last ones
+    # nothing to launch.  Every slot stepped took its token on the
+    # device (`fed`), and `unread` is what the call left in flight
+    ahead, unread, both = engine.steps_ahead, 0, 0
+    decs = sorted((r for r in recs if r["name"] == "engine.decode"),
+                  key=lambda r: r["t0"])
+    for dec in decs:
+        kids = [k["name"] for k in _kids(recs, dec)]
+        launched = kids[:2] == DECODE_PHASES[:2]
+        read = kids[-2:] == DECODE_PHASES[2:]
+        assert kids and kids == DECODE_PHASES[:2] * launched \
+            + DECODE_PHASES[2:] * read
+        assert dec["args"]["fed"] == dec["args"]["slots"]
+        assert (dec["args"]["slots"] >= 1) == launched
+        assert launched == (dec["args"]["live"] >= 1)
+        # the step a call reads is one an EARLIER call launched: when
+        # the call has launched its own, two are in flight, and the
+        # older one is read
+        unread += launched
+        assert read == (unread > (ahead if launched else 0))
+        both += launched and read
+        unread -= read
+        assert dec["args"]["unread"] == unread
+    assert both >= 2 and unread == 0
+    # ... by the ring's own order of the two phases too: no read-back
+    # before `steps_ahead` + 1 launches
+    phases = sorted((r for r in recs if r["name"] in (
+        "engine.decode:launch", "engine.decode:readback")),
+        key=lambda r: r["t0"])
+    flight = 0
+    for r in phases:
+        if r["name"].endswith(":launch"):
+            flight += 1
+            continue
+        own = any(k["name"] == "engine.decode:launch"
+                  for k in _kids(recs, ids[r["parent_id"]]))
+        assert flight == ahead + 1 if own else flight >= 1
+        flight -= 1
+    # no chunk reads a token back, a prompt's last one neither: the
+    # first token stays on the device and comes with a decode step's
+    pres = [r for r in recs if r["name"] == "engine.prefill"]
+    for pre in pres:
+        assert [k["name"] for k in _kids(recs, pre)] == [
+            "engine.prefill:launch"]
+        assert pre["args"].get("fed", 0) == int(pre["args"]["final"])
+    assert sum(p["args"]["final"] for p in pres) == 2
+    assert not any(r["name"] == "engine.prefill:readback" for r in recs)
     assert any(r["args"]["admitted"] for r in recs
                if r["name"] == "serve.admit")
     assert sum(r["args"]["tokens"] for r in recs
@@ -258,8 +298,8 @@ def test_spans_lie_in_the_profiler_trace_on_their_threads(
     assert len(caller) == 1 and len(worker) == 1
     assert {"mx:step:dispatch", "mx:step:fetch"} <= caller[0]
     assert {"mx:serve.tick", "mx:serve.admit", "mx:serve.deliver",
-            "mx:engine.prefill", "mx:engine.prefill:launch",
-            "mx:engine.prefill:readback"} <= worker[0]
+            "mx:engine.prefill", "mx:engine.prefill:launch"} <= worker[0]
+    assert "mx:engine.prefill:readback" not in worker[0]
     assert {"mx:" + n for n in DECODE_PHASES} <= worker[0]
     assert "mx:serve.tick" not in caller[0]
     assert "mx:ShardedTrainer.step" not in worker[0]
@@ -405,6 +445,36 @@ def test_the_real_ring_is_read_through_tracing_records(untraced, monkeypatch):
     assert got == pytest.approx(1e3 * tracing.records()[0]["dur"])
 
 
+@pytest.mark.parametrize("spec_k,want", [(0, 100.0), (2, 0.0)],
+                         ids=["launched_ahead", "speculating"])
+def test_fed_on_device_share_reads_the_served_ring(untraced, monkeypatch,
+                                                   engine, spec_k, want):
+    """``decode_fed_on_device_share`` (ISSUE 34) over the spans a server
+    really wrote: every slot-step took its token on the device where
+    steps are launched ahead, none where speculation reads each step
+    first; a program that writes no ``fed`` (the parent) gives the
+    reader nothing."""
+    from benchmark.lib.reducers import span_args
+
+    spec = manifest.layer_metric("decode_fed_on_device_share")
+    assert spec["reducer"] == "span_args"
+    eng = engine if not spec_k else generate.PagedGenerationEngine(
+        engine._net, slots=2, cache_len=24, page_size=4, prefill_chunk=8,
+        spec_k=spec_k, sampling=generate.SamplingConfig(greedy=True))
+    _serve(eng)
+    t0 = min(r["t0"] for r in tracing.records())
+    monkeypatch.setitem(sys.modules, "__main__",
+                        types.SimpleNamespace(T_START=t0 - 2.0))
+    ctx = {"end_to_end": {"setup_s": 1.0}, "window": {"seconds": 3600.0}}
+    assert span_args.reduce(ctx, **spec["args"]) == want
+    steps = [r for r in tracing.records() if r["name"] == "engine.decode"]
+    assert all(r["args"]["unread"] == 0 for r in steps) == bool(spec_k)
+    monkeypatch.setattr(program_spans, "ring", lambda: (
+        [dict(r, args={k: v for k, v in r["args"].items() if k != "fed"})
+         for r in steps], 0))
+    assert span_args.reduce(ctx, **spec["args"]) is None
+
+
 def _planes():
     """A device busy [0,10) [14,20) [23,30) [32,40) ms; on the host two
     steps with a dispatch each, and the fetch thread's span on another
@@ -509,8 +579,10 @@ def test_manifest_checks_and_cells_report_the_new_metrics():
     man = manifest.manifest()
     assert manifest.check(man)
     names = [m["name"] for m in man["per_layer"]]
-    # PR 26's ten follow the 17 of PR 24; PRs 28 and 33 appended four each
-    assert names[17:27] == list(NEW_METRICS) and len(names) == 35
+    # PR 26's ten follow the 17 of PR 24; PRs 28 and 33 appended four
+    # each, PR 34 the share of slot-steps fed on the device
+    assert names[17:27] == list(NEW_METRICS) and len(names) == 36
+    assert names[-1] == "decode_fed_on_device_share"
     train = {m["name"] for m in
              manifest.metrics_of(man, "per_layer", "resnet50_train_b256")}
     serve = {m["name"] for m in

@@ -86,12 +86,25 @@ def test_cached_is_for_models_that_declare_their_caches(paged):
         paged.cached([0])
 
 
+def _step(eng):
+    """One decode step launched and read at once, by hand: a call of
+    ``decode_step`` hands over the tokens of a step launched a call
+    before, ``drain`` reads what is still in flight."""
+    out = eng.decode_step()
+    for more in eng.drain():
+        for slot, toks in more.items():
+            out[slot] = out.get(slot, []) + toks
+    return out
+
+
 def _drain(eng, slot, steps):
-    """``steps`` decode ticks for one slot, its per-step token lists
-    flattened."""
+    """``steps`` decode steps for one slot, launched ahead of their
+    results as a server's are, then all read: the slot's tokens."""
     out = []
     for _ in range(steps):
         out.extend(eng.decode_step()[slot])
+    for more in eng.drain():
+        out.extend(more.get(slot, []))
     return out
 
 
@@ -181,8 +194,8 @@ def test_chunked_prefill_matches_monolithic(lm, paged):
     assert c_tok == m_tok
     c_toks, m_toks = [], []
     for _ in range(5):
-        c_toks.extend(chunked.decode_step()[c_slot])
-        m_toks.extend(paged.decode_step()[m_slot])
+        c_toks.extend(_step(chunked)[c_slot])
+        m_toks.extend(_step(paged)[m_slot])
         np.testing.assert_allclose(chunked.last_logits[0],
                                    paged.last_logits[0],
                                    rtol=0, atol=2e-5)
@@ -267,10 +280,10 @@ def test_prefix_attach_refcount_and_eviction(lm):
     # the two lanes must now decode identical greedy tokens
     steps = {s: [] for s in (s1, s2)}
     for _ in range(4):
-        out = e.decode_step()
+        out = _step(e)
         for s in steps:
             steps[s].extend(out[s])
-    assert steps[s1] == steps[s2]
+    assert steps[s1] == steps[s2] and len(steps[s1]) == 4
     # detach one user: refcount drops, pages stay mapped for the other
     e.evict(s2, "eos")
     assert all(e._page_ref[p] == 1 for p in shared)
@@ -1030,12 +1043,12 @@ def _side_by_side(eng, prompts, steps):
     active, evict: the tokens of each."""
     slots = [eng.admit(p) for p in prompts]
     got = [[tok] for _s, tok in slots]
-    for _ in range(steps):
-        out = eng.decode_step()
+    for out in [eng.decode_step() for _ in range(steps)] + eng.drain():
         for g, (s, _t) in zip(got, slots):
             g.extend(out[s])
     for s, _t in slots:
         eng.evict(s, "length")
+    assert all(len(g) == steps + 1 for g in got)
     return got
 
 
@@ -1048,8 +1061,10 @@ def _mixed_inside_page(eng):
 
 
 def _mixed_evict_reuse(eng):
-    """A slot is evicted mid-flight and the next admission takes its
-    slot and its pages while the neighbour keeps decoding."""
+    """A slot is evicted mid-flight, a step launched for it still
+    unread, and the next admission takes its slot and its pages while
+    the neighbour keeps decoding: the step in flight gives the slot's
+    next occupant nothing and the neighbour its token."""
     a, b, c = _prompt(9, seed=41), _prompt(6, seed=42), _prompt(11, seed=43)
     (sa, ta), (sb, tb) = eng.admit(a), eng.admit(b)
     got_a, got_b = [ta], [tb]
@@ -1057,6 +1072,8 @@ def _mixed_evict_reuse(eng):
         out = eng.decode_step()
         got_a.extend(out[sa])
         got_b.extend(out[sb])
+    assert len(eng._inflight) == eng.steps_ahead
+    assert len(got_a) == len(got_b) == 1 + 3 - eng.steps_ahead
     pages_a = set(int(p) for p in eng._page_table[sa] if p)
     eng.evict(sa, "eos")
     sc, tc = eng.admit(c)
@@ -1064,10 +1081,10 @@ def _mixed_evict_reuse(eng):
     assert pages_a & set(int(p) for p in eng._page_table[sc]), \
         "the next admission must take the evicted slot's pages"
     got_c = [tc]
-    for _ in range(4):
-        out = eng.decode_step()
+    for out in [eng.decode_step() for _ in range(4)] + eng.drain():
         got_b.extend(out[sb])
         got_c.extend(out[sc])
+    assert (len(got_b), len(got_c)) == (1 + 3 + 4, 1 + 4)
     eng.evict(sb, "length")
     eng.evict(sc, "length")
     return [a, b, c], [got_a, got_b, got_c]
@@ -1151,3 +1168,172 @@ def test_prewarm_check_reads_the_token_major_rows(lm, tmp_path, fault,
         assert found == []
     else:
         assert found and all(problem in m for m in found), found
+
+
+# ---------------------------------------------------------------------------
+# decode steps launched ahead of their results (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+def _ahead_engine(net, depth, greedy=True, **kw):
+    """An engine that leaves ``depth`` steps unread a call: 0 reads
+    every step before the next is launched (the read-then-launch engine
+    the others are held to), 1 is the class's own, 3 the block path's."""
+    kw.setdefault("slots", 3)
+    kw.setdefault("prefill_chunk", 8)
+    eng = generate.PagedGenerationEngine(
+        net, cache_len=32, page_size=4, spec_k=0,
+        sampling=generate.SamplingConfig(greedy=True) if greedy else
+        generate.SamplingConfig(greedy=False, top_k=8, temperature=0.9),
+        **kw)
+    eng.steps_ahead = depth
+    return eng
+
+
+def _staggered(eng, prompts, want):
+    """Prompts admitted one every second call (so that slots join a
+    program the others are already in, a first token fed on the device
+    beside tokens of steps in flight), each bounded by its ``want``
+    tokens as a server's requests are, decoded until every launch is
+    read: per prompt its tokens, and the calls it took."""
+    mx.random.seed(5)
+    slots, got, calls = {}, [[] for _ in prompts], 0
+    while len(slots) < len(prompts) or eng._inflight or any(
+            len(got[i]) < want[i] for i in slots.values()):
+        if len(slots) < len(prompts) and calls % 2 == 0:
+            i = len(slots)
+            slot = eng.admit_incremental(prompts[i], max_new=want[i])
+            while eng.pending_prefill():
+                assert eng.prefill_step() in (None, (slot, None))
+            slots[slot] = i
+        for slot, toks in eng.decode_step().items():
+            got[slots[slot]].extend(toks)
+        calls += 1
+        assert calls < 200
+    for slot in slots:
+        eng.evict(slot, "length")
+    return got, calls
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("model", ["rows", "views"])
+def test_launched_ahead_yields_the_tokens_of_read_then_launch(lm, model,
+                                                              greedy):
+    """One step or three left unread a call give, token for token, what
+    reading every step before the next launch gives: greedy and sampled
+    (keys are folded in by position), for a model that takes rows and
+    one that takes views, three requests of different lengths joining
+    and leaving a running batch.  No step is launched past a request's
+    last token, whatever the depth."""
+    from mxnet_tpu import telemetry
+
+    net = lm if model == "rows" else _moe_lm(1)
+    prompts = [_prompt(9, seed=71), _prompt(3, seed=72), _prompt(14, seed=73)]
+    want = [7, 1, 5]
+    telemetry.enable()
+    try:
+        wasted = telemetry.DECODE_STEPS_WASTED.value(reason="length")
+        ref, ref_calls = _staggered(_ahead_engine(net, 0, greedy), prompts,
+                                    want)
+        assert [len(t) for t in ref] == want
+        for depth in (1, 3):
+            eng = _ahead_engine(net, depth, greedy)
+            got, calls = _staggered(eng, prompts, want)
+            assert got == ref, depth
+            assert calls >= ref_calls
+        assert telemetry.DECODE_STEPS_WASTED.value(reason="length") == wasted
+    finally:
+        telemetry.disable()
+    if greedy and model == "rows":
+        for prompt, toks in zip(prompts, ref):
+            assert toks == _greedy_reference(lm, prompt, len(toks))
+
+
+def test_first_token_reaches_the_host_with_the_step_after_it(lm):
+    """A prompt's last chunk hands no token over: ``prefill_step`` says
+    ``(slot, None)``, the slot's first step takes the token on the
+    device, and the call that reads that step returns both; positions
+    are by the launches made, ``at_capacity`` by those read."""
+    eng = _ahead_engine(lm, 1, slots=1)
+    prompt = _prompt(11, seed=74)
+    want = _greedy_reference(lm, prompt, 4)
+    slot = eng.admit_incremental(prompt, max_new=4)
+    assert eng.prefill_step() is None
+    assert eng.prefill_step() == (slot, None)
+    assert eng.position(slot) == 11 and eng.active_slots() == [slot]
+    assert eng.decode_step() == {slot: []}
+    assert eng.position(slot) == 12 and eng._read_pos[slot] == 11
+    assert eng.decode_step() == {slot: want[:2]}
+    assert eng.decode_step() == {slot: want[2:3]}
+    # the request's last step is launched: a call now only reads
+    assert eng.position(slot) == 14 and eng._drained[slot]
+    assert eng.decode_step() == {slot: want[3:]}
+    assert eng.position(slot) == eng._read_pos[slot] == 14
+    assert eng.decode_step() == {slot: []} and not eng._inflight
+    # a request of one token: its chunk's alone, no step at all
+    eng.evict(slot, "length")
+    slot = eng.admit_incremental(prompt, max_new=1)
+    while eng.pending_prefill():
+        eng.prefill_step()
+    assert eng.decode_step() == {slot: want[:1]}
+    assert eng.position(slot) == 11 and not eng._inflight
+    eng.evict(slot, "length")
+
+
+@pytest.mark.parametrize("how", ["eos", "cancelled", "evicted"])
+def test_slot_left_with_steps_in_flight_stays_silent_and_clean(lm, how):
+    """A request that ends by what only the data says (its EOS id, a
+    cancellation) or is evicted by hand has a step launched for it and
+    not read: that step is counted as wasted, gives nobody a token, and
+    the slot's next occupant decodes the full re-forward's tokens."""
+    from mxnet_tpu import telemetry
+
+    prompt, after = _prompt(7, seed=75), _prompt(10, seed=76)
+    full = _greedy_reference(lm, prompt, 8)
+    telemetry.enable()
+    try:
+        if how == "evicted":
+            eng = _ahead_engine(lm, 1, slots=1)
+            slot, tok = eng.admit(prompt)
+            got = [tok] + [t for _ in range(3)
+                           for t in eng.decode_step()[slot]]
+            assert got == full[:3] and len(eng._inflight) == 1
+            eng.evict(slot, "test")
+            assert telemetry.DECODE_STEPS_WASTED.value(reason="test") == 1
+            slot, tok = eng.admit(after)
+            # the call that reads the dead step gives the newcomer nothing
+            assert eng.decode_step() == {slot: []}
+            got = [tok] + [t for _ in range(3)
+                           for t in eng.decode_step()[slot]]
+            assert got == _greedy_reference(lm, after, 4)
+            return
+        eos = full[3] if how == "eos" else None
+        assert eos is None or eos not in full[:3]
+        eng = generate.PagedGenerationEngine(
+            lm, slots=1, cache_len=32, page_size=4, prefill_chunk=8,
+            spec_k=0, sampling=generate.SamplingConfig(greedy=True,
+                                                       eos_id=eos))
+        seen = []
+        with generate.TokenServer(eng, max_new_tokens=8) as srv:
+            if how == "eos":
+                out = srv.generate(prompt, timeout=60)
+                assert out.finish_reason == "eos"
+                assert out.tokens == full[:4]
+                reason = "eos"
+            else:
+                fut = srv.submit(prompt, on_token=lambda t: (
+                    seen.append(t), len(seen) == 3 and fut.cancel()))
+                with pytest.raises(generate.Cancelled):
+                    fut.result(60)
+                reason = "cancelled"
+            nxt = srv.generate(after, max_new_tokens=5, timeout=60)
+        clean = _greedy_reference(lm, after, 5)
+        if eos in clean:
+            clean = clean[:clean.index(eos) + 1]
+        assert nxt.tokens == clean
+        assert seen in ([], full[:3])
+        assert telemetry.DECODE_STEPS_WASTED.value(reason=reason) == \
+            eng.steps_ahead
+        assert eng.free_slots() == 1 and eng.pages_in_use() == 0
+    finally:
+        telemetry.reset()
+        telemetry.disable()
