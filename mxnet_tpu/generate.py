@@ -226,7 +226,7 @@ def _cast_weights(names, tree, policy):
     return tuple(policy.cast_compute(n, a) for n, a in zip(names, tree))
 
 
-def _hold_weights(names, placed, policy):
+def _hold_weights(names, placed, policy, draft=0):
     """The tuple an engine holds and hands to every dispatch: the
     parameters ``placed`` (on their device, under their sharding) as
     the dispatch itself casts them (:func:`_cast_weights`), cast here
@@ -236,8 +236,9 @@ def _hold_weights(names, placed, policy):
     on a weight, and reads no float32 master.  A parameter already at
     its target is held as the very buffer it is: nothing is copied.
     Commits the ``engine.weights`` span: ``held_bytes``, ``cast_bytes``
-    (the bytes of the copies made) and ``aliased`` (the parameters kept
-    as handed over)."""
+    (the bytes of the copies made), ``aliased`` (the parameters kept
+    as handed over) and, for a model whose last ``draft`` parameters
+    are a draft block's, ``draft_bytes``."""
     import jax
 
     with _tracing.begin("engine.weights") as sp:
@@ -248,6 +249,8 @@ def _hold_weights(names, placed, policy):
                cast_bytes=sum(int(h.nbytes) for h, k in zip(held, kept)
                               if not k),
                aliased=sum(kept))
+        if draft:
+            sp.set(draft_bytes=sum(int(h.nbytes) for h in held[-draft:]))
     return held
 
 
@@ -272,18 +275,20 @@ def _ngram_draft(history, ngram, k):
     return []
 
 
-def _prefix_page_hashes(token_ids, page_size, limit):
+def _prefix_page_hashes(token_ids, page_size, limit, ahead=0):
     """Chained content hashes of the first ``limit`` FULL prompt pages:
     ``h_i = sha1(h_{i-1} || tokens of page i)``.  The chain makes a
     page's identity depend on everything before it, so two prompts
     share page i only when they agree on all of pages 0..i — exactly
-    the prefix property page attachment needs."""
+    the prefix property page attachment needs.  With ``ahead`` a page's
+    hash also covers that many tokens after it: what a draft block
+    caches of a position depends on the token that follows."""
     import hashlib
 
     hashes = []
     prev = b""
     for i in range(limit):
-        block = token_ids[i * page_size:(i + 1) * page_size]
+        block = token_ids[i * page_size:(i + 1) * page_size + ahead]
         h = hashlib.sha1(prev + block.tobytes()).hexdigest()
         hashes.append(h)
         prev = h.encode()
@@ -394,7 +399,21 @@ class PagedGenerationEngine:
       against the position-keyed sampler (each position's key is
       ``fold_in(lane_key, position)``), so accepted output is
       bit-identical to what non-speculative sampling would have
-      produced — distribution preservation by construction.
+      produced — distribution preservation by construction.  A model
+      that declares a draft block (``config["draft_layers"]`` = 1, a
+      multi-token-prediction module beside the trunk) drafts for this
+      step itself at ``spec_k`` = 1, **self-drafting**: the verify
+      program runs the trunk on ``[current, draft]``, samples both
+      rows, runs the model's ``draft_forward`` on both, each fed the
+      token the trunk chose after it, and hands back the block's
+      choices; the one at the last accepted row is the next step's
+      draft.  The chunk program runs the block over the prompt too (row
+      ``i`` fed prompt token ``i + 1``, the last row of the last chunk
+      the first token the same program chose) and yields the first
+      draft.  The block's rows live in the one pool of rows beside the
+      trunk's and are rolled back with them: rows at and after the new
+      position are masked by ``start`` and overwritten by the next
+      step.  :meth:`drafted` gives a slot's drafts.
 
     **Layers that keep other things.**  ``config["layer_caches"]`` has
     one entry a layer.  ``{"rows": width}``: one row a token of that
@@ -414,8 +433,11 @@ class PagedGenerationEngine:
     eviction clears nothing.  State cannot be cut at a page boundary,
     so a model with any is not offered prefix attachment (asked for, it
     is turned off with a warning and no page is registered), and
-    ``spec_k > 0`` or a ``block_length`` over 1 raise: a rejected draft
-    or a re-run block would have to be rolled back out of it.
+    ``spec_k > 0`` (whoever drafts) or a ``block_length`` over 1 raise:
+    a rejected draft or a re-run block would have to be rolled back out
+    of it.  A model that keeps rows alone is offered prefix attachment;
+    under self-drafting a page's hash covers one token more, the one the
+    draft block's row of the page's last position was fed.
 
     **Prefix sharing** is page-aligned copy-on-write: full prompt pages
     are content-hashed (chained, so identity implies identical prefix)
@@ -584,16 +606,30 @@ class PagedGenerationEngine:
         # None is the cache's)
         declared = cfg.get("layer_caches")
         self._declared = declared is not None
+        # a model with a draft block (``draft_layers``; its entries of
+        # ``layer_caches`` follow the trunk's) drafts for the verify
+        # step itself at ``spec_k`` 1: the block runs in the chunk and
+        # verify programs, its rows live in the pool beside the
+        # trunk's.  At any other ``spec_k`` the block is left alone and
+        # its rows have no place in the pool
+        n_draft = int(cfg.get("draft_layers", 0)) if self._declared else 0
+        self._self_draft = bool(n_draft) and self._spec_k == 1
+        if self._declared:
+            if len(declared) != L + n_draft or any(
+                    set(kind) not in ({"rows"}, {"state"})
+                    for kind in declared) or any(
+                    "rows" not in kind for kind in declared[L:]):
+                raise MXNetError(
+                    "model config's layer_caches must give each of the "
+                    "%d layers (and then each of the %d draft blocks, "
+                    "rows) {'rows': width} or {'state': [(shape, "
+                    "dtype), ...]}, got %r" % (L, n_draft, declared))
+            if not self._self_draft:
+                declared = declared[:L]
+        self._layer_caches = declared
         self._state_layers = state_layers = [
             li for li, kind in enumerate(declared or ()) if "state" in kind]
         if self._declared:
-            if len(declared) != L or any(
-                    set(kind) not in ({"rows"}, {"state"})
-                    for kind in declared):
-                raise MXNetError(
-                    "model config's layer_caches must give each of the "
-                    "%d layers {'rows': width} or {'state': [(shape, "
-                    "dtype), ...]}, got %r" % (L, declared))
             if self._mesh is not None:
                 raise MXNetError(
                     "a model that declares its layers' caches is served "
@@ -701,7 +737,9 @@ class PagedGenerationEngine:
             placed = tuple(
                 jax.device_put(p.data()._data, dev) for p in params)
             self._pool_sharding = dev
-        self._params = _hold_weights(self._param_names, placed, dt_policy)
+        self._params = _hold_weights(
+            self._param_names, placed, dt_policy,
+            draft=int(cfg.get("draft_params", 0)))
         # what every dispatch is handed donated: the K and the V pool
         # or, for a model that declares its layers' caches, its one pool
         # of rows (no second one) and the arrays of per-slot state
@@ -720,6 +758,9 @@ class PagedGenerationEngine:
                        bytes=int(self._pool_k.nbytes) + state_bytes,
                        latent_rows_bytes=int(self._pool_k.nbytes),
                        state_bytes=state_bytes)
+                if self._self_draft:
+                    sp.set(draft_rows_bytes=int(self._pool_k.nbytes)
+                           * n_draft // len(row_layers))
             else:
                 self._pool_v = zeros(pool_shape, self._cache_dtype)
                 sp.set(shape=list(pool_shape),
@@ -747,6 +788,11 @@ class PagedGenerationEngine:
         self._spec_drafted = 0
         self._spec_accepted = 0
         self._spec_steps = 0
+        # self-drafting: the draft block's choice for every slot's next
+        # position, and a slot's drafts so far, one an emitted token
+        # (the block's choice at the row that token was chosen at)
+        self._draft_tok = np.zeros(self._slots, np.int32)
+        self._drafted = {}
         self._chunks_run = 0
         # block-diffusion: the schedule of every slot's open block on
         # the host, the blocks themselves on the device (tokens, which
@@ -895,7 +941,7 @@ class PagedGenerationEngine:
 
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
                      wpage, woff, lane_keys, block=None, lanes=None,
-                     state=()):
+                     state=(), draft=None):
             """The one paged dispatch: gather the pool rows of each
             slot's pages into each layer's linear cache (a view
             (B, H, S, dh) a layer of the token-major pool; for a model
@@ -933,7 +979,15 @@ class PagedGenerationEngine:
             (its state reads as zeros: admission costs no dispatch of
             its own), ``valid`` (B,) how many of its C positions count:
             0 for a slot no one is in, whose state the model leaves as
-            it was)."""
+            it was).
+
+            ``draft`` (self-drafting) = (``follow`` (B, C) the token
+            that follows each row, ``own`` (B, C) where that token is
+            the one this program samples at the row): the model's draft
+            block runs on the trunk's states and those tokens, its rows
+            go to the pool with the trunk's, and ``extras["draft"]``
+            (B, C) is its greedy choice a row, the draft of the position
+            two on."""
             Bc, C = tokens.shape
             if block is not None:
                 (b_tok, b_mask, b_at, b_conf), fresh, given, take, \
@@ -950,12 +1004,45 @@ class PagedGenerationEngine:
             rows = (page_table[:, :, None] * page
                     + jnp.arange(page, dtype=jnp.int32)).reshape((Bc, S))
 
+            def pick(logits):
+                """A token a position: the best one, or one drawn with
+                the position's own key."""
+                if scfg.greedy:
+                    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                pos_ids = start[:, None] + jnp.arange(C, dtype=jnp.int32)
+                keys = jax.vmap(jax.vmap(jax.random.fold_in))(
+                    jnp.broadcast_to(lane_keys[:, None, :], (Bc, C, 2)),
+                    pos_ids)
+                return jax.vmap(jax.vmap(
+                    lambda lg, kk: sample_logits(lg[None, :], kk,
+                                                 scfg)[0]))(logits, keys)
+
             def run():
                 if lanes is not None:
                     caches = read_declared(pool_k, state, page_table,
                                            rows, lanes)
                     res = net.chunk_forward(tokens, caches, start, lanes[2])
-                    return res[0]._data, res[1], dict(res[2])
+                    logits, kept, extras = res[0]._data, list(res[1]), \
+                        dict(res[2])
+                    hidden = extras.pop("hidden", None)
+                    if draft is not None:
+                        # the draft block, fed the tokens this very
+                        # program chose where the host could not know
+                        # them
+                        follow, own = draft
+                        extras["sampled"] = sampled = pick(
+                            _cast_logits(logits))
+                        dres = net.draft_forward(
+                            hidden, jnp.where(own, sampled, follow),
+                            caches[L], start, lanes[2])
+                        extras["draft"] = jnp.argmax(
+                            dres[0]._data, axis=-1).astype(jnp.int32)
+                        kept.append(dres[1])
+                        if "expert_load" in dres[2]:
+                            extras["expert_load"] = jnp.concatenate([
+                                extras["expert_load"],
+                                dres[2]["expert_load"]])
+                    return logits, kept, extras
                 if cache_rows:
                     caches = [(layer_rows(pool_k, li, page_table, rows),
                                layer_rows(pool_v, li, page_table, rows))
@@ -975,16 +1062,9 @@ class PagedGenerationEngine:
 
             logits, chunk_caches, extras = _traced(run, params_)
             logits = _cast_logits(logits)              # (B, C, V) f32
-            if scfg.greedy:
-                sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                pos_ids = start[:, None] + jnp.arange(C, dtype=jnp.int32)
-                keys = jax.vmap(jax.vmap(jax.random.fold_in))(
-                    jnp.broadcast_to(lane_keys[:, None, :], (Bc, C, 2)),
-                    pos_ids)
-                sampled = jax.vmap(jax.vmap(
-                    lambda lg, kk: sample_logits(lg[None, :], kk,
-                                                 scfg)[0]))(logits, keys)
+            sampled = extras.pop("sampled", None)
+            if sampled is None:
+                sampled = pick(logits)
             if block is not None:
                 # the confidence of each position's best token: its
                 # log-probability under the softmax, in float32
@@ -1141,7 +1221,10 @@ class PagedGenerationEngine:
         once, and nothing is compiled): for a model that declares its
         layers' caches, a slot ``{"position": the positions cached,
         "tokens": the ids at them, "layers": a layer its rows
-        (position, width) or the tuple of its state arrays}``, float32.
+        (position, width) or the tuple of its state arrays}``, float32;
+        under self-drafting the draft block's rows are the last layer's,
+        ``"drafts"`` is :meth:`drafted` and ``"next_token"`` the id after
+        the cached ones, which that block's last row was fed.
         The caches are as far as the launches MADE have brought them,
         so the ids of the launches still in flight are read too (and
         stay in flight: :meth:`decode_step` hands them out as it would
@@ -1161,7 +1244,7 @@ class PagedGenerationEngine:
             tok = self._page_table[slot, at // self._page_size] \
                 * self._page_size + at % self._page_size
             layers, rows_seen, state_seen = [], 0, 0
-            for kind in self.model_config["layer_caches"]:
+            for kind in self._layer_caches:
                 if "rows" in kind:
                     layers.append(pool[rows_seen * n_tokens + tok][
                         :, :int(kind["rows"])].astype(np.float32))
@@ -1175,7 +1258,23 @@ class PagedGenerationEngine:
             ids = self._history[slot] + flight.get(int(slot), [])
             out.append({"position": n, "tokens": ids[:n],
                         "layers": layers})
+            if self._self_draft:
+                # (the draft block's row of the last cached position was
+                # fed the id after it)
+                out[-1]["drafts"] = self.drafted(slot)
+                out[-1]["next_token"] = ids[n] if len(ids) > n else None
         return out
+
+    def drafted(self, slot):
+        """Under self-drafting, the draft block's choices for ``slot``'s
+        sequence so far, one an emitted token: entry ``j`` is what the
+        block chose, at the row the ``j``-th emitted token was chosen
+        at, for the token after it (the next step's draft where that row
+        was the step's last accepted one).  None for an engine that does
+        not draft from its model."""
+        if not self._self_draft:
+            return None
+        return list(self._drafted.get(slot, ()))
 
     def _tokens_in_flight(self):
         """``{slot: ids}`` that the launches not yet read give their
@@ -1333,7 +1432,7 @@ class PagedGenerationEngine:
             return
         row = self._page_table[slot]
         for i, h in enumerate(_prefix_page_hashes(
-                token_ids, self._page_size, limit)):
+                token_ids, self._page_size, limit, int(self._self_draft))):
             if h in self._prefix_map:
                 continue
             pg = int(row[i])
@@ -1368,8 +1467,10 @@ class PagedGenerationEngine:
         if self._prefix_share:
             limit = min((n - 1) // self._page_size,
                         self._pages_per_slot)
+            # (a draft block's row of a position depends on the token
+            # after it: a page is then the same only with that one too)
             hashes = _prefix_page_hashes(token_ids, self._page_size,
-                                         limit)
+                                         limit, int(self._self_draft))
             for h in hashes:
                 pg = self._prefix_map.get(h)
                 if pg is None:
@@ -1442,6 +1543,8 @@ class PagedGenerationEngine:
     def _prefill_chunk(self, slot, read):
         """:meth:`prefill_step`; with ``read`` a prompt's first token is
         waited for and returned, wherever else it goes."""
+        import jax
+
         if not self._pending:
             return None
         if slot is None:
@@ -1476,11 +1579,24 @@ class PagedGenerationEngine:
                     step.set(state_slots=1)
                     if fresh:
                         _telemetry.DECODE_STATE_RESETS.inc()
+            draft = None
+            if self._self_draft:
+                # the draft block runs over the prompt too: row i is fed
+                # prompt token i + 1, the last row of the last chunk the
+                # first token this program chooses
+                follow = np.zeros((1, self._chunk), np.int32)
+                own = np.zeros((1, self._chunk), bool)
+                ahead = toks[filled + 1:filled + 1 + count]
+                follow[0, :len(ahead)] = ahead
+                own[0, count - 1] = final
+                draft = (follow, own)
+                step.set(drafted=int(final))
             with _tracing.begin("engine.prefill:launch"):
-                sampled, logits, _extras = self._dispatch(
+                sampled, logits, extras = self._dispatch(
                     self._page_table[slot:slot + 1].copy(), chunk,
                     np.asarray([filled], np.int32), wpage, woff,
-                    self._lane_keys[slot:slot + 1].copy(), lanes=lanes)
+                    self._lane_keys[slot:slot + 1].copy(), lanes=lanes,
+                    draft=draft)
             if read:    # (`last_logits` are of a launch that was read)
                 self._last_logits = logits
             self._chunks_run += 1
@@ -1511,7 +1627,13 @@ class PagedGenerationEngine:
                     step.set(fed=1)
             if read:
                 with _tracing.begin("engine.prefill:readback"):
-                    tok = int(np.asarray(sampled)[0, count - 1])
+                    # one wait for the small arrays, not one each
+                    got, chose = jax.device_get(
+                        (sampled, extras.get("draft")))
+                    tok = int(got[0, count - 1])
+                    if self._self_draft:
+                        self._draft_tok[slot] = chose[0, count - 1]
+                        self._drafted[slot] = [int(chose[0, count - 1])]
                 self._cur_tok[slot] = tok
                 self._history[slot].append(tok)
             if self._prefix_share:
@@ -1673,25 +1795,40 @@ class PagedGenerationEngine:
         return outs
 
     def _decode_verify(self):
-        """The step under n-gram speculation, ``(slots, spec_k + 1)``:
-        the current token and the drafts of every active slot, launched
-        and read in one call."""
+        """The step under speculation, ``(slots, spec_k + 1)``: the
+        current token and the drafts of every active slot, launched and
+        read in one call.  The drafts come of the host's n-gram history
+        or, for a model with a draft block at ``spec_k`` 1, of the model
+        itself: the program that verifies a draft also runs the draft
+        block on both rows, each fed the token the trunk chose after
+        it, and hands back the block's choices; the one at the last
+        accepted row is the next step's draft.  Rows at and after the
+        new position, the trunk's and the draft block's, are overwritten
+        by the next step."""
+        import jax
+
         B, K = self._slots, self._spec_k
         cap = min(self._capacity, self.model_config["max_len"])
         active = [int(b) for b in np.nonzero(self._active)[0]]
         C = K + 1
+        source = "model" if self._self_draft else "ngram"
         with _tracing.begin("engine.decode", args={
                 "slots": len(active),
                 "live": int(self._pos[active].sum()), "fed": 0,
-                "attn": self._attends_in(C)}) as step:
+                "attn": self._attends_in(C), "draft": source}) as step:
             with _tracing.begin("engine.decode:prep"):
                 tokens = np.zeros((B, C), np.int32)
                 drafts = {}
                 for b in active:
                     tokens[b, 0] = self._cur_tok[b]
                     room = cap - 1 - int(self._pos[b])
-                    d = _ngram_draft(self._history[b], self._spec_ngram,
-                                     min(K, room)) if room > 0 else []
+                    if room <= 0:
+                        d = []
+                    elif self._self_draft:
+                        d = [int(self._draft_tok[b])]
+                    else:
+                        d = _ngram_draft(self._history[b],
+                                         self._spec_ngram, min(K, room))
                     drafts[b] = d
                     tokens[b, 1:1 + len(d)] = d
                 wpage = np.zeros(B * C, np.int32)
@@ -1705,14 +1842,31 @@ class PagedGenerationEngine:
                 key = self._lane_keys.copy()
                 table = self._page_table.copy()
                 pos = self._pos.astype(np.int32).copy()
+                lanes = draft = None
+                if self._declared:
+                    # (rows only: a model with per-slot state is refused
+                    # speculation when the engine is built)
+                    valid = np.zeros(B, np.int32)
+                    for b in active:
+                        valid[b] = len(drafts[b]) + 1
+                    lanes = (np.arange(B, dtype=np.int32),
+                             np.zeros(B, bool), valid)
+                if self._self_draft:
+                    # every row is followed by the token chosen at it
+                    draft = (np.zeros((B, C), np.int32),
+                             np.ones((B, C), bool))
             with _tracing.begin("engine.decode:launch"):
-                sampled, self._last_logits, _extras = self._dispatch(
-                    table, tokens, pos, wpage, woff, key)
+                sampled, self._last_logits, extras = self._dispatch(
+                    table, tokens, pos, wpage, woff, key, lanes=lanes,
+                    draft=draft)
             with _tracing.begin("engine.decode:readback"):
-                sampled = np.asarray(sampled)
+                # one wait for the small arrays, not one each
+                sampled, chose, load = jax.device_get((
+                    sampled, extras.get("draft"),
+                    extras.get("expert_load")))
             with _tracing.begin("engine.decode:post"):
                 out = {}
-                emitted_total = 0
+                emitted_total = drafted = accepted = 0
                 for b in active:
                     d = drafts[b]
                     acc = 0
@@ -1720,21 +1874,30 @@ class PagedGenerationEngine:
                         acc += 1
                     emitted = [int(t) for t in sampled[b, :acc + 1]]
                     if d:
-                        self._spec_drafted += len(d)
-                        self._spec_accepted += acc
+                        drafted += len(d)
+                        accepted += acc
                         self._spec_steps += 1
-                        _telemetry.DECODE_SPEC_DRAFTED.inc(len(d))
-                        _telemetry.DECODE_SPEC_ACCEPTED.inc(acc)
+                    if self._self_draft:
+                        self._draft_tok[b] = chose[b, acc]
+                        self._drafted[b].extend(
+                            int(t) for t in chose[b, :acc + 1])
                     out[b] = emitted
                     emitted_total += len(emitted)
                     self._cur_tok[b] = emitted[-1]
                     self._pos[b] += len(emitted)
                     self._read_pos[b] = self._pos[b]
                     self._history[b].extend(emitted)
+                self._spec_drafted += drafted
+                self._spec_accepted += accepted
+                _telemetry.DECODE_SPEC_DRAFTED.inc(drafted, source=source)
+                _telemetry.DECODE_SPEC_ACCEPTED.inc(accepted, source=source)
                 _telemetry.DECODE_TOKENS.inc(emitted_total)
                 _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
+                if load is not None:
+                    self._note_expert_load(step, load)
                 self._note_occupancy()
-            step.set(unread=0)
+            step.set(unread=0, drafted=drafted, accepted=accepted,
+                     emitted=emitted_total)
         _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
 
@@ -1874,7 +2037,7 @@ class PagedGenerationEngine:
         return out
 
     def _dispatch(self, page_table, tokens, start, wpage, woff, keys,
-                  block=None, lanes=None):
+                  block=None, lanes=None, draft=None):
         """Launch the one program on the engine's donated pools (and,
         for a model that declares its layers' caches, its per-slot
         state), keep what it hands back in their place, and return
@@ -1882,6 +2045,8 @@ class PagedGenerationEngine:
         more = (block,) if block is not None else ()
         if self._declared:
             more = (block, lanes, self._state)
+            if draft is not None:
+                more += (draft,)
         sampled, logits, self._pool_k, self._pool_v, extras = \
             self._jit_chunk(self._params, self._pool_k, self._pool_v,
                             page_table, tokens, start, wpage, woff, keys,
@@ -1924,6 +2089,7 @@ class PagedGenerationEngine:
             self._firsts = [f for f in self._firsts if f[0] != slot]
         self._pending.pop(slot, None)
         self._history.pop(slot, None)
+        self._drafted.pop(slot, None)
         self._active[slot] = False
         self._pos[slot] = 0
         self._serial[slot] += 1
@@ -1994,6 +2160,9 @@ class PagedGenerationEngine:
         if self._declared:
             args += (None, (np.zeros(nb, np.int32), np.zeros(nb, bool),
                             np.zeros(nb, np.int32)), self._state)
+            if self._self_draft:
+                args += ((np.zeros((nb, nc), np.int32),
+                          np.zeros((nb, nc), bool)),)
         return args
 
 
@@ -2007,7 +2176,9 @@ class GenerationResult(dict):
     ``ttft_s`` (submit -> first token); under block-diffusion decoding
     also ``fixed_at``, the denoise pass (1..T) of its block at which
     each token was fixed, and ``confidence``, the log-probability the
-    model gave the token in that pass."""
+    model gave the token in that pass; under self-drafting ``drafts``,
+    what the model's draft block chose to follow each token
+    (:meth:`PagedGenerationEngine.drafted`)."""
 
     @property
     def tokens(self):
@@ -2280,6 +2451,10 @@ class TokenServer:
         if req.fixed_at:
             result["fixed_at"] = list(req.fixed_at)
             result["confidence"] = list(req.confidence)
+        drafts = self._engine.drafted(req.slot) \
+            if req.slot is not None else None
+        if drafts is not None:
+            result["drafts"] = drafts[:len(req.out)]
         if req.future._resolve(result=result):
             self._emit_event(req, outcome="ok", reason=reason)
 

@@ -24,12 +24,20 @@ sigmoid(W_g x))`` with one gate a head.  No positions.  What a KDA
 layer carries from one dispatch to the next is per sequence, not per
 token: ``S`` and the last ``conv_kernel - 1`` inputs of the convolution.
 
-**MLA** (no query latent): ``q = W_q x`` as heads of ``(d_nope |
-d_rope)``; ``[c | k_r] = W_dkv x`` (``d_latent | d_rope``), ``c~ =
-RMSNorm(c)``; ``[k_nope | v]_h = W_ukv c~``; rotary positions
-(interleaved pairs) on ``q_r`` and on the one ``k_r`` all heads share;
-``softmax((q_nope . k_nope + q_r . k_r) / sqrt(d_nope + d_rope)) v``,
-causal; the same head-wise sigmoid gate; ``W_o``.  **Cached a token:
+**MLA**: ``q = W_q x`` as heads of ``(d_nope | d_rope)``, or with a
+query latent (``q_latent``; DeepSeek-V3, arXiv:2412.19437, section
+2.1.1) ``q = W_qb RMSNorm(W_qa x)``; ``[c | k_r] = W_dkv x``
+(``d_latent | d_rope``), ``c~ = RMSNorm(c)``; ``[k_nope | v]_h = W_ukv
+c~`` with values of ``d_v_mla`` channels a head (``d_v`` without it);
+rotary positions (interleaved pairs) on ``q_r`` and on the one ``k_r``
+all heads share, at plain frequencies ``theta^(-2i/d_rope)`` or, with
+``rope_scaling`` of type ``yarn``, at YaRN's (:func:`yarn_corners`:
+frequency ``i`` is ``f_i (1 - ramp_i) + f_i / factor * ramp_i``, the
+ramp rising from pair ``lo`` to pair ``hi``);
+``softmax((q_nope . k_nope + q_r . k_r) * s) v``, causal, ``s = (d_nope
++ d_rope)^-1/2``, times YaRN's ``m^2`` (``m = 0.1 mscale_all_dim
+ln(factor) + 1``) under ``rope_scaling``; the same head-wise sigmoid
+gate (``mla_gate``; DeepSeek-V3 has none); ``W_o``.  **Cached a token:
 the row ``[c~ | rope(k_r)]``, ``d_latent + d_rope`` values, and nothing
 else.**  The up-projection is absorbed: ``q_nope W_uk`` (a query in
 the latent space) beside ``rope(q_r)`` attends the cached rows as they
@@ -45,11 +53,26 @@ the chip a chunk of 512 against 9216 rows takes 46.0 ms absorbed and
 experts' part.  The shared expert is the block's own: every token
 takes it, whoever holds which routed expert.
 
+**The draft block** (``draft_layers`` = 1; DeepSeek-V3's
+multi-token-prediction module, section 2.2): for position ``i`` with
+the trunk's last block output ``h_i`` (before the final norm) and the
+token that follows, ``t_{i+1}``: ``u_i = W_eh [RMSNorm_h(h_i) ;
+RMSNorm_e(Emb(t_{i+1}))]`` (``2 D -> D``), one more block of the kind of
+the trunk's last (its MLA layer caching rows of its own, at position
+``i``), its own final norm, the trunk's embedding and head: the logits
+of position ``i + 2``.  :meth:`chunk_forward` hands the trunk's ``h``
+back (``extras["hidden"]``) and :meth:`draft_forward` runs the block,
+so that a serving engine can feed it the tokens its own sampling chose
+in the same program (``generate.PagedGenerationEngine``,
+"self-drafting").
+
 The model speaks the chunk protocol of ``mxnet_tpu.generate`` and
 declares, a layer, what it caches (``config["layer_caches"]``): a KDA
 layer per-slot state (``S`` and the convolution's tail), an MLA layer
-paged rows of ``d_latent + d_rope`` values.  Parameters are registered
-in one flat list, layer by layer; every matrix is stored ``(out, in)``,
+paged rows of ``d_latent + d_rope`` values; the draft block's entry
+follows the trunk's (``config["draft_layers"]`` says how many there
+are).  Parameters are registered in one flat list, layer by layer, the
+draft block's after the head; every matrix is stored ``(out, in)``,
 the routed experts side by side as ``MoEDecoderLM`` stores them.
 ``dtype`` is the type the embedding and the matrices are stored in;
 norm weights, ``A_log``, ``dt_bias``, the router's selection bias and
@@ -60,19 +83,57 @@ from __future__ import annotations
 from ...block import HybridBlock
 from .moe_decoder import _rms
 
-__all__ = ["HybridDecoderLM"]
+__all__ = ["HybridDecoderLM", "yarn_corners", "yarn_mscale"]
 
 
-def _rope_pairs(x, pos, theta):
+def yarn_corners(theta, d_rope, original_max, beta_fast, beta_slow):
+    """The pairs between which YaRN's ramp rises (Peng et al.,
+    arXiv:2309.00071, as DeepSeek-V3 applies it): pair ``i`` of
+    ``d_rope / 2`` turns ``original_max f_i / 2 pi`` times over the
+    original context, and ``d(r) = d_rope ln(original_max / (2 pi r)) /
+    (2 ln theta)`` is the pair that turns ``r`` times.  Pairs below
+    ``lo = floor(d(beta_fast))`` keep their frequency, pairs above
+    ``hi = ceil(d(beta_slow))`` are slowed by ``factor``; both clipped
+    to ``[0, d_rope / 2 - 1]``."""
+    import math
+
+    def pair(turns):
+        return d_rope * math.log(original_max / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    last = d_rope // 2 - 1
+    lo = min(max(math.floor(pair(beta_fast)), 0), last)
+    hi = min(max(math.ceil(pair(beta_slow)), 0), last)
+    return lo, hi
+
+
+def yarn_mscale(factor, mscale):
+    """``0.1 mscale ln(factor) + 1`` (1 for no scaling)."""
+    import math
+
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_pairs(x, pos, theta, yarn=None):
     """Rotary positions over interleaved pairs ``(x[2i], x[2i+1])``.
-    x (B, C, ..., d) float32, pos (B, C) int32."""
+    x (B, C, ..., d) float32, pos (B, C) int32.  ``yarn`` = ``(factor,
+    lo, hi, gain)``: the frequencies of pairs from ``lo`` to ``hi``
+    ramp down to ``1 / factor`` of their own, cos and sin times
+    ``gain``."""
     import jax.numpy as jnp
 
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn is not None:
+        factor, lo, hi, gain = yarn
+        ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - lo)
+                        / max(hi - lo, 1e-3), 0.0, 1.0)
+        inv = inv * (1.0 - ramp) + inv / factor * ramp
     ang = pos.astype(jnp.float32)[:, :, None] * inv           # (B, C, d/2)
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None and gain != 1.0:
+        cos, sin = cos * gain, sin * gain
     x0, x1 = x[..., 0::2], x[..., 1::2]
     return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
                      axis=-1).reshape(x.shape)
@@ -91,22 +152,32 @@ def _gated_mlp(x, wg, wu, wd):
     return _mm(jax.nn.silu(_mm(x, wg)) * _mm(x, wu), wd)
 
 
+def _raw(params):
+    return [q.data()._data for q in params]
+
+
 class HybridDecoderLM(HybridBlock):
     """Token ids (batch, seq) -> logits (batch, seq, vocab).
 
-    ``mixers`` / ``ffns`` give every layer's kind.  ``experts_held`` =
-    ``(first, count)`` makes this instance hold only those routed
-    experts of every expert layer (the chip's share of an
-    expert-parallel deployment); the shared expert is always held.
+    ``mixers`` / ``ffns`` give every layer's kind; the sizes of a kind
+    no layer has may be left out.  ``experts_held`` = ``(first,
+    count)`` makes this instance hold only those routed experts of
+    every expert layer (the chip's share of an expert-parallel
+    deployment); the shared expert is always held.  ``q_latent``,
+    ``d_v_mla``, ``mla_gate`` and ``rope_scaling`` (a published
+    ``rope_scaling`` group of type ``yarn``) shape the MLA layers;
+    ``draft_layers`` = 1 adds the draft block.
     """
 
     def __init__(self, vocab_size, d_model, mixers, ffns, n_heads,
-                 d_k, d_v, conv_kernel, kda_lower_bound,
-                 d_nope, d_rope, d_latent, d_ff, n_experts, top_k,
-                 d_expert, n_group, topk_group, routed_scaling,
+                 d_k=None, d_v=None, conv_kernel=None, kda_lower_bound=None,
+                 d_nope=None, d_rope=None, d_latent=None, d_ff=None,
+                 n_experts=None, top_k=None, d_expert=None, n_group=None,
+                 topk_group=None, routed_scaling=1.0,
                  norm_topk=True, max_len=262144, rope_theta=6e6,
                  rms_eps=1e-6, experts_held=None, dtype="float32",
-                 **kwargs):
+                 q_latent=None, d_v_mla=None, mla_gate=True,
+                 rope_scaling=None, draft_layers=0, **kwargs):
         super().__init__(**kwargs)
         mixers, ffns = list(mixers), list(ffns)
         if len(mixers) != len(ffns) or not mixers:
@@ -116,90 +187,167 @@ class HybridDecoderLM(HybridBlock):
             if set(kinds) - set(known):
                 raise ValueError("layer kinds %r are not of %r"
                                  % (kinds, known))
-        first, held = experts_held if experts_held is not None \
-            else (0, n_experts)
-        if not (0 <= first and held >= 1 and first + held <= n_experts):
-            raise ValueError("experts_held %r outside [0, %d)"
-                             % (experts_held, n_experts))
-        if n_experts % n_group:
-            raise ValueError("n_experts (%d) must divide by n_group (%d)"
-                             % (n_experts, n_group))
+        needs = {"kda": dict(d_k=d_k, d_v=d_v, conv_kernel=conv_kernel,
+                             kda_lower_bound=kda_lower_bound),
+                 "mla": dict(d_nope=d_nope, d_rope=d_rope, d_latent=d_latent,
+                             d_v=d_v_mla if d_v_mla is not None else d_v),
+                 "dense": dict(d_ff=d_ff),
+                 "moe": dict(n_experts=n_experts, top_k=top_k,
+                             d_expert=d_expert, n_group=n_group,
+                             topk_group=topk_group)}
+        for kind in set(mixers) | set(ffns):
+            lacks = [k for k, v in needs[kind].items() if v is None]
+            if lacks:
+                raise ValueError("a %r layer needs %s" % (kind, lacks))
+        draft_layers = int(draft_layers)
+        if draft_layers not in (0, 1) or (
+                draft_layers and mixers[-1] != "mla"):
+            raise ValueError(
+                "draft_layers is 0 or 1, a block of the kind of the trunk's "
+                "last, whose mixer must cache rows (mla), got %r after %r"
+                % (draft_layers, mixers[-1]))
+        moe = "moe" in ffns
+        if moe:
+            first, held = experts_held if experts_held is not None \
+                else (0, n_experts)
+            if not (0 <= first and held >= 1
+                    and first + held <= n_experts):
+                raise ValueError("experts_held %r outside [0, %d)"
+                                 % (experts_held, n_experts))
+            if n_experts % n_group:
+                raise ValueError(
+                    "n_experts (%d) must divide by n_group (%d)"
+                    % (n_experts, n_group))
+        else:
+            first, held = 0, 0
         self._mixers, self._ffns = mixers, ffns
         self._first = int(first)
         self._theta, self._eps = float(rope_theta), float(rms_eps)
-        self._lower = float(kda_lower_bound)
-        self._route = dict(n_group=int(n_group), topk_group=int(topk_group),
+        self._lower = None if kda_lower_bound is None \
+            else float(kda_lower_bound)
+        self._route = dict(n_group=n_group, topk_group=topk_group,
                            scaling=float(routed_scaling),
                            norm_topk=bool(norm_topk))
-        D, H, F, K = d_model, n_heads, d_expert, int(conv_kernel)
-        wide = H * (2 * d_k + d_v)       # q, k, v channels of a KDA layer
+        D, H, F = d_model, n_heads, d_expert
+        K = None if conv_kernel is None else int(conv_kernel)
+        wide = None if d_k is None else H * (2 * d_k + d_v)  # a KDA layer's
+        dvm = d_v_mla if d_v_mla is not None else d_v        # q, k, v
         self._sizes = dict(H=H, dk=d_k, dv=d_v, K=K, wide=wide,
-                           dn=d_nope, dr=d_rope, dl=d_latent)
+                           dn=d_nope, dr=d_rope, dl=d_latent, dvm=dvm,
+                           ql=q_latent)
+        self._gate = bool(mla_gate)
+        # the frequency table and the score scale of the MLA layers
+        self._yarn, self._scale = None, None
+        if "mla" in mixers:
+            self._scale = (d_nope + d_rope) ** -0.5
+            if rope_scaling is not None:
+                kind = rope_scaling.get("rope_type",
+                                        rope_scaling.get("type"))
+                if kind != "yarn":
+                    raise ValueError("rope_scaling of type %r is not built"
+                                     % (kind,))
+                factor = float(rope_scaling["factor"])
+                lo, hi = yarn_corners(
+                    self._theta, d_rope,
+                    rope_scaling["original_max_position_embeddings"],
+                    rope_scaling.get("beta_fast", 32),
+                    rope_scaling.get("beta_slow", 1))
+                all_dim = rope_scaling.get("mscale_all_dim", 0)
+                self._yarn = (factor, lo, hi, yarn_mscale(
+                    factor, rope_scaling.get("mscale", 1))
+                    / yarn_mscale(factor, all_dim))
+                if all_dim:
+                    self._scale *= yarn_mscale(factor, all_dim) ** 2
         # what a layer keeps between dispatches: the engine allocates
         # it (generate.PagedGenerationEngine, "layer_caches")
         caches = [{"state": [((H, d_k, d_v), "float32"),
                              ((K - 1, wide), None)]} if m == "kda"
-                  else {"rows": d_latent + d_rope} for m in mixers]
+                  else {"rows": d_latent + d_rope}
+                  for m in mixers + mixers[-1:] * draft_layers]
         self._cfg = dict(
             vocab_size=vocab_size, d_model=D, n_heads=H,
-            n_layers=len(mixers), max_len=max_len, n_experts=n_experts,
-            top_k=top_k, d_expert=F, experts_held=(int(first), int(held)),
-            layer_caches=caches)
+            n_layers=len(mixers), max_len=max_len, layer_caches=caches)
+        if moe:
+            self._cfg.update(n_experts=n_experts, top_k=top_k, d_expert=F,
+                             experts_held=(int(first), int(held)))
 
         def get(name, shape, stored=dtype):
             return self.params.get(name, shape=shape, dtype=stored)
 
+        def block(h, mix, ffn):
+            """One block's parameters, registered under the prefix
+            ``h``: (mixer norm, mixer, feed-forward norm, feed-forward)."""
+            norm_mix = get(h + "attn_norm_gamma", (D,), "float32")
+            if mix == "kda":
+                mixer = [
+                    get(h + "proj_qkv_weight", (wide, D)),
+                    get(h + "conv_weight", (K, wide)),
+                    get(h + "decay_weight", (H * d_k, D)),
+                    get(h + "decay_a_log", (H,), "float32"),
+                    get(h + "decay_dt_bias", (H * d_k,), "float32"),
+                    get(h + "beta_weight", (H, D)),
+                    get(h + "gate_weight", (H, D)),
+                    get(h + "o_norm_gamma", (d_v,), "float32"),
+                    get(h + "attn_out_weight", (D, H * d_v))]
+            else:
+                q_in = D
+                mixer = []
+                if q_latent is not None:
+                    mixer += [get(h + "q_down_weight", (q_latent, D)),
+                              get(h + "q_norm_gamma", (q_latent,),
+                                  "float32")]
+                    q_in = q_latent
+                mixer += [
+                    get(h + "proj_q_weight", (H * (d_nope + d_rope), q_in)),
+                    get(h + "kv_down_weight", (d_latent + d_rope, D)),
+                    get(h + "kv_norm_gamma", (d_latent,), "float32"),
+                    get(h + "kv_up_weight", (H * (d_nope + dvm), d_latent))]
+                if self._gate:
+                    mixer.append(get(h + "gate_weight", (H, D)))
+                mixer.append(get(h + "attn_out_weight", (D, H * dvm)))
+            norm_ffn = get(h + "ffn_norm_gamma", (D,), "float32")
+            if ffn == "dense":
+                feed = [get(h + "ffn_gate_weight", (d_ff, D)),
+                        get(h + "ffn_up_weight", (d_ff, D)),
+                        get(h + "ffn_down_weight", (D, d_ff))]
+            else:
+                feed = [
+                    get(h + "router_weight", (n_experts, D)),
+                    get(h + "router_bias", (n_experts,), "float32"),
+                    get(h + "experts_gate_weight", (D, held * F)),
+                    get(h + "experts_up_weight", (D, held * F)),
+                    get(h + "experts_down_weight", (held * F, D)),
+                    get(h + "shared_gate_weight", (F, D)),
+                    get(h + "shared_up_weight", (F, D)),
+                    get(h + "shared_down_weight", (D, F))]
+            return norm_mix, mixer, norm_ffn, feed
+
         with self.name_scope():
             self._embed = get("embed_weight", (vocab_size, D))
-            self._layers = []
-            for i, (mix, ffn) in enumerate(zip(mixers, ffns)):
-                h = "h%d_" % i
-                norm_mix = get(h + "attn_norm_gamma", (D,), "float32")
-                if mix == "kda":
-                    mixer = [
-                        get(h + "proj_qkv_weight", (wide, D)),
-                        get(h + "conv_weight", (K, wide)),
-                        get(h + "decay_weight", (H * d_k, D)),
-                        get(h + "decay_a_log", (H,), "float32"),
-                        get(h + "decay_dt_bias", (H * d_k,), "float32"),
-                        get(h + "beta_weight", (H, D)),
-                        get(h + "gate_weight", (H, D)),
-                        get(h + "o_norm_gamma", (d_v,), "float32"),
-                        get(h + "attn_out_weight", (D, H * d_v))]
-                else:
-                    mixer = [
-                        get(h + "proj_q_weight", (H * (d_nope + d_rope), D)),
-                        get(h + "kv_down_weight", (d_latent + d_rope, D)),
-                        get(h + "kv_norm_gamma", (d_latent,), "float32"),
-                        get(h + "kv_up_weight",
-                            (H * (d_nope + d_v), d_latent)),
-                        get(h + "gate_weight", (H, D)),
-                        get(h + "attn_out_weight", (D, H * d_v))]
-                norm_ffn = get(h + "ffn_norm_gamma", (D,), "float32")
-                if ffn == "dense":
-                    feed = [get(h + "ffn_gate_weight", (d_ff, D)),
-                            get(h + "ffn_up_weight", (d_ff, D)),
-                            get(h + "ffn_down_weight", (D, d_ff))]
-                else:
-                    feed = [
-                        get(h + "router_weight", (n_experts, D)),
-                        get(h + "router_bias", (n_experts,), "float32"),
-                        get(h + "experts_gate_weight", (D, held * F)),
-                        get(h + "experts_up_weight", (D, held * F)),
-                        get(h + "experts_down_weight", (held * F, D)),
-                        get(h + "shared_gate_weight", (F, D)),
-                        get(h + "shared_up_weight", (F, D)),
-                        get(h + "shared_down_weight", (D, F))]
-                self._layers.append((norm_mix, mixer, norm_ffn, feed))
+            self._layers = [block("h%d_" % i, mix, ffn)
+                            for i, (mix, ffn) in enumerate(zip(mixers, ffns))]
             self._final = get("final_norm_gamma", (D,), "float32")
             self._head = get("head_weight", (vocab_size, D), "float32")
+            registered = len(self.params.keys())
+            self._draft = None
+            if draft_layers:
+                self._draft = (
+                    get("mtp_hnorm_gamma", (D,), "float32"),
+                    get("mtp_enorm_gamma", (D,), "float32"),
+                    get("mtp_proj_weight", (D, 2 * D)),
+                    block("mtp_", mixers[-1], ffns[-1]),
+                    get("mtp_final_norm_gamma", (D,), "float32"))
+            # the draft block and how many of the parameters, the last
+            # registered, are its own
+            self._cfg.update(
+                draft_layers=draft_layers,
+                draft_params=len(self.params.keys()) - registered)
 
     @property
     def config(self):
         return dict(self._cfg)
 
     # -- the mixers --------------------------------------------------------
-
     def _kda(self, n, p, cache, valid):
         """The KDA mixer on normed states ``n`` (B, C, D).  ``cache``
         is ``(S (B, H, dk, dv) float32, tail (B, K-1, wide))``;
@@ -264,23 +412,32 @@ class HybridDecoderLM(HybridBlock):
 
         from ....ops.attention_rows import _softmax_pair
 
-        wq, wdkv, g_kv, wukv, wgate, wo = p
         z = self._sizes
-        H, dn, dr, dl, dv = z["H"], z["dn"], z["dr"], z["dl"], z["dv"]
+        H, dn, dr, dl, dv = z["H"], z["dn"], z["dr"], z["dl"], z["dvm"]
+        p = list(p)
+        wo = p.pop()
+        wgate = p.pop() if self._gate else None
+        wdkv, g_kv, wukv = p[-3:]
+        wq = p[-4]
         B, C, _D = n.shape
         f32, act = jnp.float32, wq.dtype
-        q = _mm(n, wq).reshape((B, C, H, dn + dr))
+        if z["ql"] is not None:       # the query's own latent, normed
+            wqa, g_q = p[:2]
+            n_q = _rms(_mm(n, wqa), g_q, self._eps).astype(act)
+        else:
+            n_q = n
+        q = _mm(n_q, wq).reshape((B, C, H, dn + dr))
         q_nope = q[..., :dn]
-        q_r = _rope_pairs(q[..., dn:].astype(f32), pos, self._theta) \
-            .astype(act)
+        q_r = _rope_pairs(q[..., dn:].astype(f32), pos, self._theta,
+                          self._yarn).astype(act)
         down = _mm(n, wdkv)                                 # (B, C, dl+dr)
         c = _rms(down[..., :dl], g_kv, self._eps).astype(act)
-        k_r = _rope_pairs(down[..., dl:].astype(f32), pos, self._theta) \
-            .astype(act)
+        k_r = _rope_pairs(down[..., dl:].astype(f32), pos, self._theta,
+                          self._yarn).astype(act)
         new = jnp.concatenate([c, k_r], axis=-1)            # (B, C, dl+dr)
         up = wukv.reshape((H, dn + dv, dl))
         w_uk, w_uv = up[:, :dn], up[:, dn:]                 # (H, dn|dv, dl)
-        scale = (dn + dr) ** -0.5
+        scale = self._scale
         causal = jnp.tril(jnp.ones((C, C), bool))[None, None]
         cached = rows is not None
         if cached:
@@ -312,7 +469,8 @@ class HybridDecoderLM(HybridBlock):
                 jnp.where(causal, s_new, -1e30), -1).astype(act)
             ctx = dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
         o = dot("bchl,hdl->bchd", ctx.astype(act), w_uv)
-        o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
+        if wgate is not None:
+            o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
         return _mm(o.reshape((B, C, H * dv)), wo), new
 
     def _moe(self, m, p):
@@ -330,49 +488,60 @@ class HybridDecoderLM(HybridBlock):
 
     # -- the one forward ---------------------------------------------------
 
+    def _block(self, x, params, mix, ffn, cache, start, valid, pos):
+        """One block on the stream ``x`` (B, C, D): (the stream after
+        it, what its mixer keeps, its expert layer's counts or None).
+        ``cache`` None: a whole sequence from nothing."""
+        import jax.numpy as jnp
+
+        z = self._sizes
+        B, C, _D = x.shape
+        act = x.dtype
+        norm_mix, mixer, norm_ffn, feed = params
+        n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
+        if mix == "kda":
+            if cache is None:
+                cache = (
+                    jnp.zeros((B, z["H"], z["dk"], z["dv"]), jnp.float32),
+                    jnp.zeros((B, z["K"] - 1, z["wide"]), act))
+            out, kept = self._kda(n, _raw(mixer), cache, valid)
+        else:
+            out, kept = self._mla(n, _raw(mixer), cache, start, pos)
+        x = x + out.astype(act)
+        m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
+        counts = None
+        if ffn == "dense":
+            y = _gated_mlp(m, *_raw(feed))
+        else:
+            y, counts = self._moe(m.reshape((B * C, -1)), _raw(feed))
+            y = y.reshape((B, C, -1))
+        return x + y.astype(act), kept, counts
+
     def _run(self, tokens, caches, start, valid):
         """tokens (B, C) int; caches a list with, a layer, its state
         ``(S, tail)`` (KDA) or its cached rows (B, S, dl+dr) (MLA), or
         None (a whole sequence from nothing); start, valid (B,) int32.
         Returns (logits raw (B, C, V), a layer's new state or the
-        chunk's new rows, expert load (expert layers, E) int32)."""
+        chunk's new rows, expert load (expert layers, E) int32, the
+        last block's output (B, C, D))."""
         import jax.numpy as jnp
 
-        z = self._sizes
-        B, C = tokens.shape
+        C = tokens.shape[1]
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
         x = jnp.take(self._embed.data()._data, tokens, axis=0)
-        act = x.dtype
         new, loads = [], []
-        def raw(params):
-            return [q.data()._data for q in params]
-
         for li, (mix, ffn) in enumerate(zip(self._mixers, self._ffns)):
-            norm_mix, mixer, norm_ffn, feed = self._layers[li]
-            n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
-            if mix == "kda":
-                cache = caches[li] if caches is not None else (
-                    jnp.zeros((B, z["H"], z["dk"], z["dv"]), jnp.float32),
-                    jnp.zeros((B, z["K"] - 1, z["wide"]), act))
-                out, kept = self._kda(n, raw(mixer), cache, valid)
-            else:
-                out, kept = self._mla(
-                    n, raw(mixer),
-                    caches[li] if caches is not None else None, start, pos)
+            x, kept, counts = self._block(
+                x, self._layers[li], mix, ffn,
+                caches[li] if caches is not None else None, start, valid,
+                pos)
             new.append(kept)
-            x = x + out.astype(act)
-            m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
-            if ffn == "dense":
-                y = _gated_mlp(m, *raw(feed))
-            else:
-                y, counts = self._moe(m.reshape((B * C, -1)), raw(feed))
-                y = y.reshape((B, C, -1))
+            if counts is not None:
                 loads.append(counts)
-            x = x + y.astype(act)
         head = self._head.data()._data
         h = _rms(x, self._final.data()._data, self._eps).astype(head.dtype)
         load = jnp.stack(loads) if loads else None
-        return jnp.dot(h, head.T), new, load
+        return jnp.dot(h, head.T), new, load, x
 
     def hybrid_forward(self, F, tokens, **_registered):
         import jax.numpy as jnp
@@ -381,7 +550,7 @@ class HybridDecoderLM(HybridBlock):
 
         ids = tokens._data.astype(jnp.int32)
         B, T = ids.shape
-        logits, _new, _load = self._run(
+        logits, _new, _load, _x = self._run(
             ids, None, jnp.zeros((B,), jnp.int32),
             jnp.full((B,), T, jnp.int32))
         return NDArray(logits)
@@ -395,17 +564,57 @@ class HybridDecoderLM(HybridBlock):
         is, for a layer with state, the tuple of its arrays ``(B, ...)``
         as the sequence left them, and for a layer with paged rows the
         rows of positions ``< start_b``, (B, S, lanes) with ``lanes``
-        the declared width padded with zeros to whole tiles of 128.
+        the declared width padded with zeros to whole tiles of 128
+        (entries past the trunk's layers, the draft block's, are left
+        alone).
         Returns
         ``(logits NDArray (B, C, V), a list with, a layer, the state
         after the valid positions or the chunk's rows (B, C, width),
-        {"expert_load": (expert layers, experts) int32})``."""
+        {"expert_load": (expert layers, experts) int32})``; a model
+        with a draft block adds ``"hidden"``, the last block's output
+        (B, C, D), which :meth:`draft_forward` takes."""
         import jax.numpy as jnp
 
         from ....ndarray import NDArray
 
-        logits, new, load = self._run(
+        logits, new, load, x = self._run(
             tokens.astype(jnp.int32), caches, start.astype(jnp.int32),
             valid.astype(jnp.int32))
         extras = {} if load is None else {"expert_load": load}
+        if self._draft is not None:
+            extras["hidden"] = x
         return NDArray(logits), new, extras
+
+    def draft_forward(self, hidden, follow, rows, start, valid):
+        """The draft block on the trunk's ``hidden`` (B, C, D) of
+        positions ``start_b ..`` and ``follow`` (B, C) int32, the token
+        that follows each (position ``i``'s is token ``i + 1``), against
+        the block's own cached ``rows`` (as :meth:`chunk_forward` takes
+        a layer's; None: a whole sequence from nothing).  Returns
+        ``(logits NDArray (B, C, V) of positions i + 2, the chunk's rows
+        (B, C, width), {"expert_load": (1, experts)})``."""
+        import jax.numpy as jnp
+
+        from ....ndarray import NDArray
+
+        if self._draft is None:
+            raise ValueError("the model was built without a draft block "
+                             "(draft_layers=0)")
+        g_h, g_e, w_eh, block, g_out = self._draft
+        start = start.astype(jnp.int32)
+        C = follow.shape[1]
+        pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
+        emb = jnp.take(self._embed.data()._data, follow.astype(jnp.int32),
+                       axis=0)
+        act = emb.dtype
+        u = _mm(jnp.concatenate([
+            _rms(hidden, g_h.data()._data, self._eps).astype(act),
+            _rms(emb, g_e.data()._data, self._eps).astype(act)], -1),
+            w_eh.data()._data)
+        x, kept, counts = self._block(
+            u, block, self._mixers[-1], self._ffns[-1], rows, start,
+            valid.astype(jnp.int32), pos)
+        head = self._head.data()._data
+        h = _rms(x, g_out.data()._data, self._eps).astype(head.dtype)
+        extras = {} if counts is None else {"expert_load": counts[None]}
+        return NDArray(jnp.dot(h, head.T)), kept, extras

@@ -95,10 +95,12 @@ class TelemetryHeartbeat:
             if lookups > 0:
                 parts.append("prefix_hit %.0f%%" % (
                     100.0 * t.DECODE_PREFIX_HIT_TOKENS.value() / lookups))
-            drafted = t.DECODE_SPEC_DRAFTED.value()
+            drafted, accepted = (
+                sum(c.value(source=s) for s in ("ngram", "model"))
+                for c in (t.DECODE_SPEC_DRAFTED, t.DECODE_SPEC_ACCEPTED))
             if drafted > 0:
                 parts.append("spec_accept %.0f%%" % (
-                    100.0 * t.DECODE_SPEC_ACCEPTED.value() / drafted))
+                    100.0 * accepted / drafted))
         # gateway tier (omitted until the HTTP front end has served):
         # live streams plus the shed rate — the two numbers that say
         # whether the wire is healthy or dumping load

@@ -912,13 +912,14 @@ DECODE_PREFILL_CHUNKS = counter(
     "lanes).")
 DECODE_SPEC_DRAFTED = counter(
     "mxnet_tpu_decode_spec_drafted_total",
-    "Tokens drafted by the n-gram speculator and carried into verify "
-    "steps.")
+    "Tokens drafted and carried into verify steps, by source: the "
+    "host's n-gram speculator (ngram) or the model's own draft block "
+    "(model).", ("source",))
 DECODE_SPEC_ACCEPTED = counter(
     "mxnet_tpu_decode_spec_accepted_total",
-    "Drafted tokens accepted by exact-match verification (acceptance "
-    "rate = this over drafted; each accepted token is one decode "
-    "dispatch saved).")
+    "Drafted tokens accepted by exact-match verification, by source "
+    "(acceptance rate = this over drafted; each accepted token is one "
+    "decode dispatch saved).", ("source",))
 DECODE_STATE_RESETS = counter(
     "mxnet_tpu_decode_state_resets_total",
     "Sequences whose per-slot recurrent state was started from zero (in "
@@ -1309,8 +1310,10 @@ def statusz():
             "spec_accept_rate": (lambda acc, drafted:
                                  round(acc / drafted, 4)
                                  if drafted else None)(
-                DECODE_SPEC_ACCEPTED.value(),
-                DECODE_SPEC_DRAFTED.value()),
+                sum(DECODE_SPEC_ACCEPTED.value(source=s)
+                    for s in ("ngram", "model")),
+                sum(DECODE_SPEC_DRAFTED.value(source=s)
+                    for s in ("ngram", "model"))),
             # block-diffusion decoding: passes are not tokens
             "denoise_passes": DECODE_DENOISE_PASSES.value(),
             "commit_passes": DECODE_COMMIT_PASSES.value(),

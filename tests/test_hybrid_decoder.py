@@ -285,19 +285,44 @@ def test_absorbed_mla_matches_expanded(cfg, model):
     assert np.abs(np.asarray(new) - np.asarray(rows[:, 40:])).max() < 1e-5
 
 
-def test_four_shares_add_up_to_the_uncut_layer(cfg):
-    """The share test.  An expert layer of 16 routed experts held as 4
-    shares of 4: each share's part through ``routed_experts`` (the
-    router over all 16 every time), summed, plus the shared expert ONCE,
-    is the uncut layer of the reference; and one share's part is the
-    reference's for the same ``held``."""
+def _deepseek_small():
+    """The ``deepseek_v3`` reference and its configuration at the
+    rehearsal's widths, its router as published (256 experts, 8 groups
+    of which 4, 8 a token, 2.5)."""
+    from benchmark.lib.reference import deepseek_v3
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "gigachat3.1-702b-a36b.json")) as f:
+        c = json.load(f)
+    small = dict(c, **c["rehearsal"])
+    small.update({k: c[k] for k in ("n_group", "topk_group",
+                                    "num_experts_per_tok")})
+    return deepseek_v3, small
+
+
+@pytest.mark.parametrize("family, E, share", [
+    ("bailing_hybrid", 16, 4), ("deepseek_v3", 256, 16)])
+def test_the_shares_add_up_to_the_uncut_layer(cfg, family, E, share):
+    """The share test.  An expert layer of ``E`` routed experts held as
+    ``E / share`` shares of ``share`` (Ling's 16 as 4 of 4 at the
+    rehearsal's router; GigaChat's 256 as the 16 chips' 16 of 16 at the
+    published router, ISSUE 35): each share's part through
+    ``routed_experts`` (the router over all of them every time), summed,
+    plus the shared expert ONCE, is the uncut layer of the family's
+    reference; and one share's part is the reference's for the same
+    ``held``."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.parallel.moe import routed_experts, sigmoid_group_select
 
-    E, F, D = 16, cfg["moe_intermediate_size"], cfg["hidden_size"]
-    whole = dict(cfg, num_experts=E, experts_first=0)
+    if family == "bailing_hybrid":
+        fam, whole = ref, dict(cfg, num_experts=E, experts_first=0)
+    else:
+        fam, cfg = _deepseek_small()
+        whole = dict(cfg, n_routed_experts=E, experts_first=0,
+                     published={"n_routed_experts": E})
+    F, D = cfg["moe_intermediate_size"], cfg["hidden_size"]
     ks = jax.random.split(jax.random.key(2), 9)
     wr = 0.5 * jax.random.normal(ks[0], (E, D))
     bias = 0.1 * jax.random.normal(ks[1], (E,))
@@ -306,27 +331,28 @@ def test_four_shares_add_up_to_the_uncut_layer(cfg):
     sg, su = (0.1 * jax.random.normal(k, (F, D)) for k in ks[5:7])
     sd = 0.1 * jax.random.normal(ks[7], (D, F))
     x = jax.random.normal(ks[8], (64, D))
-    want = ref.experts(whole, x, (wr, bias, wg, wu, wd, sg, su, sd))
+    want = fam.experts(whole, x, (wr, bias, wg, wu, wd, sg, su, sd))
     select = sigmoid_group_select(
         bias, cfg["n_group"], cfg["topk_group"],
         cfg["routed_scaling_factor"], cfg["norm_topk_prob"])
-    total = ref._gated_mlp(x, sg, su, sd, None)     # counted once
+    total = fam._gated_mlp(x, sg, su, sd, None)     # counted once
     seen = 0
-    for first in range(0, E, 4):
-        here = slice(first * F, (first + 4) * F)
+    for first in range(0, E, share):
+        here = slice(first * F, (first + share) * F)
         part, counts = routed_experts(
             x, wr.T, wg[:, here], wu[:, here], wd[here],
             cfg["num_experts_per_tok"], F, first=first, select=select)
         assert int(counts.sum()) == 64 * cfg["num_experts_per_tok"]
-        seen += int(counts[first:first + 4].sum())
-        one = ref.experts(whole, x, (wr, bias, wg[:, here], wu[:, here],
+        seen += int(counts[first:first + share].sum())
+        one = fam.experts(whole, x, (wr, bias, wg[:, here], wu[:, here],
                                      wd[here], sg, su, sd),
-                          held=(first, 4), shared=False)
+                          held=(first, share), shared=False)
         assert np.abs(np.asarray(part) - np.asarray(one)).max() < 1e-5
         total = total + part
     assert seen == 64 * cfg["num_experts_per_tok"]
     assert np.abs(np.asarray(total) - np.asarray(want)).max() < 1e-5
     assert np.abs(np.asarray(want)).max() > 0.1
+    del jnp
 
 
 def test_group_limited_selection_by_hand():
