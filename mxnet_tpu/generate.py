@@ -2062,15 +2062,26 @@ class PagedGenerationEngine:
         ``expert_rows_held`` those that fell on the experts held here
         (a model that says ``experts_held``; all of them otherwise),
         ``experts_held_touched`` the (layer, held expert) pairs at least
-        one row fell on: the experts whose weights the step needed."""
+        one row fell on: the experts whose weights the step needed.
+        ``expert_rows_multiplied`` is the rows the held experts
+        multiplied, each one's rows padded up to whole row tiles
+        (``parallel.moe.routed_experts``), ``expert_rows_dense`` what
+        every held expert multiplying every row would be."""
+        from .parallel.moe import expert_row_tile, expert_rows_multiplied
+
         first, held = self.model_config.get("experts_held",
                                             (0, load.shape[1]))
         mine = load[:, first:first + held]
+        pairs = int(load[0].sum())                  # rows x top_k a layer
         step.set(expert_load_max=int(load.max()),
                  expert_load_mean=float(load.mean()),
                  expert_rows_held=int(mine.sum()),
                  expert_rows_all=int(load.sum()),
-                 experts_held_touched=int((mine > 0).sum()))
+                 experts_held_touched=int((mine > 0).sum()),
+                 expert_rows_multiplied=expert_rows_multiplied(
+                     mine, expert_row_tile(pairs, load.shape[1])),
+                 expert_rows_dense=pairs // self.model_config["top_k"]
+                 * held * load.shape[0])
 
     def evict(self, slot, reason):
         """Free ``slot`` (mid-prefill pendings included): drop its

@@ -19,8 +19,9 @@ its own number here, not ``d_model / n_heads``).  The expert layer is
 experts' part.  Parameters are registered in one flat list, block by
 block; every matrix is stored ``(out, in)`` as ``nn.Dense`` stores it,
 and the experts side by side as ``(d_model, experts * d_expert)`` twice
-and ``(experts * d_expert, d_model)``, the shapes their two matrix
-products read without a transpose or a copy.  ``dtype`` is the type the embedding and the
+and ``(experts * d_expert, d_model)``, the shapes from which the
+grouped product reads an expert in place (``d_expert`` whole lane
+tiles: a column block of the one, a row block of the other).  ``dtype`` is the type the embedding and the
 matrices are *stored* in (``"bfloat16"`` to serve from half the
 memory); norm weights and the output head are always float32, which is
 where ``dtype_policy``'s ``bf16_mixed`` keeps them.

@@ -26,7 +26,8 @@ from __future__ import annotations
 import functools
 
 __all__ = ["moe_ffn", "moe_ffn_sharded", "routed_experts",
-           "sigmoid_group_select"]
+           "sigmoid_group_select", "expert_row_tile",
+           "expert_rows_multiplied"]
 
 
 def _check_top_k(top_k, n_experts):
@@ -200,28 +201,191 @@ def sigmoid_group_select(bias, n_group, topk_group, scaling=1.0,
     return select
 
 
+# rows a tile of the grouped product holds at most and at least: the
+# MXU takes up to 128 rows past a weight tile for the price of one, and
+# a bfloat16 tile is 16 sublanes
+_ROW_TILE_MAX, _ROW_TILE_MIN = 128, 16
+
+
+def expert_row_tile(pairs, n_experts):
+    """The row-tile height of the grouped product for ``pairs`` chosen
+    (row, expert) pairs a call over a router of ``n_experts``: twice the
+    pairs an expert expects, in whole 16-row sublane tiles, between 16
+    and 128.  An expert's rows are padded up to whole tiles, so the
+    height trades rows multiplied in vain against experts that take a
+    second pass of the MXU over their matrices; static shapes alone
+    decide it."""
+    want = -(-2 * pairs // n_experts)
+    tile = -(-want // _ROW_TILE_MIN) * _ROW_TILE_MIN
+    return max(_ROW_TILE_MIN, min(_ROW_TILE_MAX, tile))
+
+
+def expert_rows_multiplied(counts, tile):
+    """The rows the grouped product multiplies for experts that
+    ``counts`` (..., experts) rows fell on: each expert's rows padded up
+    to whole tiles of ``tile`` rows, summed (NumPy, for the engine's
+    spans)."""
+    import numpy as np
+
+    counts = np.asarray(counts)
+    return int((-(-counts // tile) * tile).sum())
+
+
+def _plain_grouped_ffn(rows, w_gate, w_up, w_down, tiles, d_expert, tm):
+    """The grouped product without a kernel (any platform, any widths):
+    ``jax.lax.ragged_dot`` over the experts' matrices seen three
+    dimensional, which copies them, so for sizes at which that is
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    D, F = rows.shape[1], d_expert
+    held = w_down.shape[0] // F
+    f32 = jnp.float32
+    sizes = (tiles * tm).astype(jnp.int32)
+
+    def by_expert(w):                      # (D, held * F) -> (held, D, F)
+        return w.reshape((D, held, F)).transpose((1, 0, 2))
+
+    g = lax.ragged_dot(rows, by_expert(w_gate), sizes,
+                       preferred_element_type=f32)
+    u = lax.ragged_dot(rows, by_expert(w_up), sizes,
+                       preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(rows.dtype)
+    return lax.ragged_dot(h, w_down.reshape((held, F, D)), sizes,
+                          preferred_element_type=f32)
+
+
+def _grouped_experts(x, top_p, top_i, counts, w_gate, w_up, w_down,
+                     d_expert, first, interpret=False):
+    """``sum_k w_k * ffn_{e_k}(x)`` over the held experts among each
+    row's chosen ones, float32 (T, D): the chosen pairs sorted by
+    expert, laid out in row tiles of one expert each, one grouped
+    product a matrix: the Pallas kernels on a TPU where they fit
+    (``ops.grouped_ffn_pallas.fits``), else the plain product;
+    ``interpret`` runs the kernels under the Pallas interpreter
+    wherever (the tests).  A function jitted once: the layers of a
+    program, alike in their shapes, share one trace and one lowering of
+    it (the kernels' lowering is most of an expert layer's)."""
+    return _jitted_grouped_experts()(
+        x, top_p, top_i, counts, w_gate, w_up, w_down, first,
+        d_expert=int(d_expert), interpret=bool(interpret))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_grouped_experts():
+    import jax
+
+    return jax.jit(_grouped_experts_traced,
+                   static_argnames=("d_expert", "interpret"))
+
+
+def _grouped_experts_traced(x, top_p, top_i, counts, w_gate, w_up, w_down,
+                            first, d_expert, interpret):
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ..ops import grouped_ffn_pallas as kernels
+
+    T, D = x.shape
+    k = top_i.shape[1]
+    P, F = T * k, int(d_expert)
+    held = w_down.shape[0] // F
+    i32, f32 = jnp.int32, jnp.float32
+    tm = expert_row_tile(P, counts.shape[0])
+    # an expert's rows end in a tile of their own: at most one tile
+    # more than the pairs fill for every expert that has any
+    n_tiles_max = -(-P // tm) + min(held, P)
+    R = n_tiles_max * tm
+
+    local = top_i.reshape((P,)).astype(i32) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    # the pairs by expert, those on experts not held here behind the
+    # others, each with its row of ``x`` and its weight
+    place = jnp.arange(P, dtype=i32)
+    skey, srow, sw = lax.sort(
+        (key, place // k, top_p.reshape((P,)).astype(f32)), num_keys=1,
+        is_stable=True)
+    c = lax.dynamic_slice_in_dim(counts, first, held)     # rows an expert
+    tiles = -(-c // tm)
+    tile_end = jnp.cumsum(tiles)
+    # a sorted pair's padded row: its expert's first tile, then its
+    # place among its expert's rows (a pair not held: past the end)
+    shift = jnp.concatenate([(tile_end - tiles) * tm - (jnp.cumsum(c) - c),
+                             jnp.full((1,), R, i32)])
+    at = place + shift[skey]
+    packed = jnp.zeros((R, 2), i32).at[at].set(
+        jnp.stack([srow, lax.bitcast_convert_type(sw, i32)], axis=1),
+        mode="drop", indices_are_sorted=True, unique_indices=True)
+    # (padding rows: a copy of row 0 at weight 0)
+    row_of = packed[:, 0]
+    row_weight = lax.bitcast_convert_type(packed[:, 1], f32)
+    tile_expert = jnp.minimum(
+        (tile_end[None, :] <= jnp.arange(n_tiles_max, dtype=i32)[:, None])
+        .sum(1), held - 1).astype(i32)
+    rows = jnp.take(x, row_of, axis=0, mode="clip")
+
+    def by_kernel(rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
+                  row_weight):
+        n_tiles = jnp.sum(tiles).astype(i32)
+        h = kernels.gate_up(rows, w_gate, w_up, tile_expert, n_tiles, F, tm,
+                            interpret=interpret)
+        return kernels.down_combine(h, w_down, tile_expert, n_tiles, row_of,
+                                    row_weight, T, tm, interpret=interpret)
+
+    def plain(rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
+              row_weight):
+        y = _plain_grouped_ffn(rows, w_gate, w_up, w_down, tiles, F, tm)
+        return jnp.zeros((T, D), f32).at[row_of].add(
+            y * row_weight[:, None])
+
+    args = (rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
+            row_weight)
+    if interpret:
+        return by_kernel(*args)
+    if kernels.fits(D, F, R):
+        return lax.platform_dependent(*args, tpu=by_kernel, default=plain)
+    return plain(*args)
+
+
 def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
                    first=0, norm_topk=True, select=None):
     """The part that the experts held here add to a gated top-k expert
     layer, with no capacity and no token dropped: what the serving path
-    calls (``gluon.model_zoo.language.MoEDecoderLM``).
+    calls (``gluon.model_zoo.language.MoEDecoderLM``,
+    ``HybridDecoderLM``).
 
     The router is over ALL experts; this caller holds the experts
     ``[first, first + held)`` (``held`` = the matrices' width over
     ``d_expert``) and computes their part of the result for
     the tokens routed to them.  The parts of all holders add up to the
     whole layer's output (on one chip that holds every expert, the part
-    is the whole).  Shapes are static: every held expert multiplies
-    every token, and a token's weight for an expert it was not routed
-    to is zero, so the one compiled program serves any routing.
+    is the whole).  **A held expert multiplies only the rows routed to
+    it**: the ``tokens * top_k`` chosen (row, expert) pairs are sorted
+    by expert (pairs on experts not held here behind the others), each
+    held expert's rows padded up to whole row tiles
+    (:func:`expert_row_tile`), and one grouped product a matrix runs
+    over the tiles in use, so an expert no row chose is neither
+    multiplied nor read.  Shapes are static (the pair list, the most
+    tiles there can be); how many tiles are in use and which expert
+    each is are computed in the program, so the one compiled program
+    serves any routing.  On a TPU, at widths of whole lane tiles, the
+    product is ``ops.grouped_ffn_pallas``'s kernels, which read an
+    expert where it lies in the matrices as handed over; elsewhere
+    (the CPU; small test widths) the same tiles go through
+    ``jax.lax.ragged_dot``.  The kernels have no gradient.
 
       x: (tokens, d_model)
       router_w: (d_model, n_experts), every expert's column
       w_gate, w_up: (d_model, held * d_expert), expert by expert
       w_down: (held * d_expert, d_model)
     (two dimensions each: the TPU lays a (d_model, held, d_expert) array
-    out in tiles over its last two dimensions, and the program would
-    copy every expert matrix to multiply by it)
+    out in tiles over its last two dimensions, and a program that takes
+    it so copies every expert matrix to multiply by it; with
+    ``d_expert`` whole lane tiles, expert ``e`` is the column blocks
+    ``[e * d_expert, (e + 1) * d_expert)`` of the one and the row block
+    ``e`` of the other)
     Returns ``(out, counts)``:
       out: (tokens, d_model), ``sum_e w_e * down_e(silu(gate_e x) *
         up_e x)`` over the held experts among each token's ``top_k``
@@ -230,7 +394,9 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
         ``(w, index)``, each (tokens, top_k), as
         :func:`sigmoid_group_select` makes one; without it the ``top_k``
         highest of the router's softmax, over float32, renormalised
-        over the chosen ones with ``norm_topk``)
+        over the chosen ones with ``norm_topk``); operands in ``x``'s
+        dtype, products accumulated, weighted by ``w`` and summed over
+        a token's experts in float32
       counts: (n_experts,) int32, the tokens routed to each expert of
         the whole layer in this call.
     """
@@ -240,8 +406,6 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
 
     n_exp = router_w.shape[-1]
     _check_top_k(top_k, n_exp)
-    T, F = x.shape[0], int(d_expert)
-    held = w_down.shape[0] // F
     f32 = jnp.float32
     logits = jnp.dot(x, router_w, preferred_element_type=f32)
     if select is not None:
@@ -253,12 +417,6 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
             top_p = top_p / top_p.sum(-1, keepdims=True)
     chosen = top_i[:, :, None] == jnp.arange(n_exp, dtype=top_i.dtype)
     counts = chosen.sum((0, 1)).astype(jnp.int32)             # (E,)
-    combine = jnp.where(chosen, top_p[:, :, None], 0.0).sum(1)  # (T, E)
-    mine = lax.dynamic_slice_in_dim(combine, first, held, axis=1)
-    # all held experts as two plain matrix products over (held * F)
-    g = jnp.dot(x, w_gate, preferred_element_type=f32)
-    u = jnp.dot(x, w_up, preferred_element_type=f32)
-    h = (jax.nn.silu(g) * u).reshape((T, held, F)) * mine[:, :, None]
-    out = jnp.dot(h.reshape((T, held * F)).astype(x.dtype), w_down,
-                  preferred_element_type=f32)
+    out = _grouped_experts(x, top_p, top_i, counts, w_gate, w_up, w_down,
+                           d_expert, first)
     return out.astype(x.dtype), counts

@@ -71,7 +71,9 @@ def test_manifest_gains_one_configuration_and_one_cell():
         assert (layer[name]["unit"], layer[name]["layer"],
                 layer[name]["moves"], layer[name]["workloads"][0]) == \
             (unit, where, "serve_itl_p95_ms", CELL)
+    # (PR 36 added the share of rows the held experts multiply)
     assert set(layer) == set(NEW_METRICS) | {
+        "moe_expert_rows_share",
         "serve_prefill_share", "serve_tick_ms_p95", "setup_build_s",
         "setup_compile_s", "setup_trace_lower_s", "setup_executable_load_s"}
     assert {m["moves"] for m in layer.values()} <= e2e
@@ -371,6 +373,32 @@ def test_span_args_readers_of_state_and_held_pairs(ctx):
     ctx["ring"]["records"][:] = [_decode(10.2), _decode(10.4)]
     assert span_args.reduce(ctx, **_args("decode_state_slots_share")) is None
     assert span_args.reduce(ctx, **_args("moe_held_pairs_share")) is None
+
+
+def test_span_args_reader_of_the_rows_the_experts_multiply(ctx):
+    """``moe_expert_rows_share`` (PR 36): the rows the held experts
+    multiplied, each one's padded up to whole tiles, over what every
+    held expert multiplying every row would be, of the window's
+    ``engine.decode`` spans; data only, in the manifest's last place."""
+    man = manifest.manifest()
+    entry = man["per_layer"][-1]
+    assert entry == {
+        "name": "moe_expert_rows_share", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "experts",
+        "moves": "serve_itl_p95_ms",
+        "workloads": [CELL, "gigachat3_serve_reason"]}
+    ctx["ring"]["records"] += [
+        _decode(9.5, expert_rows_multiplied=1, expert_rows_dense=1),
+        _decode(10.2, expert_rows_multiplied=4608,
+                expert_rows_dense=6 * 32 * 128),
+        _decode(10.4, expert_rows_multiplied=4480,
+                expert_rows_dense=6 * 32 * 128)]
+    assert span_args.reduce(ctx, **_args("moe_expert_rows_share")) == \
+        pytest.approx(100.0 * (4608 + 4480) / (2 * 6 * 32 * 128))
+    # the parent writes neither argument: the metric is left out
+    ctx["ring"]["records"][:] = [_decode(10.2, expert_rows_held=3,
+                                         expert_rows_all=9)]
+    assert span_args.reduce(ctx, **_args("moe_expert_rows_share")) is None
 
 
 def test_roofline_and_chunk_readers(cfg, ctx, monkeypatch):

@@ -609,6 +609,12 @@ def test_spans_and_counter_of_state_and_experts(cfg, model):
     assert step["expert_load_max"] >= 1
     assert step["expert_load_mean"] == pytest.approx(
         3 * cfg["num_experts_per_tok"] / 16)
+    # how far the grouped product engages (ISSUE 36): every touched
+    # (layer, held expert) pair is one row tile of 16 at these counts,
+    # where the dense product multiplied 3 rows by 4 held in 6 layers
+    assert step["expert_rows_multiplied"] == \
+        16 * step["experts_held_touched"]
+    assert step["expert_rows_dense"] == 6 * 3 * 4
 
 
 def test_token_server_serves_the_hybrid_model(cfg, model):

@@ -566,7 +566,9 @@ def wide_hybrid_engine():
 # changed the cache protocol) compiled them under this JAX: sha256 of
 # the text with the tables of source lines and every `metadata={...}`
 # taken out.  A change that means to alter neither program leaves them;
-# one that means to re-bases them from a tree whose cells were measured.
+# one that means to re-bases them from a tree whose cells were measured:
+# the two of the expert model are ISSUE 36's (its expert layers are the
+# grouped kernels), as measured by PR 36's chip runs.
 HLO_JAX = "0.9.0"
 HLO_SHA256 = {
     ("opt", "decode"):
@@ -574,9 +576,9 @@ HLO_SHA256 = {
     ("opt", "prefill"):
         "78c914d33623a7f9f7a783b7521c7a1c5273ab4a0688605444e5d80505690a46",
     ("moe", "decode"):
-        "0001978615a5e52c0b6491c3e915bd40cc58b5d627a0c43449ba37cc6abc0d65",
+        "737d629b62425df512c8abfa7bd42c6965c844467c29066127d67b7b6569cb30",
     ("moe", "prefill"):
-        "e1d304f1f32f08e43a0014230add5d99954208fb4a2b2219c196b15a22ffbd67",
+        "c2126b8d58825a2277f4d1a4d7c6fdc574ab58710f3c22070a857ecbe94723b0",
 }
 
 
@@ -591,12 +593,19 @@ def _program_sha256(text):
     return hashlib.sha256("\n".join(lines[first:]).encode()).hexdigest()
 
 
+_COMPILED = {}      # (engine, shape) -> what the tests below share
+
+
 def _compile_for(chip, eng, shape):
     """The dispatch of ``shape`` compiled for the described chip (nothing
     runs; a compile for a described chip is written to the persistent
-    cache but cannot be read back without one, so the cache is off)."""
+    cache but cannot be read back without one, so the cache is off).
+    Compiled once an engine and shape for the tests of this module."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
+
+    if (id(eng), shape) in _COMPILED:
+        return _COMPILED[id(eng), shape]
 
     def struct(a):
         return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip)
@@ -607,10 +616,12 @@ def _compile_for(chip, eng, shape):
     compilation_cache.reset_cache()
     try:
         with _time_limit(300):
-            return eng._jit_chunk.lower(*args).compile()
+            compiled = eng._jit_chunk.lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
+    _COMPILED[id(eng), shape] = compiled
+    return compiled
 
 
 # the programs' temporaries at those sizes (compiled.memory_analysis()):
@@ -737,6 +748,42 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
         # no expert matrix is copied to be multiplied
         assert not re.findall(r"= bf16\[2048,6144\]\S* copy\(", entry)
         assert not re.findall(r"= bf16\[6144,2048\]\S* copy\(", entry)
+
+
+@pytest.mark.parametrize("shape", ["decode", "prefill"])
+@pytest.mark.parametrize("model", ["moe", "hybrid"])
+def test_tpu_program_reads_the_experts_in_place(v5e_chip, request, model,
+                                                shape):
+    """The expert layers of the two expert models' step and chunk as the
+    chip's compiler sees them (ISSUE 36; optimized HLO for a described
+    v5e, nothing runs): every expert layer is the two grouped kernels
+    (`tpu_custom_call`: gate and up with the activation, then down), and
+    no `copy` or `transpose` touches anything of an expert matrix's
+    size: the kernels take the ``(d_model, held x width)`` matrices as
+    the engine holds them and find an expert by block index."""
+    import re
+
+    eng = request.getfixturevalue(
+        "wide_moe_engine" if model == "moe" else "wide_hybrid_engine")
+    cfg = eng.model_config
+    layers = 6 if model == "moe" else 1
+    _first, held = cfg.get("experts_held", (0, cfg["n_experts"]))
+    D, F = cfg["d_model"], cfg["d_expert"]
+    shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
+    text = _compile_for(v5e_chip, eng, shapes[shape]).as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 2 * layers, len(calls)
+    expert = held * F * D
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = (\w+\[[\d,]*\])\S* "
+                     r"(copy|transpose|copy-start)\(", line)
+        if m:
+            dims = re.findall(r"\d+", m.group(1).split("[")[1])
+            assert np.prod([int(d) for d in dims]) != expert, line
+    # the matrices enter as they are held: two dimensions each
+    entry = text[text.index("\nENTRY"):]
+    for dims in ("%d,%d" % (D, held * F), "%d,%d" % (held * F, D)):
+        assert re.findall(r"= bf16\[%s\]\S* parameter\(" % dims, entry)
 
 
 def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):
