@@ -80,24 +80,34 @@ class DecoderBlock(gluon.HybridBlock):
     def _attend(self, F, x):
         """Causal MHA over the full sequence (the train path and the
         full re-forward the serving tests hold ``forward_chunk`` to)."""
+        import jax
+
         B, T, _D = x.shape
         dh = self._d_head
-        q = self._split_heads(self.proj_q(x))
-        k = self._split_heads(self.proj_k(x))
-        v = self._split_heads(self.proj_v(x))
-        scores = F.batch_dot(q, k, transpose_b=True) * (dh ** -0.5)
-        pos = F.arange(T)
-        causal = F.broadcast_greater_equal(pos.reshape((T, 1)),
-                                           pos.reshape((1, T)))
-        scores = F.where(causal.reshape((1, T, T)), scores,
-                         F.ones_like(scores) * -1e30)
-        att = F.softmax(scores, axis=-1)
-        out = F.batch_dot(att, v)  # (B*H, T, dh)
-        return self.attn_out(self._merge_heads(out, B, T))
+        with jax.named_scope("attn.proj"):
+            q = self._split_heads(self.proj_q(x))
+            k = self._split_heads(self.proj_k(x))
+            v = self._split_heads(self.proj_v(x))
+        with jax.named_scope("attn.core"):
+            scores = F.batch_dot(q, k, transpose_b=True) * (dh ** -0.5)
+            pos = F.arange(T)
+            causal = F.broadcast_greater_equal(pos.reshape((T, 1)),
+                                               pos.reshape((1, T)))
+            scores = F.where(causal.reshape((1, T, T)), scores,
+                             F.ones_like(scores) * -1e30)
+            att = F.softmax(scores, axis=-1)
+            out = F.batch_dot(att, v)  # (B*H, T, dh)
+        with jax.named_scope("attn.proj"):
+            return self.attn_out(self._merge_heads(out, B, T))
 
     def hybrid_forward(self, F, x):
-        x = x + self._attend(F, self.ln1(x))
-        return x + self.ffn_down(self.ffn_up(self.ln2(x)))
+        import jax
+
+        with jax.named_scope("attn.proj"):
+            h = self.ln1(x)
+        x = x + self._attend(F, h)
+        with jax.named_scope("ffn"):
+            return x + self.ffn_down(self.ffn_up(self.ln2(x)))
 
     def forward_chunk(self, F, x, k_rows, v_rows, start):
         """One block's C-position chunk forward against its layer's
@@ -115,17 +125,26 @@ class DecoderBlock(gluon.HybridBlock):
         the chunk K/V as raw (B, C, D) rows for the caller to write
         into its pool.  The projection/LN/FFN submodules are the SAME
         children the train path runs, so chunk logits track the
-        full-context forward."""
+        full-context forward.  The parts are traced under the named
+        scopes ``attn.proj``, ``attn.core`` (opened by
+        ``chunk_attention_rows``) and ``ffn``, which a device trace
+        reads the time of (``mxnet_tpu.profiler.device_table``)."""
+        import jax
+
         from mxnet_tpu.ndarray import NDArray
         from mxnet_tpu.ops.attention_rows import chunk_attention_rows
 
-        h = self.ln1(x)
-        k_c, v_c = self.proj_k(h)._data, self.proj_v(h)._data
-        out = chunk_attention_rows(self.proj_q(h)._data, k_c, v_c,
-                                   k_rows, v_rows, start, self._n_heads)
-        x = x + self.attn_out(NDArray(out))
-        return (x + self.ffn_down(self.ffn_up(self.ln2(x))),
-                k_c, v_c)
+        with jax.named_scope("attn.proj"):
+            h = self.ln1(x)
+            k_c, v_c = self.proj_k(h)._data, self.proj_v(h)._data
+            q = self.proj_q(h)._data
+        out = chunk_attention_rows(q, k_c, v_c, k_rows, v_rows, start,
+                                   self._n_heads)
+        with jax.named_scope("attn.proj"):
+            x = x + self.attn_out(NDArray(out))
+        with jax.named_scope("ffn"):
+            return (x + self.ffn_down(self.ffn_up(self.ln2(x))),
+                    k_c, v_c)
 
 
 class TransformerLM(gluon.HybridBlock):
@@ -182,13 +201,17 @@ class TransformerLM(gluon.HybridBlock):
         if T > self._cfg["max_len"]:
             raise ValueError("sequence length %d > max_len %d"
                              % (T, self._cfg["max_len"]))
-        pos = F.arange(T)
-        x = F.broadcast_add(self.embed(tokens),
-                            self.pos_embed(pos).reshape(
-                                (1, T, self._cfg["d_model"])))
+        import jax
+
+        with jax.named_scope("embed"):
+            pos = F.arange(T)
+            x = F.broadcast_add(self.embed(tokens),
+                                self.pos_embed(pos).reshape(
+                                    (1, T, self._cfg["d_model"])))
         for blk in self._blocks:
             x = blk(x)
-        return self.head(self.ln_f(x))
+        with jax.named_scope("head"):
+            return self.head(self.ln_f(x))
 
     # -- generation protocol (mxnet_tpu/generate.py) ---------------------
     #
@@ -218,6 +241,7 @@ class TransformerLM(gluon.HybridBlock):
         raw (B, C, D) rows per layer for the caller to write back
         (positions past a sequence's real length just produce values the
         caller routes to its trash page)."""
+        import jax
         import jax.numpy as jnp
 
         from mxnet_tpu import ndarray as F
@@ -226,15 +250,18 @@ class TransformerLM(gluon.HybridBlock):
         B, C = tokens.shape
         D = self._cfg["d_model"]
         start = start.astype(jnp.int32)
-        pos_ids = jnp.clip(start[:, None] + jnp.arange(C, dtype=jnp.int32),
-                           0, self._cfg["max_len"] - 1)     # (B, C)
-        x = self.embed(NDArray(tokens)) + self.pos_embed(
-            NDArray(pos_ids)).reshape((B, C, D))
+        with jax.named_scope("embed"):
+            pos_ids = jnp.clip(
+                start[:, None] + jnp.arange(C, dtype=jnp.int32),
+                0, self._cfg["max_len"] - 1)                # (B, C)
+            x = self.embed(NDArray(tokens)) + self.pos_embed(
+                NDArray(pos_ids)).reshape((B, C, D))
         chunk_caches = []
         for blk, (k_rows, v_rows) in zip(self._blocks, caches):
             x, k_c, v_c = blk.forward_chunk(F, x, k_rows, v_rows, start)
             chunk_caches.append((k_c, v_c))
-        return self.head(self.ln_f(x)), chunk_caches
+        with jax.named_scope("head"):
+            return self.head(self.ln_f(x)), chunk_caches
 
 
 def lm_loss_fn(vocab_size):

@@ -622,11 +622,25 @@ def enable_compile_cache():
     :data:`COMPILE_CACHE_DIR` — the directory is part of the cache key,
     so it is one fixed path, never derived from ``~``, a pid or a
     temporary name.  The flag is read at compile time, so this works
-    before or after backend init."""
+    before or after backend init.
+
+    An executable loaded from the cache carries the names and source
+    lines it was compiled with, and ``profiler.device_table`` reads a
+    trace by them: so the key includes them (by jax's default it leaves
+    them out, and a program whose instructions an earlier checkout
+    compiled came back with that checkout's scopes, or none), with
+    source files named from the checkout's root, so that two checkouts
+    of the same files share their entries."""
+    import re
+
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(os.path.dirname(COMPILE_CACHE_DIR) + os.sep))
     return jax.config.jax_compilation_cache_dir
 
 

@@ -878,10 +878,12 @@ class PagedGenerationEngine:
             shared by a pool's 24 gathers the chip's compiler schedules
             the same operations a tenth slower (7.44 against 6.70 ms a
             decode program; PERF.md, PR 32)."""
-            if not by_page:
-                return pool[li * n_tokens + rows]
-            return pool.reshape((L * n_pages, page, H * dh))[
-                li * n_pages + page_table].reshape(rows.shape + (H * dh,))
+            with jax.named_scope("cache.gather"):
+                if not by_page:
+                    return pool[li * n_tokens + rows]
+                return pool.reshape((L * n_pages, page, H * dh))[
+                    li * n_pages + page_table].reshape(
+                        rows.shape + (H * dh,))
 
         def read_declared(pool, state, page_table, rows, lanes):
             """What every layer of a model that declares its caches
@@ -893,26 +895,27 @@ class PagedGenerationEngine:
             slot_ids, fresh, _valid = lanes
             over_all = rows.shape[0] == self._slots
             caches, at = [], 0
-            for li, kind in enumerate(declared):
-                if "rows" in kind:
-                    k = row_layers.index(li)
-                    if by_page:
-                        got = pool.reshape(
-                            (len(row_layers) * n_pages, page, -1))[
-                            k * n_pages + page_table].reshape(
-                                rows.shape + (-1,))
-                    else:
-                        got = pool[k * n_tokens + rows]
-                    caches.append(got)
-                    continue
-                mine = []
-                for _spec in kind["state"]:
-                    a = state[at] if over_all else state[at][slot_ids]
-                    mine.append(jnp.where(
-                        fresh.reshape((-1,) + (1,) * (a.ndim - 1)),
-                        jnp.zeros((), a.dtype), a))
-                    at += 1
-                caches.append(tuple(mine))
+            with jax.named_scope("cache.gather"):
+                for li, kind in enumerate(declared):
+                    if "rows" in kind:
+                        k = row_layers.index(li)
+                        if by_page:
+                            got = pool.reshape(
+                                (len(row_layers) * n_pages, page, -1))[
+                                k * n_pages + page_table].reshape(
+                                    rows.shape + (-1,))
+                        else:
+                            got = pool[k * n_tokens + rows]
+                        caches.append(got)
+                        continue
+                    mine = []
+                    for _spec in kind["state"]:
+                        a = state[at] if over_all else state[at][slot_ids]
+                        mine.append(jnp.where(
+                            fresh.reshape((-1,) + (1,) * (a.ndim - 1)),
+                            jnp.zeros((), a.dtype), a))
+                        at += 1
+                    caches.append(tuple(mine))
             return caches
 
         def write_declared(pool, state, kept, wpage, woff, lanes):
@@ -921,22 +924,23 @@ class PagedGenerationEngine:
             slot_ids = lanes[0]
             over_all = slot_ids.shape[0] == self._slots
             state, at = list(state), 0
-            if row_layers:
-                vals = jnp.stack([kept[li] for li in row_layers])
-                wrow = (jnp.arange(len(row_layers),
-                                   dtype=jnp.int32)[:, None]
-                        * n_tokens + (wpage * page + woff)[None, :]
-                        ).reshape(-1)
-                vals = vals.astype(cache_dtype).reshape(
-                    (-1, vals.shape[-1]))
-                pool = pool.at[wrow].set(jnp.pad(vals, (
-                    (0, 0), (0, pool.shape[1] - vals.shape[1]))))
-            for li in state_layers:
-                for new in kept[li]:
-                    new = new.astype(state[at].dtype)
-                    state[at] = new if over_all \
-                        else state[at].at[slot_ids].set(new)
-                    at += 1
+            with jax.named_scope("cache.write"):
+                if row_layers:
+                    vals = jnp.stack([kept[li] for li in row_layers])
+                    wrow = (jnp.arange(len(row_layers),
+                                       dtype=jnp.int32)[:, None]
+                            * n_tokens + (wpage * page + woff)[None, :]
+                            ).reshape(-1)
+                    vals = vals.astype(cache_dtype).reshape(
+                        (-1, vals.shape[-1]))
+                    pool = pool.at[wrow].set(jnp.pad(vals, (
+                        (0, 0), (0, pool.shape[1] - vals.shape[1]))))
+                for li in state_layers:
+                    for new in kept[li]:
+                        new = new.astype(state[at].dtype)
+                        state[at] = new if over_all \
+                            else state[at].at[slot_ids].set(new)
+                        at += 1
             return pool, tuple(state)
 
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
@@ -987,35 +991,48 @@ class PagedGenerationEngine:
             block runs on the trunk's states and those tokens, its rows
             go to the pool with the trunk's, and ``extras["draft"]``
             (B, C) is its greedy choice a row, the draft of the position
-            two on."""
+            two on.
+
+            The engine's own parts of the program are traced under
+            ``jax.named_scope`` s, ``cache.gather``, ``cache.write`` and
+            ``sample``, beside those the model opens, so that a device
+            trace says which part took the time
+            (``profiler.device_table``)."""
             Bc, C = tokens.shape
             if block is not None:
-                (b_tok, b_mask, b_at, b_conf), fresh, given, take, \
-                    number = block
-                opens = fresh[:, None]
-                b_tok = jnp.where(opens, tokens, b_tok)
-                b_mask = jnp.where(
-                    opens, jnp.arange(C)[None, :] >= given[:, None], b_mask)
-                b_at = jnp.where(opens, 0, b_at)
-                b_conf = jnp.where(opens, 0.0, b_conf)
-                tokens = jnp.where(b_mask, mask_id, b_tok)
+                with jax.named_scope("sample"):
+                    (b_tok, b_mask, b_at, b_conf), fresh, given, take, \
+                        number = block
+                    opens = fresh[:, None]
+                    b_tok = jnp.where(opens, tokens, b_tok)
+                    b_mask = jnp.where(
+                        opens, jnp.arange(C)[None, :] >= given[:, None],
+                        b_mask)
+                    b_at = jnp.where(opens, 0, b_at)
+                    b_conf = jnp.where(opens, 0.0, b_conf)
+                    tokens = jnp.where(b_mask, mask_id, b_tok)
 
             # pool token of every cache position: (B, P) pages -> (B, S)
-            rows = (page_table[:, :, None] * page
-                    + jnp.arange(page, dtype=jnp.int32)).reshape((Bc, S))
+            with jax.named_scope("cache.gather"):
+                rows = (page_table[:, :, None] * page
+                        + jnp.arange(page, dtype=jnp.int32)).reshape(
+                            (Bc, S))
 
             def pick(logits):
                 """A token a position: the best one, or one drawn with
                 the position's own key."""
-                if scfg.greedy:
-                    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                pos_ids = start[:, None] + jnp.arange(C, dtype=jnp.int32)
-                keys = jax.vmap(jax.vmap(jax.random.fold_in))(
-                    jnp.broadcast_to(lane_keys[:, None, :], (Bc, C, 2)),
-                    pos_ids)
-                return jax.vmap(jax.vmap(
-                    lambda lg, kk: sample_logits(lg[None, :], kk,
-                                                 scfg)[0]))(logits, keys)
+                with jax.named_scope("sample"):
+                    if scfg.greedy:
+                        return jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32)
+                    pos_ids = start[:, None] + jnp.arange(
+                        C, dtype=jnp.int32)
+                    keys = jax.vmap(jax.vmap(jax.random.fold_in))(
+                        jnp.broadcast_to(lane_keys[:, None, :],
+                                         (Bc, C, 2)), pos_ids)
+                    return jax.vmap(jax.vmap(
+                        lambda lg, kk: sample_logits(
+                            lg[None, :], kk, scfg)[0]))(logits, keys)
 
             def run():
                 if lanes is not None:
@@ -1035,8 +1052,9 @@ class PagedGenerationEngine:
                         dres = net.draft_forward(
                             hidden, jnp.where(own, sampled, follow),
                             caches[L], start, lanes[2])
-                        extras["draft"] = jnp.argmax(
-                            dres[0]._data, axis=-1).astype(jnp.int32)
+                        with jax.named_scope("sample"):
+                            extras["draft"] = jnp.argmax(
+                                dres[0]._data, axis=-1).astype(jnp.int32)
                         kept.append(dres[1])
                         if "expert_load" in dres[2]:
                             extras["expert_load"] = jnp.concatenate([
@@ -1052,8 +1070,9 @@ class PagedGenerationEngine:
                         return pool[rows].reshape(
                             (Bc, S, L, H, dh)).transpose(2, 0, 3, 1, 4)
 
-                    gk, gv = view(pool_k), view(pool_v)
-                    caches = [(gk[li], gv[li]) for li in range(L)]
+                    with jax.named_scope("cache.gather"):
+                        gk, gv = view(pool_k), view(pool_v)
+                        caches = [(gk[li], gv[li]) for li in range(L)]
                 res = net.chunk_forward(tokens, caches, start)
                 # a model may hand back a third item: arrays about the
                 # forward itself (an expert layer's token counts)
@@ -1066,47 +1085,51 @@ class PagedGenerationEngine:
             if sampled is None:
                 sampled = pick(logits)
             if block is not None:
-                # the confidence of each position's best token: its
-                # log-probability under the softmax, in float32
-                lg = logits.astype(jnp.float32)
-                conf = jnp.max(lg, axis=-1) - \
-                    jax.scipy.special.logsumexp(lg, axis=-1)
-                # of each row's masked positions the `take` most
-                # confident are fixed; a stable sort leaves ties to the
-                # lower position
-                order = jnp.argsort(jnp.where(b_mask, -conf, jnp.inf),
-                                    axis=1, stable=True)
-                rank = jnp.argsort(order, axis=1)
-                fix = b_mask & (rank < take[:, None])
-                extras["masked"] = b_mask
-                extras["block"] = (
-                    jnp.where(fix, sampled, b_tok), b_mask & ~fix,
-                    jnp.where(fix, number[:, None], b_at),
-                    jnp.where(fix, conf, b_conf))
+                with jax.named_scope("sample"):
+                    # the confidence of each position's best token: its
+                    # log-probability under the softmax, in float32
+                    lg = logits.astype(jnp.float32)
+                    conf = jnp.max(lg, axis=-1) - \
+                        jax.scipy.special.logsumexp(lg, axis=-1)
+                    # of each row's masked positions the `take` most
+                    # confident are fixed; a stable sort leaves ties to
+                    # the lower position
+                    order = jnp.argsort(jnp.where(b_mask, -conf, jnp.inf),
+                                        axis=1, stable=True)
+                    rank = jnp.argsort(order, axis=1)
+                    fix = b_mask & (rank < take[:, None])
+                    extras["masked"] = b_mask
+                    extras["block"] = (
+                        jnp.where(fix, sampled, b_tok), b_mask & ~fix,
+                        jnp.where(fix, number[:, None], b_at),
+                        jnp.where(fix, conf, b_conf))
             if lanes is not None:
                 pool_k, extras["state"] = write_declared(
                     pool_k, state, chunk_caches, wpage, woff, lanes)
                 return sampled, logits, pool_k, pool_v, extras
-            k_new = jnp.stack([k for k, _v in chunk_caches])
-            v_new = jnp.stack([v for _k, v in chunk_caches])
-            # leading-dimension scatter, in place on the donated pool;
-            # padded positions collide on the trash page, so the rows
-            # are not unique
-            if cache_rows:
-                # (L, B, C, H*dh): one pool row a (layer, chunk position)
-                kvals = k_new.astype(cache_dtype).reshape((-1,) + row)
-                vvals = v_new.astype(cache_dtype).reshape((-1,) + row)
-                wrow = (jnp.arange(L, dtype=jnp.int32)[:, None] * n_tokens
-                        + (wpage * page + woff)[None, :]).reshape(-1)
-            else:
-                # (L, B, H, C, dh): one pool row a chunk position
-                kvals = k_new.astype(cache_dtype).transpose(
-                    1, 3, 0, 2, 4).reshape((Bc * C,) + row)
-                vvals = v_new.astype(cache_dtype).transpose(
-                    1, 3, 0, 2, 4).reshape((Bc * C,) + row)
-                wrow = wpage * page + woff
-            pool_k = pool_k.at[wrow].set(kvals)
-            pool_v = pool_v.at[wrow].set(vvals)
+            with jax.named_scope("cache.write"):
+                k_new = jnp.stack([k for k, _v in chunk_caches])
+                v_new = jnp.stack([v for _k, v in chunk_caches])
+                # leading-dimension scatter, in place on the donated
+                # pool; padded positions collide on the trash page, so
+                # the rows are not unique
+                if cache_rows:
+                    # (L, B, C, H*dh): one pool row a (layer, chunk
+                    # position)
+                    kvals = k_new.astype(cache_dtype).reshape((-1,) + row)
+                    vvals = v_new.astype(cache_dtype).reshape((-1,) + row)
+                    wrow = (jnp.arange(L, dtype=jnp.int32)[:, None]
+                            * n_tokens
+                            + (wpage * page + woff)[None, :]).reshape(-1)
+                else:
+                    # (L, B, H, C, dh): one pool row a chunk position
+                    kvals = k_new.astype(cache_dtype).transpose(
+                        1, 3, 0, 2, 4).reshape((Bc * C,) + row)
+                    vvals = v_new.astype(cache_dtype).transpose(
+                        1, 3, 0, 2, 4).reshape((Bc * C,) + row)
+                    wrow = wpage * page + woff
+                pool_k = pool_k.at[wrow].set(kvals)
+                pool_v = pool_v.at[wrow].set(vvals)
             return sampled, logits, pool_k, pool_v, extras
 
         self._jit_chunk = jax.jit(chunk_fn, donate_argnums=(1, 2, 11))

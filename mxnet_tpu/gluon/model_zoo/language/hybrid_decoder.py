@@ -352,7 +352,9 @@ class HybridDecoderLM(HybridBlock):
         """The KDA mixer on normed states ``n`` (B, C, D).  ``cache``
         is ``(S (B, H, dk, dv) float32, tail (B, K-1, wide))``;
         ``valid`` (B,) the leading positions of each row that count.
-        Returns (out (B, C, D), (S, tail) after the valid positions)."""
+        Returns (out (B, C, D), (S, tail) after the valid positions).
+        Traced under the named scope ``kda.proj`` (the products and the
+        convolution's tail); the delta rule opens ``kda.scan``."""
         import jax
         import jax.numpy as jnp
 
@@ -364,30 +366,31 @@ class HybridDecoderLM(HybridBlock):
         B, C, _D = n.shape
         state, tail = cache
         f32 = jnp.float32
-        x = _mm(n, wqkv)                                    # (B, C, wide)
-        seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-        # the tail after this dispatch: the last K-1 inputs that count
-        # (with valid = 0 the tail it came with)
-        keep = valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-        tail = jnp.take_along_axis(seen, keep[:, :, None], axis=1)
-        y = sum(seen[:, j:j + C].astype(f32) * wc[j].astype(f32)
-                for j in range(K))
-        y = jax.nn.silu(y)
-        q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
-        q = q.reshape((B, C, H, dk))
-        k = k.reshape((B, C, H, dk))
-        v = v.reshape((B, C, H, dv))
+        with jax.named_scope("kda.proj"):
+            x = _mm(n, wqkv)                                    # (B, C, wide)
+            seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+            # the tail after this dispatch: the last K-1 inputs that count
+            # (with valid = 0 the tail it came with)
+            keep = valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+            tail = jnp.take_along_axis(seen, keep[:, :, None], axis=1)
+            y = sum(seen[:, j:j + C].astype(f32) * wc[j].astype(f32)
+                    for j in range(K))
+            y = jax.nn.silu(y)
+            q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
+            q = q.reshape((B, C, H, dk))
+            k = k.reshape((B, C, H, dk))
+            v = v.reshape((B, C, H, dv))
 
-        def l2(a):
-            return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True)
-                                     + 1e-6)
+            def l2(a):
+                return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True)
+                                         + 1e-6)
 
-        q, k = l2(q) * dk ** -0.5, l2(k)
-        gate_in = _mm(n, wf).astype(f32).reshape((B, C, H, dk)) \
-            + dt_bias.reshape((H, dk))
-        g = self._lower * jax.nn.sigmoid(
-            jnp.exp(a_log)[:, None] * gate_in)              # in [lower, 0]
-        beta = jax.nn.sigmoid(_mm(n, wb).astype(f32))       # (B, C, H)
+            q, k = l2(q) * dk ** -0.5, l2(k)
+            gate_in = _mm(n, wf).astype(f32).reshape((B, C, H, dk)) \
+                + dt_bias.reshape((H, dk))
+            g = self._lower * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None] * gate_in)              # in [lower, 0]
+            beta = jax.nn.sigmoid(_mm(n, wb).astype(f32))       # (B, C, H)
         if C == 1:
             live = valid > 0
             o, state = gated_delta_step(
@@ -397,16 +400,20 @@ class HybridDecoderLM(HybridBlock):
             o = o[:, None]
         else:
             o, state = gated_delta_chunk(q, k, v, g, beta, state, valid)
-        o = _rms(o, g_o, self._eps) \
-            * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
-        return _mm(o.reshape((B, C, H * dv)), wo), (state, tail)
+        with jax.named_scope("kda.proj"):
+            o = _rms(o, g_o, self._eps) \
+                * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
+            return _mm(o.reshape((B, C, H * dv)), wo), (state, tail)
 
     def _mla(self, n, p, rows, start, pos):
         """The MLA mixer on normed states ``n`` (B, C, D) at positions
         ``pos`` (B, C).  ``rows`` (B, S, >= dl + dr) are the cached
         positions' ``[c~ | rope(k_r)]`` (those under ``start`` count),
         with whatever zero lanes the pool keeps after them, or None.
-        Returns (out (B, C, D), the chunk's rows (B, C, dl+dr))."""
+        Returns (out (B, C, D), the chunk's rows (B, C, dl+dr)).
+        Traced under the named scopes ``attn.proj`` (every product with
+        a weight, the rotary positions) and ``attn.core`` (scores,
+        softmax and context over the rows)."""
         import jax
         import jax.numpy as jnp
 
@@ -421,22 +428,23 @@ class HybridDecoderLM(HybridBlock):
         wq = p[-4]
         B, C, _D = n.shape
         f32, act = jnp.float32, wq.dtype
-        if z["ql"] is not None:       # the query's own latent, normed
-            wqa, g_q = p[:2]
-            n_q = _rms(_mm(n, wqa), g_q, self._eps).astype(act)
-        else:
-            n_q = n
-        q = _mm(n_q, wq).reshape((B, C, H, dn + dr))
-        q_nope = q[..., :dn]
-        q_r = _rope_pairs(q[..., dn:].astype(f32), pos, self._theta,
-                          self._yarn).astype(act)
-        down = _mm(n, wdkv)                                 # (B, C, dl+dr)
-        c = _rms(down[..., :dl], g_kv, self._eps).astype(act)
-        k_r = _rope_pairs(down[..., dl:].astype(f32), pos, self._theta,
-                          self._yarn).astype(act)
-        new = jnp.concatenate([c, k_r], axis=-1)            # (B, C, dl+dr)
-        up = wukv.reshape((H, dn + dv, dl))
-        w_uk, w_uv = up[:, :dn], up[:, dn:]                 # (H, dn|dv, dl)
+        with jax.named_scope("attn.proj"):
+            if z["ql"] is not None:       # the query's own latent, normed
+                wqa, g_q = p[:2]
+                n_q = _rms(_mm(n, wqa), g_q, self._eps).astype(act)
+            else:
+                n_q = n
+            q = _mm(n_q, wq).reshape((B, C, H, dn + dr))
+            q_nope = q[..., :dn]
+            q_r = _rope_pairs(q[..., dn:].astype(f32), pos, self._theta,
+                              self._yarn).astype(act)
+            down = _mm(n, wdkv)                             # (B, C, dl+dr)
+            c = _rms(down[..., :dl], g_kv, self._eps).astype(act)
+            k_r = _rope_pairs(down[..., dl:].astype(f32), pos, self._theta,
+                              self._yarn).astype(act)
+            new = jnp.concatenate([c, k_r], axis=-1)        # (B, C, dl+dr)
+            up = wukv.reshape((H, dn + dv, dl))
+            w_uk, w_uv = up[:, :dn], up[:, dn:]             # (H, dn|dv, dl)
         scale = self._scale
         causal = jnp.tril(jnp.ones((C, C), bool))[None, None]
         cached = rows is not None
@@ -453,30 +461,36 @@ class HybridDecoderLM(HybridBlock):
         # rows as they lie; the row is contracted whole and read whole
         # (the lanes after the latent are dropped from the result), so
         # no slice of the cache is ever copied
-        q_lat = dot("bchd,hdl->bchl", q_nope, w_uk).astype(act)
-        q_cat = jnp.concatenate([q_lat, q_r], axis=-1)      # (B,C,H,dl+dr)
-        s_new = dot("bchw,bsw->bhcs", q_cat, new) * scale
-        if cached:
-            # (zeros under the pool's zero lanes)
-            q_old = jnp.pad(q_cat, [(0, 0)] * 3 + [
-                (0, rows.shape[-1] - dl - dr)])
-            s_old = dot("bchw,bsw->bhcs", q_old, rows) * scale
-            p_old, p_new = _softmax_pair(s_old, s_new, cache_ok, causal, act)
-            ctx = dot("bhcs,bsw->bchw", p_old, rows)[..., :dl] \
-                + dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
-        else:
-            p_new = jax.nn.softmax(
-                jnp.where(causal, s_new, -1e30), -1).astype(act)
-            ctx = dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
-        o = dot("bchl,hdl->bchd", ctx.astype(act), w_uv)
-        if wgate is not None:
-            o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
-        return _mm(o.reshape((B, C, H * dv)), wo), new
+        with jax.named_scope("attn.proj"):
+            q_lat = dot("bchd,hdl->bchl", q_nope, w_uk).astype(act)
+            q_cat = jnp.concatenate([q_lat, q_r], axis=-1)      # (B,C,H,dl+dr)
+        with jax.named_scope("attn.core"):
+            s_new = dot("bchw,bsw->bhcs", q_cat, new) * scale
+            if cached:
+                # (zeros under the pool's zero lanes)
+                q_old = jnp.pad(q_cat, [(0, 0)] * 3 + [
+                    (0, rows.shape[-1] - dl - dr)])
+                s_old = dot("bchw,bsw->bhcs", q_old, rows) * scale
+                p_old, p_new = _softmax_pair(s_old, s_new, cache_ok, causal,
+                                             act)
+                ctx = dot("bhcs,bsw->bchw", p_old, rows)[..., :dl] \
+                    + dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
+            else:
+                p_new = jax.nn.softmax(
+                    jnp.where(causal, s_new, -1e30), -1).astype(act)
+                ctx = dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
+        with jax.named_scope("attn.proj"):
+            o = dot("bchl,hdl->bchd", ctx.astype(act), w_uv)
+            if wgate is not None:
+                o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
+            return _mm(o.reshape((B, C, H * dv)), wo), new
 
     def _moe(self, m, p):
         """The expert layer on normed states ``m`` (N, D): (the held
         routed experts' part + the shared expert, counts (E,))."""
         from ....parallel.moe import routed_experts, sigmoid_group_select
+
+        import jax
 
         wr, bias, wg, wu, wd, sg, su, sd = p
         c = self._cfg
@@ -484,21 +498,28 @@ class HybridDecoderLM(HybridBlock):
             m, wr.T, wg, wu, wd, c["top_k"], c["d_expert"],
             first=self._first,
             select=sigmoid_group_select(bias, **self._route))
-        return y + _gated_mlp(m, sg, su, sd).astype(y.dtype), counts
+        with jax.named_scope("ffn"):
+            return y + _gated_mlp(m, sg, su, sd).astype(y.dtype), counts
 
     # -- the one forward ---------------------------------------------------
 
     def _block(self, x, params, mix, ffn, cache, start, valid, pos):
         """One block on the stream ``x`` (B, C, D): (the stream after
         it, what its mixer keeps, its expert layer's counts or None).
-        ``cache`` None: a whole sequence from nothing."""
+        ``cache`` None: a whole sequence from nothing.  Every part is
+        traced under a named scope (``kda.proj``, ``kda.scan``,
+        ``attn.proj``, ``attn.core``, ``ffn``, ``experts.route``,
+        ``experts.ffn``), all layers' work under the one name, which
+        ``profiler.device_table`` reads a device trace by."""
+        import jax
         import jax.numpy as jnp
 
         z = self._sizes
         B, C, _D = x.shape
         act = x.dtype
         norm_mix, mixer, norm_ffn, feed = params
-        n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
+        with jax.named_scope("kda.proj" if mix == "kda" else "attn.proj"):
+            n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
         if mix == "kda":
             if cache is None:
                 cache = (
@@ -508,10 +529,12 @@ class HybridDecoderLM(HybridBlock):
         else:
             out, kept = self._mla(n, _raw(mixer), cache, start, pos)
         x = x + out.astype(act)
-        m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
+        with jax.named_scope("ffn"):
+            m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
         counts = None
         if ffn == "dense":
-            y = _gated_mlp(m, *_raw(feed))
+            with jax.named_scope("ffn"):
+                y = _gated_mlp(m, *_raw(feed))
         else:
             y, counts = self._moe(m.reshape((B * C, -1)), _raw(feed))
             y = y.reshape((B, C, -1))
@@ -524,11 +547,13 @@ class HybridDecoderLM(HybridBlock):
         Returns (logits raw (B, C, V), a layer's new state or the
         chunk's new rows, expert load (expert layers, E) int32, the
         last block's output (B, C, D))."""
+        import jax
         import jax.numpy as jnp
 
         C = tokens.shape[1]
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
-        x = jnp.take(self._embed.data()._data, tokens, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(self._embed.data()._data, tokens, axis=0)
         new, loads = [], []
         for li, (mix, ffn) in enumerate(zip(self._mixers, self._ffns)):
             x, kept, counts = self._block(
@@ -538,10 +563,13 @@ class HybridDecoderLM(HybridBlock):
             new.append(kept)
             if counts is not None:
                 loads.append(counts)
-        head = self._head.data()._data
-        h = _rms(x, self._final.data()._data, self._eps).astype(head.dtype)
+        with jax.named_scope("head"):
+            head = self._head.data()._data
+            h = _rms(x, self._final.data()._data,
+                     self._eps).astype(head.dtype)
+            logits = jnp.dot(h, head.T)
         load = jnp.stack(loads) if loads else None
-        return jnp.dot(h, head.T), new, load, x
+        return logits, new, load, x
 
     def hybrid_forward(self, F, tokens, **_registered):
         import jax.numpy as jnp
@@ -592,7 +620,9 @@ class HybridDecoderLM(HybridBlock):
         the block's own cached ``rows`` (as :meth:`chunk_forward` takes
         a layer's; None: a whole sequence from nothing).  Returns
         ``(logits NDArray (B, C, V) of positions i + 2, the chunk's rows
-        (B, C, width), {"expert_load": (1, experts)})``."""
+        (B, C, width), {"expert_load": (1, experts)})``.  Traced under
+        the named scope ``draft``, the block's parts nested inside it."""
+        import jax
         import jax.numpy as jnp
 
         from ....ndarray import NDArray
@@ -604,17 +634,22 @@ class HybridDecoderLM(HybridBlock):
         start = start.astype(jnp.int32)
         C = follow.shape[1]
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
-        emb = jnp.take(self._embed.data()._data, follow.astype(jnp.int32),
-                       axis=0)
-        act = emb.dtype
-        u = _mm(jnp.concatenate([
-            _rms(hidden, g_h.data()._data, self._eps).astype(act),
-            _rms(emb, g_e.data()._data, self._eps).astype(act)], -1),
-            w_eh.data()._data)
-        x, kept, counts = self._block(
-            u, block, self._mixers[-1], self._ffns[-1], rows, start,
-            valid.astype(jnp.int32), pos)
-        head = self._head.data()._data
-        h = _rms(x, g_out.data()._data, self._eps).astype(head.dtype)
+        with jax.named_scope("draft"):
+            with jax.named_scope("embed"):
+                emb = jnp.take(self._embed.data()._data,
+                               follow.astype(jnp.int32), axis=0)
+            act = emb.dtype
+            u = _mm(jnp.concatenate([
+                _rms(hidden, g_h.data()._data, self._eps).astype(act),
+                _rms(emb, g_e.data()._data, self._eps).astype(act)], -1),
+                w_eh.data()._data)
+            x, kept, counts = self._block(
+                u, block, self._mixers[-1], self._ffns[-1], rows, start,
+                valid.astype(jnp.int32), pos)
+            with jax.named_scope("head"):
+                head = self._head.data()._data
+                h = _rms(x, g_out.data()._data,
+                         self._eps).astype(head.dtype)
+                logits = jnp.dot(h, head.T)
         extras = {} if counts is None else {"expert_load": counts[None]}
-        return NDArray(jnp.dot(h, head.T)), kept, extras
+        return NDArray(logits), kept, extras

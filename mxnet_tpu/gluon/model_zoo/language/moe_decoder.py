@@ -124,7 +124,10 @@ class MoEDecoderLM(HybridBlock):
         """One block over C positions a sequence.  ``x`` (B, C, D) raw;
         ``k_cache``/``v_cache`` (B, Hkv, S, dh) raw or None;
         ``cache_mask`` (B, 1, 1, S); ``chunk_mask`` (C, C).  Returns
-        (x_out, k_chunk, v_chunk (B, Hkv, C, dh), counts (E,))."""
+        (x_out, k_chunk, v_chunk (B, Hkv, C, dh), counts (E,)).  Its
+        parts are traced under the named scopes ``attn.proj``,
+        ``attn.core`` and the expert layer's own (``experts.route``,
+        ``experts.ffn``), which ``profiler.device_table`` reads."""
         import jax
         import jax.numpy as jnp
 
@@ -142,34 +145,38 @@ class MoEDecoderLM(HybridBlock):
         def mm(a, w):                     # a (..., in) x w (out, in)
             return jnp.dot(a.astype(w.dtype), w.T)
 
-        n = _rms(x, g1, self._eps)
-        q = _rms(mm(n, wq).reshape((B, C, Hq, dh)), gq, self._eps)
-        k = _rms(mm(n, wk).reshape((B, C, Hkv, dh)), gk, self._eps)
-        v = mm(n, wv).reshape((B, C, Hkv, dh))
-        q = _rope(q, pos, self._theta).astype(act)
-        k = _rope(k, pos, self._theta).astype(act)
-        # a key/value head serves G query heads: fold them into the
-        # query rows, so the cache is read once and never repeated
-        qg = q.reshape((B, C, Hkv, G, dh)).transpose(0, 2, 3, 1, 4) \
-            .reshape((B, Hkv, G * C, dh))
-        k_c, v_c = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-        scale = dh ** -0.5
-        neg = jnp.asarray(-1e30, f32)
-        s = jnp.einsum("bhqd,bhsd->bhqs", qg, k_c,
-                       preferred_element_type=f32) * scale
-        s = jnp.where(jnp.tile(chunk_mask, (G, 1))[None, None], s, neg)
-        vals = v_c
-        if k_cache is not None:
-            sc = jnp.einsum("bhqd,bhsd->bhqs", qg, k_cache.astype(act),
-                            preferred_element_type=f32) * scale
-            s = jnp.concatenate([jnp.where(cache_mask, sc, neg), s], -1)
-            vals = jnp.concatenate([v_cache.astype(act), v_c], 2)
-        att = jax.nn.softmax(s, axis=-1).astype(act)
-        o = jnp.einsum("bhqs,bhsd->bhqd", att, vals)
-        o = o.reshape((B, Hkv, G, C, dh)).transpose(0, 3, 1, 2, 4) \
-            .reshape((B, C, Hq * dh))
-        x = x + mm(o, wo).astype(x.dtype)
-        m = _rms(x, g2, self._eps).astype(act).reshape((B * C, D))
+        with jax.named_scope("attn.proj"):
+            n = _rms(x, g1, self._eps)
+            q = _rms(mm(n, wq).reshape((B, C, Hq, dh)), gq, self._eps)
+            k = _rms(mm(n, wk).reshape((B, C, Hkv, dh)), gk, self._eps)
+            v = mm(n, wv).reshape((B, C, Hkv, dh))
+            q = _rope(q, pos, self._theta).astype(act)
+            k = _rope(k, pos, self._theta).astype(act)
+            # a key/value head serves G query heads: fold them into the
+            # query rows, so the cache is read once and never repeated
+            qg = q.reshape((B, C, Hkv, G, dh)).transpose(0, 2, 3, 1, 4) \
+                .reshape((B, Hkv, G * C, dh))
+            k_c, v_c = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        with jax.named_scope("attn.core"):
+            scale = dh ** -0.5
+            neg = jnp.asarray(-1e30, f32)
+            s = jnp.einsum("bhqd,bhsd->bhqs", qg, k_c,
+                           preferred_element_type=f32) * scale
+            s = jnp.where(jnp.tile(chunk_mask, (G, 1))[None, None], s, neg)
+            vals = v_c
+            if k_cache is not None:
+                sc = jnp.einsum("bhqd,bhsd->bhqs", qg, k_cache.astype(act),
+                                preferred_element_type=f32) * scale
+                s = jnp.concatenate([jnp.where(cache_mask, sc, neg), s], -1)
+                vals = jnp.concatenate([v_cache.astype(act), v_c], 2)
+            att = jax.nn.softmax(s, axis=-1).astype(act)
+            o = jnp.einsum("bhqs,bhsd->bhqd", att, vals)
+        with jax.named_scope("attn.proj"):
+            o = o.reshape((B, Hkv, G, C, dh)).transpose(0, 3, 1, 2, 4) \
+                .reshape((B, C, Hq * dh))
+            x = x + mm(o, wo).astype(x.dtype)
+        with jax.named_scope("experts.route"):
+            m = _rms(x, g2, self._eps).astype(act).reshape((B * C, D))
         y, counts = routed_experts(
             m, wr.T, wg, wu, wd, c["top_k"], c["d_expert"],
             first=self._first, norm_topk=self._norm_topk)
@@ -179,13 +186,15 @@ class MoEDecoderLM(HybridBlock):
         """tokens (B, C) int; caches a list of (k, v) raw (B, Hkv, S,
         dh) or None; start (B,) int32.  Returns (logits raw (B, C, V),
         [(k, v) raw (B, Hkv, C, dh)], expert load (L, E) int32)."""
+        import jax
         import jax.numpy as jnp
 
         c = self._cfg
         B, C = tokens.shape
         Bl = c["block_length"]
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
-        x = jnp.take(self._embed.data()._data, tokens, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(self._embed.data()._data, tokens, axis=0)
         # within the chunk: a chunk starts on a block boundary
         blk = jnp.arange(C, dtype=jnp.int32) // Bl
         chunk_mask = blk[:, None] >= blk[None, :]
@@ -201,9 +210,12 @@ class MoEDecoderLM(HybridBlock):
                                               cache_mask, chunk_mask)
             new.append((k_c, v_c))
             loads.append(counts)
-        head = self._head.data()._data
-        h = _rms(x, self._final.data()._data, self._eps).astype(head.dtype)
-        return jnp.dot(h, head.T), new, jnp.stack(loads)
+        with jax.named_scope("head"):
+            head = self._head.data()._data
+            h = _rms(x, self._final.data()._data,
+                     self._eps).astype(head.dtype)
+            logits = jnp.dot(h, head.T)
+        return logits, new, jnp.stack(loads)
 
     def hybrid_forward(self, F, tokens, **_registered):
         import jax.numpy as jnp

@@ -93,8 +93,18 @@ def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
     int32.  Query head ``h`` reads key/value head ``h // (n_heads /
     n_kv_heads)``.  Chunk position ``c`` attends every cached position
     below ``start_b`` and chunk positions ``c' <= c``.  Returns
-    (B, C, n_heads * d_head) in ``q``'s dtype.
+    (B, C, n_heads * d_head) in ``q``'s dtype.  Traced under the named
+    scope ``attn.core`` (``mxnet_tpu.profiler.device_table``).
     """
+    import jax
+
+    with jax.named_scope("attn.core"):
+        return _chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows,
+                                     start, n_heads, n_kv_heads, scale)
+
+
+def _chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
+                          n_heads, n_kv_heads, scale):
     import jax.numpy as jnp
 
     B, C, _ = q.shape

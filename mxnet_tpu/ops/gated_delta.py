@@ -45,15 +45,18 @@ SUB = 64
 def gated_delta_step(q, k, v, g, beta, state):
     """One token a row.  ``q``, ``k``, ``g`` (..., d_k); ``v``
     (..., d_v); ``beta`` (...,); ``state`` (..., d_k, d_v) float32.
-    Returns ``(o (..., d_v), state)``."""
+    Returns ``(o (..., d_v), state)``.  Traced under the named scope
+    ``kda.scan``."""
+    import jax
     import jax.numpy as jnp
 
-    f32 = jnp.float32
-    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
-    s = state * jnp.exp(g)[..., None]
-    delta = beta[..., None] * (v - jnp.sum(s * k[..., None], axis=-2))
-    s = s + k[..., None] * delta[..., None, :]
-    return jnp.sum(s * q[..., None], axis=-2), s
+    with jax.named_scope("kda.scan"):
+        f32 = jnp.float32
+        q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+        s = state * jnp.exp(g)[..., None]
+        delta = beta[..., None] * (v - jnp.sum(s * k[..., None], axis=-2))
+        s = s + k[..., None] * delta[..., None, :]
+        return jnp.sum(s * q[..., None], axis=-2), s
 
 
 def _unit_lower_inverse(low):
@@ -104,7 +107,16 @@ def gated_delta_chunk(q, k, v, g, beta, state, valid=None):
     ``k``, ``g`` (B, T, H, d_k); ``v`` (B, T, H, d_v);
     ``beta`` (B, T, H); ``state`` (B, H, d_k, d_v) float32; ``valid``
     (B,) int32, the leading positions of each row that count (all of
-    them without it).  Returns ``(o (B, T, H, d_v) float32, state)``."""
+    them without it).  Returns ``(o (B, T, H, d_v) float32, state)``.
+    Traced under the named scope ``kda.scan``: the decay sums, the block
+    inverse and the scan over sub-chunks."""
+    import jax
+
+    with jax.named_scope("kda.scan"):
+        return _gated_delta_chunk(q, k, v, g, beta, state, valid)
+
+
+def _gated_delta_chunk(q, k, v, g, beta, state, valid):
     import jax.numpy as jnp
     from jax import lax
 

@@ -267,7 +267,10 @@ def _grouped_experts(x, top_p, top_i, counts, w_gate, w_up, w_down,
     ``interpret`` runs the kernels under the Pallas interpreter
     wherever (the tests).  A function jitted once: the layers of a
     program, alike in their shapes, share one trace and one lowering of
-    it (the kernels' lowering is most of an expert layer's)."""
+    it (the kernels' lowering is most of an expert layer's); its named
+    scopes, ``experts.route`` round the sort, the tile map and the
+    padded rows' gather and ``experts.ffn`` round the grouped product,
+    are inside it, so the shared trace carries them."""
     return _jitted_grouped_experts()(
         x, top_p, top_i, counts, w_gate, w_up, w_down, first,
         d_expert=int(d_expert), interpret=bool(interpret))
@@ -283,6 +286,7 @@ def _jitted_grouped_experts():
 
 def _grouped_experts_traced(x, top_p, top_i, counts, w_gate, w_up, w_down,
                             first, d_expert, interpret):
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -299,54 +303,57 @@ def _grouped_experts_traced(x, top_p, top_i, counts, w_gate, w_up, w_down,
     n_tiles_max = -(-P // tm) + min(held, P)
     R = n_tiles_max * tm
 
-    local = top_i.reshape((P,)).astype(i32) - first
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    # the pairs by expert, those on experts not held here behind the
-    # others, each with its row of ``x`` and its weight
-    place = jnp.arange(P, dtype=i32)
-    skey, srow, sw = lax.sort(
-        (key, place // k, top_p.reshape((P,)).astype(f32)), num_keys=1,
-        is_stable=True)
-    c = lax.dynamic_slice_in_dim(counts, first, held)     # rows an expert
-    tiles = -(-c // tm)
-    tile_end = jnp.cumsum(tiles)
-    # a sorted pair's padded row: its expert's first tile, then its
-    # place among its expert's rows (a pair not held: past the end)
-    shift = jnp.concatenate([(tile_end - tiles) * tm - (jnp.cumsum(c) - c),
-                             jnp.full((1,), R, i32)])
-    at = place + shift[skey]
-    packed = jnp.zeros((R, 2), i32).at[at].set(
-        jnp.stack([srow, lax.bitcast_convert_type(sw, i32)], axis=1),
-        mode="drop", indices_are_sorted=True, unique_indices=True)
-    # (padding rows: a copy of row 0 at weight 0)
-    row_of = packed[:, 0]
-    row_weight = lax.bitcast_convert_type(packed[:, 1], f32)
-    tile_expert = jnp.minimum(
-        (tile_end[None, :] <= jnp.arange(n_tiles_max, dtype=i32)[:, None])
-        .sum(1), held - 1).astype(i32)
-    rows = jnp.take(x, row_of, axis=0, mode="clip")
+    with jax.named_scope("experts.route"):
+        local = top_i.reshape((P,)).astype(i32) - first
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        # the pairs by expert, those on experts not held here behind the
+        # others, each with its row of ``x`` and its weight
+        place = jnp.arange(P, dtype=i32)
+        skey, srow, sw = lax.sort(
+            (key, place // k, top_p.reshape((P,)).astype(f32)), num_keys=1,
+            is_stable=True)
+        c = lax.dynamic_slice_in_dim(counts, first, held)     # rows an expert
+        tiles = -(-c // tm)
+        tile_end = jnp.cumsum(tiles)
+        # a sorted pair's padded row: its expert's first tile, then its
+        # place among its expert's rows (a pair not held: past the end)
+        shift = jnp.concatenate([(tile_end - tiles) * tm - (jnp.cumsum(c) - c),
+                                 jnp.full((1,), R, i32)])
+        at = place + shift[skey]
+        packed = jnp.zeros((R, 2), i32).at[at].set(
+            jnp.stack([srow, lax.bitcast_convert_type(sw, i32)], axis=1),
+            mode="drop", indices_are_sorted=True, unique_indices=True)
+        # (padding rows: a copy of row 0 at weight 0)
+        row_of = packed[:, 0]
+        row_weight = lax.bitcast_convert_type(packed[:, 1], f32)
+        tile_expert = jnp.minimum(
+            (tile_end[None, :] <= jnp.arange(n_tiles_max, dtype=i32)[:, None])
+            .sum(1), held - 1).astype(i32)
+        rows = jnp.take(x, row_of, axis=0, mode="clip")
 
-    def by_kernel(rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
+    with jax.named_scope("experts.ffn"):
+        def by_kernel(rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
+                      row_weight):
+            n_tiles = jnp.sum(tiles).astype(i32)
+            h = kernels.gate_up(rows, w_gate, w_up, tile_expert, n_tiles,
+                                F, tm, interpret=interpret)
+            return kernels.down_combine(
+                h, w_down, tile_expert, n_tiles, row_of, row_weight, T, tm,
+                interpret=interpret)
+
+        def plain(rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
                   row_weight):
-        n_tiles = jnp.sum(tiles).astype(i32)
-        h = kernels.gate_up(rows, w_gate, w_up, tile_expert, n_tiles, F, tm,
-                            interpret=interpret)
-        return kernels.down_combine(h, w_down, tile_expert, n_tiles, row_of,
-                                    row_weight, T, tm, interpret=interpret)
+            y = _plain_grouped_ffn(rows, w_gate, w_up, w_down, tiles, F, tm)
+            return jnp.zeros((T, D), f32).at[row_of].add(
+                y * row_weight[:, None])
 
-    def plain(rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
-              row_weight):
-        y = _plain_grouped_ffn(rows, w_gate, w_up, w_down, tiles, F, tm)
-        return jnp.zeros((T, D), f32).at[row_of].add(
-            y * row_weight[:, None])
-
-    args = (rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
-            row_weight)
-    if interpret:
-        return by_kernel(*args)
-    if kernels.fits(D, F, R):
-        return lax.platform_dependent(*args, tpu=by_kernel, default=plain)
-    return plain(*args)
+        args = (rows, w_gate, w_up, w_down, tile_expert, tiles, row_of,
+                row_weight)
+        if interpret:
+            return by_kernel(*args)
+        if kernels.fits(D, F, R):
+            return lax.platform_dependent(*args, tpu=by_kernel, default=plain)
+        return plain(*args)
 
 
 def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
@@ -399,6 +406,10 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
         a token's experts in float32
       counts: (n_experts,) int32, the tokens routed to each expert of
         the whole layer in this call.
+    Traced under the named scopes ``experts.route`` (router, selection,
+    sort, tile map, the padded rows' gather) and ``experts.ffn`` (the
+    grouped product), which ``profiler.device_table`` reads the device's
+    time by.
     """
     import jax
     import jax.numpy as jnp
@@ -407,16 +418,17 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, top_k, d_expert,
     n_exp = router_w.shape[-1]
     _check_top_k(top_k, n_exp)
     f32 = jnp.float32
-    logits = jnp.dot(x, router_w, preferred_element_type=f32)
-    if select is not None:
-        top_p, top_i = select(logits, top_k)
-    else:
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = lax.top_k(probs, top_k)                # (T, k)
-        if norm_topk:
-            top_p = top_p / top_p.sum(-1, keepdims=True)
-    chosen = top_i[:, :, None] == jnp.arange(n_exp, dtype=top_i.dtype)
-    counts = chosen.sum((0, 1)).astype(jnp.int32)             # (E,)
+    with jax.named_scope("experts.route"):
+        logits = jnp.dot(x, router_w, preferred_element_type=f32)
+        if select is not None:
+            top_p, top_i = select(logits, top_k)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_i = lax.top_k(probs, top_k)            # (T, k)
+            if norm_topk:
+                top_p = top_p / top_p.sum(-1, keepdims=True)
+        chosen = top_i[:, :, None] == jnp.arange(n_exp, dtype=top_i.dtype)
+        counts = chosen.sum((0, 1)).astype(jnp.int32)         # (E,)
     out = _grouped_experts(x, top_p, top_i, counts, w_gate, w_up, w_down,
                            d_expert, first)
     return out.astype(x.dtype), counts

@@ -52,13 +52,20 @@ def f(x):
     return jnp.sin(x) @ jnp.cos(x.T) + jnp.tanh(x).sum()
 
 x = jnp.asarray(np.random.RandomState(0).rand(64, 64), jnp.float32)
-cold = jax.jit(f)(x).block_until_ready()
-entries = [e for e in os.listdir(placed) if e.endswith("-cache")]
-assert entries, "first compile wrote no cache entry"
-
-events.clear()
-jax.clear_caches()  # drop in-memory executables; disk cache remains
-warm = jax.jit(f)(x).block_until_ready()
+# both calls from one line: the key holds the names and source lines a
+# device profile is read by (config.enable_compile_cache), the caller's
+# among them
+for again in (False, True):
+    if again:
+        events.clear()
+        jax.clear_caches()  # drop in-memory executables; disk cache remains
+    out = jax.jit(f)(x).block_until_ready()
+    if not again:
+        cold = out
+        entries = [e for e in os.listdir(placed) if e.endswith("-cache")]
+        assert entries, "first compile wrote no cache entry"
+warm = out
+assert jax.config.jax_compilation_cache_include_metadata_in_key
 assert "/jax/compilation_cache/cache_hits" in events, \
     "second compile missed the persistent cache: %s" % [
         e for e in events if "cache" in e]

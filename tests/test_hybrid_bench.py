@@ -71,8 +71,10 @@ def test_manifest_gains_one_configuration_and_one_cell():
         assert (layer[name]["unit"], layer[name]["layer"],
                 layer[name]["moves"], layer[name]["workloads"][0]) == \
             (unit, where, "serve_itl_p95_ms", CELL)
-    # (PR 36 added the share of rows the held experts multiply)
-    assert set(layer) == set(NEW_METRICS) | {
+    # (PR 36 added the share of rows the held experts multiply, PR 37
+    # the device's time by named scope: `tests/test_device_scopes.py`)
+    assert {n for n in layer if manifest.layer_metric(n)["reducer"]
+            != "device_by_scope"} == set(NEW_METRICS) | {
         "moe_expert_rows_share",
         "serve_prefill_share", "serve_tick_ms_p95", "setup_build_s",
         "setup_compile_s", "setup_trace_lower_s", "setup_executable_load_s"}
@@ -379,9 +381,10 @@ def test_span_args_reader_of_the_rows_the_experts_multiply(ctx):
     """``moe_expert_rows_share`` (PR 36): the rows the held experts
     multiplied, each one's padded up to whole tiles, over what every
     held expert multiplying every row would be, of the window's
-    ``engine.decode`` spans; data only, in the manifest's last place."""
+    ``engine.decode`` spans; data only, the manifest's last entry
+    before PR 37's eleven."""
     man = manifest.manifest()
-    entry = man["per_layer"][-1]
+    entry = man["per_layer"][-12]
     assert entry == {
         "name": "moe_expert_rows_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "experts",
