@@ -1581,7 +1581,10 @@ class PagedGenerationEngine:
                 "slot": int(slot), "filled": int(filled),
                 "count": int(count), "final": final,
                 "block": self._block,
-                "attn": self._attends_in(self._chunk)}) as step:
+                "attn": self._attends_in(self._chunk),
+                "cache_rows_attended": self._cache_rows_attended(
+                    self._chunk, filled),
+                "cache_rows_held": self._capacity}) as step:
             chunk = np.zeros((1, self._chunk), np.int32)
             chunk[0, :count] = toks[filled:filled + count]
             wpage = np.zeros(self._chunk, np.int32)
@@ -2171,6 +2174,21 @@ class PagedGenerationEngine:
         from .ops.attention_rows import attends_in
 
         return attends_in(chunk, self.model_config["n_heads"])
+
+    def _cache_rows_attended(self, chunk, written):
+        """Cached rows a slot that the program of a dispatch of
+        ``chunk`` positions a slot multiplies when the longest of its
+        sequences has written ``written`` (the ``cache_rows_attended``
+        of ``engine.prefill`` beside ``cache_rows_held``, a slot's
+        capacity): a model that declares its layers' caches attends its
+        rows itself, by ``ops.attention_rows``'s rule on the dispatch's
+        shape, a chunk in whole blocks up to ``written``; every other
+        attention multiplies all it is handed and masks."""
+        if not self._declared:
+            return self._capacity
+        from .ops.attention_rows import attended_cache_rows
+
+        return attended_cache_rows(chunk, written, self._capacity)
 
     def _dispatch_args(self, shape):
         """Arguments of the dispatch at one token shape, all zeros

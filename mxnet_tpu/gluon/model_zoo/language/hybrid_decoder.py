@@ -45,7 +45,9 @@ lie and the result goes through ``W_uv``, so no key or value of a
 cached position is ever rebuilt, in a decode step or in a chunk (on
 the chip a chunk of 512 against 9216 rows takes 46.0 ms absorbed and
 48.0 with the rows expanded to per-head keys and values: PERF.md, PR
-33).
+33).  A chunk attends the cached rows a block at a time up to the rows
+its sequence has written, a step all the rows a slot holds
+(``ops.attention_rows.cached_rows_in``; PERF.md, PR 38).
 
 **The expert layer** is ``parallel.moe.routed_experts`` with
 ``sigmoid_group_select``; told which experts it holds
@@ -154,6 +156,70 @@ def _gated_mlp(x, wg, wu, wd):
 
 def _raw(params):
     return [q.data()._data for q in params]
+
+
+def _attend_in_blocks(q, rows, start, scale, s_own, own, keep):
+    """The context of absorbed latent attention over the cached rows a
+    dispatch's sequences have written and over the chunk's own, the
+    cached ones a block of ``ops.attention_rows.CACHE_BLOCK_ROWS`` at a
+    time: ``q`` (B, C, H, lanes) the queries in the latent space (zeros
+    under the rows' zero lanes), ``rows`` (B, S, lanes) the cache, of
+    which a sequence attends positions ``< start_b``; ``s_own`` (B, H,
+    C, C) float32 the chunk's own scores, scaled and causally masked,
+    ``own`` (B, C, >= keep) its rows.  Returns (B, C, H, keep) float32.
+
+    The softmax runs online (a running maximum, denominator and
+    context, float32), started from the chunk's own block, whose
+    maximum is finite: every query attends at least itself.  The loop's
+    trip count is ``ceil(max(start) / block)``, computed by the program:
+    a block past the longest sequence's rows is never read, one partly
+    live (and every block of a shorter sequence of the dispatch) is
+    masked by ``s < start_b`` as the whole-``S`` products mask.  The
+    last block of a cache that is not whole blocks is read ending at
+    ``S`` and its rows under the block's own first are masked."""
+    import jax
+    import jax.numpy as jnp
+
+    from ....ops.attention_rows import cache_block_rows
+
+    f32, act = jnp.float32, rows.dtype
+    B, S, lanes = rows.shape
+    K = cache_block_rows(S)
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a, b, preferred_element_type=f32)
+
+    m = s_own.max(-1, keepdims=True)                        # (B, H, C, 1)
+    e = jnp.exp(s_own - m)
+    den = e.sum(-1, keepdims=True)
+    ctx = dot("bhcs,bsw->bhcw", e.astype(act), own[..., :keep])
+
+    def block(j, carry):
+        m, den, ctx = carry
+        first = j * K
+        at = jnp.minimum(first, S - K)
+        blk = jax.lax.dynamic_slice(rows, (0, at, 0), (B, K, lanes))
+        s = dot("bchw,bsw->bhcs", q, blk) * scale           # (B, H, C, K)
+        idx = at + jnp.arange(K, dtype=jnp.int32)
+        ok = (idx >= first)[None, :] & (idx[None, :] < start[:, None])
+        s = jnp.where(ok[:, None, None, :], s, -1e30)
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        shrink = jnp.exp(m - m_new)
+        e = jnp.exp(s - m_new)              # a masked score's is exactly 0
+        den = den * shrink + e.sum(-1, keepdims=True)
+        # (the block's latent lanes are sliced off before the product,
+        # where the whole form multiplies the row whole and slices the
+        # result: a block is small to copy, and the product then adds
+        # into the carried context without a pass over a wider result;
+        # on a v5e, GigaChat's chunk at start 512: 1.11 against 1.58 ms
+        # a layer, tools/bench_mla_chunk.py, PERF.md PR 38)
+        ctx = ctx * shrink \
+            + dot("bhcs,bsw->bhcw", e.astype(act), blk[..., :keep])
+        return m_new, den, ctx
+
+    blocks = (jnp.max(start) + K - 1) // K
+    _m, den, ctx = jax.lax.fori_loop(0, blocks, block, (m, den, ctx))
+    return jnp.swapaxes(ctx / den, 1, 2)
 
 
 class HybridDecoderLM(HybridBlock):
@@ -413,11 +479,28 @@ class HybridDecoderLM(HybridBlock):
         Returns (out (B, C, D), the chunk's rows (B, C, dl+dr)).
         Traced under the named scopes ``attn.proj`` (every product with
         a weight, the rotary positions) and ``attn.core`` (scores,
-        softmax and context over the rows)."""
+        softmax and context over the rows).
+
+        How much of ``rows`` is multiplied follows the dispatch's static
+        shape (``ops.attention_rows.cached_rows_in``, nothing else): a
+        **prefill chunk** (many query positions of one slot) attends
+        blocks of cached rows up to its longest ``start``
+        (:func:`_attend_in_blocks`: a loop in the one program, its trip
+        count computed from ``start``), so it costs the rows its
+        sequence has written and not the slot's capacity ``S``; a
+        **decode or verify step** (one or two positions of every slot)
+        multiplies all ``S`` rows and masks: over 32 slots the longest
+        sequence bounds the loop, its products are small and bound by
+        the rows' bytes, and a loop a layer a step costs more at its
+        edges than the masked rows it would skip (a v5e, GigaChat's
+        ``(32, 2)``: 0.88 ms a layer whole, 0.98-1.33 in blocks; PERF.md,
+        PR 38).  One mathematics either way: operands in the dtype they
+        arrive in, products accumulated in float32, a float32 softmax
+        over the cached and the chunk's own positions together."""
         import jax
         import jax.numpy as jnp
 
-        from ....ops.attention_rows import _softmax_pair
+        from ....ops.attention_rows import _softmax_pair, cached_rows_in
 
         z = self._sizes
         H, dn, dr, dl, dv = z["H"], z["dn"], z["dr"], z["dl"], z["dvm"]
@@ -449,10 +532,7 @@ class HybridDecoderLM(HybridBlock):
         causal = jnp.tril(jnp.ones((C, C), bool))[None, None]
         cached = rows is not None
         if cached:
-            S = rows.shape[1]
             rows = rows.astype(act)
-            cache_ok = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                        < start[:, None])[:, None, None, :]
 
         def dot(spec, a, b):
             return jnp.einsum(spec, a, b, preferred_element_type=f32)
@@ -466,19 +546,27 @@ class HybridDecoderLM(HybridBlock):
             q_cat = jnp.concatenate([q_lat, q_r], axis=-1)      # (B,C,H,dl+dr)
         with jax.named_scope("attn.core"):
             s_new = dot("bchw,bsw->bhcs", q_cat, new) * scale
-            if cached:
-                # (zeros under the pool's zero lanes)
-                q_old = jnp.pad(q_cat, [(0, 0)] * 3 + [
-                    (0, rows.shape[-1] - dl - dr)])
-                s_old = dot("bchw,bsw->bhcs", q_old, rows) * scale
-                p_old, p_new = _softmax_pair(s_old, s_new, cache_ok, causal,
-                                             act)
-                ctx = dot("bhcs,bsw->bchw", p_old, rows)[..., :dl] \
-                    + dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
-            else:
+            if not cached:
                 p_new = jax.nn.softmax(
                     jnp.where(causal, s_new, -1e30), -1).astype(act)
                 ctx = dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
+            else:
+                # (zeros under the pool's zero lanes)
+                q_old = jnp.pad(q_cat, [(0, 0)] * 3 + [
+                    (0, rows.shape[-1] - dl - dr)])
+                if cached_rows_in(C) == "whole":
+                    cache_ok = (
+                        jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
+                        < start[:, None])[:, None, None, :]
+                    s_old = dot("bchw,bsw->bhcs", q_old, rows) * scale
+                    p_old, p_new = _softmax_pair(s_old, s_new, cache_ok,
+                                                 causal, act)
+                    ctx = dot("bhcs,bsw->bchw", p_old, rows)[..., :dl] \
+                        + dot("bhcs,bsw->bchw", p_new, new)[..., :dl]
+                else:
+                    ctx = _attend_in_blocks(
+                        q_old, rows, start, scale,
+                        jnp.where(causal, s_new, -1e30), new, dl)
         with jax.named_scope("attn.proj"):
             o = dot("bchl,hdl->bchd", ctx.astype(act), w_uv)
             if wgate is not None:

@@ -29,10 +29,30 @@ and nothing else decides it.
 Either way the operands stay in the dtype they arrive in, the products
 accumulate in float32, and the softmax over the cached and the chunk's
 own positions together is float32.
+
+**How much of the cache a dispatch attends** is a second rule on the
+same static shape, :func:`cached_rows_in`, for a model that attends its
+own rows (``HybridDecoderLM._mla``'s absorbed latent attention; the
+products above attend all ``S`` rows in every shape).  A slot's rows are
+gathered at its whole capacity ``S`` and those at or above ``start`` are
+masked, so products over all of them cost the capacity whatever the
+sequence has written.  A **prefill chunk** (many query positions of one
+slot, compute-bound on them) attends in **blocks** of
+:data:`CACHE_BLOCK_ROWS` rows, a loop whose trip count the program
+computes from ``start``: it multiplies the blocks the sequence has
+reached and never reads the rest.  A **decode or verify step** (one or a
+few query positions of every slot) keeps the whole-``S`` products: over
+32 slots the bound is the longest sequence's, the products are
+bound by the rows' bytes and small, and a loop in every layer of every
+step costs its edges (no operation is scheduled across them, weights
+are not fetched ahead over them) more than the masked rows it would
+skip.  :func:`attended_cache_rows` is the count a dispatch multiplies,
+which the engine writes on its ``engine.prefill`` span.
 """
 from __future__ import annotations
 
-__all__ = ["chunk_attention_rows", "attends_in"]
+__all__ = ["chunk_attention_rows", "attends_in", "cached_rows_in",
+           "cache_block_rows", "attended_cache_rows"]
 
 # query rows a slot (chunk positions x query heads) up to which the
 # block-diagonal products beat the head-split ones.  Timed on a TPU v5e
@@ -50,6 +70,55 @@ def attends_in(chunk, n_heads):
     head-split products)."""
     return "rows" if chunk * n_heads <= BLOCK_DIAGONAL_MAX_QUERY_ROWS \
         else "heads"
+
+
+# rows of cache a block of the blocked form holds: whole pages and whole
+# sublane tiles.  Timed on a TPU v5e in a loop of its own
+# (tools/bench_mla_chunk.py; PERF.md, PR 38), one layer's scores,
+# softmax and context of a chunk of 512 at GigaChat's 64 heads against
+# 6144 rows, milliseconds at start 0 | 512 | 1536 | 3584: blocks of 256
+# 0.96 | 1.23 | 1.73 | 2.78, of 512 0.77 | 1.11 | 1.78 | 3.11, of 1024
+# 0.98 | 1.69 | 2.42 | 3.82, of 2048 0.95 | 2.35 | 2.36 | 3.74, all the
+# rows 5.55-5.57; at Ling's 32 heads against 9216 rows 512 is the
+# fastest at every start (0.58 at 512, 1.28 at 3584; all rows 3.88).
+# Half the serving cells' chunks start at or under 512
+CACHE_BLOCK_ROWS = 512
+
+# query positions a slot from which a dispatch is a chunk and attends
+# its cache in blocks; under it (a decode step's 1, a verify step's
+# 1 + spec_k) the whole-S products stay.  The same timing, a layer of a
+# (32, 2) verify step at GigaChat's widths with the longest slot at
+# 501 | 1503 | 3506 rows: all the rows 0.88, blocks of 512 0.98 | 1.10 |
+# 1.33; a (32, 1) step at Ling's: 1.19 against 1.31 | 1.43 | 1.65
+BLOCKED_CACHE_MIN_QUERY_POSITIONS = 16
+
+
+def cached_rows_in(chunk):
+    """How a dispatch of ``chunk`` query positions a slot attends the
+    rows its model caches and attends itself (``HybridDecoderLM._mla``):
+    ``"blocks"`` (blocks of :data:`CACHE_BLOCK_ROWS` rows up to the
+    longest ``start`` of the dispatch, a loop inside the one program)
+    or ``"whole"`` (all ``S`` rows a slot holds, masked).  The static
+    shape decides and nothing else."""
+    return "blocks" if chunk >= BLOCKED_CACHE_MIN_QUERY_POSITIONS \
+        else "whole"
+
+
+def cache_block_rows(held):
+    """Rows a block of the blocked form holds when a slot holds
+    ``held``: :data:`CACHE_BLOCK_ROWS`, or all of a smaller cache."""
+    return min(CACHE_BLOCK_ROWS, int(held))
+
+
+def attended_cache_rows(chunk, start, held):
+    """Cached rows a slot a dispatch of ``chunk`` query positions
+    multiplies when its longest sequence has written ``start`` of the
+    ``held`` rows a slot holds: whole blocks up to ``start`` under
+    ``"blocks"``, ``held`` under ``"whole"``."""
+    if cached_rows_in(chunk) == "whole":
+        return int(held)
+    k = cache_block_rows(held)
+    return min(-(-int(start) // k) * k, int(held))
 
 
 def _softmax_pair(s_cache, s_chunk, cache_ok, chunk_ok, dtype):

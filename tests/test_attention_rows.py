@@ -1,7 +1,9 @@
 """``ops.attention_rows.chunk_attention_rows`` (ISSUE 32): the attention
 the decode engine's row pool is read by, in both of its forms, held to a
-plain head-split attention in float32; and the rule that picks the form
-from the dispatch's shape."""
+plain head-split attention in float32; the rule that picks the form
+from the dispatch's shape; and the second rule on that shape (ISSUE 38):
+whether a model that attends its own rows reads them in blocks up to
+``start`` or over all a slot holds, and the count the engine reports."""
 import numpy as np
 import pytest
 
@@ -75,6 +77,44 @@ def test_form_follows_the_query_rows_a_slot():
     assert attention_rows.attends_in(32, 32) == "heads"
     assert attention_rows.attends_in(limit, 1) == "rows"
     assert attention_rows.attends_in(limit + 1, 1) == "heads"
+
+
+@pytest.mark.parametrize("chunk,form", [
+    (1, "whole"), (2, "whole"), (8, "whole"), (16, "blocks"),
+    (24, "blocks"), (512, "blocks")])
+def test_cached_rows_are_attended_in_blocks_by_a_chunk_alone(chunk, form):
+    """The second rule on the static shape (ISSUE 38): a decode step's
+    one position a slot and a verify step's few attend all the rows a
+    slot holds, a prefill chunk blocks of them up to ``start``."""
+    assert attention_rows.cached_rows_in(chunk) == form
+    limit = attention_rows.BLOCKED_CACHE_MIN_QUERY_POSITIONS
+    assert attention_rows.cached_rows_in(limit - 1) == "whole"
+    assert attention_rows.cached_rows_in(limit) == "blocks"
+
+
+@pytest.mark.parametrize("start,held,block,want", [
+    (0, 6144, 512, 0), (1, 6144, 512, 512), (512, 6144, 512, 512),
+    (513, 6144, 512, 1024), (3584, 6144, 512, 3584),
+    (3584, 6144, 1024, 4096), (6000, 6144, 1024, 6144),
+    (9000, 9216, 512, 9216), (30, 128, 512, 128), (0, 128, 512, 0),
+    (33, 40, 16, 40), (32, 40, 16, 32)])
+def test_rows_a_dispatch_multiplies(monkeypatch, start, held, block, want):
+    """``attended_cache_rows``: whole blocks up to the longest ``start``
+    under ``"blocks"`` (a block is all of a smaller cache; the last
+    block of a cache that is not whole blocks ends with it), all a slot
+    holds under ``"whole"`` whatever is written."""
+    monkeypatch.setattr(attention_rows, "CACHE_BLOCK_ROWS", block)
+    assert attention_rows.cache_block_rows(held) == min(block, held)
+    assert attention_rows.attended_cache_rows(512, start, held) == want
+    assert attention_rows.attended_cache_rows(2, start, held) == held
+
+
+def test_a_block_is_whole_pages_and_whole_sublane_tiles():
+    """The constant as committed: a multiple of the serving cells' page
+    (16 rows) and of a bfloat16 sublane tile (16 rows), so that a
+    block's first row is a tile's first row."""
+    assert attention_rows.CACHE_BLOCK_ROWS % 16 == 0
+    assert attention_rows.CACHE_BLOCK_ROWS >= 128
 
 
 def test_operands_keep_their_dtype_and_accumulate_in_f32():

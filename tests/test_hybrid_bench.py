@@ -72,10 +72,11 @@ def test_manifest_gains_one_configuration_and_one_cell():
                 layer[name]["moves"], layer[name]["workloads"][0]) == \
             (unit, where, "serve_itl_p95_ms", CELL)
     # (PR 36 added the share of rows the held experts multiply, PR 37
-    # the device's time by named scope: `tests/test_device_scopes.py`)
+    # the device's time by named scope: `tests/test_device_scopes.py`,
+    # PR 38 the share of a slot's cache a chunk's attention multiplies)
     assert {n for n in layer if manifest.layer_metric(n)["reducer"]
             != "device_by_scope"} == set(NEW_METRICS) | {
-        "moe_expert_rows_share",
+        "moe_expert_rows_share", "prefill_attended_rows_share",
         "serve_prefill_share", "serve_tick_ms_p95", "setup_build_s",
         "setup_compile_s", "setup_trace_lower_s", "setup_executable_load_s"}
     assert {m["moves"] for m in layer.values()} <= e2e
@@ -382,9 +383,9 @@ def test_span_args_reader_of_the_rows_the_experts_multiply(ctx):
     multiplied, each one's padded up to whole tiles, over what every
     held expert multiplying every row would be, of the window's
     ``engine.decode`` spans; data only, the manifest's last entry
-    before PR 37's eleven."""
+    before PR 37's eleven and PR 38's one."""
     man = manifest.manifest()
-    entry = man["per_layer"][-12]
+    entry = man["per_layer"][-13]
     assert entry == {
         "name": "moe_expert_rows_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "experts",
@@ -402,6 +403,41 @@ def test_span_args_reader_of_the_rows_the_experts_multiply(ctx):
     ctx["ring"]["records"][:] = [_decode(10.2, expert_rows_held=3,
                                          expert_rows_all=9)]
     assert span_args.reduce(ctx, **_args("moe_expert_rows_share")) is None
+
+
+def test_span_args_reader_of_the_rows_a_chunk_attends(ctx):
+    """``prefill_attended_rows_share`` (PR 38): the cached rows a
+    chunk's attention multiplies, whole blocks up to what its sequence
+    has written, over the rows a slot holds, of the window's
+    ``engine.prefill`` spans; data only, the manifest's last entry, in
+    the two cells whose model attends its own rows."""
+    man = manifest.manifest()
+    assert man["per_layer"][-1] == {
+        "name": "prefill_attended_rows_share", "unit": "%",
+        "better": "lower", "source": "program_counter", "layer": "kernels",
+        "moves": "serve_itl_p95_ms",
+        "workloads": [CELL, "gigachat3_serve_reason"]}
+    assert manifest.layer_metric("prefill_attended_rows_share") == {
+        "name": "prefill_attended_rows_share", "reducer": "span_args",
+        "args": {"span": "engine.prefill", "num": ["cache_rows_attended"],
+                 "den": ["cache_rows_held"], "scale": 100.0}}
+
+    def chunk(t0, **args):
+        return {"name": "engine.prefill", "t0": t0, "dur": 0.01, "tid": 1,
+                "args": dict({"slot": 0, "filled": 0, "count": 512}, **args)}
+
+    ctx["ring"]["records"] += [
+        chunk(9.5, cache_rows_attended=9216, cache_rows_held=9216),
+        chunk(10.2, cache_rows_attended=0, cache_rows_held=9216),
+        chunk(10.3, cache_rows_attended=1536, cache_rows_held=9216),
+        chunk(10.4, cache_rows_attended=4096, cache_rows_held=9216)]
+    assert span_args.reduce(
+        ctx, **_args("prefill_attended_rows_share")) == pytest.approx(
+            100.0 * (1536 + 4096) / (3 * 9216))
+    # the parent writes neither argument: the metric is left out
+    ctx["ring"]["records"][:] = [chunk(10.2), chunk(10.4)]
+    assert span_args.reduce(
+        ctx, **_args("prefill_attended_rows_share")) is None
 
 
 def test_roofline_and_chunk_readers(cfg, ctx, monkeypatch):

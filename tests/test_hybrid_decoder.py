@@ -285,6 +285,129 @@ def test_absorbed_mla_matches_expanded(cfg, model):
     assert np.abs(np.asarray(new) - np.asarray(rows[:, 40:])).max() < 1e-5
 
 
+# the blocked form of a chunk's cached attention (ISSUE 38): 64 cached
+# rows in blocks of 16, chunks of 16 queries
+BLOCK, HELD, CHUNK = 16, 64, 16
+
+
+@pytest.fixture
+def mla_layer(cfg, model, monkeypatch):
+    """The rehearsal model's MLA layer alone with 80 positions' rows
+    made from nothing, and ``attend(start, cached, blocks)``: a chunk of
+    16 queries a slot at ``start`` (2,) against ``cached`` (2, 64, 128),
+    in blocks of 16 rows or over all 64."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention_rows
+
+    net, _arrays = model
+    li = net._mixers.index("mla")
+    p = [q.data()._data for q in net._layers[li][1]]
+    w = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    n = jax.random.normal(jax.random.key(3), (2, HELD + CHUNK,
+                                              cfg["hidden_size"]))
+    zero = jnp.zeros((2,), jnp.int32)
+    _out, rows = net._mla(n, p, None, zero,
+                          jnp.arange(HELD + CHUNK)[None, :] + zero[:, None])
+    cached = jnp.pad(rows[:, :HELD], ((0, 0), (0, 0), (0, 128 - w)))
+    monkeypatch.setattr(attention_rows, "CACHE_BLOCK_ROWS", BLOCK)
+
+    def attend(start, cached, blocks):
+        monkeypatch.setattr(
+            attention_rows, "BLOCKED_CACHE_MIN_QUERY_POSITIONS",
+            CHUNK if blocks else CHUNK + 1)
+        assert attention_rows.cached_rows_in(CHUNK) == \
+            ("blocks" if blocks else "whole")
+        start = jnp.asarray(start, jnp.int32)
+        pos = start[:, None] + jnp.arange(CHUNK, dtype=jnp.int32)[None, :]
+        # (the queries of positions 0..15 wherever the chunk is placed:
+        # the two forms are held to each other, not to a sequence)
+        return np.asarray(net._mla(n[:, :CHUNK], p, cached, start, pos)[0])
+
+    return attend, cached
+
+
+@pytest.mark.parametrize("start", [
+    (0, 0), (1, 1), (BLOCK - 1, BLOCK - 1), (BLOCK, BLOCK),
+    (BLOCK + 1, BLOCK + 1), (HELD - CHUNK, HELD - CHUNK),
+    (BLOCK + 1, 3), (0, HELD - CHUNK), (HELD, 2 * BLOCK)],
+    ids=lambda s: "start_%d_%d" % s)
+def test_a_chunk_attends_in_blocks_what_it_attends_whole(mla_layer, start):
+    """``_mla`` over blocks of cached rows up to the longest ``start``
+    of the dispatch (ISSUE 38) against ``_mla`` over all the rows a slot
+    holds, float32: one mathematics in another order of summation.  Both
+    slots at one ``start`` (nothing cached; one row; a block less one, a
+    whole block, a block and one; all a slot holds less the chunk) and
+    two slots at different ones (the dispatch is bound by its longest,
+    the shorter one masked in every block).  The rows from the end of
+    the bound's last block on are never read: filled with NaN they leave
+    the result finite and the same."""
+    import jax.numpy as jnp
+
+    attend, cached = mla_layer
+    whole = attend(start, cached, blocks=False)
+    blocked = attend(start, cached, blocks=True)
+    assert np.abs(whole).max() > 1e-3
+    assert np.abs(blocked - whole).max() < 1e-6
+    bound = -(-max(start) // BLOCK) * BLOCK
+    poisoned = attend(start, cached.at[:, bound:].set(jnp.nan),
+                      blocks=True)
+    assert np.isfinite(poisoned).all()
+    assert np.array_equal(poisoned, blocked)
+    if bound < HELD:    # (the whole form reads them, and masks too late)
+        assert np.isnan(attend(start, cached.at[:, bound:].set(jnp.nan),
+                               blocks=False)).any()
+
+
+@pytest.mark.parametrize("held,block", [(40, 16), (24, 512)],
+                         ids=["ragged_last_block", "cache_under_a_block"])
+def test_blocks_of_a_cache_that_is_not_whole_blocks(mla_layer, monkeypatch,
+                                                    held, block):
+    """A cache of 40 rows in blocks of 16 (the last block is read ending
+    at row 40, its rows under 32 masked: no row counts twice) and one of
+    24 rows under a block of 512 (one block of all 24)."""
+    from mxnet_tpu.ops import attention_rows
+
+    attend, cached = mla_layer
+    monkeypatch.setattr(attention_rows, "CACHE_BLOCK_ROWS", block)
+    assert attention_rows.cache_block_rows(held) == min(block, held)
+    for start in ((held, 5), (held - 3, held - 9), (17, 0)):
+        whole = attend(start, cached[:, :held], blocks=False)
+        blocked = attend(start, cached[:, :held], blocks=True)
+        assert np.abs(blocked - whole).max() < 1e-6
+
+
+def test_only_a_chunk_shape_lowers_to_a_loop():
+    """Which dispatches attend in blocks is decided by the static shape
+    alone (ISSUE 38): of the three programs of an engine over a stack of
+    five MLA layers and a draft block of one more, the chunk's ``(1,
+    24)`` holds one ``while`` an MLA layer and the decode step's ``(3,
+    1)`` and the verify step's ``(3, 2)`` hold none; the engine's count
+    of the rows a chunk multiplies follows the same rule."""
+    from mxnet_tpu.ops import attention_rows
+
+    _fam, small = _deepseek_small()
+    net = programs.program(small).build_net(small)
+    net.initialize()
+    eng = generate.PagedGenerationEngine(
+        net, slots=3, cache_len=128, page_size=8, prefill_chunk=24,
+        spec_k=1, prefix_share=False,
+        sampling=generate.SamplingConfig(greedy=True))
+    loops = {
+        shape: eng._jit_chunk.lower(
+            *eng._dispatch_args(shape)).as_text().count("stablehlo.while")
+        for shape in eng.dispatch_shapes()}
+    assert loops == {(1, 24): len(net._mixers) + 1, (3, 1): 0, (3, 2): 0}
+    assert [attention_rows.cached_rows_in(c) for _b, c in loops] == \
+        ["blocks", "whole", "whole"]
+    # a slot holds 128 rows, under one block of 512: a chunk that has
+    # rows to attend multiplies them all, a step always does
+    assert eng._cache_rows_attended(24, 0) == 0
+    assert eng._cache_rows_attended(24, 48) == 128
+    assert eng._cache_rows_attended(2, 0) == 128
+
+
 def _deepseek_small():
     """The ``deepseek_v3`` reference and its configuration at the
     rehearsal's widths, its router as published (256 experts, 8 groups
@@ -594,6 +717,13 @@ def test_spans_and_counter_of_state_and_experts(cfg, model):
     assert pool["bytes"] == pool["latent_rows_bytes"] + pool["state_bytes"]
     chunks = [r["args"] for r in recs if r["name"] == "engine.prefill"]
     assert len(chunks) == 3 and all(c["state_slots"] == 1 for c in chunks)
+    # how much of a slot's cache a chunk's attention multiplies (ISSUE
+    # 38): whole blocks up to the rows its sequence has written, here one
+    # block of all a slot holds (128 rows under a block of 512) for the
+    # second chunk of the prompt of 30 and none for a first chunk
+    assert [(c["filled"], c["cache_rows_attended"]) for c in chunks] == [
+        (0, 0), (24, 128), (0, 0)]
+    assert all(c["cache_rows_held"] == 128 for c in chunks)
     assert telemetry.DECODE_STATE_RESETS.value() - resets == 2
     step = [r for r in recs if r["name"] == "engine.decode"][-1]["args"]
     assert step["slots"] == step["state_slots"] == 2

@@ -69,8 +69,8 @@ def test_manifest_gains_one_configuration_and_one_cell():
         assert (layer[name]["unit"], layer[name]["layer"],
                 layer[name]["moves"], layer[name]["workloads"]) == \
             (unit, where, "serve_itl_p95_ms", [CELL])
-    # (PR 36 appended one more after them, PR 37 eleven)
-    assert [m["name"] for m in man["per_layer"]][-15:-12] == \
+    # (PR 36 appended one more after them, PR 37 eleven, PR 38 one)
+    assert [m["name"] for m in man["per_layer"]][-16:-13] == \
         list(NEW_METRICS)
     assert set(NEW_METRICS) | {
         "serve_prefill_share", "serve_tick_ms_p95",

@@ -826,8 +826,10 @@ def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):
     which is no copy of it); pool and state are all aliased to the
     results.  The decode program attends in the latent space: nothing
     has the size of the slots' cached rows expanded to 32 heads, and no
-    gather or scatter became a loop.  (The convolution's tails, 2.4 MB,
-    are re-tiled; they are not asserted on.)"""
+    gather or scatter became a loop; the chunk's attends the slot's rows
+    a block at a time (ISSUE 38), its one loop besides the delta rule's
+    scan.  (The convolution's tails, 2.4 MB, are re-tiled; they are not
+    asserted on.)"""
     import re
 
     eng = wide_hybrid_engine
@@ -865,8 +867,15 @@ def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):
             assert np.prod([int(d) for d in dims.split(",")]) < expanded
         assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
     else:
-        # the chunkwise delta rule's scan over 8 sub-chunks, and no other
-        assert text.count(" while(") == 1
+        # the chunkwise delta rule's scan over 8 sub-chunks and the MLA
+        # layer's blocks of cached rows up to `start` (ISSUE 38: a chunk
+        # attends in blocks, a step does not), and no other: nothing in
+        # the chunk's program has the size of its float32 scores over
+        # all the 9216 rows a slot holds
+        assert text.count(" while(") == 2
+        whole = 32 * 512 * 9216
+        for dims in re.findall(r"= f32\[([\d,]+)\]", text):
+            assert np.prod([int(d) for d in dims.split(",")]) < whole
 
 
 # ---------------------------------------------------------------------------
