@@ -62,9 +62,11 @@ verify step (``examples/transformer_lm.py``,
 d_head)``, and not as a head-split view, ``(B, heads, S, d_head)``).  A
 model whose layers do not all cache K and V a head
 (``gluon.model_zoo.language.HybridDecoderLM``) says what each keeps in
-``layer_caches``: paged rows of a width, in one pool, or arrays a
-slot (recurrent state); its ``chunk_forward`` takes a fourth
-argument, the positions of each row that count.
+``layer_caches``: paged rows of a width, in one pool, arrays a slot
+(recurrent state), or a window: rows a slot in a ring beside the pool,
+the last positions of a sliding-window layer and no page; its
+``chunk_forward`` takes a fourth argument, the positions of each row
+that count.
 Benchmarks: ``tools/bench_decode.py`` (tokens/s/user, TTFT p50/p99,
 the KV-cache-vs-reforward ratio, plus the prefix-share /
 chunked-prefill / speculative modes); docs: ``docs/lm_serving.md``.
@@ -345,7 +347,8 @@ class PagedGenerationEngine:
     every dispatch: for a model of attention layers alone one
     fixed-shape page pool per K/V, in one of two forms; for a model that
     says a layer at a time what it caches (``layer_caches``), one pool
-    of paged rows and arrays of per-slot state side by side (below).
+    of paged rows, arrays of per-slot state and rings of per-slot rows
+    side by side (below).
     A model whose ``config`` says ``cache_rows`` takes each layer's
     cache as rows, and the pool is two-dimensional, ``(layers * pages *
     page_size, heads * d_head)``: row ``layer * tokens + page *
@@ -425,9 +428,10 @@ class PagedGenerationEngine:
     arrays a SLOT (a recurrent layer's state), held as ``(slots,) +
     shape`` in a tuple of their own, handed to every dispatch donated
     like the pools and taken back from it; :meth:`cached` reads a
-    slot's caches back for a check.  A ``(slots, 1)`` decode step hands the model every slot's
-    row and takes every row back, the model leaving alone the rows of
-    slots no one is in (``valid`` 0); a ``(1, C)`` prefill chunk reads
+    slot's caches back for a check.  A ``(slots, 1)`` decode step hands
+    the model every slot's row and takes every row back, the model
+    leaving alone the rows of slots no one is in (``valid`` 0); a
+    ``(1, C)`` prefill chunk reads
     and writes its own slot's row only, and the chunk that starts a
     sequence reads it as zeros, so admission costs no dispatch and
     eviction clears nothing.  State cannot be cut at a page boundary,
@@ -438,6 +442,33 @@ class PagedGenerationEngine:
     of it.  A model that keeps rows alone is offered prefix attachment;
     under self-drafting a page's hash covers one token more, the one the
     draft block's row of the page's last position was fed.
+    ``{"window": (positions, width)}``: a sliding-window layer, which
+    attends the last ``positions`` positions (itself included) and so
+    keeps rows **a slot in a ring**, ``(slots, rows, width padded to
+    whole tiles of 128 lanes)`` beside the pool, ``rows`` =
+    ``ops.attention_rows.ring_rows(positions, spec_k)`` (``positions +
+    spec_k`` up to whole sublane tiles), and no page: what it costs does
+    not grow with a slot's capacity.  Position ``p`` lies in row ``p mod
+    rows``; positions are never stored, the model computes them from
+    ``start``.  The rings follow the state arrays in the tuple every
+    dispatch is handed donated.  A dispatch hands the model the ring of
+    each of its slots as the dispatch before left it, the model attends
+    it by position beside the chunk's own rows under the band and
+    returns the chunk's rows like a layer of paged rows, and the engine
+    puts those that count in their places (``ring_write``: a ``(1, C)``
+    prefill chunk longer than the ring leaves its last rows; a
+    ``(slots, C)`` step scatters its ``C`` rows a slot in place).  The
+    chunk that starts a sequence masks whatever the ring held (no
+    position is below 0), so admission and eviction cost no dispatch.
+    A rejected draft's row lies at or above the next ``start``: by
+    position it reads as ``rows`` earlier, which no query's band
+    reaches, and the next step overwrites it, as the pool's rows are;
+    so speculation (the model's own drafts or n-gram drafts) stays on,
+    and nothing is copied to roll back.  A prefix's pages do not hold
+    what a ring kept of it, so prefix attachment is turned off with a
+    warning for a model with any window layer; block-diffusion decoding
+    over one raises.  :meth:`cached` returns a window layer's rows in
+    position order with the first position they hold.
 
     **Prefix sharing** is page-aligned copy-on-write: full prompt pages
     are content-hashed (chained, so identity implies identical prefix)
@@ -616,25 +647,49 @@ class PagedGenerationEngine:
         self._self_draft = bool(n_draft) and self._spec_k == 1
         if self._declared:
             if len(declared) != L + n_draft or any(
-                    set(kind) not in ({"rows"}, {"state"})
+                    set(kind) not in ({"rows"}, {"rows", "attended"},
+                                      {"state"}, {"window"})
+                    or kind.get("attended", "whole") != "whole"
                     for kind in declared) or any(
                     "rows" not in kind for kind in declared[L:]):
                 raise MXNetError(
                     "model config's layer_caches must give each of the "
                     "%d layers (and then each of the %d draft blocks, "
-                    "rows) {'rows': width} or {'state': [(shape, "
-                    "dtype), ...]}, got %r" % (L, n_draft, declared))
+                    "rows) {'rows': width} (with 'attended': 'whole' "
+                    "where every dispatch multiplies all the rows a slot "
+                    "holds), {'state': [(shape, dtype), ...]} or "
+                    "{'window': (positions, width)}, got %r"
+                    % (L, n_draft, declared))
             if not self._self_draft:
                 declared = declared[:L]
         self._layer_caches = declared
         self._state_layers = state_layers = [
             li for li, kind in enumerate(declared or ()) if "state" in kind]
+        self._window_layers = window_layers = [
+            li for li, kind in enumerate(declared or ()) if "window" in kind]
         if self._declared:
             if self._mesh is not None:
                 raise MXNetError(
                     "a model that declares its layers' caches is served "
                     "on one device: the layouts have no rule yet for "
-                    "pools of latent rows or per-slot state")
+                    "pools of latent rows, per-slot state or rings")
+            if window_layers:
+                # a ring holds a sequence's last rows and nothing a
+                # prefix's pages could stand for; a rejected draft's row
+                # is rolled back by position, so speculation stays
+                if Bl > 1:
+                    raise MXNetError(
+                        "block-diffusion decoding runs a block several "
+                        "times before it commits it; a model with "
+                        "windowed layers (rings a slot, layers %s) "
+                        "cannot" % window_layers)
+                if self._prefix_share:
+                    _logger.warning(
+                        "prefix sharing is not offered to a model with "
+                        "windowed layers (layers %s): a prefix's pages do "
+                        "not hold what those layers' rings kept of it; "
+                        "every prompt prefills whole", window_layers)
+                    self._prefix_share = False
             if state_layers:
                 # state cannot be cut at a page boundary, copied by
                 # attaching pages or rolled back past a rejected draft
@@ -669,6 +724,7 @@ class PagedGenerationEngine:
             # The model is handed the rows as they lie, zeros included
             row_layers = [li for li, kind in enumerate(declared)
                           if "rows" in kind]
+            self._row_layers = row_layers
             widths = {int(declared[li]["rows"]) for li in row_layers}
             if len(widths) > 1:
                 raise MXNetError(
@@ -682,6 +738,22 @@ class PagedGenerationEngine:
                  np.dtype(dt) if dt is not None else self._cache_dtype)
                 for li in state_layers
                 for shape, dt in declared[li]["state"]]
+            # a windowed layer's rows, a ring a slot beside the pool:
+            # (slots, rows, lanes), position p in row p mod rows, lanes
+            # as the pool's (whole tiles of 128).  The rings follow the
+            # state arrays in the one tuple every dispatch is handed
+            # donated
+            from .ops.attention_rows import ring_rows
+
+            self._ring_rows = [
+                ring_rows(declared[li]["window"][0], self._spec_k,
+                          self._cache_dtype.itemsize)
+                for li in window_layers]
+            ring_specs = [
+                ((self._slots, rows,
+                  -(-int(declared[li]["window"][1]) // 128) * 128),
+                 self._cache_dtype)
+                for li, rows in zip(window_layers, self._ring_rows)]
         elif self._cache_rows:
             # one row a (layer, token): the layer is part of the row
             # index, so a layer's cache is a gather of whole rows,
@@ -752,12 +824,20 @@ class PagedGenerationEngine:
             self._pool_k = zeros(pool_shape, self._cache_dtype)
             if self._declared:
                 self._state = tuple(zeros(sh, dt)
-                                    for sh, dt in state_specs)
-                state_bytes = sum(int(a.nbytes) for a in self._state)
+                                    for sh, dt in state_specs + ring_specs)
+                n_state = len(state_specs)
+                state_bytes = sum(int(a.nbytes)
+                                  for a in self._state[:n_state])
+                window_bytes = sum(int(a.nbytes)
+                                   for a in self._state[n_state:])
                 sp.set(shape=list(pool_shape), cache_rows=True,
-                       bytes=int(self._pool_k.nbytes) + state_bytes,
+                       bytes=int(self._pool_k.nbytes) + state_bytes
+                       + window_bytes,
                        latent_rows_bytes=int(self._pool_k.nbytes),
                        state_bytes=state_bytes)
+                if window_layers:
+                    sp.set(window_rows_bytes=window_bytes,
+                           window_rows=list(self._ring_rows))
                 if self._self_draft:
                     sp.set(draft_rows_bytes=int(self._pool_k.nbytes)
                            * n_draft // len(row_layers))
@@ -793,6 +873,13 @@ class PagedGenerationEngine:
         # (the block's choice at the row that token was chosen at)
         self._draft_tok = np.zeros(self._slots, np.int32)
         self._drafted = {}
+        # speculation: a prompt's first token (and first draft) stay on
+        # the device when its last chunk is launched, so that the tick's
+        # verify step is queued behind the chunk and both are waited for
+        # once; `_spec_firsts` holds them (slot, sampled, drafts, row)
+        # until that step's read-back, and a slot in it is given no row
+        # of a verify step: its current token is not on the host yet
+        self._spec_firsts = []
         self._chunks_run = 0
         # block-diffusion: the schedule of every slot's open block on
         # the host, the blocks themselves on the device (tokens, which
@@ -891,12 +978,18 @@ class PagedGenerationEngine:
             the pool's lanes), gathered like a layer of the pool of
             rows; a layer's state, each array's rows ``slot_ids`` of it
             (the arrays themselves where the dispatch is over all
-            slots), zeros for a slot whose sequence starts here."""
+            slots), zeros for a slot whose sequence starts here; a
+            windowed layer's ring, the same rows of it as they lie (a
+            sequence that starts here masks them all by position)."""
             slot_ids, fresh, _valid = lanes
             over_all = rows.shape[0] == self._slots
             caches, at = [], 0
             with jax.named_scope("cache.gather"):
                 for li, kind in enumerate(declared):
+                    if "window" in kind:
+                        ring = state[n_state + window_layers.index(li)]
+                        caches.append(ring if over_all else ring[slot_ids])
+                        continue
                     if "rows" in kind:
                         k = row_layers.index(li)
                         if by_page:
@@ -918,10 +1011,14 @@ class PagedGenerationEngine:
                     caches.append(tuple(mine))
             return caches
 
-        def write_declared(pool, state, kept, wpage, woff, lanes):
-            """The chunk's rows scattered to the pool, in place, and the
-            state of the dispatch's slots put back."""
-            slot_ids = lanes[0]
+        def write_declared(pool, state, kept, wpage, woff, lanes, start):
+            """The chunk's rows scattered to the pool, in place, the
+            state of the dispatch's slots put back, and a windowed
+            layer's rows that count put in its ring by their positions
+            (``ops.attention_rows.ring_write``)."""
+            from .ops.attention_rows import ring_write
+
+            slot_ids, valid = lanes[0], lanes[2]
             over_all = slot_ids.shape[0] == self._slots
             state, at = list(state), 0
             with jax.named_scope("cache.write"):
@@ -941,6 +1038,13 @@ class PagedGenerationEngine:
                         state[at] = new if over_all \
                             else state[at].at[slot_ids].set(new)
                         at += 1
+                for at, li in enumerate(window_layers, n_state):
+                    ring = state[at]
+                    new = jnp.pad(kept[li], ((0, 0), (0, 0), (
+                        0, ring.shape[2] - kept[li].shape[2])))
+                    state[at] = ring_write(ring, new, start, valid) \
+                        if over_all else ring.at[slot_ids].set(ring_write(
+                            ring[slot_ids], new, start, valid))
             return pool, tuple(state)
 
         def chunk_fn(params_, pool_k, pool_v, page_table, tokens, start,
@@ -1105,7 +1209,7 @@ class PagedGenerationEngine:
                         jnp.where(fix, conf, b_conf))
             if lanes is not None:
                 pool_k, extras["state"] = write_declared(
-                    pool_k, state, chunk_caches, wpage, woff, lanes)
+                    pool_k, state, chunk_caches, wpage, woff, lanes, start)
                 return sampled, logits, pool_k, pool_v, extras
             with jax.named_scope("cache.write"):
                 k_new = jnp.stack([k for k, _v in chunk_caches])
@@ -1139,6 +1243,9 @@ class PagedGenerationEngine:
             pool_tag = "rows%dx%d:state%s" % (
                 pool_shape + ("+".join("x".join(str(d) for d in sh)
                                        for sh, _dt in state_specs),))
+            if ring_specs:
+                pool_tag += ":rings" + "+".join(
+                    "x".join(str(d) for d in sh) for sh, _dt in ring_specs)
         elif self._cache_rows:   # one row a (layer, token)
             pool_tag = "L%dxtokens%dxHD%d" % (L, n_tokens, H * dh)
         elif row == (L, H * dh):  # token-major, a token's layers a row
@@ -1244,7 +1351,12 @@ class PagedGenerationEngine:
         once, and nothing is compiled): for a model that declares its
         layers' caches, a slot ``{"position": the positions cached,
         "tokens": the ids at them, "layers": a layer its rows
-        (position, width) or the tuple of its state arrays}``, float32;
+        (position, width), the tuple of its state arrays or, for a
+        windowed layer, ``{"first": p, "rows": (position - p, width)}``,
+        the rows its ring holds in position order from the first
+        position it still holds whole (a ring of R rows the last ``R -
+        spec_k``: a rejected draft's row may lie where the one before
+        them lay)}``, float32;
         under self-drafting the draft block's rows are the last layer's,
         ``"drafts"`` is :meth:`drafted` and ``"next_token"`` the id after
         the cached ones, which that block's last row was fed.
@@ -1267,7 +1379,16 @@ class PagedGenerationEngine:
             tok = self._page_table[slot, at // self._page_size] \
                 * self._page_size + at % self._page_size
             layers, rows_seen, state_seen = [], 0, 0
-            for kind in self._layer_caches:
+            for li, kind in enumerate(self._layer_caches):
+                if "window" in kind:    # (the rings follow the state)
+                    ring = state[len(state) - len(self._window_layers)
+                                 + self._window_layers.index(li)]
+                    rows = ring.shape[1]
+                    first = max(0, n - (rows - self._spec_k))
+                    layers.append({"first": first, "rows": ring[
+                        slot, np.arange(first, n) % rows,
+                        :int(kind["window"][1])].astype(np.float32)})
+                    continue
                 if "rows" in kind:
                     layers.append(pool[rows_seen * n_tokens + tok][
                         :, :int(kind["rows"])].astype(np.float32))
@@ -1556,12 +1677,15 @@ class PagedGenerationEngine:
         on the device, where the slot's first decode step takes it, and
         comes to the host in :meth:`decode_step`'s result (under
         block-diffusion decoding: of the first block's commit).  With
-        speculation, whose drafts need it on the host, it is read here:
-        ``(slot, first_token)``.  The TokenServer calls
+        speculation the next verify step's read-back brings it (that
+        step is launched without the slot: its drafts need the token on
+        the host); :meth:`admit` by hand reads it at once.  The
+        TokenServer calls
         this once per loop tick, interleaving long prefills with decode
         steps; the round-robin keeps a short prompt's TTFT from hiding
         behind a long prompt admitted just before it."""
-        return self._prefill_chunk(slot, read=not self._feeds)
+        return self._prefill_chunk(
+            slot, read=not self._feeds and not self._spec_k)
 
     def _prefill_chunk(self, slot, read):
         """:meth:`prefill_step`; with ``read`` a prompt's first token is
@@ -1584,7 +1708,7 @@ class PagedGenerationEngine:
                 "attn": self._attends_in(self._chunk),
                 "cache_rows_attended": self._cache_rows_attended(
                     self._chunk, filled),
-                "cache_rows_held": self._capacity}) as step:
+                "cache_rows_held": self._cache_rows_held()}) as step:
             chunk = np.zeros((1, self._chunk), np.int32)
             chunk[0, :count] = toks[filled:filled + count]
             wpage = np.zeros(self._chunk, np.int32)
@@ -1662,6 +1786,9 @@ class PagedGenerationEngine:
                         self._drafted[slot] = [int(chose[0, count - 1])]
                 self._cur_tok[slot] = tok
                 self._history[slot].append(tok)
+            elif self._spec_k:
+                self._spec_firsts.append(
+                    (int(slot), sampled, extras.get("draft"), count - 1))
             if self._prefix_share:
                 self._register_prefix(slot, toks, n)
             self._note_occupancy()
@@ -1706,7 +1833,9 @@ class PagedGenerationEngine:
         :meth:`drain` reads them all.  ``last_logits`` are of the step
         read.  With speculation a call launches AND reads its own step:
         up to ``spec_k + 1`` tokens a slot (drafted tokens that
-        verified, plus the one token sampling always yields).  Rejected
+        verified, plus the one token sampling always yields), and for a
+        slot whose prompt completed since the last call its first token
+        alone, read with the step (the slot has no row in it).  Rejected
         drafts leave K/V at positions >= the new ``pos``; those entries
         are masked by ``start`` and overwritten as decode advances."""
         if not self._active.any():
@@ -1725,7 +1854,10 @@ class PagedGenerationEngine:
         # "Spans of the hot loops"); DECODE_STEP_SECONDS reads the span
         with _tracing.begin("engine.decode", args={
                 "slots": stepped, "live": int(self._pos[on].sum()),
-                "fed": stepped, "attn": self._attends_in(1)}) as step:
+                "fed": stepped, "attn": self._attends_in(1),
+                "cache_rows_attended": self._cache_rows_attended(
+                    1, int(self._pos[on].max(initial=0))),
+                "cache_rows_held": self._cache_rows_held()}) as step:
             logits, extras = None, {}
             if stepped:
                 with _tracing.begin("engine.decode:prep"):
@@ -1835,13 +1967,23 @@ class PagedGenerationEngine:
 
         B, K = self._slots, self._spec_k
         cap = min(self._capacity, self.model_config["max_len"])
-        active = [int(b) for b in np.nonzero(self._active)[0]]
+        # the slots whose current token is on the host; one whose prompt
+        # completed since the last call is given no row: its first token
+        # comes with this step's read-back
+        firsts, self._spec_firsts = self._spec_firsts, []
+        waiting = {f[0] for f in firsts}
+        active = [int(b) for b in np.nonzero(self._active)[0]
+                  if b not in waiting]
         C = K + 1
         source = "model" if self._self_draft else "ngram"
         with _tracing.begin("engine.decode", args={
                 "slots": len(active),
                 "live": int(self._pos[active].sum()), "fed": 0,
-                "attn": self._attends_in(C), "draft": source}) as step:
+                "attn": self._attends_in(C), "draft": source,
+                "cache_rows_attended": self._cache_rows_attended(
+                    C, int(self._pos[active].max(initial=0))),
+                "cache_rows_held": self._cache_rows_held()}) as step:
+            sampled = chose = load = None
             with _tracing.begin("engine.decode:prep"):
                 tokens = np.zeros((B, C), np.int32)
                 drafts = {}
@@ -1881,18 +2023,31 @@ class PagedGenerationEngine:
                     # every row is followed by the token chosen at it
                     draft = (np.zeros((B, C), np.int32),
                              np.ones((B, C), bool))
-            with _tracing.begin("engine.decode:launch"):
-                sampled, self._last_logits, extras = self._dispatch(
-                    table, tokens, pos, wpage, woff, key, lanes=lanes,
-                    draft=draft)
+            if active:
+                with _tracing.begin("engine.decode:launch"):
+                    sampled, self._last_logits, extras = self._dispatch(
+                        table, tokens, pos, wpage, woff, key, lanes=lanes,
+                        draft=draft)
+                    chose = extras.get("draft")
+                    load = extras.get("expert_load")
             with _tracing.begin("engine.decode:readback"):
                 # one wait for the small arrays, not one each
-                sampled, chose, load = jax.device_get((
-                    sampled, extras.get("draft"),
-                    extras.get("expert_load")))
+                sampled, chose, load, first = jax.device_get((
+                    sampled, chose, load,
+                    [(got, d) for _slot, got, d, _at in firsts]))
             with _tracing.begin("engine.decode:post"):
                 out = {}
                 emitted_total = drafted = accepted = 0
+                for (b, _got, _d, at), (got, d) in zip(firsts, first):
+                    # (a slot let go since its last chunk is not in
+                    # `firsts` any more: `evict`)
+                    tok = int(got[0, at])
+                    self._cur_tok[b] = tok
+                    self._history[b].append(tok)
+                    if self._self_draft:
+                        self._draft_tok[b] = d[0, at]
+                        self._drafted[b] = [int(d[0, at])]
+                    out[b] = [tok]
                 for b in active:
                     d = drafts[b]
                     acc = 0
@@ -1917,13 +2072,15 @@ class PagedGenerationEngine:
                 self._spec_accepted += accepted
                 _telemetry.DECODE_SPEC_DRAFTED.inc(drafted, source=source)
                 _telemetry.DECODE_SPEC_ACCEPTED.inc(accepted, source=source)
-                _telemetry.DECODE_TOKENS.inc(emitted_total)
-                _telemetry.DECODE_BATCH_TOKENS.observe(len(out))
+                _telemetry.DECODE_TOKENS.inc(emitted_total + len(firsts))
+                _telemetry.DECODE_BATCH_TOKENS.observe(len(active))
                 if load is not None:
                     self._note_expert_load(step, load)
                 self._note_occupancy()
             step.set(unread=0, drafted=drafted, accepted=accepted,
                      emitted=emitted_total)
+            if firsts:
+                step.set(firsts=len(firsts))
         _telemetry.DECODE_STEP_SECONDS.observe(step.dur)
         return out
 
@@ -2124,6 +2281,7 @@ class PagedGenerationEngine:
             _telemetry.DECODE_STEPS_WASTED.inc(wasted, reason=reason)
         if self._feeds:
             self._firsts = [f for f in self._firsts if f[0] != slot]
+        self._spec_firsts = [f for f in self._spec_firsts if f[0] != slot]
         self._pending.pop(slot, None)
         self._history.pop(slot, None)
         self._drafted.pop(slot, None)
@@ -2177,18 +2335,33 @@ class PagedGenerationEngine:
 
     def _cache_rows_attended(self, chunk, written):
         """Cached rows a slot that the program of a dispatch of
-        ``chunk`` positions a slot multiplies when the longest of its
-        sequences has written ``written`` (the ``cache_rows_attended``
-        of ``engine.prefill`` beside ``cache_rows_held``, a slot's
-        capacity): a model that declares its layers' caches attends its
-        rows itself, by ``ops.attention_rows``'s rule on the dispatch's
-        shape, a chunk in whole blocks up to ``written``; every other
-        attention multiplies all it is handed and masks."""
+        ``chunk`` positions a slot multiplies, summed over its layers
+        that cache rows, when the longest of its sequences has written
+        ``written`` (the ``cache_rows_attended`` of ``engine.prefill``
+        and ``engine.decode`` beside ``cache_rows_held``): a model that
+        declares its layers' caches attends its rows itself, by
+        ``ops.attention_rows``'s rule on the dispatch's shape, a chunk
+        in whole blocks up to ``written``, unless the layer says
+        ``"attended": "whole"``; a windowed layer multiplies its ring's
+        rows; every other attention multiplies all it is handed and
+        masks."""
         if not self._declared:
-            return self._capacity
+            return self._L * self._capacity
         from .ops.attention_rows import attended_cache_rows
 
-        return attended_cache_rows(chunk, written, self._capacity)
+        return sum(
+            attended_cache_rows(chunk, written, self._capacity,
+                                self._layer_caches[li].get("attended"))
+            for li in self._row_layers) + sum(self._ring_rows)
+
+    def _cache_rows_held(self):
+        """What :meth:`_cache_rows_attended` would count were every
+        layer that caches rows paged and multiplied at a slot's
+        capacity."""
+        if not self._declared:
+            return self._L * self._capacity
+        return (len(self._row_layers) + len(self._window_layers)) \
+            * self._capacity
 
     def _dispatch_args(self, shape):
         """Arguments of the dispatch at one token shape, all zeros
@@ -2626,9 +2799,8 @@ class TokenServer:
             req.fixed_at.append(fixed[0])
             req.confidence.append(fixed[1])
         if req.ttft is None:
-            # a prompt's last chunk hands no token over (only a
-            # speculating engine reads it there): the first one comes of
-            # a decode step, and is stamped as it is read
+            # a prompt's last chunk hands no token over: the first one
+            # comes of a decode step, and is stamped as it is read
             req.ttft = now - req.t_submit
             _telemetry.DECODE_TTFT_SECONDS.observe(
                 req.ttft, exemplar={"trace_id": _tracing.TRACE_ID,

@@ -1,16 +1,18 @@
-"""A hybrid decoder-only language model: most layers mix tokens by a
+"""A hybrid decoder-only language model: a layer mixes tokens by a
 linear-attention recurrence (Kimi Delta Attention, arXiv:2510.26692),
-every few by softmax attention over a cached latent (multi-head latent
-attention, DeepSeek-V2, arXiv:2405.04434, section 2.1), and the
-feed-forward of a layer is a dense gated-SiLU MLP or a sparse expert
-layer with a sigmoid, group-limited router and a shared expert
-(DeepSeek-V3, arXiv:2412.19437, section 2.1.2): the ``bailing_hybrid``
-block.
+by softmax attention over a cached latent (multi-head latent
+attention, DeepSeek-V2, arXiv:2405.04434, section 2.1), or by
+grouped-query softmax attention over cached keys and values, over the
+whole sequence or in a sliding window (EXAONE 4.0, arXiv:2507.11407,
+section 2), and the feed-forward of a layer is a dense gated-SiLU MLP
+or a sparse expert layer with a sigmoid, group-limited router and a
+shared expert (DeepSeek-V3, arXiv:2412.19437, section 2.1.2).
 
     h = x + Mix_l(RMSNorm(x));  y = h + FFN_l(RMSNorm(h))
 
-``mixers[l]`` is ``"kda"`` or ``"mla"`` and ``ffns[l]`` ``"dense"`` or
-``"moe"``; no bias anywhere, an untied output head.
+``mixers[l]`` is ``"kda"``, ``"mla"``, ``"gqa"`` or ``"swa"`` and
+``ffns[l]`` ``"dense"`` or ``"moe"``; no bias anywhere, an untied
+output head.
 
 **KDA** (``n_heads`` heads of ``d_k`` key and ``d_v`` value channels):
 ``q, k, v = SiLU(conv(W_qkv x))``, a causal depthwise convolution of
@@ -49,6 +51,24 @@ the chip a chunk of 512 against 9216 rows takes 46.0 ms absorbed and
 its sequence has written, a step all the rows a slot holds
 (``ops.attention_rows.cached_rows_in``; PERF.md, PR 38).
 
+**GQA** (``"gqa"``, full) and **SWA** (``"swa"``, windowed): ``q = W_q
+x`` as ``n_heads`` heads of ``d_head``, ``k = W_k x`` and ``v = W_v x``
+as ``n_kv_heads`` heads; with ``qk_norm`` an RMSNorm with a learned
+weight over the ``d_head`` channels of every q and k head; rotate-half
+rotary positions at ``theta^(-2i/d_head)`` on the kinds named in
+``rotary`` (by default the windowed layers alone: a full layer then
+carries no positions at all); ``softmax(q . k * d_head^-1/2) v``,
+causal, ``n_heads / n_kv_heads`` query heads sharing a key/value head;
+in a ``"swa"`` layer position ``i`` attends ``j`` with ``0 <= i - j <
+window``; ``W_o``.  **Cached a token: the row ``[K | V]``, ``2 *
+n_kv_heads * d_head`` values, normed and where the layer rotates
+rotated.**  A ``"gqa"`` layer keeps every position's row in pages and
+attends all a slot holds through ``ops.attention_rows
+.chunk_attention_rows``; a ``"swa"`` layer keeps the last rows of a
+sequence in a ring a slot and attends them by the positions they hold
+(``ops.attention_rows.window_attention_rows``), whatever the sequence's
+length.
+
 **The expert layer** is ``parallel.moe.routed_experts`` with
 ``sigmoid_group_select``; told which experts it holds
 (``experts_held``) it routes over all of them and computes its own
@@ -60,9 +80,9 @@ multi-token-prediction module, section 2.2): for position ``i`` with
 the trunk's last block output ``h_i`` (before the final norm) and the
 token that follows, ``t_{i+1}``: ``u_i = W_eh [RMSNorm_h(h_i) ;
 RMSNorm_e(Emb(t_{i+1}))]`` (``2 D -> D``), one more block of the kind of
-the trunk's last (its MLA layer caching rows of its own, at position
-``i``), its own final norm, the trunk's embedding and head: the logits
-of position ``i + 2``.  :meth:`chunk_forward` hands the trunk's ``h``
+the trunk's last (its MLA or GQA layer caching rows of its own, at
+position ``i``), its own final norm, the trunk's embedding and head:
+the logits of position ``i + 2``.  :meth:`chunk_forward` hands the trunk's ``h``
 back (``extras["hidden"]``) and :meth:`draft_forward` runs the block,
 so that a serving engine can feed it the tokens its own sampling chose
 in the same program (``generate.PagedGenerationEngine``,
@@ -71,8 +91,11 @@ in the same program (``generate.PagedGenerationEngine``,
 The model speaks the chunk protocol of ``mxnet_tpu.generate`` and
 declares, a layer, what it caches (``config["layer_caches"]``): a KDA
 layer per-slot state (``S`` and the convolution's tail), an MLA layer
-paged rows of ``d_latent + d_rope`` values; the draft block's entry
-follows the trunk's (``config["draft_layers"]`` says how many there
+paged rows of ``d_latent + d_rope`` values, a GQA layer paged rows of
+``2 * n_kv_heads * d_head`` (of which every dispatch multiplies all a
+slot holds: ``"attended": "whole"``), an SWA layer a window:
+``{"window": (window, 2 * n_kv_heads * d_head)}``, rows a slot in a
+ring; the draft block's entry follows the trunk's (``config["draft_layers"]`` says how many there
 are).  Parameters are registered in one flat list, layer by layer, the
 draft block's after the head; every matrix is stored ``(out, in)``,
 the routed experts side by side as ``MoEDecoderLM`` stores them.
@@ -83,7 +106,7 @@ the output head are always float32.
 from __future__ import annotations
 
 from ...block import HybridBlock
-from .moe_decoder import _rms
+from .moe_decoder import _rms, _rope
 
 __all__ = ["HybridDecoderLM", "yarn_corners", "yarn_mscale"]
 
@@ -232,7 +255,10 @@ class HybridDecoderLM(HybridBlock):
     deployment); the shared expert is always held.  ``q_latent``,
     ``d_v_mla``, ``mla_gate`` and ``rope_scaling`` (a published
     ``rope_scaling`` group of type ``yarn``) shape the MLA layers;
-    ``draft_layers`` = 1 adds the draft block.
+    ``n_kv_heads``, ``d_head``, ``qk_norm`` and ``rotary`` (the kinds
+    among ``"gqa"`` and ``"swa"`` that rotate) the grouped-query
+    layers, ``window`` the ``"swa"`` ones; ``draft_layers`` = 1 adds the
+    draft block.
     """
 
     def __init__(self, vocab_size, d_model, mixers, ffns, n_heads,
@@ -243,12 +269,14 @@ class HybridDecoderLM(HybridBlock):
                  norm_topk=True, max_len=262144, rope_theta=6e6,
                  rms_eps=1e-6, experts_held=None, dtype="float32",
                  q_latent=None, d_v_mla=None, mla_gate=True,
-                 rope_scaling=None, draft_layers=0, **kwargs):
+                 rope_scaling=None, draft_layers=0, n_kv_heads=None,
+                 d_head=None, window=None, qk_norm=True, rotary=("swa",),
+                 **kwargs):
         super().__init__(**kwargs)
         mixers, ffns = list(mixers), list(ffns)
         if len(mixers) != len(ffns) or not mixers:
             raise ValueError("mixers and ffns give one kind a layer each")
-        for kinds, known in ((mixers, ("kda", "mla")),
+        for kinds, known in ((mixers, ("kda", "mla", "gqa", "swa")),
                              (ffns, ("dense", "moe"))):
             if set(kinds) - set(known):
                 raise ValueError("layer kinds %r are not of %r"
@@ -257,6 +285,9 @@ class HybridDecoderLM(HybridBlock):
                              kda_lower_bound=kda_lower_bound),
                  "mla": dict(d_nope=d_nope, d_rope=d_rope, d_latent=d_latent,
                              d_v=d_v_mla if d_v_mla is not None else d_v),
+                 "gqa": dict(n_kv_heads=n_kv_heads, d_head=d_head),
+                 "swa": dict(n_kv_heads=n_kv_heads, d_head=d_head,
+                             window=window),
                  "dense": dict(d_ff=d_ff),
                  "moe": dict(n_experts=n_experts, top_k=top_k,
                              d_expert=d_expert, n_group=n_group,
@@ -267,11 +298,14 @@ class HybridDecoderLM(HybridBlock):
                 raise ValueError("a %r layer needs %s" % (kind, lacks))
         draft_layers = int(draft_layers)
         if draft_layers not in (0, 1) or (
-                draft_layers and mixers[-1] != "mla"):
+                draft_layers and mixers[-1] not in ("mla", "gqa")):
             raise ValueError(
                 "draft_layers is 0 or 1, a block of the kind of the trunk's "
-                "last, whose mixer must cache rows (mla), got %r after %r"
-                % (draft_layers, mixers[-1]))
+                "last, whose mixer must cache paged rows (mla, gqa), got "
+                "%r after %r" % (draft_layers, mixers[-1]))
+        if {"gqa", "swa"} & set(mixers) and n_heads % n_kv_heads:
+            raise ValueError("n_heads (%d) must divide by n_kv_heads (%d)"
+                             % (n_heads, n_kv_heads))
         moe = "moe" in ffns
         if moe:
             first, held = experts_held if experts_held is not None \
@@ -300,7 +334,9 @@ class HybridDecoderLM(HybridBlock):
         dvm = d_v_mla if d_v_mla is not None else d_v        # q, k, v
         self._sizes = dict(H=H, dk=d_k, dv=d_v, K=K, wide=wide,
                            dn=d_nope, dr=d_rope, dl=d_latent, dvm=dvm,
-                           ql=q_latent)
+                           ql=q_latent, Hkv=n_kv_heads, dh=d_head,
+                           window=None if window is None else int(window))
+        self._qk_norm, self._rotary = bool(qk_norm), tuple(rotary)
         self._gate = bool(mla_gate)
         # the frequency table and the score scale of the MLA layers
         self._yarn, self._scale = None, None
@@ -326,10 +362,18 @@ class HybridDecoderLM(HybridBlock):
                     self._scale *= yarn_mscale(factor, all_dim) ** 2
         # what a layer keeps between dispatches: the engine allocates
         # it (generate.PagedGenerationEngine, "layer_caches")
-        caches = [{"state": [((H, d_k, d_v), "float32"),
-                             ((K - 1, wide), None)]} if m == "kda"
-                  else {"rows": d_latent + d_rope}
-                  for m in mixers + mixers[-1:] * draft_layers]
+        def keeps(m):
+            if m == "kda":
+                return {"state": [((H, d_k, d_v), "float32"),
+                                  ((K - 1, wide), None)]}
+            if m == "mla":
+                return {"rows": d_latent + d_rope}
+            kv = 2 * n_kv_heads * d_head        # a position's [K | V]
+            if m == "gqa":
+                return {"rows": kv, "attended": "whole"}
+            return {"window": (int(window), kv)}
+
+        caches = [keeps(m) for m in mixers + mixers[-1:] * draft_layers]
         self._cfg = dict(
             vocab_size=vocab_size, d_model=D, n_heads=H,
             n_layers=len(mixers), max_len=max_len, layer_caches=caches)
@@ -355,6 +399,14 @@ class HybridDecoderLM(HybridBlock):
                     get(h + "gate_weight", (H, D)),
                     get(h + "o_norm_gamma", (d_v,), "float32"),
                     get(h + "attn_out_weight", (D, H * d_v))]
+            elif mix in ("gqa", "swa"):
+                mixer = [get(h + "proj_q_weight", (H * d_head, D)),
+                         get(h + "proj_k_weight", (n_kv_heads * d_head, D)),
+                         get(h + "proj_v_weight", (n_kv_heads * d_head, D))]
+                if self._qk_norm:
+                    mixer += [get(h + "q_norm_gamma", (d_head,), "float32"),
+                              get(h + "k_norm_gamma", (d_head,), "float32")]
+                mixer.append(get(h + "attn_out_weight", (D, H * d_head)))
             else:
                 q_in = D
                 mixer = []
@@ -573,6 +625,54 @@ class HybridDecoderLM(HybridBlock):
                 o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
             return _mm(o.reshape((B, C, H * dv)), wo), new
 
+    def _gqa(self, n, p, kept, start, pos, mix):
+        """The grouped-query mixer on normed states ``n`` (B, C, D) at
+        positions ``pos`` (B, C): ``mix`` ``"gqa"`` attends ``kept`` (B,
+        S, >= 2 w), the cached positions' ``[K | V]`` rows (those under
+        ``start`` count), ``"swa"`` the ring ``kept`` (B, R, >= 2 w)
+        its sequence's last rows lie in, inside the window; None: a
+        whole sequence from nothing.  Returns (out (B, C, D), the
+        chunk's rows (B, C, 2 w)).  Traced under the named scopes
+        ``attn.proj`` (the products with a weight, the norms of q and k,
+        the rotary positions) and ``attn.core`` (scores, softmax and
+        context over a slot's whole rows) or ``attn.window`` (the same
+        over a ring)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ....ops.attention_rows import (chunk_attention_rows,
+                                            window_attention_rows)
+
+        z = self._sizes
+        H, Hkv, dh = z["H"], z["Hkv"], z["dh"]
+        wq, wk, wv = p[:3]
+        wo = p[-1]
+        B, C, _D = n.shape
+        f32, act = jnp.float32, wq.dtype
+        with jax.named_scope("attn.proj"):
+            q = _mm(n, wq).reshape((B, C, H, dh)).astype(f32)
+            k = _mm(n, wk).reshape((B, C, Hkv, dh)).astype(f32)
+            v = _mm(n, wv)
+            if self._qk_norm:
+                q, k = _rms(q, p[3], self._eps), _rms(k, p[4], self._eps)
+            if mix in self._rotary:
+                q, k = _rope(q, pos, self._theta), _rope(k, pos, self._theta)
+            q = q.astype(act).reshape((B, C, H * dh))
+            k = k.astype(act).reshape((B, C, Hkv * dh))
+            new = jnp.concatenate([k, v.astype(act)], axis=-1)
+        w = Hkv * dh
+        if kept is None:        # one row no query attends
+            kept = jnp.zeros((B, 1, 2 * w), act)
+        kept = kept.astype(act)
+        if mix == "swa":
+            ctx = window_attention_rows(q, k, new[..., w:], kept, start,
+                                        z["window"], H, Hkv)
+        else:
+            ctx = chunk_attention_rows(q, k, new[..., w:], kept[..., :w],
+                                       kept[..., w:2 * w], start, H, Hkv)
+        with jax.named_scope("attn.proj"):
+            return _mm(ctx, wo), new
+
     def _moe(self, m, p):
         """The expert layer on normed states ``m`` (N, D): (the held
         routed experts' part + the shared expert, counts (E,))."""
@@ -596,8 +696,9 @@ class HybridDecoderLM(HybridBlock):
         it, what its mixer keeps, its expert layer's counts or None).
         ``cache`` None: a whole sequence from nothing.  Every part is
         traced under a named scope (``kda.proj``, ``kda.scan``,
-        ``attn.proj``, ``attn.core``, ``ffn``, ``experts.route``,
-        ``experts.ffn``), all layers' work under the one name, which
+        ``attn.proj``, ``attn.core``, ``attn.window``, ``ffn``,
+        ``experts.route``, ``experts.ffn``), all layers' work under the
+        one name, which
         ``profiler.device_table`` reads a device trace by."""
         import jax
         import jax.numpy as jnp
@@ -614,8 +715,10 @@ class HybridDecoderLM(HybridBlock):
                     jnp.zeros((B, z["H"], z["dk"], z["dv"]), jnp.float32),
                     jnp.zeros((B, z["K"] - 1, z["wide"]), act))
             out, kept = self._kda(n, _raw(mixer), cache, valid)
-        else:
+        elif mix == "mla":
             out, kept = self._mla(n, _raw(mixer), cache, start, pos)
+        else:
+            out, kept = self._gqa(n, _raw(mixer), cache, start, pos, mix)
         x = x + out.astype(act)
         with jax.named_scope("ffn"):
             m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
@@ -630,8 +733,9 @@ class HybridDecoderLM(HybridBlock):
 
     def _run(self, tokens, caches, start, valid):
         """tokens (B, C) int; caches a list with, a layer, its state
-        ``(S, tail)`` (KDA) or its cached rows (B, S, dl+dr) (MLA), or
-        None (a whole sequence from nothing); start, valid (B,) int32.
+        ``(S, tail)`` (KDA), its cached rows (B, S, lanes) (MLA, GQA)
+        or its ring (B, R, lanes) (SWA), or None (a whole sequence from
+        nothing); start, valid (B,) int32.
         Returns (logits raw (B, C, V), a layer's new state or the
         chunk's new rows, expert load (expert layers, E) int32, the
         last block's output (B, C, D))."""
@@ -680,12 +784,15 @@ class HybridDecoderLM(HybridBlock):
         is, for a layer with state, the tuple of its arrays ``(B, ...)``
         as the sequence left them, and for a layer with paged rows the
         rows of positions ``< start_b``, (B, S, lanes) with ``lanes``
-        the declared width padded with zeros to whole tiles of 128
+        the declared width padded with zeros to whole tiles of 128, for
+        a layer with a window its ring (B, R, lanes), row ``r`` holding
+        the last position under ``start_b`` that is ``r`` modulo ``R``
         (entries past the trunk's layers, the draft block's, are left
         alone).
         Returns
         ``(logits NDArray (B, C, V), a list with, a layer, the state
-        after the valid positions or the chunk's rows (B, C, width),
+        after the valid positions or the chunk's rows (B, C, width; a
+        windowed layer's too: the engine puts them in the ring),
         {"expert_load": (expert layers, experts) int32})``; a model
         with a draft block adds ``"hidden"``, the last block's output
         (B, C, D), which :meth:`draft_forward` takes."""

@@ -47,12 +47,31 @@ bound by the rows' bytes and small, and a loop in every layer of every
 step costs its edges (no operation is scheduled across them, weights
 are not fetched ahead over them) more than the masked rows it would
 skip.  :func:`attended_cache_rows` is the count a dispatch multiplies,
-which the engine writes on its ``engine.prefill`` span.
+which the engine writes on its ``engine.prefill`` and ``engine.decode``
+spans.
+
+**Rows kept in a ring.**  A sliding-window layer attends the last
+``window`` positions (itself included) and nothing before them, so what
+it keeps of a sequence is a ring a slot, ``(B, R, lanes)``, and no page:
+position ``p`` lies in ring row ``p mod R`` and the positions are never
+stored, :func:`ring_positions` computes them from ``start``.  A
+dispatch **attends before it writes**: :func:`window_attention_rows`
+attends the ring as the last dispatch left it, each row by the position
+it holds, and the chunk's own rows under the band, in the two forms
+above; :func:`ring_write` then puts the chunk's rows that count in
+their places.  A row written for a draft that was rejected lies at or
+above the next ``start``; by position it reads as ``R`` earlier, which
+no query's band reaches in a ring of :func:`ring_rows` rows, and the
+next dispatch overwrites it: nothing is copied to roll back.  A chunk
+longer than the ring leaves its last ``R`` rows.  A windowed layer
+multiplies its ring's rows in every shape, and that is what
+:func:`attended_cache_rows` counts for it.
 """
 from __future__ import annotations
 
 __all__ = ["chunk_attention_rows", "attends_in", "cached_rows_in",
-           "cache_block_rows", "attended_cache_rows"]
+           "cache_block_rows", "attended_cache_rows", "ring_rows",
+           "ring_positions", "window_attention_rows", "ring_write"]
 
 # query rows a slot (chunk positions x query heads) up to which the
 # block-diagonal products beat the head-split ones.  Timed on a TPU v5e
@@ -110,12 +129,16 @@ def cache_block_rows(held):
     return min(CACHE_BLOCK_ROWS, int(held))
 
 
-def attended_cache_rows(chunk, start, held):
+def attended_cache_rows(chunk, start, held, rule=None):
     """Cached rows a slot a dispatch of ``chunk`` query positions
-    multiplies when its longest sequence has written ``start`` of the
-    ``held`` rows a slot holds: whole blocks up to ``start`` under
-    ``"blocks"``, ``held`` under ``"whole"``."""
-    if cached_rows_in(chunk) == "whole":
+    multiplies in one layer when its longest sequence has written
+    ``start`` of the ``held`` rows a slot holds: whole blocks up to
+    ``start`` under ``"blocks"``, ``held`` under ``"whole"``.  ``rule``
+    (:func:`cached_rows_in`'s answer without it) is ``"whole"`` for a
+    layer that multiplies all it holds in every shape: attention through
+    :func:`chunk_attention_rows`, and a windowed layer, whose ``held``
+    is its ring's rows."""
+    if (rule or cached_rows_in(chunk)) == "whole":
         return int(held)
     k = cache_block_rows(held)
     return min(-(-int(start) // k) * k, int(held))
@@ -166,27 +189,121 @@ def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
     scope ``attn.core`` (``mxnet_tpu.profiler.device_table``).
     """
     import jax
+    import jax.numpy as jnp
 
+    C, S = q.shape[1], k_rows.shape[1]
     with jax.named_scope("attn.core"):
-        return _chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows,
-                                     start, n_heads, n_kv_heads, scale)
+        cache_ok = (jnp.arange(S, dtype=jnp.int32)[None, :]
+                    < start.astype(jnp.int32)[:, None])         # (B, S)
+        c_idx = jnp.arange(C, dtype=jnp.int32)
+        causal = c_idx[:, None] >= c_idx[None, :]               # (C, C')
+        return _attend_rows(q, k_chunk, v_chunk, k_rows, v_rows,
+                            cache_ok[:, None, :], causal, n_heads,
+                            n_kv_heads, scale)
 
 
-def _chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
-                          n_heads, n_kv_heads, scale):
+def ring_rows(window, spec_k=0, itemsize=2):
+    """Rows of the ring a windowed layer keeps a slot: ``window +
+    spec_k``, up to whole sublane tiles (8 rows of 32 bits: 16 of
+    bfloat16).  A query attends the ``window - 1`` positions before it;
+    the dispatch before may have written ``spec_k`` rows past the
+    position this one starts at (drafts that were rejected), each of
+    which displaced the row ``R`` positions earlier.  Attending before
+    writing, ``window - 1 + spec_k`` rows would do; one more keeps a
+    whole window readable beside them (``PagedGenerationEngine.cached``)
+    and would let a step write before it attends."""
+    tile = 8 * max(1, 4 // int(itemsize))
+    return -(-(int(window) + int(spec_k)) // tile) * tile
+
+
+def ring_positions(start, rows):
+    """The position each of a ring's ``rows`` rows holds before a
+    dispatch that starts at ``start`` (B,): the largest ``p < start_b``
+    with ``p mod rows == r``, (B, rows) int32; negative where the
+    sequence has not reached the row (whatever lies there is another
+    sequence's, or nothing)."""
+    import jax.numpy as jnp
+
+    last = start.astype(jnp.int32)[:, None] - 1
+    r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    return last - jnp.mod(last - r, rows)
+
+
+def window_attention_rows(q, k_chunk, v_chunk, ring, start, window,
+                          n_heads, n_kv_heads=None, scale=None):
+    """Attention of a chunk in a sliding window of ``window`` positions
+    (position ``i`` attends ``j`` with ``0 <= i - j < window``) against
+    the ring its sequence's last rows lie in.
+
+    ``q``, ``k_chunk``, ``v_chunk`` and ``start`` as
+    :func:`chunk_attention_rows` takes them; ``ring`` (B, R, lanes) with
+    a row's ``[K | V]`` (``2 * n_kv_heads * d_head`` values) first and
+    whatever lanes the engine pads with after them, as the dispatch
+    before left it: row ``r`` holds position :func:`ring_positions`
+    ``[b, r]``.  Chunk position ``c`` attends the ring's rows whose
+    position is not negative and more than ``start_b + c - window``, and
+    chunk positions ``c - window < c' <= c``.  Returns (B, C, n_heads *
+    d_head) in ``q``'s dtype.  Traced under the named scope
+    ``attn.window``."""
+    import jax
     import jax.numpy as jnp
 
     B, C, _ = q.shape
-    S = k_rows.shape[1]
+    R, w = ring.shape[1], k_chunk.shape[2]
+    with jax.named_scope("attn.window"):
+        held = ring_positions(start, R)                         # (B, R)
+        at = start.astype(jnp.int32)[:, None] \
+            + jnp.arange(C, dtype=jnp.int32)[None, :]            # (B, C)
+        ring_ok = (held[:, None, :] >= 0) \
+            & (at[:, :, None] - held[:, None, :] < window)      # (B, C, R)
+        c_idx = jnp.arange(C, dtype=jnp.int32)
+        ahead = c_idx[:, None] - c_idx[None, :]
+        band = (ahead >= 0) & (ahead < window)                  # (C, C')
+        return _attend_rows(q, k_chunk, v_chunk, ring[..., :w],
+                            ring[..., w:2 * w], ring_ok, band, n_heads,
+                            n_kv_heads, scale)
+
+
+def ring_write(ring, rows, start, valid):
+    """The ring after a dispatch: ``rows`` (B, C, lanes), the chunk's
+    rows of positions ``start_b ..``, of which the first ``valid_b``
+    count, put at ``position mod R``; every other row of ``ring`` (B, R,
+    lanes) stays.  A chunk no longer than the ring scatters its rows in
+    place (a row that does not count is dropped); a longer one gathers,
+    for every ring row, the last chunk row that lands on it."""
+    import jax.numpy as jnp
+
+    B, R, _ = ring.shape
+    C = rows.shape[1]
+    start = start.astype(jnp.int32)[:, None]
+    valid = valid.astype(jnp.int32)[:, None]
+    rows = rows.astype(ring.dtype)
+    if C <= R:
+        c = jnp.arange(C, dtype=jnp.int32)[None, :]
+        to = jnp.where(c < valid, jnp.mod(start + c, R), R)     # (B, C)
+        return ring.at[jnp.arange(B)[:, None], to].set(rows, mode="drop")
+    r = jnp.arange(R, dtype=jnp.int32)[None, :]
+    last = valid - 1                                            # (B, 1)
+    c = last - jnp.mod(start + last - r, R)                     # (B, R)
+    new = jnp.take_along_axis(rows, jnp.maximum(c, 0)[:, :, None], axis=1)
+    return jnp.where((c >= 0)[:, :, None], new, ring)
+
+
+def _attend_rows(q, k_chunk, v_chunk, k_rows, v_rows, cache_ok, chunk_ok,
+                 n_heads, n_kv_heads, scale):
+    """The two forms on masks given: ``cache_ok`` (B, 1 or C, S) the
+    cached rows a chunk position attends, ``chunk_ok`` (C, C') the
+    chunk's own."""
+    import jax.numpy as jnp
+
+    B, C, _ = q.shape
     H = int(n_heads)
     Hkv = int(n_kv_heads) if n_kv_heads else H
     G, dh = H // Hkv, q.shape[2] // H
     if scale is None:
         scale = dh ** -0.5
-    cache_ok = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                < start.astype(jnp.int32)[:, None])             # (B, S)
-    c_idx = jnp.arange(C, dtype=jnp.int32)
-    causal = c_idx[:, None] >= c_idx[None, :]                   # (C, C')
+    causal = chunk_ok
+    by_query = cache_ok.shape[1] != 1
 
     if attends_in(C, H) == "rows":
         # row (c, h) = head h's values at the lanes of its key/value
@@ -201,7 +318,8 @@ def _chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
         s_cache = _dot(q_bd, k_rows, rows_t) * scale            # (B, CH, S)
         s_chunk = _dot(q_bd, k_chunk, rows_t) * scale           # (B, CH, C)
         p_cache, p_chunk = _softmax_pair(
-            s_cache, s_chunk, cache_ok[:, None, :],
+            s_cache, s_chunk,
+            jnp.repeat(cache_ok, H, axis=1) if by_query else cache_ok,
             jnp.repeat(causal, H, axis=0)[None], k_rows.dtype)
         over_s = (((2,), (1,)), ((0,), (0,)))
         o_bd = _dot(p_cache, v_rows, over_s) + _dot(p_chunk, v_chunk,
@@ -227,7 +345,9 @@ def _chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
         s_cache = _dot(qh, heads(k_rows), over_d) * scale   # (B,Hkv,GC,S)
         s_chunk = _dot(qh, heads(k_chunk), over_d) * scale
         p_cache, p_chunk = _softmax_pair(
-            s_cache, s_chunk, cache_ok[:, None, None, :],
+            s_cache, s_chunk,
+            (jnp.tile(cache_ok, (1, G, 1)) if by_query else cache_ok)[
+                :, None],
             jnp.tile(causal, (G, 1))[None, None], k_rows.dtype)
         over_s = (((3,), (3,)), ((0, 1), (0, 1)))
         out = _dot(p_cache, heads(v_rows), over_s) + _dot(

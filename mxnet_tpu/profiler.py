@@ -223,8 +223,8 @@ def device_memory_stats():
 # "Where the device's time goes"); ``draft`` is an outer scope: the
 # others nest inside it.  A Gluon block opens one named for itself.
 PART_SCOPES = ("cache.gather", "cache.write", "sample", "embed", "head",
-               "attn.proj", "attn.core", "kda.proj", "kda.scan", "ffn",
-               "experts.route", "experts.ffn", "draft")
+               "attn.proj", "attn.core", "attn.window", "kda.proj",
+               "kda.scan", "ffn", "experts.route", "experts.ffn", "draft")
 
 DeviceOp = collections.namedtuple("DeviceOp", (
     "program_id", "run_start_ns", "start_ns", "duration_ns", "scope",

@@ -265,18 +265,20 @@ def test_manifest_lists_the_eleven_after_what_it_had():
 
     man = manifest.manifest()
     assert manifest.check(man)
-    # (PR 38 appended one more after them)
-    assert [m["name"] for m in man["per_layer"]][-12:-1] == \
-        list(SCOPE_METRICS)
-    for m in man["per_layer"][-12:-1]:
+    # (later PRs appended more after them, and their cells to the
+    # lists of the metrics whose scopes their programs open)
+    names = [m["name"] for m in man["per_layer"]]
+    at = names.index(next(iter(SCOPE_METRICS)))
+    assert at == 40 and names[at:at + 11] == list(SCOPE_METRICS)
+    for m in man["per_layer"][at:at + 11]:
         scope, cells = SCOPE_METRICS[m["name"]]
         spec = manifest.layer_metric(m["name"])
         assert spec["reducer"] == "device_by_scope"
         assert spec["args"].get("scope") == scope
         assert scope is None or scope in profiler.PART_SCOPES
         inspect.signature(device_by_scope.reduce).bind({}, **spec["args"])
-        assert (m["source"], m["better"], len(m["workloads"])) == \
-            ("device_trace", "lower", cells)
+        assert (m["source"], m["better"]) == ("device_trace", "lower")
+        assert len(m["workloads"]) >= cells
         assert m["moves"] == ("train_throughput" if m["name"].startswith(
             "train") else "serve_itl_p95_ms")
         assert m["layer"] == {"experts.ffn": "experts"}.get(

@@ -382,15 +382,18 @@ def test_span_args_reader_of_the_rows_the_experts_multiply(ctx):
     """``moe_expert_rows_share`` (PR 36): the rows the held experts
     multiplied, each one's padded up to whole tiles, over what every
     held expert multiplying every row would be, of the window's
-    ``engine.decode`` spans; data only, the manifest's last entry
-    before PR 37's eleven and PR 38's one."""
+    ``engine.decode`` spans; data only, the manifest's entry before PR
+    37's eleven (cells that later PRs add follow the two it was in)."""
     man = manifest.manifest()
-    entry = man["per_layer"][-13]
+    names = [m["name"] for m in man["per_layer"]]
+    at = names.index("moe_expert_rows_share")
+    assert names[at + 1] == "decode_cache_gather_device_ms_per_step"
+    entry = dict(man["per_layer"][at])
+    assert entry.pop("workloads")[:2] == [CELL, "gigachat3_serve_reason"]
     assert entry == {
         "name": "moe_expert_rows_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "experts",
-        "moves": "serve_itl_p95_ms",
-        "workloads": [CELL, "gigachat3_serve_reason"]}
+        "moves": "serve_itl_p95_ms"}
     ctx["ring"]["records"] += [
         _decode(9.5, expert_rows_multiplied=1, expert_rows_dense=1),
         _decode(10.2, expert_rows_multiplied=4608,
@@ -409,14 +412,19 @@ def test_span_args_reader_of_the_rows_a_chunk_attends(ctx):
     """``prefill_attended_rows_share`` (PR 38): the cached rows a
     chunk's attention multiplies, whole blocks up to what its sequence
     has written, over the rows a slot holds, of the window's
-    ``engine.prefill`` spans; data only, the manifest's last entry, in
-    the two cells whose model attends its own rows."""
+    ``engine.prefill`` spans; data only, the manifest's entry after PR
+    37's eleven, in the two cells whose model attended its own rows
+    then (cells that later PRs add follow them)."""
     man = manifest.manifest()
-    assert man["per_layer"][-1] == {
+    names = [m["name"] for m in man["per_layer"]]
+    at = names.index("prefill_attended_rows_share")
+    assert names[at - 1] == "train_norm_device_ms_per_step"
+    entry = dict(man["per_layer"][at])
+    assert entry.pop("workloads")[:2] == [CELL, "gigachat3_serve_reason"]
+    assert entry == {
         "name": "prefill_attended_rows_share", "unit": "%",
         "better": "lower", "source": "program_counter", "layer": "kernels",
-        "moves": "serve_itl_p95_ms",
-        "workloads": [CELL, "gigachat3_serve_reason"]}
+        "moves": "serve_itl_p95_ms"}
     assert manifest.layer_metric("prefill_attended_rows_share") == {
         "name": "prefill_attended_rows_share", "reducer": "span_args",
         "args": {"span": "engine.prefill", "num": ["cache_rows_attended"],

@@ -402,10 +402,12 @@ def test_only_a_chunk_shape_lowers_to_a_loop():
     assert [attention_rows.cached_rows_in(c) for _b, c in loops] == \
         ["blocks", "whole", "whole"]
     # a slot holds 128 rows, under one block of 512: a chunk that has
-    # rows to attend multiplies them all, a step always does
+    # rows to attend multiplies them all, a step always does; counted
+    # over the six layers that cache rows (ISSUE 39)
     assert eng._cache_rows_attended(24, 0) == 0
-    assert eng._cache_rows_attended(24, 48) == 128
-    assert eng._cache_rows_attended(2, 0) == 128
+    assert eng._cache_rows_attended(24, 48) == 6 * 128
+    assert eng._cache_rows_attended(2, 0) == 6 * 128
+    assert eng._cache_rows_held() == 6 * 128
 
 
 def _deepseek_small():
@@ -423,13 +425,31 @@ def _deepseek_small():
     return deepseek_v3, small
 
 
+def _exaone_small():
+    """The ``exaone_moe`` reference and its configuration at the
+    rehearsal's widths, its router as published (128 experts in one
+    group, 8 a token, 2.5)."""
+    from benchmark.lib.reference import exaone_moe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        c = json.load(f)
+    small = dict(c, **c["rehearsal"])
+    small.update({k: c[k] for k in ("n_group", "topk_group",
+                                    "num_experts_per_tok")})
+    return exaone_moe, small
+
+
 @pytest.mark.parametrize("family, E, share", [
-    ("bailing_hybrid", 16, 4), ("deepseek_v3", 256, 16)])
+    ("bailing_hybrid", 16, 4), ("deepseek_v3", 256, 16),
+    ("exaone_moe", 128, 16)])
 def test_the_shares_add_up_to_the_uncut_layer(cfg, family, E, share):
     """The share test.  An expert layer of ``E`` routed experts held as
     ``E / share`` shares of ``share`` (Ling's 16 as 4 of 4 at the
     rehearsal's router; GigaChat's 256 as the 16 chips' 16 of 16 at the
-    published router, ISSUE 35): each share's part through
+    published router, ISSUE 35; K-EXAONE's 128 as the 8 chips' 16 of 16
+    at the published router of one group, ISSUE 39): each share's part
+    through
     ``routed_experts`` (the router over all of them every time), summed,
     plus the shared expert ONCE, is the uncut layer of the family's
     reference; and one share's part is the reference's for the same
@@ -441,6 +461,10 @@ def test_the_shares_add_up_to_the_uncut_layer(cfg, family, E, share):
 
     if family == "bailing_hybrid":
         fam, whole = ref, dict(cfg, num_experts=E, experts_first=0)
+    elif family == "exaone_moe":
+        fam, cfg = _exaone_small()
+        whole = dict(cfg, num_experts=E, experts_first=0,
+                     published={"num_experts": E})
     else:
         fam, cfg = _deepseek_small()
         whole = dict(cfg, n_routed_experts=E, experts_first=0,
@@ -524,6 +548,36 @@ def test_group_limited_selection_by_hand():
     assert sorted(np.asarray(picks)[0].tolist()) == [2, 4]
     np.testing.assert_allclose(np.asarray(weight)[0, [2, 4]],
                                [2.5 * .8 / 1.4, 2.5 * .6 / 1.4], 1e-5)
+
+
+def test_one_group_selects_a_plain_top_k():
+    """``n_group`` 1 and ``topk_group`` 1 (K-EXAONE's router, ISSUE
+    39): the one group is always kept, so group limiting selects every
+    expert and the choice is the 8 highest ``s + bias`` of all 128,
+    weighted by their unbiased scores over their sum, times 2.5."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel.moe import sigmoid_group_select
+
+    k1, k2 = jax.random.split(jax.random.key(4))
+    logits = 2.0 * jax.random.normal(k1, (64, 128))
+    bias = 0.3 * jax.random.normal(k2, (128,))
+    w, i = sigmoid_group_select(bias, 1, 1, 2.5)(logits, 8)
+    s = np.asarray(jax.nn.sigmoid(logits), np.float64)
+    plain = np.argsort(-(s + np.asarray(bias)), axis=1)[:, :8]
+    assert (np.sort(np.asarray(i), 1) == np.sort(plain, 1)).all()
+    picked = np.take_along_axis(s, np.asarray(i), 1)
+    np.testing.assert_allclose(
+        np.asarray(w), 2.5 * picked / picked.sum(1, keepdims=True), 1e-5)
+    # the reference's router says the same
+    fam, small = _exaone_small()
+    weight, picks = fam.route(small, logits, bias)
+    assert (np.sort(np.asarray(picks), 1) == np.sort(plain, 1)).all()
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(weight), np.asarray(i), 1),
+        np.asarray(w), 1e-5)
+    del jnp
 
 
 # -- the life cycle of per-slot state ----------------------------------------

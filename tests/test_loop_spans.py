@@ -583,7 +583,8 @@ def test_manifest_checks_and_cells_report_the_new_metrics():
     # each, PR 34 the share of slot-steps fed on the device, PR 35 three
     # of self-drafting, PR 36 the share of rows the held experts
     # multiply, PR 37 eleven parts of the device's time by named scope
-    assert names[17:27] == list(NEW_METRICS) and len(names) == 52
+    # (and PR 38 one, PR 39 four: the list only grows at its end)
+    assert names[17:27] == list(NEW_METRICS) and len(names) >= 52
     assert names[35] == "decode_fed_on_device_share"
     train = {m["name"] for m in
              manifest.metrics_of(man, "per_layer", "resnet50_train_b256")}

@@ -20,7 +20,12 @@ float32, seeded random weights:
   trunk's and the draft block's layers of the pool;
 * prefix attachment is offered to a model that keeps rows alone, the
   draft block's among them; n-gram speculation is as it was; a model
-  with per-slot state refuses ``spec_k`` 1 as it refuses 2.
+  with per-slot state refuses ``spec_k`` 1 as it refuses 2;
+* (ISSUE 39) the same under a model whose windowed layers keep their
+  rows in rings (``tests/test_window_cache.py``'s model and helpers):
+  with every draft accepted, with none and with every other one the
+  tokens are the plain engine's and the rings hold the reference's
+  rows; a ring one row short of what a verify step needs fails that.
 """
 import json
 import math
@@ -40,6 +45,7 @@ if ROOT not in sys.path:
 from benchmark import programs  # noqa: E402
 from benchmark.lib import weights  # noqa: E402
 from benchmark.lib.reference import deepseek_v3 as ref  # noqa: E402
+import test_window_cache as wc  # noqa: E402
 
 # float32 on both sides, the same weights: what is left is the order of
 # the sums (the absorbed products, XLA's own fusions)
@@ -511,3 +517,104 @@ def test_a_model_with_state_still_refuses_speculation(spec_k):
     with pytest.raises(ValueError, match="needs"):
         HybridDecoderLM(vocab_size=64, d_model=32, mixers=["kda"],
                         ffns=["dense"], n_heads=2, d_ff=32)
+
+
+# -- windowed layers' rings under self-drafting (ISSUE 39) -------------------
+
+@pytest.fixture(scope="module")
+def window_cfg():
+    return wc.small_config()
+
+
+@pytest.fixture(scope="module")
+def window_model(window_cfg):
+    return wc.build_model(window_cfg)
+
+
+def _drafting_against_plain(cfg, model, accept, steps=14):
+    """Greedy output and ring rows under self-drafting against the plain
+    engine's: ``(tokens equal, rings equal)`` over three slots."""
+    net, arrays = model
+    prompts = [wc._ids(cfg, n, seed)
+               for n, seed in ((50, 3), (7, 4), (33, 6))]
+    if "plain" not in wc._REF:     # (the plain engine's ring is the window)
+        wc._REF["plain"] = wc._serve(wc._engine(net, spec_k=0), prompts,
+                                     2 * steps + 3)
+    plain = wc._REF["plain"]
+    eng = wc._engine(net, spec_k=1)
+    V = cfg["vocab_size"]
+    plant = {"all": lambda s, toks: plain[s][len(toks)],
+             "none": lambda s, toks: (plain[s][len(toks)] + 1) % V,
+             "mixed": lambda s, toks: (plain[s][len(toks)]
+                                       + (len(toks) + s) % 2) % V}[accept]
+    got = wc._serve(eng, prompts, steps, plant)
+    tokens_equal, rings_equal = True, True
+    for slot, toks in got.items():
+        tokens_equal &= toks == plain[slot][:len(toks)]
+        seq = list(prompts[slot]) + plain[slot]
+        snap = eng.cached([slot])[0]
+        n = snap["position"]
+        theirs = wc._ref_rows(cfg, arrays, seq)
+        for mine, want in zip(snap["layers"][:4], theirs):
+            first = mine["first"]
+            rings_equal &= bool(
+                np.abs(mine["rows"] - want[first:n]).max() < wc.TOL)
+    return got, eng, tokens_equal, rings_equal
+
+
+@pytest.mark.parametrize("accept", ["all", "none", "mixed"])
+def test_drafting_on_serves_the_tokens_and_keeps_the_rings_of_off(
+        window_cfg, window_model, accept):
+    """With every draft accepted (planted from the plain run's tokens:
+    two rows a step written and kept), with none (planted one off: the
+    second row written at ``p + 1`` and then overwritten) and with every
+    other one: token for token the plain engine's output, and ring row
+    for ring row the reference's rows of the tokens served.  Nothing is
+    copied to roll a rejected row back."""
+    got, eng, tokens_equal, rings_equal = _drafting_against_plain(
+        window_cfg, window_model, accept)
+    assert tokens_equal and rings_equal
+    lens = {"all": 29, "none": 15}
+    if accept in lens:
+        assert all(len(t) == lens[accept] for t in got.values())
+    assert eng.spec_accept_rate() == {"all": 1.0, "none": 0.0}.get(
+        accept, eng.spec_accept_rate())
+
+
+@pytest.mark.parametrize("rows, fails", [(wc.WINDOW, False),
+                                         (wc.WINDOW - 1, True)])
+def test_a_ring_one_row_short_fails_under_the_verify_step(
+        window_cfg, window_model, monkeypatch, rows, fails):
+    """The engine attends before it writes, so a query needs the
+    ``window - 1`` positions before it in the ring, and the verify step
+    before it may have left ``spec_k`` rejected rows on the rows before
+    those: ``window - 1 + spec_k`` rows are the least
+    (``ops.attention_rows.ring_rows`` gives one more, in whole tiles;
+    an engine that wrote before it attended would need that one more,
+    and a ring of exactly ``window`` rows would fail there).  A ring of
+    ``window - 1`` rows serves plain decoding right
+    (``test_plain_decoding_stands_the_least_ring``) and fails this
+    test under the verify step: the rejected draft's row at ``p + 1``
+    displaces position ``p + 2 - window``, which the query at ``p + 1``
+    still attends."""
+    monkeypatch.setattr(
+        wc.attention_rows, "ring_rows",
+        lambda window, spec_k=0, itemsize=2: rows if spec_k else window)
+    _got, eng, tokens_equal, rings_equal = _drafting_against_plain(
+        window_cfg, window_model, "none")
+    assert eng._ring_rows == [rows] * 4
+    assert (not (tokens_equal and rings_equal)) == fails
+
+
+def test_plain_decoding_stands_the_least_ring(window_cfg, window_model,
+                                              monkeypatch):
+    """Without a draft to reject, ``window - 1`` rows are enough."""
+    monkeypatch.setattr(wc.attention_rows, "ring_rows",
+                        lambda window, spec_k=0, itemsize=2: window - 1)
+    net, arrays = window_model
+    eng = wc._engine(net, spec_k=0)
+    assert eng._ring_rows == [wc.WINDOW - 1] * 4
+    p = wc._ids(window_cfg, 61, 5)
+    got = wc._serve(eng, [p], 12)[0]
+    trunk, _draft = wc._ref_logits(window_cfg, arrays, list(p) + got)
+    assert got == list(trunk[60:60 + len(got)].argmax(-1))
