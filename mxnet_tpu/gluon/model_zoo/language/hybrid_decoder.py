@@ -1,18 +1,24 @@
 """A hybrid decoder-only language model: a layer mixes tokens by a
-linear-attention recurrence (Kimi Delta Attention, arXiv:2510.26692),
-by softmax attention over a cached latent (multi-head latent
-attention, DeepSeek-V2, arXiv:2405.04434, section 2.1), or by
-grouped-query softmax attention over cached keys and values, over the
-whole sequence or in a sliding window (EXAONE 4.0, arXiv:2507.11407,
-section 2), and the feed-forward of a layer is a dense gated-SiLU MLP
-or a sparse expert layer with a sigmoid, group-limited router and a
-shared expert (DeepSeek-V3, arXiv:2412.19437, section 2.1.2).
+linear-attention recurrence (Kimi Delta Attention, arXiv:2510.26692, or
+Gated DeltaNet, arXiv:2412.06464), by softmax attention over a cached
+latent (multi-head latent attention, DeepSeek-V2, arXiv:2405.04434,
+section 2.1), or by grouped-query softmax attention over cached keys
+and values, over the whole sequence or in a sliding window (EXAONE 4.0,
+arXiv:2507.11407, section 2), and the feed-forward of a layer is a
+dense gated-SiLU MLP or a sparse expert layer with a sigmoid,
+group-limited router and a shared expert (DeepSeek-V3,
+arXiv:2412.19437, section 2.1.2).
 
     h = x + Mix_l(RMSNorm(x));  y = h + FFN_l(RMSNorm(h))
 
-``mixers[l]`` is ``"kda"``, ``"mla"``, ``"gqa"`` or ``"swa"`` and
-``ffns[l]`` ``"dense"`` or ``"moe"``; no bias anywhere, an untied
-output head.
+or, with ``post_norm`` (OLMo 2's reordered block, arXiv:2501.00656,
+section 3.2: the norm on each sub-layer's output),
+
+    h = x + RMSNorm(Mix_l(x));  y = h + RMSNorm(FFN_l(h))
+
+``mixers[l]`` is ``"kda"``, ``"gdn"``, ``"mla"``, ``"gqa"`` or
+``"swa"`` and ``ffns[l]`` ``"dense"`` or ``"moe"``; no bias anywhere,
+an untied output head.
 
 **KDA** (``n_heads`` heads of ``d_k`` key and ``d_v`` value channels):
 ``q, k, v = SiLU(conv(W_qkv x))``, a causal depthwise convolution of
@@ -25,6 +31,17 @@ its chunkwise form for a chunk); ``out = W_o (RMSNorm_head(o) *
 sigmoid(W_g x))`` with one gate a head.  No positions.  What a KDA
 layer carries from one dispatch to the next is per sequence, not per
 token: ``S`` and the last ``conv_kernel - 1`` inputs of the convolution.
+
+**GDN** (Gated DeltaNet with one decay a head, as FLA's
+``GatedDeltaNet`` builds it): ``q = W_q x``, ``k = W_k x`` (``n_heads``
+heads of ``d_k``) and ``v = W_v x`` (of ``d_v``), three products, then
+KDA's convolution, SiLU and l2 norms; a log-decay a HEAD ``g =
+-exp(A_log_h) softplus(W_a x + dt_bias_h)``, with no bound below;
+``beta = sigmoid(W_b x)`` a head, times 2 with ``neg_eigval`` (the
+transition ``I - beta k k^T`` then has eigenvalues down to -1, Grazzi
+et al., arXiv:2411.12537); the same state and rule; ``out = W_o
+(RMSNorm_head(o) * SiLU(W_g x))`` with a gate a CHANNEL.  It keeps what
+a KDA layer keeps.
 
 **MLA**: ``q = W_q x`` as heads of ``(d_nope | d_rope)``, or with a
 query latent (``q_latent``; DeepSeek-V3, arXiv:2412.19437, section
@@ -54,7 +71,9 @@ its sequence has written, a step all the rows a slot holds
 **GQA** (``"gqa"``, full) and **SWA** (``"swa"``, windowed): ``q = W_q
 x`` as ``n_heads`` heads of ``d_head``, ``k = W_k x`` and ``v = W_v x``
 as ``n_kv_heads`` heads; with ``qk_norm`` an RMSNorm with a learned
-weight over the ``d_head`` channels of every q and k head; rotate-half
+weight over the ``d_head`` channels of every q and k head, or with
+``qk_norm="whole"`` over all of q's and all of k's channels at once
+(OLMo 2's); rotate-half
 rotary positions at ``theta^(-2i/d_head)`` on the kinds named in
 ``rotary`` (by default the windowed layers alone: a full layer then
 carries no positions at all); ``softmax(q . k * d_head^-1/2) v``,
@@ -93,7 +112,7 @@ in the same program (``generate.PagedGenerationEngine``,
 
 The model speaks the chunk protocol of ``mxnet_tpu.generate`` and
 declares, a layer, what it caches (``config["layer_caches"]``): a KDA
-layer per-slot state (``S`` and the convolution's tail), an MLA layer
+or GDN layer per-slot state (``S`` and the convolution's tail), an MLA layer
 paged rows of ``d_latent + d_rope`` values, a GQA layer paged rows of
 ``2 * n_kv_heads * d_head`` (of which a step reads each slot's written
 pages in place and a chunk multiplies all a slot holds: ``"attended":
@@ -105,7 +124,9 @@ draft block's after the head; every matrix is stored ``(out, in)``,
 the routed experts side by side as ``MoEDecoderLM`` stores them.
 ``dtype`` is the type the embedding and the matrices are stored in;
 norm weights, ``A_log``, ``dt_bias``, the router's selection bias and
-the output head are always float32.
+the output head are always float32.  A GDN layer's convolution is one
+``(conv_kernel, 2 n_heads d_k + n_heads d_v)`` weight over ``[q | k |
+v]``, as KDA's is.
 """
 from __future__ import annotations
 
@@ -185,6 +206,25 @@ def _raw(params):
     return [q.data()._data for q in params]
 
 
+def _delta_rule(q, k, v, g, beta, state, valid, scope):
+    """The gated delta rule over a dispatch (``ops.gated_delta``): its
+    one-token form for a decode step (a row past ``valid`` keeps its
+    state), its chunkwise form for a chunk.  ``g`` (B, C, H, dk) a key
+    channel or (B, C, H) a head.  Returns (o (B, C, H, dv), state)."""
+    import jax.numpy as jnp
+
+    from ....ops.gated_delta import gated_delta_chunk, gated_delta_step
+
+    if q.shape[1] > 1:
+        return gated_delta_chunk(q, k, v, g, beta, state, valid, scope)
+    live = valid > 0
+    o, state = gated_delta_step(
+        q[:, 0], k[:, 0], v[:, 0],
+        jnp.where(live.reshape((-1,) + (1,) * (g.ndim - 2)), g[:, 0], 0.0),
+        jnp.where(live[:, None], beta[:, 0], 0.0), state, scope)
+    return o[:, None], state
+
+
 def _attend_in_blocks(q, rows, start, scale, s_own, own, keep):
     """The context of absorbed latent attention over the cached rows a
     dispatch's sequences have written and over the chunk's own, the
@@ -259,10 +299,13 @@ class HybridDecoderLM(HybridBlock):
     deployment); the shared expert is always held.  ``q_latent``,
     ``d_v_mla``, ``mla_gate`` and ``rope_scaling`` (a published
     ``rope_scaling`` group of type ``yarn``) shape the MLA layers;
-    ``n_kv_heads``, ``d_head``, ``qk_norm`` and ``rotary`` (the kinds
-    among ``"gqa"`` and ``"swa"`` that rotate) the grouped-query
-    layers, ``window`` the ``"swa"`` ones; ``draft_layers`` = 1 adds the
-    draft block.
+    ``n_kv_heads``, ``d_head``, ``qk_norm`` (True or ``"head"``: a
+    head at a time; ``"whole"``: the whole projection) and ``rotary``
+    (the kinds among ``"gqa"`` and ``"swa"`` that rotate) the
+    grouped-query layers, ``window`` the ``"swa"`` ones; ``d_k``,
+    ``d_v``, ``conv_kernel`` and ``neg_eigval`` (``beta`` in [0, 2])
+    the ``"gdn"`` ones; ``post_norm`` puts every block's two norms on
+    its sub-layers' outputs; ``draft_layers`` = 1 adds the draft block.
     """
 
     def __init__(self, vocab_size, d_model, mixers, ffns, n_heads,
@@ -275,18 +318,20 @@ class HybridDecoderLM(HybridBlock):
                  q_latent=None, d_v_mla=None, mla_gate=True,
                  rope_scaling=None, draft_layers=0, n_kv_heads=None,
                  d_head=None, window=None, qk_norm=True, rotary=("swa",),
-                 **kwargs):
+                 neg_eigval=False, post_norm=False, **kwargs):
         super().__init__(**kwargs)
         mixers, ffns = list(mixers), list(ffns)
         if len(mixers) != len(ffns) or not mixers:
             raise ValueError("mixers and ffns give one kind a layer each")
-        for kinds, known in ((mixers, ("kda", "mla", "gqa", "swa")),
+        for kinds, known in ((mixers, ("kda", "gdn", "mla", "gqa",
+                                       "swa")),
                              (ffns, ("dense", "moe"))):
             if set(kinds) - set(known):
                 raise ValueError("layer kinds %r are not of %r"
                                  % (kinds, known))
         needs = {"kda": dict(d_k=d_k, d_v=d_v, conv_kernel=conv_kernel,
                              kda_lower_bound=kda_lower_bound),
+                 "gdn": dict(d_k=d_k, d_v=d_v, conv_kernel=conv_kernel),
                  "mla": dict(d_nope=d_nope, d_rope=d_rope, d_latent=d_latent,
                              d_v=d_v_mla if d_v_mla is not None else d_v),
                  "gqa": dict(n_kv_heads=n_kv_heads, d_head=d_head),
@@ -310,6 +355,10 @@ class HybridDecoderLM(HybridBlock):
         if {"gqa", "swa"} & set(mixers) and n_heads % n_kv_heads:
             raise ValueError("n_heads (%d) must divide by n_kv_heads (%d)"
                              % (n_heads, n_kv_heads))
+        if qk_norm not in (True, False, "head", "whole"):
+            raise ValueError("qk_norm is True or 'head' (a head at a time), "
+                             "'whole' (the whole projection) or False, got %r"
+                             % (qk_norm,))
         moe = "moe" in ffns
         if moe:
             first, held = experts_held if experts_held is not None \
@@ -334,13 +383,16 @@ class HybridDecoderLM(HybridBlock):
                            norm_topk=bool(norm_topk))
         D, H, F = d_model, n_heads, d_expert
         K = None if conv_kernel is None else int(conv_kernel)
-        wide = None if d_k is None else H * (2 * d_k + d_v)  # a KDA layer's
-        dvm = d_v_mla if d_v_mla is not None else d_v        # q, k, v
+        # a KDA or GDN layer's q, k, v
+        wide = None if d_k is None else H * (2 * d_k + d_v)
+        dvm = d_v_mla if d_v_mla is not None else d_v
         self._sizes = dict(H=H, dk=d_k, dv=d_v, K=K, wide=wide,
                            dn=d_nope, dr=d_rope, dl=d_latent, dvm=dvm,
                            ql=q_latent, Hkv=n_kv_heads, dh=d_head,
                            window=None if window is None else int(window))
-        self._qk_norm, self._rotary = bool(qk_norm), tuple(rotary)
+        self._qk_norm = "head" if qk_norm is True else qk_norm
+        self._rotary = tuple(rotary)
+        self._neg_eigval, self._post_norm = bool(neg_eigval), bool(post_norm)
         self._gate = bool(mla_gate)
         # the frequency table and the score scale of the MLA layers
         self._yarn, self._scale = None, None
@@ -367,7 +419,7 @@ class HybridDecoderLM(HybridBlock):
         # what a layer keeps between dispatches: the engine allocates
         # it (generate.PagedGenerationEngine, "layer_caches")
         def keeps(m):
-            if m == "kda":
+            if m in ("kda", "gdn"):
                 return {"state": [((H, d_k, d_v), "float32"),
                                   ((K - 1, wide), None)]}
             if m == "mla":
@@ -403,13 +455,31 @@ class HybridDecoderLM(HybridBlock):
                     get(h + "gate_weight", (H, D)),
                     get(h + "o_norm_gamma", (d_v,), "float32"),
                     get(h + "attn_out_weight", (D, H * d_v))]
+            elif mix == "gdn":
+                mixer = [
+                    get(h + "proj_q_weight", (H * d_k, D)),
+                    get(h + "proj_k_weight", (H * d_k, D)),
+                    get(h + "proj_v_weight", (H * d_v, D)),
+                    get(h + "conv_weight", (K, wide)),
+                    get(h + "decay_weight", (H, D)),
+                    get(h + "decay_a_log", (H,), "float32"),
+                    get(h + "decay_dt_bias", (H,), "float32"),
+                    get(h + "beta_weight", (H, D)),
+                    get(h + "gate_weight", (H * d_v, D)),
+                    get(h + "o_norm_gamma", (d_v,), "float32"),
+                    get(h + "attn_out_weight", (D, H * d_v))]
             elif mix in ("gqa", "swa"):
                 mixer = [get(h + "proj_q_weight", (H * d_head, D)),
                          get(h + "proj_k_weight", (n_kv_heads * d_head, D)),
                          get(h + "proj_v_weight", (n_kv_heads * d_head, D))]
                 if self._qk_norm:
-                    mixer += [get(h + "q_norm_gamma", (d_head,), "float32"),
-                              get(h + "k_norm_gamma", (d_head,), "float32")]
+                    whole = self._qk_norm == "whole"
+                    mixer += [get(h + "q_norm_gamma",
+                                  (H * d_head if whole else d_head,),
+                                  "float32"),
+                              get(h + "k_norm_gamma",
+                                  (n_kv_heads * d_head if whole else d_head,),
+                                  "float32")]
                 mixer.append(get(h + "attn_out_weight", (D, H * d_head)))
             else:
                 q_in = D
@@ -480,51 +550,85 @@ class HybridDecoderLM(HybridBlock):
         import jax
         import jax.numpy as jnp
 
-        from ....ops.gated_delta import gated_delta_chunk, gated_delta_step
-
         wqkv, wc, wf, a_log, dt_bias, wb, wgate, g_o, wo = p
         z = self._sizes
-        H, dk, dv, K = z["H"], z["dk"], z["dv"], z["K"]
+        H, dk, dv = z["H"], z["dk"], z["dv"]
         B, C, _D = n.shape
         state, tail = cache
         f32 = jnp.float32
         with jax.named_scope("kda.proj"):
-            x = _mm(n, wqkv)                                    # (B, C, wide)
-            seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-            # the tail after this dispatch: the last K-1 inputs that count
-            # (with valid = 0 the tail it came with)
-            keep = valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-            tail = jnp.take_along_axis(seen, keep[:, :, None], axis=1)
-            y = sum(seen[:, j:j + C].astype(f32) * wc[j].astype(f32)
-                    for j in range(K))
-            y = jax.nn.silu(y)
-            q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
-            q = q.reshape((B, C, H, dk))
-            k = k.reshape((B, C, H, dk))
-            v = v.reshape((B, C, H, dv))
-
-            def l2(a):
-                return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True)
-                                         + 1e-6)
-
-            q, k = l2(q) * dk ** -0.5, l2(k)
+            q, k, v, tail = self._qkv_conv(_mm(n, wqkv), tail, wc, valid)
             gate_in = _mm(n, wf).astype(f32).reshape((B, C, H, dk)) \
                 + dt_bias.reshape((H, dk))
             g = self._lower * jax.nn.sigmoid(
                 jnp.exp(a_log)[:, None] * gate_in)              # in [lower, 0]
             beta = jax.nn.sigmoid(_mm(n, wb).astype(f32))       # (B, C, H)
-        if C == 1:
-            live = valid > 0
-            o, state = gated_delta_step(
-                q[:, 0], k[:, 0], v[:, 0],
-                jnp.where(live[:, None, None], g[:, 0], 0.0),
-                jnp.where(live[:, None], beta[:, 0], 0.0), state)
-            o = o[:, None]
-        else:
-            o, state = gated_delta_chunk(q, k, v, g, beta, state, valid)
+        o, state = _delta_rule(q, k, v, g, beta, state, valid, "kda.scan")
         with jax.named_scope("kda.proj"):
             o = _rms(o, g_o, self._eps) \
                 * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
+            return _mm(o.reshape((B, C, H * dv)), wo), (state, tail)
+
+    def _qkv_conv(self, x, tail, wc, valid):
+        """A KDA or GDN layer's causal depthwise convolution over the
+        projected ``x`` = ``[q | k | v]`` (B, C, wide) after the layer's
+        ``tail`` (B, K-1, wide), SiLU, and l2 norms a head (``q`` also
+        scaled by ``d_k^-1/2``).  Returns (q, k (B, C, H, dk), v (B, C,
+        H, dv), float32; the tail after the valid positions)."""
+        import jax
+        import jax.numpy as jnp
+
+        z = self._sizes
+        H, dk, dv, K = z["H"], z["dk"], z["dv"], z["K"]
+        B, C, _w = x.shape
+        f32 = jnp.float32
+        seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+        # the tail after this dispatch: the last K-1 inputs that count
+        # (with valid = 0 the tail it came with)
+        keep = valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        tail = jnp.take_along_axis(seen, keep[:, :, None], axis=1)
+        y = sum(seen[:, j:j + C].astype(f32) * wc[j].astype(f32)
+                for j in range(K))
+        y = jax.nn.silu(y)
+        q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
+        q = q.reshape((B, C, H, dk))
+        k = k.reshape((B, C, H, dk))
+        v = v.reshape((B, C, H, dv))
+
+        def l2(a):
+            return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True)
+                                     + 1e-6)
+
+        return l2(q) * dk ** -0.5, l2(k), v, tail
+
+    def _gdn(self, n, p, cache, valid):
+        """The Gated DeltaNet mixer on the states ``n`` (B, C, D), as
+        :meth:`_kda` takes them and with what it returns.  Traced under
+        the named scope ``gdn.proj`` (the products, the convolution, the
+        output norm and gate); the delta rule opens ``gdn.scan``."""
+        import jax
+        import jax.numpy as jnp
+
+        wq, wk, wv, wc, wa, a_log, dt_bias, wb, wgate, g_o, wo = p
+        z = self._sizes
+        H, dv = z["H"], z["dv"]
+        B, C, _D = n.shape
+        state, tail = cache
+        f32 = jnp.float32
+        with jax.named_scope("gdn.proj"):
+            q, k, v, tail = self._qkv_conv(jnp.concatenate(
+                [_mm(n, wq), _mm(n, wk), _mm(n, wv)], axis=-1), tail, wc,
+                valid)
+            # one log-decay a head, <= 0 and unbounded below
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                _mm(n, wa).astype(f32) + dt_bias)               # (B, C, H)
+            beta = jax.nn.sigmoid(_mm(n, wb).astype(f32))       # (B, C, H)
+            if self._neg_eigval:
+                beta = 2.0 * beta
+        o, state = _delta_rule(q, k, v, g, beta, state, valid, "gdn.scan")
+        with jax.named_scope("gdn.proj"):
+            o = _rms(o, g_o, self._eps) * jax.nn.silu(
+                _mm(n, wgate).astype(f32)).reshape((B, C, H, dv))
             return _mm(o.reshape((B, C, H * dv)), wo), (state, tail)
 
     def _mla(self, n, p, rows, start, pos):
@@ -663,7 +767,12 @@ class HybridDecoderLM(HybridBlock):
             q = _mm(n, wq).reshape((B, C, H, dh)).astype(f32)
             k = _mm(n, wk).reshape((B, C, Hkv, dh)).astype(f32)
             v = _mm(n, wv)
-            if self._qk_norm:
+            if self._qk_norm == "whole":      # over all of q's channels
+                q = _rms(q.reshape((B, C, H * dh)), p[3], self._eps) \
+                    .reshape((B, C, H, dh))
+                k = _rms(k.reshape((B, C, Hkv * dh)), p[4], self._eps) \
+                    .reshape((B, C, Hkv, dh))
+            elif self._qk_norm:
                 q, k = _rms(q, p[3], self._eps), _rms(k, p[4], self._eps)
             if mix in self._rotary:
                 q, k = _rope(q, pos, self._theta), _rope(k, pos, self._theta)
@@ -710,10 +819,11 @@ class HybridDecoderLM(HybridBlock):
         it, what its mixer keeps, its expert layer's counts or None).
         ``cache`` None: a whole sequence from nothing.  Every part is
         traced under a named scope (``kda.proj``, ``kda.scan``,
-        ``attn.proj``, ``attn.core``, ``attn.window``, ``ffn``,
-        ``experts.route``, ``experts.ffn``), all layers' work under the
-        one name, which
-        ``profiler.device_table`` reads a device trace by."""
+        ``gdn.proj``, ``gdn.scan``, ``attn.proj``, ``attn.core``,
+        ``attn.window``, ``ffn``, ``experts.route``, ``experts.ffn``),
+        all layers' work under the one name, which
+        ``profiler.device_table`` reads a device trace by; a norm under
+        its sub-layer's, before it or, with ``post_norm``, after it."""
         import jax
         import jax.numpy as jnp
 
@@ -721,21 +831,33 @@ class HybridDecoderLM(HybridBlock):
         B, C, _D = x.shape
         act = x.dtype
         norm_mix, mixer, norm_ffn, feed = params
-        with jax.named_scope("kda.proj" if mix == "kda" else "attn.proj"):
-            n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
-        if mix == "kda":
+        proj = {"kda": "kda.proj", "gdn": "gdn.proj"}.get(mix, "attn.proj")
+        post = self._post_norm
+        if post:
+            n = x
+        else:
+            with jax.named_scope(proj):
+                n = _rms(x, norm_mix.data()._data, self._eps).astype(act)
+        if mix in ("kda", "gdn"):
             if cache is None:
                 cache = (
                     jnp.zeros((B, z["H"], z["dk"], z["dv"]), jnp.float32),
                     jnp.zeros((B, z["K"] - 1, z["wide"]), act))
-            out, kept = self._kda(n, _raw(mixer), cache, valid)
+            out, kept = (self._kda if mix == "kda" else self._gdn)(
+                n, _raw(mixer), cache, valid)
         elif mix == "mla":
             out, kept = self._mla(n, _raw(mixer), cache, start, pos)
         else:
             out, kept = self._gqa(n, _raw(mixer), cache, start, pos, mix)
+        if post:
+            with jax.named_scope(proj):
+                out = _rms(out, norm_mix.data()._data, self._eps)
         x = x + out.astype(act)
-        with jax.named_scope("ffn"):
-            m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
+        if post:
+            m = x
+        else:
+            with jax.named_scope("ffn"):
+                m = _rms(x, norm_ffn.data()._data, self._eps).astype(act)
         counts = None
         if ffn == "dense":
             with jax.named_scope("ffn"):
@@ -743,11 +865,14 @@ class HybridDecoderLM(HybridBlock):
         else:
             y, counts = self._moe(m.reshape((B * C, -1)), _raw(feed))
             y = y.reshape((B, C, -1))
+        if post:
+            with jax.named_scope("ffn"):
+                y = _rms(y, norm_ffn.data()._data, self._eps)
         return x + y.astype(act), kept, counts
 
     def _run(self, tokens, caches, start, valid):
         """tokens (B, C) int; caches a list with, a layer, its state
-        ``(S, tail)`` (KDA), its cached rows (B, S, lanes) (MLA, GQA)
+        ``(S, tail)`` (KDA, GDN), its cached rows (B, S, lanes) (MLA, GQA)
         or its ring (B, R, lanes) (SWA), or None (a whole sequence from
         nothing); start, valid (B,) int32.
         Returns (logits raw (B, C, V), a layer's new state or the

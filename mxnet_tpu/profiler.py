@@ -224,7 +224,8 @@ def device_memory_stats():
 # others nest inside it.  A Gluon block opens one named for itself.
 PART_SCOPES = ("cache.gather", "cache.write", "sample", "embed", "head",
                "attn.proj", "attn.core", "attn.window", "kda.proj",
-               "kda.scan", "ffn", "experts.route", "experts.ffn", "draft")
+               "kda.scan", "gdn.proj", "gdn.scan", "ffn", "experts.route",
+               "experts.ffn", "draft")
 
 DeviceOp = collections.namedtuple("DeviceOp", (
     "program_id", "run_start_ns", "start_ns", "duration_ns", "scope",

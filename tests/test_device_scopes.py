@@ -335,6 +335,14 @@ def _giga():
              prefix_share=False)
 
 
+def _olmo():
+    return _hybrid(mixers=["gdn", "gqa"], ffns=["dense", "dense"],
+                   n_kv_heads=2, d_head=16, qk_norm="whole", rotary=(),
+                   neg_eigval=True, post_norm=True), \
+        dict(cache_len=64, page_size=8, prefill_chunk=16,
+             prefix_share=False)
+
+
 ENGINE = "cache.gather cache.write sample embed head".split()
 PARTS = {
     "opt": (_opt, ENGINE + ["attn.proj", "attn.core", "ffn"]),
@@ -345,6 +353,8 @@ PARTS = {
                               "experts.ffn"]),
     "giga": (_giga, ENGINE + ["attn.proj", "attn.core", "ffn",
                               "experts.route", "experts.ffn", "draft"]),
+    "olmo": (_olmo, ENGINE + ["attn.proj", "attn.core", "gdn.proj",
+                              "gdn.scan", "ffn"]),
 }
 _ENGINES = {}
 

@@ -585,6 +585,31 @@ def wide_window_engine():
         sampling=generate.SamplingConfig(greedy=True))
 
 
+@pytest.fixture(scope="module")
+def wide_gdn_engine():
+    """Olmo-Hybrid-7B's engine at its mixers' widths: a GDN layer of 30
+    heads of keys of 96 and values of 192 (state 30 x 96 x 192 float32 a
+    slot) and a full layer of 30 query and 30 key/value heads of 128
+    (rows of 7680 lanes), 32 slots, page 16, prefill chunks of 512, bf16
+    weights and cache; cut to one layer of each, a d_model of 512, a
+    small vocabulary and 512 positions a slot (what the kernel copies a
+    block, 16 pages of 16 rows of 7680 lanes, depends on none of them)."""
+    from mxnet_tpu.gluon.model_zoo.language import HybridDecoderLM
+
+    net = HybridDecoderLM(
+        vocab_size=256, d_model=512, mixers=["gdn", "gqa"],
+        ffns=["dense", "dense"], n_heads=30, d_k=96, d_v=192,
+        conv_kernel=4, neg_eigval=True, n_kv_heads=30, d_head=128,
+        qk_norm="whole", rotary=(), post_norm=True, d_ff=256, max_len=512,
+        dtype="bfloat16")
+    net.initialize(mx.init.Zero())
+    return generate.PagedGenerationEngine(
+        net, slots=32, cache_len=512, page_size=16, num_pages=1025,
+        prefill_chunk=512, spec_k=0, prefix_share=False,
+        dtype_policy="bf16_mixed",
+        sampling=generate.SamplingConfig(greedy=True))
+
+
 # the optimized HLO of the two accepted serving cells' programs at the
 # fixtures' sizes, as commit 686993b (the parent of ISSUE 33, which
 # changed the cache protocol) compiled them under this JAX: sha256 of
@@ -882,6 +907,45 @@ def test_tpu_verify_step_reads_the_pages_in_place(v5e_chip,
     assert re.findall(r"= bf16\[589856,2048\]\S* fusion\(.*"
                       r"op_name=\"jit\(chunk_fn\)/cache.write/scatter", entry)
     assert text.count("may-alias") + text.count("must-alias") >= 2
+
+
+@pytest.mark.parametrize("shape", ["decode", "prefill"])
+def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
+                                                       wide_gdn_engine,
+                                                       monkeypatch, shape):
+    """The GDN model's two programs for a described v5e (nothing runs):
+    a decode step reads its full layer's written pages in place through
+    ``paged_rows_attention`` at one query row a key/value head of 128
+    and rows of 7680 lanes (two buffers of 16 pages of 16 rows, 3.9 MB
+    each, inside the kernel's fast memory), gathers no layer's rows and
+    keeps the GDN state a parameter; a chunk of 512 runs the delta rule
+    with a decay a head (its scan over 8 sub-chunks) and gathers its
+    slot's pages."""
+    import re
+
+    from mxnet_tpu.ops import paged_rows_pallas
+
+    monkeypatch.setattr(paged_rows_pallas, "_interpret", lambda: False)
+    eng = wide_gdn_engine
+    assert eng.pool_shape == (1025 * 16, 7680)
+    assert [tuple(a.shape) for a in eng._state] == [
+        (32, 30, 96, 192), (32, 3, 30 * (2 * 96 + 192))]
+    shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
+    compiled = _compile_for(v5e_chip, eng, shapes[shape])
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    kernels = re.findall(r"%paged_rows_attention[\.\d]* = \(.*custom-call\(",
+                         text)
+    gathered = re.findall(r"bf16\[(?:1024,16,7680|32,512,7680)\]", text)
+    state_ops = [op for op in re.findall(
+        r"= f32\[32,30,96,192\]\S* ([\w\-]+)\(", entry)
+        if op not in ("get-tuple-element", "bitcast")]
+    assert "parameter" in state_ops and "copy" not in state_ops
+    if shape == "decode":
+        assert len(kernels) == 1 and not gathered, (kernels, gathered[:3])
+    else:
+        assert not kernels and text.count(" while(") == 1
+        assert re.findall(r"bf16\[(?:32,16,7680|1,512,7680)\]", text)
 
 
 def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):

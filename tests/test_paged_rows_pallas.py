@@ -20,13 +20,14 @@ S = PAGE * P
 STARTS = [0, 1, 15, 16, 17, S]
 
 
-def _case(C, H, Hkv, dtype, seed):
+def _case(C, H, Hkv, dtype, seed, dh=DH):
     """Queries, the chunk's own rows, a pool and a page table: slot
     ``b``'s pages scattered over the pool, its unwritten pages the trash
     page 0 (NaN), the rows at or above its ``start`` in its last written
-    page NaN or 1e4, its pages past the written ones large values."""
+    page NaN or 1e4, its pages past the written ones large values; heads
+    of ``dh``."""
     rs = np.random.RandomState(seed)
-    B, w = len(STARTS), Hkv * DH
+    B, w = len(STARTS), Hkv * dh
     lanes = -(-2 * w // 128) * 128
     n_pages = 1 + B * P
     pool = rs.standard_normal((n_pages, PAGE, lanes)).astype(np.float32)
@@ -44,7 +45,7 @@ def _case(C, H, Hkv, dtype, seed):
         if n % PAGE:
             pool[table[b, written - 1], n % PAGE:, :2 * w] = \
                 np.nan if b % 2 else -1e4
-    q = rs.standard_normal((B, C, H * DH)).astype(np.float32)
+    q = rs.standard_normal((B, C, H * dh)).astype(np.float32)
     k_c, v_c = rs.standard_normal((2, B, C, w)).astype(np.float32)
     cast = (lambda a: a.astype(dtype))
     return cast(q), cast(k_c), cast(v_c), cast(pool), table, start
@@ -60,7 +61,7 @@ def _gathered(pool, table, start):
     return np.where(ok, rows, 0.0)
 
 
-def _reference(q, k_c, v_c, rows, start, H, Hkv):
+def _reference(q, k_c, v_c, rows, start, H, Hkv, DH=DH):
     """Head by head, slot by slot, over the attended positions alone:
     numpy, float64."""
     B, C, _ = q.shape
@@ -154,3 +155,26 @@ def test_the_partials_of_a_slot_with_nothing_written():
     assert acc.shape == (6, 2, 16, DH) and m.shape == l.shape == (6, 2, 16, 1)
     assert (acc[0] == 0).all() and (l[0] == 0).all() and (m[0] == -1e30).all()
     assert np.isfinite(acc).all() and (l[1:] >= 1).all()
+
+
+@pytest.mark.parametrize("per_block", [2, 16])
+def test_one_query_row_a_head_of_a_width_of_128(monkeypatch, per_block):
+    """Olmo-Hybrid-7B's full layers as a decode step hands them over: as
+    many key/value heads as query heads (one query row a key/value head)
+    of 128, a head count that is no multiple of 8 (6 here, 30 in the
+    model), rows of 2 x 6 x 128 = 1536 lanes; float32 against float64."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(paged_rows_pallas, "PAGES_PER_BLOCK", per_block)
+    dh, H = 128, 6
+    q, k_c, v_c, pool, table, start = _case(1, H, H, np.float32, per_block,
+                                            dh)
+    assert pool.shape[-1] == 2 * H * dh
+    got = np.asarray(attention_rows.paged_attention_rows(
+        jnp.asarray(q), jnp.asarray(k_c), jnp.asarray(v_c),
+        PagedRows(jnp.asarray(pool), jnp.asarray(table),
+                  jnp.asarray(start)), H, H))
+    assert np.isfinite(got).all()
+    want = _reference(q, k_c, v_c, _gathered(pool, table, start), start,
+                      H, H, dh)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)

@@ -101,12 +101,10 @@ def test_configuration_is_the_catalog_row_cut_three_ways(cfg):
     keys apart (listed in ``reduced`` with the published numbers beside
     them); no width differs, the lists a layer and the
     ``rope_parameters`` group are whole."""
-    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if not os.path.exists(catalog):
-        pytest.skip("no catalog here")
-    with open(catalog) as f:
-        row = [r for r in map(json.loads, f)
-               if r["name"] == "K-EXAONE-236B-A23B"]
+    # the row as the model-configs catalog gave it, kept in the
+    # repository (tests/data/catalog_rows.json): nothing outside it is read
+    with open(os.path.join(ROOT, "tests", "data", "catalog_rows.json")) as f:
+        row = [json.load(f)["K-EXAONE-236B-A23B"]]
     published = row[0]["config"]
     assert row[0]["source_url"] == cfg["source"] == SOURCE
     cut = {"num_hidden_layers": 5, "num_experts": 16, "vocab_size": 19200}
