@@ -430,7 +430,9 @@ class PagedGenerationEngine:
     gathered rows but the pool seen as pages, the page table pointing at
     its pages and each slot's ``start`` (``ops.paged_rows_pallas
     .PagedRows``), and reads the written pages where they lie before the
-    step's rows are scattered.  ``{"state": [(shape, dtype), ...]}``:
+    step's rows are scattered (a chunk is handed the gathered rows and
+    attends them in blocks up to ``start``).  ``{"state": [(shape,
+    dtype), ...]}``:
     arrays a SLOT (a recurrent layer's state), held as ``(slots,) +
     shape`` in a tuple of their own, handed to every dispatch donated
     like the pools and taken back from it; :meth:`cached` reads a
@@ -985,10 +987,12 @@ class PagedGenerationEngine:
             """What every layer of a model that declares its caches
             kept: a layer's rows of every slot of the dispatch, (B, S,
             the pool's lanes), gathered like a layer of the pool of
-            rows, or, for a layer that says ``"attended": "pages"`` in a
-            dispatch of fewer than ``BLOCKED_CACHE_MIN_QUERY_POSITIONS``
-            query positions a slot (``ops.attention_rows
-            .cached_rows_in``), nothing gathered: the pool seen as
+            rows (a chunk of a layer that says ``"attended": "pages"``
+            too, which the model attends in blocks up to ``start``),
+            or, for such a layer in a dispatch of fewer than
+            ``BLOCKED_CACHE_MIN_QUERY_POSITIONS`` query positions a slot
+            (``ops.attention_rows.cached_rows_in``), nothing gathered:
+            the pool seen as
             pages, the page table pointing at the layer's pages and the
             rows each slot has written, 0 for a slot the dispatch does
             not step (``ops.paged_rows_pallas.PagedRows``; the model

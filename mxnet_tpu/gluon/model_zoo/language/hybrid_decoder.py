@@ -82,10 +82,11 @@ in a ``"swa"`` layer position ``i`` attends ``j`` with ``0 <= i - j <
 window``; ``W_o``.  **Cached a token: the row ``[K | V]``, ``2 *
 n_kv_heads * d_head`` values, normed and where the layer rotates
 rotated.**  A ``"gqa"`` layer keeps every position's row in pages; a
-chunk attends all a slot holds, gathered, through ``ops.attention_rows
-.chunk_attention_rows``, and a decode or verify step reads each slot's
-written pages where they lie in the pool (a Pallas kernel,
-``ops.paged_rows_pallas``; ``ops.attention_rows.cached_rows_in``); a
+chunk attends the slot's gathered rows a block at a time up to the rows
+its sequence has written (``ops.attention_rows.chunk_attention_blocks``),
+and a decode or verify step reads each slot's written pages where they
+lie in the pool (a Pallas kernel, ``ops.paged_rows_pallas``;
+``ops.attention_rows.cached_rows_in``); a
 ``"swa"`` layer keeps the last rows of a
 sequence in a ring a slot and attends them by the positions they hold
 (``ops.attention_rows.window_attention_rows``), whatever the sequence's
@@ -115,8 +116,8 @@ declares, a layer, what it caches (``config["layer_caches"]``): a KDA
 or GDN layer per-slot state (``S`` and the convolution's tail), an MLA layer
 paged rows of ``d_latent + d_rope`` values, a GQA layer paged rows of
 ``2 * n_kv_heads * d_head`` (of which a step reads each slot's written
-pages in place and a chunk multiplies all a slot holds: ``"attended":
-"pages"``), an SWA layer a window:
+pages in place and a chunk multiplies, gathered, the blocks up to its
+``start``: ``"attended": "pages"``), an SWA layer a window:
 ``{"window": (window, 2 * n_kv_heads * d_head)}``, rows a slot in a
 ring; the draft block's entry follows the trunk's (``config["draft_layers"]`` says how many there
 are).  Parameters are registered in one flat list, layer by layer, the
@@ -733,7 +734,7 @@ class HybridDecoderLM(HybridBlock):
                 o = o * jax.nn.sigmoid(_mm(n, wgate).astype(f32))[..., None]
             return _mm(o.reshape((B, C, H * dv)), wo), new
 
-    def _gqa(self, n, p, kept, start, pos, mix):
+    def _gqa(self, n, p, kept, start, pos, mix, attended=None):
         """The grouped-query mixer on normed states ``n`` (B, C, D) at
         positions ``pos`` (B, C): ``mix`` ``"gqa"`` attends ``kept`` (B,
         S, >= 2 w), the cached positions' ``[K | V]`` rows (those under
@@ -748,11 +749,23 @@ class HybridDecoderLM(HybridBlock):
         weight, the norms of q and k, the rotary positions) and
         ``attn.core`` (scores, softmax and context over a slot's rows,
         gathered or in place) or ``attn.window`` (the same over a
-        ring)."""
+        ring).
+
+        How much of the gathered rows a ``"gqa"`` layer multiplies
+        follows the dispatch's static shape and what the layer declares
+        (``attended``; ``ops.attention_rows.cached_rows_in``): a
+        **prefill chunk** attends blocks of cached rows up to its longest
+        ``start`` (``ops.attention_rows.chunk_attention_blocks``, a loop
+        whose trip count the program computes), so it costs the rows its
+        sequence has written and not the slot's capacity; a layer
+        declared ``"whole"``, and a step handed gathered rows, multiply
+        all ``S`` and mask (``chunk_attention_rows``)."""
         import jax
         import jax.numpy as jnp
 
-        from ....ops.attention_rows import (chunk_attention_rows,
+        from ....ops.attention_rows import (cached_rows_in,
+                                            chunk_attention_blocks,
+                                            chunk_attention_rows,
                                             paged_attention_rows,
                                             window_attention_rows)
         from ....ops.paged_rows_pallas import PagedRows
@@ -790,6 +803,9 @@ class HybridDecoderLM(HybridBlock):
         if mix == "swa":
             ctx = window_attention_rows(q, k, new[..., w:], kept, start,
                                         z["window"], H, Hkv)
+        elif cached_rows_in(C, attended) == "blocks":
+            ctx = chunk_attention_blocks(q, k, new[..., w:], kept, start,
+                                         H, Hkv)
         else:
             ctx = chunk_attention_rows(q, k, new[..., w:], kept[..., :w],
                                        kept[..., w:2 * w], start, H, Hkv)
@@ -814,11 +830,13 @@ class HybridDecoderLM(HybridBlock):
 
     # -- the one forward ---------------------------------------------------
 
-    def _block(self, x, params, mix, ffn, cache, start, valid, pos):
+    def _block(self, x, params, mix, ffn, cache, start, valid, pos,
+               attended=None):
         """One block on the stream ``x`` (B, C, D): (the stream after
         it, what its mixer keeps, its expert layer's counts or None).
-        ``cache`` None: a whole sequence from nothing.  Every part is
-        traced under a named scope (``kda.proj``, ``kda.scan``,
+        ``cache`` None: a whole sequence from nothing; ``attended`` what
+        the layer declares in ``layer_caches``.  Every part is traced
+        under a named scope (``kda.proj``, ``kda.scan``,
         ``gdn.proj``, ``gdn.scan``, ``attn.proj``, ``attn.core``,
         ``attn.window``, ``ffn``, ``experts.route``, ``experts.ffn``),
         all layers' work under the one name, which
@@ -848,7 +866,8 @@ class HybridDecoderLM(HybridBlock):
         elif mix == "mla":
             out, kept = self._mla(n, _raw(mixer), cache, start, pos)
         else:
-            out, kept = self._gqa(n, _raw(mixer), cache, start, pos, mix)
+            out, kept = self._gqa(n, _raw(mixer), cache, start, pos, mix,
+                                  attended)
         if post:
             with jax.named_scope(proj):
                 out = _rms(out, norm_mix.data()._data, self._eps)
@@ -886,11 +905,12 @@ class HybridDecoderLM(HybridBlock):
         with jax.named_scope("embed"):
             x = jnp.take(self._embed.data()._data, tokens, axis=0)
         new, loads = [], []
+        kinds = self._cfg["layer_caches"]
         for li, (mix, ffn) in enumerate(zip(self._mixers, self._ffns)):
             x, kept, counts = self._block(
                 x, self._layers[li], mix, ffn,
                 caches[li] if caches is not None else None, start, valid,
-                pos)
+                pos, kinds[li].get("attended"))
             new.append(kept)
             if counts is not None:
                 loads.append(counts)
@@ -979,7 +999,8 @@ class HybridDecoderLM(HybridBlock):
                 w_eh.data()._data)
             x, kept, counts = self._block(
                 u, block, self._mixers[-1], self._ffns[-1], rows, start,
-                valid.astype(jnp.int32), pos)
+                valid.astype(jnp.int32), pos,
+                self._cfg["layer_caches"][len(self._mixers)].get("attended"))
             with jax.named_scope("head"):
                 head = self._head.data()._data
                 h = _rms(x, g_out.data()._data,

@@ -32,27 +32,29 @@ own positions together is float32.
 
 **How much of the cache a dispatch attends** is a second rule on the
 same static shape, :func:`cached_rows_in`, for a model that attends its
-own rows (``HybridDecoderLM._mla``'s absorbed latent attention; the
-products above attend all ``S`` rows in every shape).  A slot's rows are
-gathered at its whole capacity ``S`` and those at or above ``start`` are
-masked, so products over all of them cost the capacity whatever the
-sequence has written.  A **prefill chunk** (many query positions of one
-slot, compute-bound on them) attends in **blocks** of
-:data:`CACHE_BLOCK_ROWS` rows, a loop whose trip count the program
-computes from ``start``: it multiplies the blocks the sequence has
-reached and never reads the rest.  A **decode or verify step** (one or a
-few query positions of every slot) keeps the whole-``S`` products: over
-32 slots the bound is the longest sequence's, the products are
-bound by the rows' bytes and small, and a loop in every layer of every
-step costs its edges (no operation is scheduled across them, weights
-are not fetched ahead over them) more than the masked rows it would
-skip.  A layer whose engine hands a step its pages instead
-(``"attended": "pages"``: ``HybridDecoderLM``'s full ``"gqa"`` layers,
-since ISSUE 40) reads each slot's **written pages in place**,
-:func:`paged_attention_rows` over the Pallas kernel of
-``ops.paged_rows_pallas``: no gather, no row past a slot's ``start``
-copied, the cost the sum of the slots' lengths and not the longest's;
-its chunk is gathered and attended whole as before.
+own rows (``HybridDecoderLM``'s absorbed latent attention and its full
+``"gqa"`` layers; the products above attend all ``S`` rows in every
+shape, and a layer that declares ``"attended": "whole"`` keeps them).  A
+slot's rows are gathered at its whole capacity ``S`` and those at or
+above ``start`` are masked, so products over all of them cost the
+capacity whatever the sequence has written.  A **prefill chunk** (many
+query positions of one slot, compute-bound on them) attends in
+**blocks** of :data:`CACHE_BLOCK_ROWS` rows, a loop whose trip count the
+program computes from ``start``: it multiplies the blocks the sequence
+has reached and never reads the rest (``HybridDecoderLM._mla`` for
+latent rows, :func:`chunk_attention_blocks` for grouped-query ``[K |
+V]`` rows).  A **decode or verify step** (one or a few query positions
+of every slot) keeps the whole-``S`` products: over 32 slots the bound
+is the longest sequence's, the products are bound by the rows' bytes
+and small, and a loop in every layer of every step costs its edges (no
+operation is scheduled across them, weights are not fetched ahead over
+them) more than the masked rows it would skip.  A layer whose engine
+hands a step its pages instead (``"attended": "pages"``:
+``HybridDecoderLM``'s full ``"gqa"`` layers) reads each slot's
+**written pages in place**, :func:`paged_attention_rows` over the
+Pallas kernel of ``ops.paged_rows_pallas``: no gather, no row past a
+slot's ``start`` copied, the cost the sum of the slots' lengths and not
+the longest's; its chunk is gathered and attended in blocks.
 :func:`attended_cache_rows` is the count a dispatch reads and
 multiplies, which the engine writes on its ``engine.prefill`` and
 ``engine.decode`` spans.
@@ -76,7 +78,8 @@ multiplies its ring's rows in every shape, and that is what
 """
 from __future__ import annotations
 
-__all__ = ["chunk_attention_rows", "paged_attention_rows", "attends_in",
+__all__ = ["chunk_attention_rows", "chunk_attention_blocks",
+           "paged_attention_rows", "attends_in",
            "cached_rows_in", "cache_block_rows", "attended_cache_rows",
            "ring_rows",
            "ring_positions", "window_attention_rows", "ring_write"]
@@ -108,7 +111,15 @@ def attends_in(chunk, n_heads):
 # 0.98 | 1.69 | 2.42 | 3.82, of 2048 0.95 | 2.35 | 2.36 | 3.74, all the
 # rows 5.55-5.57; at Ling's 32 heads against 9216 rows 512 is the
 # fastest at every start (0.58 at 512, 1.28 at 3584; all rows 3.88).
-# Half the serving cells' chunks start at or under 512
+# Half the serving cells' chunks start at or under 512.  The grouped-query
+# blocks (chunk_attention_blocks; tools/bench_gqa_chunk.py, PERF.md), a
+# chunk of 512 at start 0 | 512 | 1536 | 3584 | 8192: K-EXAONE's 64 / 8
+# heads of 128 against 9216 rows, blocks of 256 0.48 | 0.53 | 0.73 |
+# 1.14 | 1.98, of 512 0.52 | 0.56 | 0.71 | 1.07 | 1.75, of 1024 0.48 |
+# 1.01 | 1.58 | 2.71 | 4.94, all the rows 5.76-5.80; Olmo-Hybrid's 30 /
+# 30 against 5120 rows (start 4608 for 8192), of 256 0.76 | 0.82 | 0.98
+# | 1.27 | 1.41, of 512 0.76 | 0.80 | 0.92 | 1.13 | 1.24, of 1024 0.76 |
+# 0.85 | 0.93 | 1.12 | 1.19, all the rows 2.21-2.23: one size serves both
 CACHE_BLOCK_ROWS = 512
 
 # query positions a slot from which a dispatch is a chunk and attends
@@ -131,13 +142,13 @@ def cached_rows_in(chunk, attended=None):
     a model that attends the rows it is handed itself
     (``HybridDecoderLM._mla``: blocks in a chunk, whole in a step),
     ``"whole"`` for a layer that multiplies all a slot holds in every
-    shape, ``"pages"`` for a layer that a step reads in place (whole in a
-    chunk, which is gathered).  The static shape and the declaration
-    decide and nothing else."""
+    shape, ``"pages"`` for a layer that a step reads in place and a
+    chunk, gathered, in blocks (:func:`chunk_attention_blocks`).  The
+    static shape and the declaration decide and nothing else."""
     if attended == "whole":
         return "whole"
     if chunk >= BLOCKED_CACHE_MIN_QUERY_POSITIONS:
-        return "whole" if attended == "pages" else "blocks"
+        return "blocks"
     return "pages" if attended == "pages" else "whole"
 
 
@@ -154,12 +165,14 @@ def attended_cache_rows(chunk, start, held, rule=None, page_size=16):
     ``rule`` (``"whole"`` for a layer that multiplies all it holds in
     every shape, attention through :func:`chunk_attention_rows` and a
     windowed layer, whose ``held`` is its ring's rows; ``"pages"`` for
-    one a step reads in place).  ``start`` is the rows each slot of the
-    dispatch has written (a sequence), or the longest's: ``held`` under
-    ``"whole"``; whole blocks up to the longest under ``"blocks"``;
-    under ``"pages"`` each slot's whole pages of ``page_size`` rows up
-    to its own ``start``, summed over the slots and divided by their
-    number (what the kernel copies; not the longest's)."""
+    one a step reads in place and a chunk in blocks).  ``start`` is the
+    rows each slot of the dispatch has written (a sequence), or the
+    longest's: ``held`` under ``"whole"``; whole blocks up to the
+    longest under ``"blocks"`` (a chunk, whether the layer says None or
+    ``"pages"``); under ``"pages"`` each slot's whole pages of
+    ``page_size`` rows up to its own ``start``, summed over the slots
+    and divided by their number (what the kernel copies; not the
+    longest's)."""
     import numpy as np
 
     starts = [int(s) for s in np.atleast_1d(start)]
@@ -230,6 +243,87 @@ def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
         return _attend_rows(q, k_chunk, v_chunk, k_rows, v_rows,
                             cache_ok[:, None, :], causal, n_heads,
                             n_kv_heads, scale)
+
+
+def chunk_attention_blocks(q, k_chunk, v_chunk, rows, start, n_heads,
+                           n_kv_heads=None, scale=None):
+    """:func:`chunk_attention_rows` of a prefill chunk that multiplies
+    the cached rows its sequence has written, not all ``S`` a slot
+    holds: ``q``, ``k_chunk``, ``v_chunk`` and ``start`` as that takes
+    them, ``rows`` (B, S, lanes) the cache as the pool holds it, a
+    row's ``[K | V]`` (``2 * n_kv_heads * d_head`` values) first and
+    whatever lanes the pool pads with after them.
+
+    Head-split as :func:`chunk_attention_rows` splits a chunk: the G
+    query heads of a key/value head side by side, the rows transposed
+    so that the heads split off a major dimension.  The softmax runs
+    online (a running maximum, denominator and context, float32),
+    started from the chunk's own causal block, whose maximum is finite:
+    every query attends at least itself.  The cached rows follow in a
+    loop over blocks of :func:`cache_block_rows` rows, each block's K
+    and V sliced and transposed as it is read, for ``j <
+    ceil(max(start) / block)``, a trip count the program computes: a
+    block past the longest sequence's rows is never read, one partly
+    live (and every block of a shorter sequence of the dispatch) is
+    masked by ``s < start_b``.  The last block of a cache that is not
+    whole blocks is read ending at ``S`` and its rows under the block's
+    own first are masked.  The same mathematics in another order of
+    summation.  Traced under the named scope ``attn.core``."""
+    import jax
+    import jax.numpy as jnp
+
+    B, C, _ = q.shape
+    S = rows.shape[1]
+    H = int(n_heads)
+    Hkv = int(n_kv_heads) if n_kv_heads else H
+    G, dh = H // Hkv, q.shape[2] // H
+    w = Hkv * dh
+    K = cache_block_rows(S)
+    if scale is None:
+        scale = dh ** -0.5
+    start = start.astype(jnp.int32)
+
+    def heads(a):       # (B, T, Hkv * dh) -> (B, Hkv, dh, T)
+        return jnp.swapaxes(a, 1, 2).reshape((B, Hkv, dh, a.shape[1]))
+
+    over_d = (((3,), (2,)), ((0, 1), (0, 1)))
+    over_s = (((3,), (3,)), ((0, 1), (0, 1)))
+    with jax.named_scope("attn.core"):
+        # the G query heads of a key/value head side by side: row (g, c)
+        qh = q.reshape((B, C, Hkv, G, dh)).transpose(
+            (0, 2, 3, 1, 4)).reshape((B, Hkv, G * C, dh))
+        c_idx = jnp.arange(C, dtype=jnp.int32)
+        causal = jnp.tile(c_idx[:, None] >= c_idx[None, :], (G, 1))
+        s = _dot(qh, heads(k_chunk), over_d) * scale        # (B,Hkv,GC,C)
+        s = jnp.where(causal[None, None], s, jnp.float32(-1e30))
+        m = s.max(-1, keepdims=True)
+        e = jnp.exp(s - m)
+        den = e.sum(-1, keepdims=True)
+        ctx = _dot(e.astype(v_chunk.dtype), heads(v_chunk), over_s)
+
+        def block(j, carry):
+            m, den, ctx = carry
+            first = j * K
+            at = jnp.minimum(first, S - K)
+            blk = jax.lax.dynamic_slice(rows, (0, at, 0),
+                                        (B, K, rows.shape[2]))
+            s = _dot(qh, heads(blk[..., :w]), over_d) * scale   # (.., K)
+            idx = at + jnp.arange(K, dtype=jnp.int32)
+            ok = (idx >= first)[None, :] & (idx[None, :] < start[:, None])
+            s = jnp.where(ok[:, None, None, :], s, jnp.float32(-1e30))
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            shrink = jnp.exp(m - m_new)
+            e = jnp.exp(s - m_new)          # a masked score's is exactly 0
+            den = den * shrink + e.sum(-1, keepdims=True)
+            ctx = ctx * shrink + _dot(e.astype(rows.dtype),
+                                      heads(blk[..., w:2 * w]), over_s)
+            return m_new, den, ctx
+
+        blocks = (jnp.max(start) + K - 1) // K
+        _m, den, ctx = jax.lax.fori_loop(0, blocks, block, (m, den, ctx))
+        out = (ctx / den).reshape((B, Hkv, G, C, dh)).transpose(
+            (0, 3, 1, 2, 4))
+        return out.reshape((B, C, H * dh)).astype(q.dtype)
 
 
 def paged_attention_rows(q, k_chunk, v_chunk, paged, n_heads,

@@ -3,7 +3,9 @@ the decode engine's row pool is read by, in both of its forms, held to a
 plain head-split attention in float32; the rule that picks the form
 from the dispatch's shape; and the second rule on that shape (ISSUE 38):
 whether a model that attends its own rows reads them in blocks up to
-``start`` or over all a slot holds, and the count the engine reports."""
+``start`` or over all a slot holds, and the count the engine reports;
+and ``chunk_attention_blocks``, a grouped-query chunk's cached rows in
+blocks up to ``start``, held to the whole form and the reference."""
 import numpy as np
 import pytest
 
@@ -85,11 +87,18 @@ def test_form_follows_the_query_rows_a_slot():
 def test_cached_rows_are_attended_in_blocks_by_a_chunk_alone(chunk, form):
     """The second rule on the static shape (ISSUE 38): a decode step's
     one position a slot and a verify step's few attend all the rows a
-    slot holds, a prefill chunk blocks of them up to ``start``."""
+    slot holds, a prefill chunk blocks of them up to ``start``.  A layer
+    that a step reads in place (``"pages"``) attends a chunk in blocks
+    too; one declared ``"whole"`` attends all it holds in every shape."""
     assert attention_rows.cached_rows_in(chunk) == form
+    paged = "pages" if form == "whole" else "blocks"
+    assert attention_rows.cached_rows_in(chunk, "pages") == paged
+    assert attention_rows.cached_rows_in(chunk, "whole") == "whole"
     limit = attention_rows.BLOCKED_CACHE_MIN_QUERY_POSITIONS
     assert attention_rows.cached_rows_in(limit - 1) == "whole"
     assert attention_rows.cached_rows_in(limit) == "blocks"
+    assert attention_rows.cached_rows_in(limit - 1, "pages") == "pages"
+    assert attention_rows.cached_rows_in(limit, "pages") == "blocks"
 
 
 @pytest.mark.parametrize("start,held,block,want", [
@@ -107,6 +116,11 @@ def test_rows_a_dispatch_multiplies(monkeypatch, start, held, block, want):
     assert attention_rows.cache_block_rows(held) == min(block, held)
     assert attention_rows.attended_cache_rows(512, start, held) == want
     assert attention_rows.attended_cache_rows(2, start, held) == held
+    # a layer read in place by a step counts its chunk the same
+    assert attention_rows.attended_cache_rows(512, start, held,
+                                              "pages") == want
+    assert attention_rows.attended_cache_rows(512, start, held,
+                                              "whole") == held
 
 
 def test_a_block_is_whole_pages_and_whole_sublane_tiles():
@@ -139,3 +153,96 @@ def test_operands_keep_their_dtype_and_accumulate_in_f32():
                    static_argnums=(6,)).lower(*arrs, start, H).as_text()
     assert "exponential" in text and "xf32>" in text
     assert "-> tensor<2x8x24xf32>" in text, "scores are not float32"
+
+
+# the blocked form of a grouped-query chunk: blocks of 16 cached rows,
+# chunks of 16 queries a slot
+BLOCK, CHUNK = 16, 16
+
+
+def _chunk_operands(H, Hkv, dh, held, start, seed, pad=16):
+    """A chunk of ``CHUNK`` queries a slot and its own keys and values,
+    and the cache as the pool holds it, ``[K | V]`` a row and ``pad``
+    lanes after them; rows from a slot's ``start`` on hold what the
+    trash page or an evicted request left (large values that must reach
+    no query), the padding lanes too."""
+    rs = np.random.RandomState(seed)
+    B, w = len(start), Hkv * dh
+    q = rs.standard_normal((B, CHUNK, H * dh)).astype(np.float32)
+    k_c, v_c = rs.standard_normal((2, B, CHUNK, w)).astype(np.float32)
+    rows = rs.standard_normal((B, held, 2 * w + pad)).astype(np.float32)
+    rows[..., 2 * w:] = 1e3
+    for b, n in enumerate(start):
+        rows[b, n:] = 1e3
+    return q, k_c, v_c, rows, np.asarray(start, np.int32)
+
+
+@pytest.mark.parametrize("heads", [(8, 1, 16), (4, 4, 32), (8, 2, 16)],
+                         ids=["gqa_8over1", "mha_4x32", "gqa_8over2"])
+@pytest.mark.parametrize("held,start", [
+    (64, (0, 7, BLOCK, 63)),            # nothing cached beside a block's
+    (64, (0, 0, 0, 0)),                 # inside, a boundary and S - 1
+    (64, (BLOCK - 1, BLOCK + 1, 2 * BLOCK, 48)),
+    (40, (0, 33, 39, 17)),              # the last block ends at S = 40
+    (24, (23, 0, 5, 16))],              # a cache under two blocks
+    ids=["ragged", "nothing_cached", "boundaries", "ragged_last_block",
+         "short_cache"])
+def test_a_chunk_attends_grouped_rows_in_blocks_what_it_attends_whole(
+        monkeypatch, heads, held, start):
+    """``chunk_attention_blocks`` against ``chunk_attention_rows`` over
+    the same gathered rows and against the plain reference, float32: one
+    mathematics in another order of summation.  Four slots of one
+    dispatch at ragged ``start`` values, G query heads a key/value head
+    from 8 to 1.  The rows from the end of the bound's last block on are
+    never read: filled with NaN they leave the result the same."""
+    import jax.numpy as jnp
+
+    H, Hkv, dh = heads
+    w = Hkv * dh
+    monkeypatch.setattr(attention_rows, "CACHE_BLOCK_ROWS", BLOCK)
+    assert attention_rows.cached_rows_in(CHUNK, "pages") == "blocks"
+    q, k_c, v_c, rows, start = _chunk_operands(H, Hkv, dh, held, start,
+                                               H * held + dh)
+    args = [jnp.asarray(a) for a in (q, k_c, v_c)]
+    got = np.asarray(attention_rows.chunk_attention_blocks(
+        *args, jnp.asarray(rows), jnp.asarray(start), H, Hkv))
+    whole = np.asarray(attention_rows.chunk_attention_rows(
+        *args, jnp.asarray(rows[..., :w]), jnp.asarray(rows[..., w:2 * w]),
+        jnp.asarray(start), H, Hkv))
+    assert got.shape == (len(start), CHUNK, H * dh)
+    np.testing.assert_allclose(got, whole, atol=1e-5, rtol=1e-5)
+    want = _reference(q, k_c, v_c, rows[..., :w], rows[..., w:2 * w],
+                      start, H, Hkv)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    bound = min(-(-int(start.max()) // BLOCK) * BLOCK, held)
+    if bound < held:
+        poisoned = rows.copy()
+        poisoned[:, bound:] = np.nan
+        again = np.asarray(attention_rows.chunk_attention_blocks(
+            *args, jnp.asarray(poisoned), jnp.asarray(start), H, Hkv))
+        assert np.array_equal(again, got)
+
+
+def test_blocked_operands_keep_their_dtype_and_one_loop_a_call():
+    """bfloat16 in, bfloat16 out, within bfloat16's rounding of the
+    float32 result on the same (rounded) operands; scores float32; the
+    cached half one ``while`` whose trip count is computed from
+    ``start``, not unrolled by the cache's length."""
+    import jax
+    import jax.numpy as jnp
+
+    H, Hkv, dh = 4, 2, 16
+    q, k_c, v_c, rows, start = _chunk_operands(H, Hkv, dh, 64, (5, 40), 3)
+    arrs = [jnp.asarray(a, jnp.bfloat16) for a in (q, k_c, v_c, rows)]
+    start = jnp.asarray(start)
+    got = attention_rows.chunk_attention_blocks(*arrs, start, H, Hkv)
+    assert got.dtype == jnp.bfloat16
+    want = attention_rows.chunk_attention_blocks(
+        *(a.astype(jnp.float32) for a in arrs), start, H, Hkv)
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want), atol=3e-2, rtol=3e-2)
+    text = jax.jit(attention_rows.chunk_attention_blocks,
+                   static_argnums=(5, 6)).lower(*arrs, start, H,
+                                                Hkv).as_text()
+    assert text.count("stablehlo.while") == 1
+    assert "exponential" in text and "xf32>" in text

@@ -203,6 +203,43 @@ def test_prefill_then_decode_and_the_caches_match_reference(cfg, model,
         eng.evict(sl, "length")
 
 
+def test_full_layers_chunk_in_blocks_serves_the_gathered_tokens(
+        cfg, model, monkeypatch):
+    """The two full layers (one query head a key/value head) attend a
+    chunk's cached rows in blocks of 16 up to ``start`` (prompts of 61,
+    7 and 40 tokens in chunks of 24: no block, two and three): the
+    engine serves token for token what the engine that gathers and
+    attends all a slot holds serves, and holds the same GDN states and
+    ``[K | V]`` rows; every chunk calls the blocked form."""
+    from mxnet_tpu.ops import attention_rows
+
+    import test_window_cache as wc
+
+    net, _arrays = model
+    prompts = [_ids(cfg, n, seed) for n, seed in ((61, 5), (7, 4), (40, 8))]
+
+    def serve():
+        eng = generate.PagedGenerationEngine(
+            net, slots=3, cache_len=128, page_size=8, prefill_chunk=24,
+            spec_k=0, prefix_share=False,
+            sampling=generate.SamplingConfig(greedy=True))
+        return eng, wc._serve(eng, prompts, 8)
+
+    monkeypatch.setattr(attention_rows, "CACHE_BLOCK_ROWS", 16)
+    with monkeypatch.context() as m:
+        seen = wc._spy_attention(m)
+        blocked, got = serve()
+    assert set(seen["blocks"]) == {24} and 24 not in seen["gathered"]
+    assert set(seen["pages"]) == {1}
+    with monkeypatch.context() as m:
+        wc.gathered_whole(net, m)
+        seen = wc._spy_attention(m)
+        whole, want = serve()
+    assert seen["blocks"] == seen["pages"] == []
+    assert got == want and all(len(t) == 9 for t in got.values())
+    wc.assert_same_caches(blocked, whole, sorted(got), tol=TOL)
+
+
 def test_options_are_checked():
     from mxnet_tpu.gluon.model_zoo.language import HybridDecoderLM
 

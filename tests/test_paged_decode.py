@@ -876,7 +876,10 @@ def test_tpu_verify_step_reads_the_pages_in_place(v5e_chip,
     as it lies; nothing the size of a layer's gathered rows (32 x 576
     pages of 16 x 2048) is built, and the pool is a parameter, the
     pool seen as pages and the in-place scatter, no copy.  A chunk of
-    512 still gathers its slot's 576 pages and calls no kernel."""
+    512 still gathers its slot's 576 pages and calls no kernel; it
+    attends them in blocks up to ``start``, a loop a paged layer, and
+    builds nothing float32 the size of a layer's scores over all 9216
+    rows."""
     import re
 
     from mxnet_tpu.ops import paged_rows_pallas
@@ -901,6 +904,11 @@ def test_tpu_verify_step_reads_the_pages_in_place(v5e_chip,
         assert not gathered, gathered[:3]
     else:
         assert not calls and one_slot
+        assert text.count(" while(") == 2
+        # 8 key/value heads x 8 x 512 query rows x 9216 rows
+        whole = 8 * 4096 * 9216
+        for dims in re.findall(r"= f32\[([\d,]+)\]", text):
+            assert np.prod([int(d) for d in dims.split(",")]) < whole
     pool_ops = [op for _l, op in re.findall(
         r"= bf16\[589856,2048\](\{[^ ]*\})? ([\w\-]+)\(", entry)]
     assert "copy" not in pool_ops and "parameter" in pool_ops, pool_ops
@@ -919,8 +927,8 @@ def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
     and rows of 7680 lanes (two buffers of 16 pages of 16 rows, 3.9 MB
     each, inside the kernel's fast memory), gathers no layer's rows and
     keeps the GDN state a parameter; a chunk of 512 runs the delta rule
-    with a decay a head (its scan over 8 sub-chunks) and gathers its
-    slot's pages."""
+    with a decay a head (its scan over 8 sub-chunks), gathers its slot's
+    pages and attends them in blocks up to ``start`` (a second loop)."""
     import re
 
     from mxnet_tpu.ops import paged_rows_pallas
@@ -944,7 +952,7 @@ def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
     if shape == "decode":
         assert len(kernels) == 1 and not gathered, (kernels, gathered[:3])
     else:
-        assert not kernels and text.count(" while(") == 1
+        assert not kernels and text.count(" while(") == 2
         assert re.findall(r"bf16\[(?:32,16,7680|1,512,7680)\]", text)
 
 

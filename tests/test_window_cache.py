@@ -365,8 +365,9 @@ def gathered_whole(net, monkeypatch):
 
 def assert_same_caches(a, b, slots, tol=1e-5):
     """What two engines' ``cached()`` give for ``slots``: the same
-    positions and tokens, every paged layer's rows and every ring's rows
-    (from the same first position) within ``tol``."""
+    positions and tokens, every paged layer's rows, every ring's rows
+    (from the same first position) and every array of a layer's state
+    within ``tol``."""
     for snap_a, snap_b in zip(a.cached(slots), b.cached(slots)):
         assert snap_a["position"] == snap_b["position"]
         assert list(snap_a["tokens"]) == list(snap_b["tokens"])
@@ -374,15 +375,20 @@ def assert_same_caches(a, b, slots, tol=1e-5):
             if isinstance(la, dict):
                 assert la["first"] == lb["first"]
                 la, lb = la["rows"], lb["rows"]
-            np.testing.assert_allclose(la, lb, atol=tol, rtol=tol)
+            for xa, xb in (zip(la, lb) if isinstance(la, tuple)
+                           else [(la, lb)]):
+                np.testing.assert_allclose(xa, xb, atol=tol, rtol=tol)
 
 
 def _spy_attention(monkeypatch):
     """The query positions a slot of every call, at trace time, of the
-    full layers' two forms: ``{"pages": [...], "gathered": [...]}``."""
-    seen = {"pages": [], "gathered": []}
+    full layers' three forms: ``{"pages": [...], "gathered": [...],
+    "blocks": [...]}`` (a step's pages in place, all a slot holds
+    gathered, a chunk's gathered rows in blocks up to ``start``)."""
+    seen = {"pages": [], "gathered": [], "blocks": []}
     for name, tag in (("paged_attention_rows", "pages"),
-                      ("chunk_attention_rows", "gathered")):
+                      ("chunk_attention_rows", "gathered"),
+                      ("chunk_attention_blocks", "blocks")):
         real = getattr(attention_rows, name)
 
         def spy(q, *a, _real=real, _tag=tag, **kw):
@@ -401,28 +407,64 @@ def test_a_step_reads_pages_in_place_and_serves_the_gathered_tokens(
     served with every slot's whole page list gathered, and holds the same
     rows in its pages and rings.  A step calls the in-place form and a
     chunk of 24 (16 or more query positions a slot: a chunk of 512
-    alike) still gathers; the gathered engine calls the kernel never."""
+    alike) gathers and attends in blocks up to ``start``; the gathered
+    engine calls neither."""
     net, _arrays = model
     prompts = [_ids(cfg, n, seed) for n, seed in ((50, 3), (7, 4), (33, 6))]
-    assert attention_rows.cached_rows_in(512, "pages") == "whole"
-    assert attention_rows.cached_rows_in(24, "pages") == "whole"
+    assert attention_rows.cached_rows_in(512, "pages") == "blocks"
+    assert attention_rows.cached_rows_in(24, "pages") == "blocks"
     assert attention_rows.cached_rows_in(2, "pages") == "pages"
     with monkeypatch.context() as m:
         seen = _spy_attention(m)
         in_place = _engine(net)
         got = _serve(in_place, prompts, 12)
-    # (a probe of the block's shapes, a whole sequence from nothing,
-    # attends through the gathered form too)
-    assert set(seen["pages"]) == {1}
-    assert 24 in seen["gathered"] and 1 not in seen["gathered"]
+    assert set(seen["pages"]) == {1} and 24 in seen["blocks"]
+    assert 24 not in seen["gathered"] and 1 not in seen["gathered"]
     with monkeypatch.context() as m:
         gathered_whole(net, m)
         seen = _spy_attention(m)
         whole = _engine(net)
         want = _serve(whole, prompts, 12)
-    assert seen["pages"] == [] and {1, 24} <= set(seen["gathered"])
+    assert seen["pages"] == seen["blocks"] == []
+    assert {1, 24} <= set(seen["gathered"])
     assert got == want and all(len(t) == 13 for t in got.values())
     assert_same_caches(in_place, whole, sorted(got))
+
+
+@pytest.mark.parametrize("spec_k", [0, 1])
+def test_a_chunk_attends_full_layers_in_blocks_and_serves_the_gathered_tokens(
+        cfg, model, monkeypatch, spec_k):
+    """Blocks of 16 cached rows (a slot holds 128): prompts of 61, 7 and
+    40 tokens in chunks of 24 start at 0, 24 and 48, so the full layers'
+    chunks attend no block, two (one of them partly live) and three.
+    The engine serves token for token what the engine that gathers and
+    attends all a slot holds serves, and holds the same rows; every
+    chunk calls the blocked form, and its span counts, beside the rings,
+    whole blocks up to its ``start`` in each of the paged layers."""
+    net, _arrays = model
+    prompts = [_ids(cfg, n, seed) for n, seed in ((61, 5), (7, 4), (40, 8))]
+    monkeypatch.setattr(attention_rows, "CACHE_BLOCK_ROWS", 16)
+    with monkeypatch.context() as m:
+        seen = _spy_attention(m)
+        blocked = _engine(net, spec_k=spec_k)
+        t0 = len(tracing.records())
+        got = _serve(blocked, prompts, 8)
+        chunks = [r["args"] for r in tracing.records()[t0:]
+                  if r["name"] == "engine.prefill"]
+    assert set(seen["blocks"]) == {24} and 24 not in seen["gathered"]
+    ring = 4 * attention_rows.ring_rows(WINDOW, spec_k, 4)
+    paged = 1 + spec_k
+    assert [(c["filled"], c["cache_rows_attended"]) for c in chunks] == [
+        (0, ring), (24, ring + paged * 32), (48, ring + paged * 48),
+        (0, ring), (0, ring), (24, ring + paged * 32)]
+    with monkeypatch.context() as m:
+        gathered_whole(net, m)
+        seen = _spy_attention(m)
+        whole = _engine(net, spec_k=spec_k)
+        want = _serve(whole, prompts, 8)
+    assert seen["blocks"] == [] and 24 in seen["gathered"]
+    assert got == want and all(len(t) >= 9 for t in got.values())
+    assert_same_caches(blocked, whole, sorted(got))
 
 
 # -- what the engine refuses, warns of and writes on its spans ----------------
@@ -473,13 +515,14 @@ def test_spans_and_counting_rules_by_hand(cfg, model):
     assert pool["latent_rows_bytes"] == 2 * 49 * 8 * 128 * 4
     assert pool["bytes"] == pool["window_rows_bytes"] \
         + pool["latent_rows_bytes"]
-    # a chunk gathers the two paged layers and multiplies all 128 rows
-    # a slot holds; the step reads the slot's written pages in place:
-    # 30 positions are 4 whole pages of 8
+    # a chunk gathers the two paged layers and multiplies whole blocks
+    # up to its start, here one block of all 128 rows a slot holds (none
+    # for the first chunk, at 0); the step reads the slot's written
+    # pages in place: 30 positions are 4 whole pages of 8
     chunks = [r["args"] for r in recs if r["name"] == "engine.prefill"]
     steps = [r["args"] for r in recs if r["name"] == "engine.decode"]
     assert len(chunks) == 2 and len(steps) == 1
-    for args, paged in [(a, 128) for a in chunks] + [(steps[0], 32)]:
+    for args, paged in [(chunks[0], 0), (chunks[1], 128), (steps[0], 32)]:
         assert args["cache_rows_attended"] == 4 * 24 + 2 * paged
         assert args["cache_rows_held"] == 6 * 128
     # without the draft block's layer (spec_k 0) and its extra ring row
