@@ -12,8 +12,10 @@ float32, seeded random weights, and compares logits:
 * prefill in chunks whose length is no multiple of the delta rule's
   sub-chunk, then token-by-token decode through the engine, against the
   reference's full forward;
-* the chunkwise gated delta rule against the recurrence, outputs and
-  state; absorbed MLA against expanded;
+* the chunkwise gated delta rule (one Pallas kernel, in the TPU
+  interpreter here) against the recurrence, outputs and state, also at
+  the cell's own head widths, past KDA's range of decays and across
+  the kernel's segments; absorbed MLA against expanded;
 * the share test: the parts of the four shares, the shared expert
   counted once, add up to the uncut layer of the reference;
 * the group-limited selection against a hand-worked case;
@@ -253,6 +255,77 @@ def test_delta_rule_matches_the_reference_recurrence():
                                 beta[:, t], s)
         assert np.abs(np.asarray(o) - np.asarray(want_o[:, t])).max() < 1e-6
     assert np.abs(np.asarray(s) - np.asarray(want_s)).max() < 1e-6
+
+
+def _recurrence(q, k, v, g, beta, s0, count=None):
+    """The one-token form over every position (a row's positions at or
+    past ``count`` keep its state): outputs and final state."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.gated_delta import gated_delta_step
+
+    T = q.shape[1]
+    live = jnp.ones((q.shape[0], T), bool) if count is None else \
+        jnp.arange(T)[None, :] < count[:, None]
+
+    def token(s, x):
+        *x, on = x
+        o, s_t = gated_delta_step(*x, s)
+        return jnp.where(on[:, None, None, None], s_t, s), o
+
+    s, o = jax.lax.scan(token, s0, tuple(jnp.moveaxis(a, 1, 0) for a in (
+        q, k, v, g, beta, live)))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+@pytest.mark.parametrize("case", ["widths", "valid", "floor", "unbounded",
+                                  "long"])
+def test_kernel_with_a_decay_a_channel_at_the_cells_widths(case):
+    """The chunkwise kernel at Ling's head widths (128 keys and values)
+    against the recurrence a token, outputs and final state, float32:
+    ``widths`` at 150 positions (no multiple of the sub-chunk of 64),
+    decays in KDA's range [-5, 0], also against the reference's scan
+    from a zero state; ``valid`` with 70 of 100 positions valid in one
+    row and none in the other, which keeps its state to the bit;
+    ``floor`` with every channel at -5 for every token, a decay of
+    exp(-320) across a sub-chunk that underflows to 0; ``unbounded``
+    with decays down to -20 a token, past KDA's range, where the
+    factored sums below the diagonal blocks underflow and lose nothing;
+    ``long`` at 1100 positions, three segments of the kernel's grid
+    with the state carried from one to the next, 700 of them valid in
+    one row."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.gated_delta import SEGMENT, gated_delta_chunk
+
+    B, T, count = {"widths": (1, 150, None), "valid": (2, 100, (70, 0)),
+                   "floor": (1, 128, None), "unbounded": (1, 150, None),
+                   "long": (2, 1100, (1100, 700))}[case]
+    q, k, v, g, beta, s0 = _delta_inputs(B, T, 2, 128, 128, 43)
+    if case == "floor":
+        g = jnp.full_like(g, -5.0)
+    if case == "unbounded":
+        g = 4 * g                                        # down to -20
+    if case == "long":
+        assert T > 2 * SEGMENT
+    count = None if count is None else jnp.asarray(count, jnp.int32)
+    o_c, s_c = gated_delta_chunk(q, k, v, g, beta, s0, count)
+    o_r, s_r = _recurrence(q, k, v, g, beta, s0, count)
+    o_r, o_c = np.asarray(o_r), np.asarray(o_c)
+    if count is not None:
+        live = np.arange(T)[None, :] < np.asarray(count)[:, None]
+        o_r, o_c = o_r * live[..., None, None], o_c * live[..., None, None]
+    if case == "valid":
+        np.testing.assert_array_equal(np.asarray(s_c)[1], np.asarray(s0)[1])
+    assert np.abs(o_c - o_r).max() < 1e-5
+    assert np.abs(np.asarray(s_c) - np.asarray(s_r)).max() < 1e-5
+    assert np.abs(o_r).max() > 0.05
+    if case == "widths":
+        o_ref, s_ref = ref.delta_rule(q, k, v, g, beta)
+        o, s = gated_delta_chunk(q, k, v, g, beta, jnp.zeros_like(s0))
+        assert np.abs(np.asarray(o) - np.asarray(o_ref)).max() < 1e-5
+        assert np.abs(np.asarray(s) - np.asarray(s_ref)).max() < 1e-5
 
 
 def test_absorbed_mla_matches_expanded(cfg, model):

@@ -18,6 +18,7 @@ weights, against the plain reference
   form and against the reference's recurrence, at ``beta`` up to 2, keys
   that resemble each other, ``d_k != d_v`` and lengths that are no
   multiple of the sub-chunk;
+* the kernel at the cell's own head widths (96 and 192);
 * the per-channel (KDA) path to the bit;
 * the configuration file against the published row.
 """
@@ -330,11 +331,57 @@ def test_per_head_rule_matches_the_reference_recurrence():
     assert np.abs(np.asarray(want_s)).max() > 0.1
 
 
-# the per-channel (KDA) rule's outputs on the inputs below, as the tree
-# before the decay a head was added computed them on this CPU and JAX:
-# the per-channel path is the same program to the bit
+@pytest.mark.parametrize("T, valid, cos", [
+    (150, None, 0.0), (100, (70, 0), 0.0), (130, None, 0.9),
+    (600, (600, 530), 0.0)])
+def test_kernel_with_a_decay_a_head_at_the_cells_widths(T, valid, cos):
+    """The chunkwise kernel at Olmo-Hybrid's head widths (keys of 96,
+    values of 192, each zero-padded to whole lane tiles inside the op),
+    ``beta`` up to 2, against the one-token form (outputs and final
+    state) and against the reference's recurrence from a zero state;
+    lengths that are no multiple of the sub-chunk of 64, a row with
+    nothing valid keeping its state to the bit, keys that resemble each
+    other, and a chunk longer than the kernel's segment of 512 (two
+    grid steps a row, the state carried between them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.gated_delta import gated_delta_chunk, gated_delta_step
+
+    q, k, v, g, beta, s0 = _per_head_inputs(2, T, 2, 96, 192, T, cos)
+    count = None if valid is None else jnp.asarray(valid, jnp.int32)
+    o_c, s_c = gated_delta_chunk(q, k, v, g, beta, s0, count, "gdn.scan")
+    live = np.ones((2, T), bool) if valid is None else \
+        np.arange(T)[None, :] < np.asarray(valid)[:, None]
+
+    def token(s, x):
+        *x, on = x
+        o, s_t = gated_delta_step(*x, s, "gdn.scan")
+        return jnp.where(on[:, None, None, None], s_t, s), o
+
+    s_r, o_r = jax.lax.scan(token, jnp.asarray(s0), tuple(
+        jnp.moveaxis(jnp.asarray(a), 1, 0) for a in (q, k, v, g, beta, live)))
+    o_r = np.asarray(jnp.moveaxis(o_r, 0, 1)) * live[..., None, None]
+    o_c = np.asarray(o_c) * live[..., None, None]
+    if valid is not None and valid[1] == 0:
+        np.testing.assert_array_equal(np.asarray(s_c)[1], s0[1])
+    scale = max(np.abs(o_r).max(), 1.0)
+    assert np.abs(o_c - o_r).max() < 2e-5 * scale
+    assert np.abs(np.asarray(s_c) - np.asarray(s_r)).max() < 2e-5 * max(
+        np.abs(np.asarray(s_r)).max(), 1.0)
+    assert np.abs(o_r).max() > 0.05 and (beta > 1.9).any()
+    want_o, want_s = ref.delta_rule(*map(jnp.asarray, (q, k, v, g, beta)))
+    o, s = gated_delta_chunk(q, k, v, g, beta, np.zeros_like(s0))
+    assert np.abs(np.asarray(o) - np.asarray(want_o)).max() < 2e-5
+    assert np.abs(np.asarray(s) - np.asarray(want_s)).max() < 2e-5
+
+
+# the per-channel (KDA) rule's outputs on the inputs below, as the
+# chunkwise kernel computes them in the TPU interpreter of Pallas on the
+# CPU under this JAX: a change to the per-channel path's arithmetic
+# shows here
 KDA_JAX = "0.9.0"
-KDA_SHA256 = "1d694506756df40e521166e93367447e12dbda5a91f4defb69f120c40dd72d56"
+KDA_SHA256 = "60df8395784dd977e2e3912626e685a629acc38067d9845fb053664e51c9a859"
 
 
 def test_per_channel_rule_is_unchanged_to_the_bit():

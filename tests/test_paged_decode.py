@@ -738,7 +738,8 @@ class _time_limit:
 
 @pytest.mark.parametrize("shape", ["decode", "prefill"])
 @pytest.mark.parametrize("model", ["opt", "moe", "hybrid"])
-def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
+def test_tpu_program_copies_no_pool(v5e_chip, request, monkeypatch, model,
+                                    shape):
     """What the chip's compiler makes of the dispatch (optimized HLO for
     a described v5e, nothing runs): the pool keeps a row-major layout
     with its rows outermost, and besides the parameter, the in-place
@@ -754,6 +755,11 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
     import jax
 
     if model == "hybrid":      # latent pages and per-slot state (ISSUE 33)
+        from mxnet_tpu.ops import paged_rows_pallas
+
+        # the chunkwise delta rule's kernel as the chip's program holds
+        # it, not the interpreter
+        monkeypatch.setattr(paged_rows_pallas, "_interpret", lambda: False)
         return _hybrid_program_copies_nothing(
             v5e_chip, request.getfixturevalue("wide_hybrid_engine"), shape)
     eng = request.getfixturevalue(
@@ -831,17 +837,22 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, model, shape):
 
 @pytest.mark.parametrize("shape", ["decode", "prefill"])
 @pytest.mark.parametrize("model", ["moe", "hybrid"])
-def test_tpu_program_reads_the_experts_in_place(v5e_chip, request, model,
-                                                shape):
+def test_tpu_program_reads_the_experts_in_place(v5e_chip, request,
+                                                monkeypatch, model, shape):
     """The expert layers of the two expert models' step and chunk as the
     chip's compiler sees them (ISSUE 36; optimized HLO for a described
     v5e, nothing runs): every expert layer is the two grouped kernels
     (`tpu_custom_call`: gate and up with the activation, then down), and
     no `copy` or `transpose` touches anything of an expert matrix's
     size: the kernels take the ``(d_model, held x width)`` matrices as
-    the engine holds them and find an expert by block index."""
+    the engine holds them and find an expert by block index.  (The
+    hybrid model's chunk has one kernel more, its KDA layer's delta
+    rule.)"""
     import re
 
+    from mxnet_tpu.ops import paged_rows_pallas
+
+    monkeypatch.setattr(paged_rows_pallas, "_interpret", lambda: False)
     eng = request.getfixturevalue(
         "wide_moe_engine" if model == "moe" else "wide_hybrid_engine")
     cfg = eng.model_config
@@ -851,7 +862,8 @@ def test_tpu_program_reads_the_experts_in_place(v5e_chip, request, model,
     shapes = dict(zip(("prefill", "decode"), eng.dispatch_shapes()))
     text = _compile_for(v5e_chip, eng, shapes[shape]).as_text()
     calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
-    assert len(calls) == 2 * layers, len(calls)
+    scans = int(model == "hybrid" and shape == "prefill")
+    assert len(calls) == 2 * layers + scans, len(calls)
     expert = held * F * D
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?\S+ = (\w+\[[\d,]*\])\S* "
@@ -917,6 +929,43 @@ def test_tpu_verify_step_reads_the_pages_in_place(v5e_chip,
     assert text.count("may-alias") + text.count("must-alias") >= 2
 
 
+@pytest.mark.parametrize("family", ["kda", "gdn"])
+def test_tpu_delta_chunk_compiles_for_a_long_chunk(v5e_chip, monkeypatch,
+                                                   family):
+    """The chunkwise delta rule's kernel for one slot's chunk of 4096
+    positions at the cells' widths (Ling's 32 heads of 128 with a decay
+    a key channel, Olmo-Hybrid's 30 of 96 and 192 with one a head),
+    compiled for a described v5e (nothing runs): a grid step holds a
+    segment of 512 positions whatever the chunk, so a prefill chunk
+    longer than the cells' 512 compiles too, eight grid steps a group of
+    heads, and the fast memory it asks for is the segment's."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta, paged_rows_pallas
+
+    monkeypatch.setattr(paged_rows_pallas, "_interpret", lambda: False)
+    H, dk, dv = (32, 128, 128) if family == "kda" else (30, 96, 192)
+    B, T = 1, 4096
+
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e_chip)
+
+    g = struct(B, T, H, dk) if family == "kda" else struct(B, T, H)
+    args = (struct(B, T, H, dk), struct(B, T, H, dk), struct(B, T, H, dv), g,
+            struct(B, T, H), struct(B, H, dk, dv),
+            jax.ShapeDtypeStruct((B,), jnp.int32, sharding=v5e_chip))
+    with _time_limit(300):
+        text = jax.jit(gated_delta.gated_delta_chunk).lower(
+            *args).compile().as_text()
+    calls = re.findall(r"%gated_delta_chunk[\.\d]* = \(.*custom-call\(",
+                       text)
+    assert len(calls) == 1 and " while(" not in text
+    assert T // gated_delta.SEGMENT == 8
+
+
 @pytest.mark.parametrize("shape", ["decode", "prefill"])
 def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
                                                        wide_gdn_engine,
@@ -927,8 +976,9 @@ def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
     and rows of 7680 lanes (two buffers of 16 pages of 16 rows, 3.9 MB
     each, inside the kernel's fast memory), gathers no layer's rows and
     keeps the GDN state a parameter; a chunk of 512 runs the delta rule
-    with a decay a head (its scan over 8 sub-chunks), gathers its slot's
-    pages and attends them in blocks up to ``start`` (a second loop)."""
+    with a decay a head as one kernel, ``gated_delta_chunk`` (its walk
+    over 8 sub-chunks inside it), gathers its slot's pages and attends
+    them in blocks up to ``start``, the program's one loop."""
     import re
 
     from mxnet_tpu.ops import paged_rows_pallas
@@ -944,6 +994,8 @@ def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
     entry = text[text.index("\nENTRY"):]
     kernels = re.findall(r"%paged_rows_attention[\.\d]* = \(.*custom-call\(",
                          text)
+    scans = re.findall(r"%gated_delta_chunk[\.\d]* = \(.*custom-call\(",
+                       text)
     gathered = re.findall(r"bf16\[(?:1024,16,7680|32,512,7680)\]", text)
     state_ops = [op for op in re.findall(
         r"= f32\[32,30,96,192\]\S* ([\w\-]+)\(", entry)
@@ -951,8 +1003,10 @@ def test_tpu_gdn_programs_compile_at_the_models_widths(v5e_chip,
     assert "parameter" in state_ops and "copy" not in state_ops
     if shape == "decode":
         assert len(kernels) == 1 and not gathered, (kernels, gathered[:3])
+        assert not scans
     else:
-        assert not kernels and text.count(" while(") == 2
+        assert not kernels and len(scans) == 1
+        assert text.count(" while(") == 1
         assert re.findall(r"bf16\[(?:32,16,7680|1,512,7680)\]", text)
 
 
@@ -967,9 +1021,10 @@ def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):
     results.  The decode program attends in the latent space: nothing
     has the size of the slots' cached rows expanded to 32 heads, and no
     gather or scatter became a loop; the chunk's attends the slot's rows
-    a block at a time (ISSUE 38), its one loop besides the delta rule's
-    scan.  (The convolution's tails, 2.4 MB, are re-tiled; they are not
-    asserted on.)"""
+    a block at a time, its one loop (the delta rule's walk over
+    sub-chunks is inside its kernel, ``gated_delta_chunk``).  (The
+    convolution's tails, 2.4 MB, are re-tiled; they are not asserted
+    on.)"""
     import re
 
     eng = wide_hybrid_engine
@@ -1007,12 +1062,14 @@ def _hybrid_program_copies_nothing(v5e_chip, wide_hybrid_engine, shape):
             assert np.prod([int(d) for d in dims.split(",")]) < expanded
         assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
     else:
-        # the chunkwise delta rule's scan over 8 sub-chunks and the MLA
-        # layer's blocks of cached rows up to `start` (ISSUE 38: a chunk
-        # attends in blocks, a step does not), and no other: nothing in
-        # the chunk's program has the size of its float32 scores over
-        # all the 9216 rows a slot holds
-        assert text.count(" while(") == 2
+        # the chunkwise delta rule's kernel and the MLA layer's blocks
+        # of cached rows up to `start` (a chunk attends in blocks, a
+        # step does not), the one loop: nothing in the chunk's
+        # program has the size of its float32 scores over all the 9216
+        # rows a slot holds
+        assert len(re.findall(
+            r"%gated_delta_chunk[\.\d]* = \(.*custom-call\(", text)) == 1
+        assert text.count(" while(") == 1
         whole = 32 * 512 * 9216
         for dims in re.findall(r"= f32\[([\d,]+)\]", text):
             assert np.prod([int(d) for d in dims.split(",")]) < whole
