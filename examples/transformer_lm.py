@@ -176,9 +176,7 @@ class TransformerLM(gluon.HybridBlock):
 
     @property
     def config(self):
-        # cache_rows: chunk_forward takes a layer's cache as rows,
-        # (B, S, heads * d_head), and returns the chunk's K/V as rows
-        return dict(self._cfg, cache_rows=True)
+        return dict(self._cfg)
 
     def flops_per_token(self, seq_len=None):
         """Train FLOPs/token: the standard 6N dense term plus — when
@@ -217,8 +215,8 @@ class TransformerLM(gluon.HybridBlock):
     #
     # chunk_forward is the cache-aware inference form of hybrid_forward:
     # any model exposing it (plus .config with vocab_size / d_model /
-    # n_heads / n_layers / max_len, and cache_rows for the form its
-    # caches take) plugs into generate.PagedGenerationEngine.  It is
+    # n_heads / n_layers / max_len) plugs into
+    # generate.PagedGenerationEngine, which hands it K/V rows.  It is
     # called under the gluon trace machinery with parameters swapped
     # in, exactly like serving.Predictor.from_block's traced forward.
 
@@ -230,9 +228,9 @@ class TransformerLM(gluon.HybridBlock):
         ``tokens`` raw (B, C) int32 — the tokens occupying positions
         ``start_b .. start_b+C-1`` of each sequence; ``caches`` one
         ``(k, v)`` pair of raw (B, S, D) jax arrays per layer, one row
-        a position with every head side by side (``config`` says
-        ``cache_rows``), holding the already cached positions
-        0..start_b-1 (a layer's gathered pages in the paged engine);
+        a position with every head side by side, holding the already
+        cached positions 0..start_b-1 (a layer's gathered pages in the
+        paged engine);
         ``start`` raw (B,) int32.
         Position j of the chunk attends cache positions ``s < start_b``
         plus chunk positions ``j' <= j`` — exactly the causal window the

@@ -12,13 +12,11 @@ compilation" line):
 * **The KV cache is a page pool, donated device state** —
   :class:`PagedGenerationEngine` holds one fixed-shape pool per K and
   V, indexed on its leading dimension (one row a (layer, token),
-  ``(layers * pages * page_size, heads * d_head)``, for a model that
-  takes its caches as rows; else token-major, ``(pages * page_size,
-  layers, heads * d_head)``),
-  donated into every dispatch so it updates in place; host-side page
-  tables map each decode slot's positions onto pool pages, so
-  admission and eviction flip host state and never the compiled
-  program.  The pool's dtype follows the ``dtype_policy=`` compute
+  ``(layers * pages * page_size, heads * d_head)``; or what a model's
+  ``layer_caches`` declares), donated into every dispatch so it
+  updates in place; host-side page tables map each decode slot's
+  positions onto pool pages, so admission and eviction flip host state
+  and never the compiled program.  The pool's dtype follows the ``dtype_policy=`` compute
   dtype (bf16 under ``bf16_mixed``), and with a mesh the pool shards
   by the ``kv_pool`` spec rule of the layouts (``heads * d_head`` over
   tp, rows over the data axes where they divide them — tp serving
@@ -51,15 +49,15 @@ compilation" line):
   429/504 exactly like predict failures.
 
 The model protocol: a served net implements ``chunk_forward(tokens,
-caches, start)`` — C positions a sequence against a linear view of the
-cached ones; one entry point covers prefill chunks, decode and the
-verify step (``examples/transformer_lm.py``,
+caches, start)`` — C positions a sequence against the cached ones, a
+layer's cache handed as K and V rows, ``(B, S, heads * d_head)``, one
+row a position with every key/value head side by side; one entry point
+covers prefill chunks, decode and the verify step
+(``examples/transformer_lm.py``,
 ``gluon.model_zoo.language.MoEDecoderLM``) — and a ``config`` dict with
 ``vocab_size`` / ``d_model`` / ``n_heads`` / ``n_layers`` / ``max_len``
 (``n_kv_heads`` / ``d_head`` where they are not the defaults;
-``block_length`` and ``mask_token_id`` for block-diffusion decoding;
-``cache_rows`` where it takes a layer's cache as rows, ``(B, S, heads *
-d_head)``, and not as a head-split view, ``(B, heads, S, d_head)``).  A
+``block_length`` and ``mask_token_id`` for block-diffusion decoding).  A
 model whose layers do not all cache K and V a head
 (``gluon.model_zoo.language.HybridDecoderLM``) says what each keeps in
 ``layer_caches``: paged rows of a width, in one pool, arrays a slot
@@ -344,26 +342,22 @@ class PagedGenerationEngine:
     """Paged/block KV-cache generation over a chunk-protocol model.
 
     Device state is what the model's protocol declares, donated through
-    every dispatch: for a model of attention layers alone one
-    fixed-shape page pool per K/V, in one of two forms; for a model that
-    says a layer at a time what it caches (``layer_caches``), one pool
-    of paged rows, arrays of per-slot state and rings of per-slot rows
-    side by side (below).
-    A model whose ``config`` says ``cache_rows`` takes each layer's
-    cache as rows, and the pool is two-dimensional, ``(layers * pages *
+    every dispatch, in one of two forms: for a model of attention
+    layers alone one fixed-shape page pool per K/V of rows; for a model
+    that says a layer at a time what it caches (``layer_caches``), one
+    pool of paged rows, arrays of per-slot state and rings of per-slot
+    rows side by side (below).
+    The K and V pools are two-dimensional, ``(layers * pages *
     page_size, heads * d_head)``: row ``layer * tokens + page *
     page_size + offset`` holds one position's K (or V) of one layer,
-    every head side by side.  A dispatch gathers a layer's rows at a
-    time, ``(slots, cache_len, heads * d_head)``, and the model attends
-    them as they lie (``ops.attention_rows``): nothing the size of all
-    layers' caches is ever built, sliced or re-tiled.  Any other model
-    gets the token-major pool, ``(pages * page_size, layers, heads *
-    d_head)``: row ``page * page_size + offset`` holds one position's
-    K (or V) of every layer and head, gathered whole into a view that
-    is split by layer and head for the model.  Either way both index
-    operations of a dispatch address dimension 0, so XLA gathers whole
-    rows and scatters the chunk's rows in place on the donated buffer;
-    no dispatch copies the pool.  Each decode slot maps its positions
+    every key/value head side by side.  A dispatch gathers a layer's
+    rows at a time, whole pages at once, ``(slots, cache_len, heads *
+    d_head)``, and the model attends them as they lie
+    (``ops.attention_rows``): nothing the size of all layers' caches is
+    ever built, sliced or re-tiled.  Both index operations of a
+    dispatch address dimension 0, so XLA gathers whole rows and
+    scatters the chunk's rows in place on the donated buffer; no
+    dispatch copies the pool.  Each decode slot maps its positions
     onto pool pages through a host-side page table (page 0 is a
     write-through "trash" page absorbing padded/invalid positions, so
     shapes never change).
@@ -636,8 +630,6 @@ class PagedGenerationEngine:
         H = int(cfg.get("n_kv_heads", cfg["n_heads"]))
         dh = int(cfg.get("d_head", cfg["d_model"] // cfg["n_heads"]))
         n_tokens = self._num_pages * self._page_size
-        # what the model's protocol declares picks the pool's rows
-        self._cache_rows = bool(cfg.get("cache_rows"))
         # a model whose layers do not all cache K and V a head says, a
         # layer, what it caches: {"rows": width}, paged rows of that
         # many values a token in ONE pool (no second one), or
@@ -764,7 +756,7 @@ class PagedGenerationEngine:
                   -(-int(declared[li]["window"][1]) // 128) * 128),
                  self._cache_dtype)
                 for li, rows in zip(window_layers, self._ring_rows)]
-        elif self._cache_rows:
+        else:
             # one row a (layer, token): the layer is part of the row
             # index, so a layer's cache is a gather of whole rows,
             # (slots * cache_len, heads * d_head), with nothing to slice
@@ -772,30 +764,7 @@ class PagedGenerationEngine:
             # rows as they lie (ops.attention_rows).  Both index
             # operations address dimension 0 and heads * d_head is the
             # one minor dimension (the one `tp` shards under a mesh)
-            row = (H * dh,)
-            pool_shape = (L * n_tokens,) + row
-        else:
-            # token-major: the dimension the page table addresses leads
-            # and a token's (layers, heads * d_head) trail as one
-            # contiguous row.  Heads and d_head are kept as ONE dimension
-            # because the TPU runtime lays an array out by its shape:
-            # with a d_head under 128 lanes minor-most it would make the
-            # tokens the minor-most dimension instead and copy the pool
-            # to index it.  For the same reason the row's second-minor
-            # dimension has to tile without padding (1, 2, 4 or a
-            # multiple of 8 sublanes): with 6 layers of 4 x 128 the
-            # runtime puts the layers outermost and the program copies
-            # the pool twice a dispatch to index it.  Such a row folds
-            # the heads into the layers (one chip; under a mesh
-            # heads * d_head stays the dimension that `tp` shards).
-            def tiles(n):
-                return n in (1, 2, 4) or n % 8 == 0
-
-            row = (L, H * dh)
-            if self._mesh is None and not tiles(L):
-                row = (L * H, dh) if tiles(L * H) and dh % 128 == 0 \
-                    else (L * H * dh,)
-            pool_shape = (n_tokens,) + row
+            pool_shape = (L * n_tokens, H * dh)
         if self._mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -840,7 +809,7 @@ class PagedGenerationEngine:
                                   for a in self._state[:n_state])
                 window_bytes = sum(int(a.nbytes)
                                    for a in self._state[n_state:])
-                sp.set(shape=list(pool_shape), cache_rows=True,
+                sp.set(shape=list(pool_shape),
                        bytes=int(self._pool_k.nbytes) + state_bytes
                        + window_bytes,
                        latent_rows_bytes=int(self._pool_k.nbytes),
@@ -854,7 +823,6 @@ class PagedGenerationEngine:
             else:
                 self._pool_v = zeros(pool_shape, self._cache_dtype)
                 sp.set(shape=list(pool_shape),
-                       cache_rows=self._cache_rows,
                        bytes=2 * int(self._pool_k.nbytes))
 
         # host control plane: page tables + slot state + the prefix map
@@ -944,7 +912,6 @@ class PagedGenerationEngine:
         S = self._capacity
         cache_dtype = self._cache_dtype
         page = self._page_size
-        cache_rows = self._cache_rows
         mask_id = int(cfg["mask_token_id"]) if Bl > 1 else None
 
         def _traced(fn, params_):
@@ -970,17 +937,17 @@ class PagedGenerationEngine:
         by_page = page % (8 * max(1, 4 // cache_dtype.itemsize)) == 0
 
         def layer_rows(pool, li, page_table, rows):
-            """Layer ``li``'s cached rows of every slot, (B, S, H*dh).
-            Every gather takes its own view of the pool: with one view
-            shared by a pool's 24 gathers the chip's compiler schedules
-            the same operations a tenth slower (7.44 against 6.70 ms a
-            decode program; PERF.md, PR 32)."""
-            with jax.named_scope("cache.gather"):
-                if not by_page:
-                    return pool[li * n_tokens + rows]
-                return pool.reshape((L * n_pages, page, H * dh))[
-                    li * n_pages + page_table].reshape(
-                        rows.shape + (H * dh,))
+            """The ``li``-th of a pool's layers of rows, every slot's,
+            (B, S, the pool's lanes).  Every gather takes its own view
+            of the pool: with one view shared by a pool's 24 gathers the
+            chip's compiler schedules the same operations a tenth slower
+            (7.44 against 6.70 ms a decode program on a TPU v5e;
+            PERF.md)."""
+            lanes = pool.shape[1]
+            if not by_page:
+                return pool[li * n_tokens + rows]
+            return pool.reshape((-1, page, lanes))[
+                li * n_pages + page_table].reshape(rows.shape + (lanes,))
 
         def read_declared(pool, state, page_table, rows, lanes, start,
                           chunk):
@@ -1024,13 +991,8 @@ class PagedGenerationEngine:
                                               page, -1)),
                                 page_table + k * n_pages,
                                 jnp.where(valid > 0, start, 0))
-                        elif by_page:
-                            got = pool.reshape(
-                                (len(row_layers) * n_pages, page, -1))[
-                                k * n_pages + page_table].reshape(
-                                    rows.shape + (-1,))
                         else:
-                            got = pool[k * n_tokens + rows]
+                            got = layer_rows(pool, k, page_table, rows)
                         caches.append(got)
                         continue
                     mine = []
@@ -1083,13 +1045,12 @@ class PagedGenerationEngine:
                      wpage, woff, lane_keys, block=None, lanes=None,
                      state=(), draft=None):
             """The one paged dispatch: gather the pool rows of each
-            slot's pages into each layer's linear cache (a view
-            (B, H, S, dh) a layer of the token-major pool; for a model
-            that takes rows, (B, S, H*dh), a layer at a time), run the
-            model's chunk_forward, sample EVERY chunk position with its
-            position-derived key, and scatter the chunk's K/V rows back
-            to the pool at token ``wpage * page_size + woff`` — trash
-            page 0 absorbs padded positions.  pool_k/pool_v as
+            slot's pages into each layer's cache rows, (B, S, H*dh), a
+            layer at a time, run the model's chunk_forward, sample EVERY
+            chunk position with its position-derived key, and scatter
+            the chunk's K/V rows back to the pool, a row a (layer,
+            token ``wpage * page_size + woff``) — trash page 0 absorbs
+            padded positions.  pool_k/pool_v as
             ``pool_shape``; tokens (B, C); page_table (B, P);
             wpage/woff flat (B*C,).  The fifth result is a dict of what
             only some models give: ``expert_load`` (L, E) from an expert
@@ -1197,18 +1158,10 @@ class PagedGenerationEngine:
                                 extras["expert_load"],
                                 dres[2]["expert_load"]])
                     return logits, kept, extras
-                if cache_rows:
+                with jax.named_scope("cache.gather"):
                     caches = [(layer_rows(pool_k, li, page_table, rows),
                                layer_rows(pool_v, li, page_table, rows))
                               for li in range(L)]
-                else:
-                    def view(pool):  # (B, S, L, H*dh) -> (L, B, H, S, dh)
-                        return pool[rows].reshape(
-                            (Bc, S, L, H, dh)).transpose(2, 0, 3, 1, 4)
-
-                    with jax.named_scope("cache.gather"):
-                        gk, gv = view(pool_k), view(pool_v)
-                        caches = [(gk[li], gv[li]) for li in range(L)]
                 res = net.chunk_forward(tokens, caches, start)
                 # a model may hand back a third item: arrays about the
                 # forward itself (an expert layer's token counts)
@@ -1248,22 +1201,13 @@ class PagedGenerationEngine:
                 v_new = jnp.stack([v for _k, v in chunk_caches])
                 # leading-dimension scatter, in place on the donated
                 # pool; padded positions collide on the trash page, so
-                # the rows are not unique
-                if cache_rows:
-                    # (L, B, C, H*dh): one pool row a (layer, chunk
-                    # position)
-                    kvals = k_new.astype(cache_dtype).reshape((-1,) + row)
-                    vvals = v_new.astype(cache_dtype).reshape((-1,) + row)
-                    wrow = (jnp.arange(L, dtype=jnp.int32)[:, None]
-                            * n_tokens
-                            + (wpage * page + woff)[None, :]).reshape(-1)
-                else:
-                    # (L, B, H, C, dh): one pool row a chunk position
-                    kvals = k_new.astype(cache_dtype).transpose(
-                        1, 3, 0, 2, 4).reshape((Bc * C,) + row)
-                    vvals = v_new.astype(cache_dtype).transpose(
-                        1, 3, 0, 2, 4).reshape((Bc * C,) + row)
-                    wrow = wpage * page + woff
+                # the rows are not unique.  (L, B, C, H*dh): one pool
+                # row a (layer, chunk position)
+                kvals = k_new.astype(cache_dtype).reshape((-1, H * dh))
+                vvals = v_new.astype(cache_dtype).reshape((-1, H * dh))
+                wrow = (jnp.arange(L, dtype=jnp.int32)[:, None]
+                        * n_tokens
+                        + (wpage * page + woff)[None, :]).reshape(-1)
                 pool_k = pool_k.at[wrow].set(kvals)
                 pool_v = pool_v.at[wrow].set(vvals)
             return sampled, logits, pool_k, pool_v, extras
@@ -1278,12 +1222,8 @@ class PagedGenerationEngine:
             if ring_specs:
                 pool_tag += ":rings" + "+".join(
                     "x".join(str(d) for d in sh) for sh, _dt in ring_specs)
-        elif self._cache_rows:   # one row a (layer, token)
+        else:                    # one row a (layer, token)
             pool_tag = "L%dxtokens%dxHD%d" % (L, n_tokens, H * dh)
-        elif row == (L, H * dh):  # token-major, a token's layers a row
-            pool_tag = "tokens%dxL%dxHD%d" % pool_shape
-        else:                     # token-major, heads folded into layers
-            pool_tag = "tokens" + "x".join(str(d) for d in pool_shape)
         self._aot_spec = aot_spec or (
             "lm_decode_paged:slots%dxpages%dxpg%d:%s"
             % (self._slots, self._num_pages, page, pool_tag))
@@ -1301,7 +1241,7 @@ class PagedGenerationEngine:
                 self._jit_chunk, "generate:paged_chunk", store,
                 fingerprint_extra=fp, manifest_kind="generate",
                 manifest_spec=self._aot_spec, manifest_extra=mext)
-        self._H, self._dh, self._L = H, dh, L
+        self._L = L
 
     # -- introspection ---------------------------------------------------
 
@@ -2352,15 +2292,12 @@ class PagedGenerationEngine:
         """The form the program of a dispatch of ``chunk`` positions a
         slot attends in (the ``attn`` of the step spans): ``"rows"``
         where the model reads its cache rows as they lie, ``"heads"``
-        where it splits them by head.  A model that takes rows attends
-        through ``ops.attention_rows``, whose rule goes by the query
-        rows a slot; a head-split view is attended head by head; a
-        model that declares its layers' caches reads its rows as they
-        lie in every shape."""
+        where it splits them by head.  A model of attention layers
+        alone attends through ``ops.attention_rows``, whose rule goes by
+        the query rows a slot; a model that declares its layers' caches
+        reads its rows as they lie in every shape."""
         if self._declared:      # handed rows as they lie, nothing else
             return "rows"
-        if not self._cache_rows:
-            return "heads"
         from .ops.attention_rows import attends_in
 
         return attends_in(chunk, self.model_config["n_heads"])

@@ -13,7 +13,10 @@ causal mask; B > 1 is what generation by diffusion over blocks needs
 The model speaks the chunk protocol of ``mxnet_tpu.generate``
 (``chunk_forward`` / ``config``), with the key/value head count and the
 head size in ``config`` (``n_kv_heads``, ``d_head``: the head size is
-its own number here, not ``d_model / n_heads``).  The expert layer is
+its own number here, not ``d_model / n_heads``).  It takes its cache as
+K/V rows and attends through ``ops.attention_rows.chunk_attention_rows``
+under the block-causal mask; the full forward is the same attention
+over nothing cached.  The expert layer is
 ``parallel.moe.routed_experts``: told which experts it holds
 (``experts_held``), it routes over all of them and computes its own
 experts' part.  Parameters are registered in one flat list, block by
@@ -120,17 +123,19 @@ class MoEDecoderLM(HybridBlock):
 
     # -- the one forward ---------------------------------------------------
 
-    def _block(self, x, p, pos, k_cache, v_cache, cache_mask, chunk_mask):
+    def _block(self, x, p, pos, k_rows, v_rows, start):
         """One block over C positions a sequence.  ``x`` (B, C, D) raw;
-        ``k_cache``/``v_cache`` (B, Hkv, S, dh) raw or None;
-        ``cache_mask`` (B, 1, 1, S); ``chunk_mask`` (C, C).  Returns
-        (x_out, k_chunk, v_chunk (B, Hkv, C, dh), counts (E,)).  Its
-        parts are traced under the named scopes ``attn.proj``,
-        ``attn.core`` and the expert layer's own (``experts.route``,
-        ``experts.ffn``), which ``profiler.device_table`` reads."""
+        ``k_rows``/``v_rows`` (B, S, Hkv * dh) raw, the cached rows, of
+        which positions ``< start`` (B,) are attended.  Returns (x_out,
+        k_chunk, v_chunk (B, C, Hkv * dh), counts (E,)).  Its parts are
+        traced under the named scopes ``attn.proj``, ``attn.core`` (in
+        ``ops.attention_rows``) and the expert layer's own
+        (``experts.route``, ``experts.ffn``), which
+        ``profiler.device_table`` reads."""
         import jax
         import jax.numpy as jnp
 
+        from ....ops.attention_rows import chunk_attention_rows
         from ....parallel.moe import routed_experts
 
         (g1, wq, wk, wv, gq, gk, wo, g2, wr, wg, wu, wd) = \
@@ -138,9 +143,7 @@ class MoEDecoderLM(HybridBlock):
         c = self._cfg
         B, C, D = x.shape
         Hq, Hkv, dh = c["n_heads"], c["n_kv_heads"], c["d_head"]
-        G = Hq // Hkv
         act = wq.dtype                    # compute follows the weight
-        f32 = jnp.float32
 
         def mm(a, w):                     # a (..., in) x w (out, in)
             return jnp.dot(a.astype(w.dtype), w.T)
@@ -149,65 +152,43 @@ class MoEDecoderLM(HybridBlock):
             n = _rms(x, g1, self._eps)
             q = _rms(mm(n, wq).reshape((B, C, Hq, dh)), gq, self._eps)
             k = _rms(mm(n, wk).reshape((B, C, Hkv, dh)), gk, self._eps)
-            v = mm(n, wv).reshape((B, C, Hkv, dh))
-            q = _rope(q, pos, self._theta).astype(act)
-            k = _rope(k, pos, self._theta).astype(act)
-            # a key/value head serves G query heads: fold them into the
-            # query rows, so the cache is read once and never repeated
-            qg = q.reshape((B, C, Hkv, G, dh)).transpose(0, 2, 3, 1, 4) \
-                .reshape((B, Hkv, G * C, dh))
-            k_c, v_c = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-        with jax.named_scope("attn.core"):
-            scale = dh ** -0.5
-            neg = jnp.asarray(-1e30, f32)
-            s = jnp.einsum("bhqd,bhsd->bhqs", qg, k_c,
-                           preferred_element_type=f32) * scale
-            s = jnp.where(jnp.tile(chunk_mask, (G, 1))[None, None], s, neg)
-            vals = v_c
-            if k_cache is not None:
-                sc = jnp.einsum("bhqd,bhsd->bhqs", qg, k_cache.astype(act),
-                                preferred_element_type=f32) * scale
-                s = jnp.concatenate([jnp.where(cache_mask, sc, neg), s], -1)
-                vals = jnp.concatenate([v_cache.astype(act), v_c], 2)
-            att = jax.nn.softmax(s, axis=-1).astype(act)
-            o = jnp.einsum("bhqs,bhsd->bhqd", att, vals)
+            q = _rope(q, pos, self._theta).astype(act).reshape(
+                (B, C, Hq * dh))
+            k = _rope(k, pos, self._theta).astype(act).reshape(
+                (B, C, Hkv * dh))
+            v = mm(n, wv)
+        o = chunk_attention_rows(q, k, v, k_rows.astype(act),
+                                 v_rows.astype(act), start, Hq, Hkv,
+                                 block_length=c["block_length"])
         with jax.named_scope("attn.proj"):
-            o = o.reshape((B, Hkv, G, C, dh)).transpose(0, 3, 1, 2, 4) \
-                .reshape((B, C, Hq * dh))
             x = x + mm(o, wo).astype(x.dtype)
         with jax.named_scope("experts.route"):
             m = _rms(x, g2, self._eps).astype(act).reshape((B * C, D))
         y, counts = routed_experts(
             m, wr.T, wg, wu, wd, c["top_k"], c["d_expert"],
             first=self._first, norm_topk=self._norm_topk)
-        return x + y.reshape((B, C, D)).astype(x.dtype), k_c, v_c, counts
+        return x + y.reshape((B, C, D)).astype(x.dtype), k, v, counts
 
     def _run(self, tokens, caches, start):
-        """tokens (B, C) int; caches a list of (k, v) raw (B, Hkv, S,
-        dh) or None; start (B,) int32.  Returns (logits raw (B, C, V),
-        [(k, v) raw (B, Hkv, C, dh)], expert load (L, E) int32)."""
+        """tokens (B, C) int; caches a list of (k, v) raw (B, S, Hkv *
+        dh), or None: nothing cached (one row, never attended, stands
+        for it); start (B,) int32.  Returns (logits raw (B, C, V),
+        [(k, v) raw (B, C, Hkv * dh)], expert load (L, E) int32)."""
         import jax
         import jax.numpy as jnp
 
         c = self._cfg
         B, C = tokens.shape
-        Bl = c["block_length"]
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)
         with jax.named_scope("embed"):
             x = jnp.take(self._embed.data()._data, tokens, axis=0)
-        # within the chunk: a chunk starts on a block boundary
-        blk = jnp.arange(C, dtype=jnp.int32) // Bl
-        chunk_mask = blk[:, None] >= blk[None, :]
-        cache_mask = None
-        if caches is not None:
-            S = caches[0][0].shape[2]
-            cache_mask = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                          < start[:, None]).reshape((B, 1, 1, S))
+        if caches is None:
+            empty = jnp.zeros((B, 1, c["n_kv_heads"] * c["d_head"]),
+                              x.dtype)
+            caches = [(empty, empty)] * len(self._layers)
         new, loads = [], []
-        for li, p in enumerate(self._layers):
-            kc, vc = caches[li] if caches is not None else (None, None)
-            x, k_c, v_c, counts = self._block(x, p, pos, kc, vc,
-                                              cache_mask, chunk_mask)
+        for p, (kr, vr) in zip(self._layers, caches):
+            x, k_c, v_c, counts = self._block(x, p, pos, kr, vr, start)
             new.append((k_c, v_c))
             loads.append(counts)
         with jax.named_scope("head"):
@@ -228,14 +209,15 @@ class MoEDecoderLM(HybridBlock):
         return NDArray(logits)
 
     def chunk_forward(self, tokens, caches, start):
-        """C positions a sequence against a linear K/V cache view (the
-        chunk protocol of ``generate.PagedGenerationEngine``): ``tokens``
-        raw (B, C) int32 at positions ``start_b ..``, where ``start_b``
-        is a multiple of the block length; ``caches`` one ``(k, v)`` of
-        raw (B, n_kv_heads, S, d_head) a layer, holding positions
-        ``< start_b``.  A chunk position attends the cache and the chunk
+        """C positions a sequence against its cached K/V rows (the chunk
+        protocol of ``generate.PagedGenerationEngine``): ``tokens`` raw
+        (B, C) int32 at positions ``start_b ..``, where ``start_b`` is a
+        multiple of the block length; ``caches`` one ``(k, v)`` of raw
+        (B, S, n_kv_heads * d_head) a layer, one row a position with
+        every key/value head side by side, holding positions ``<
+        start_b``.  A chunk position attends the cache and the chunk
         positions of its own and earlier blocks.  Returns ``(logits
-        NDArray (B, C, V), [(k, v) raw (B, n_kv_heads, C, d_head)],
+        NDArray (B, C, V), [(k, v) raw (B, C, n_kv_heads * d_head)],
         {"expert_load": (layers, experts) int32})``."""
         import jax.numpy as jnp
 

@@ -3,7 +3,7 @@ holds them: one row a position, every head side by side,
 ``(B, S, kv_heads * d_head)``.
 
 The decode engine (``generate.PagedGenerationEngine``) gathers such rows
-a layer at a time for a model whose ``config`` says ``cache_rows``.  A
+a layer at a time for a model that does not declare ``layer_caches``.  A
 head size under the TPU's 128 lanes must never become the minor-most
 dimension of anything as large as the cache: splitting the rows into
 ``(B, heads, S, d_head)`` makes the compiler re-tile all of them, every
@@ -216,8 +216,10 @@ def _dot(a, b, dims):
 
 
 def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
-                         n_heads, n_kv_heads=None, scale=None):
-    """Causal attention of a chunk against its sequence's cached rows.
+                         n_heads, n_kv_heads=None, scale=None,
+                         block_length=1):
+    """Causal (or block-causal) attention of a chunk against its
+    sequence's cached rows.
 
     ``q`` (B, C, n_heads * d_head): the chunk's queries, at positions
     ``start_b .. start_b + C - 1``; ``k_chunk`` / ``v_chunk``
@@ -227,9 +229,12 @@ def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
     (unwritten, stale or the trash page's) are not; ``start`` (B,)
     int32.  Query head ``h`` reads key/value head ``h // (n_heads /
     n_kv_heads)``.  Chunk position ``c`` attends every cached position
-    below ``start_b`` and chunk positions ``c' <= c``.  Returns
-    (B, C, n_heads * d_head) in ``q``'s dtype.  Traced under the named
-    scope ``attn.core`` (``mxnet_tpu.profiler.device_table``).
+    below ``start_b`` and chunk positions ``c'`` with ``c' // B <= c //
+    B`` for ``block_length`` B: ``c' <= c`` at 1; bidirectional inside
+    a block and causal across blocks above it (a chunk starts on a block
+    boundary).  Returns (B, C, n_heads * d_head) in ``q``'s dtype.
+    Traced under the named scope ``attn.core``
+    (``mxnet_tpu.profiler.device_table``).
     """
     import jax
     import jax.numpy as jnp
@@ -239,6 +244,8 @@ def chunk_attention_rows(q, k_chunk, v_chunk, k_rows, v_rows, start,
         cache_ok = (jnp.arange(S, dtype=jnp.int32)[None, :]
                     < start.astype(jnp.int32)[:, None])         # (B, S)
         c_idx = jnp.arange(C, dtype=jnp.int32)
+        if block_length > 1:
+            c_idx = c_idx // int(block_length)
         causal = c_idx[:, None] >= c_idx[None, :]               # (C, C')
         return _attend_rows(q, k_chunk, v_chunk, k_rows, v_rows,
                             cache_ok[:, None, :], causal, n_heads,

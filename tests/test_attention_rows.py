@@ -5,7 +5,8 @@ from the dispatch's shape; and the second rule on that shape (ISSUE 38):
 whether a model that attends its own rows reads them in blocks up to
 ``start`` or over all a slot holds, and the count the engine reports;
 and ``chunk_attention_blocks``, a grouped-query chunk's cached rows in
-blocks up to ``start``, held to the whole form and the reference."""
+blocks up to ``start``, held to the whole form and the reference; the
+block-causal mask of decoding by diffusion over blocks."""
 import numpy as np
 import pytest
 
@@ -67,6 +68,57 @@ def test_rows_attention_matches_head_split_f32(monkeypatch, heads, chunk,
     assert got.shape == (3, chunk, H * dh) and got.dtype == jnp.float32
     want = _reference(q, k_c, v_c, k_r, v_r, start, H, Hkv)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("form", ["rows", "heads"])
+@pytest.mark.parametrize("chunk", [4, 8], ids=["pass", "prefill_chunk"])
+def test_block_causal_rows_match_a_dense_reference_f32(monkeypatch, chunk,
+                                                       form):
+    """``block_length`` 4 (decoding by diffusion over blocks): chunk
+    position ``c`` attends ``c'`` iff ``c' // 4 <= c // 4``, in both
+    forms, held to a dense float32 attention over the cached rows and
+    the chunk's under that mask, grouped-query (8 query heads over 2).
+    Every ``start`` is a block boundary, as the engine's are."""
+    import jax.numpy as jnp
+
+    H, Hkv, dh, Bl = 8, 2, 16, 4
+    monkeypatch.setattr(attention_rows, "BLOCK_DIAGONAL_MAX_QUERY_ROWS",
+                        10 ** 6 if form == "rows" else 0)
+    assert attention_rows.attends_in(chunk, H) == form
+    rs = np.random.RandomState(chunk)
+    start = np.asarray([0, S, 8], np.int32)
+    q = rs.standard_normal((3, chunk, H * dh)).astype(np.float32)
+    k_c, v_c = rs.standard_normal((2, 3, chunk, Hkv * dh)).astype(np.float32)
+    k_r, v_r = rs.standard_normal((2, 3, S, Hkv * dh)).astype(np.float32)
+    got = attention_rows.chunk_attention_rows(
+        *(jnp.asarray(a) for a in (q, k_c, v_c, k_r, v_r, start)), H, Hkv,
+        block_length=Bl)
+    assert got.shape == (3, chunk, H * dh) and got.dtype == jnp.float32
+    # dense: (B, H, C, S + C) scores, one mask, one softmax
+    G = H // Hkv
+    qh = q.reshape((3, chunk, H, dh)).transpose((0, 2, 1, 3))
+    keys = np.concatenate([k_r, k_c], 1).reshape((3, S + chunk, Hkv, dh))
+    vals = np.concatenate([v_r, v_c], 1).reshape((3, S + chunk, Hkv, dh))
+    keys = np.repeat(keys.transpose((0, 2, 1, 3)), G, axis=1)
+    vals = np.repeat(vals.transpose((0, 2, 1, 3)), G, axis=1)
+    s = np.einsum("bhcd,bhsd->bhcs", qh, keys) * np.float32(dh ** -0.5)
+    blk = np.arange(chunk) // Bl
+    mask = np.concatenate([
+        np.broadcast_to(np.arange(S)[None, None, :] < start[:, None, None],
+                        (3, chunk, S)),
+        np.broadcast_to(blk[None, None, :] <= blk[None, :, None],
+                        (3, chunk, chunk))], -1)[:, None]
+    s = np.where(mask, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("bhcs,bhsd->bhcd", p, vals).transpose(
+        (0, 2, 1, 3)).reshape((3, chunk, H * dh))
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-5, rtol=1e-5)
+    # the mask is the block's, not the causal one: a position that
+    # attends a later one of its block reads otherwise
+    causal = attention_rows.chunk_attention_rows(
+        *(jnp.asarray(a) for a in (q, k_c, v_c, k_r, v_r, start)), H, Hkv)
+    assert not np.allclose(np.asarray(causal), want, atol=1e-3)
 
 
 def test_form_follows_the_query_rows_a_slot():

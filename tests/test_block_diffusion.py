@@ -94,17 +94,18 @@ def test_config_names_the_cache_shape(net):
     c = net.config
     assert (c["n_kv_heads"], c["d_head"], c["block_length"]) == (2, 16, 4)
     eng = make_engine(net, 2)
-    # (pages * page_size, layers, key/value heads * head size): not
+    # (layers * pages * page_size, key/value heads * head size): not
     # n_heads * (d_model // n_heads)
-    assert eng.pool_shape == (eng.num_pages * 8, 2, 2 * 16)
-    # a layer count that tiles badly (6) folds the heads into it
+    assert eng.pool_shape == (2 * eng.num_pages * 8, 2 * 16)
+    # a layer count that tiles badly (6) folds nothing: a row a (layer,
+    # token) whatever the layers
     six = MoEDecoderLM(64, 256, 6, 4, 4, 128, 4, 2, 16, block_length=4,
                        mask_token_id=63)
     six.initialize(mx.init.Zero())
     eng6 = generate.PagedGenerationEngine(
         six, slots=1, cache_len=16, page_size=8, prefill_chunk=8, spec_k=0,
         dtype_policy="f32", denoise_steps=2)
-    assert eng6.pool_shape == (eng6.num_pages * 8, 6 * 4, 128)
+    assert eng6.pool_shape == (6 * eng6.num_pages * 8, 4 * 128)
     assert eng.dispatch_shapes() == [(1, 8), (2, BL)]
 
 

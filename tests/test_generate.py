@@ -173,14 +173,13 @@ def test_chunk_forward_matches_full_forward_f32(models, model, chunk):
     H = cfg.get("n_kv_heads", cfg["n_heads"])
     dh = cfg.get("d_head", cfg["d_model"] // cfg["n_heads"])
     S = 16
-    # the cache form the model's config declares: rows, one a position
-    # with every head side by side, or a head-split view
-    rows = bool(cfg.get("cache_rows"))
-    shape = (lambda n: (1, n, H * dh)) if rows \
-        else (lambda n: (1, H, n, dh))
+    # the one cache form: rows, one a position with every key/value
+    # head side by side
+    def shape(n):
+        return (1, n, H * dh)
+
     caches = [(jnp.zeros(shape(S), jnp.float32),) * 2
               for _ in range(cfg["n_layers"])]
-    assert rows == (model == "transformer_lm")
     for start in range(0, len(seq), chunk):
         res = net.chunk_forward(
             jnp.asarray(seq[None, start:start + chunk]), caches,
@@ -191,8 +190,7 @@ def test_chunk_forward_matches_full_forward_f32(models, model, chunk):
         assert (got.argmax(-1) == ref[start:start + chunk].argmax(-1)).all()
         assert len(res[1]) == cfg["n_layers"]
         assert res[1][0][0].shape == shape(chunk)
-        at = (slice(None), slice(start, start + chunk)) if rows \
-            else (slice(None), slice(None), slice(start, start + chunk))
+        at = (slice(None), slice(start, start + chunk))
         caches = [(k.at[at].set(kc), v.at[at].set(vc))
                   for (k, v), (kc, vc) in zip(caches, res[1])]
 

@@ -394,7 +394,7 @@ def test_perf_gate_directions_for_paged_metrics():
 
 
 # ---------------------------------------------------------------------------
-# the token-major pool: structure of the one dispatch (ISSUE 27)
+# the pool of rows: structure of the one dispatch
 # ---------------------------------------------------------------------------
 
 def _dispatch_args(eng, shape):
@@ -404,24 +404,30 @@ def _dispatch_args(eng, shape):
         {"prefill": prefill, "decode": decode, "verify": verify}[shape])
 
 
-@pytest.mark.parametrize("page_size", [4, 8], ids=["by_row", "by_page"])
+@pytest.mark.parametrize("model,page_size", [
+    pytest.param("opt", 4, id="by_row"), pytest.param("opt", 8, id="by_page"),
+    pytest.param("moe", 8, id="moe")])
 @pytest.mark.parametrize("shape", ["decode", "prefill", "verify"])
-def test_pool_is_indexed_on_its_leading_dimension(lm, shape, page_size):
+def test_pool_is_indexed_on_its_leading_dimension(lm, shape, model,
+                                                  page_size):
     """Both index operations of the dispatch take the donated pool
     itself — no transpose or copy of it first — and address its
     dimension 0: a gather a layer of whole rows (of whole pages, from
     the pool seen page by page, where a page is a whole number of
     sublane tiles: 8 rows of float32), one scatter of the chunk's rows
-    of every layer; nothing else in the program has the pool's size."""
+    of every layer; nothing else in the program has the pool's size.
+    The same for the expert decoder, whose rows hold its key/value
+    heads (grouped-query attention)."""
     import jax
 
+    net = lm if model == "opt" else _moe_lm(1)
     eng = generate.PagedGenerationEngine(
-        lm, slots=3, cache_len=MAX_LEN, page_size=page_size,
+        net, slots=3, cache_len=MAX_LEN, page_size=page_size,
         prefill_chunk=8, spec_k=2,
         sampling=generate.SamplingConfig(greedy=True))
-    # one row a (layer, token): TransformerLM's config says cache_rows
-    assert eng.pool_shape == (N_LAYERS * eng.num_pages * page_size,
-                              D_MODEL)
+    # one row a (layer, token): every head, or key/value head, of it
+    width = D_MODEL if model == "opt" else 2 * 16
+    assert eng.pool_shape == (N_LAYERS * eng.num_pages * page_size, width)
     args = _dispatch_args(eng, shape)
     closed = jax.make_jaxpr(eng._jit_chunk)(*args)
     (call,) = closed.jaxpr.eqns          # the jitted chunk_fn itself
@@ -436,7 +442,7 @@ def test_pool_is_indexed_on_its_leading_dimension(lm, shape, page_size):
     assert len(views) == (2 * N_LAYERS if by_page else 0)
     for e in views:
         assert e.outvars[0].aval.shape == (
-            N_LAYERS * eng.num_pages, page_size, D_MODEL)
+            N_LAYERS * eng.num_pages, page_size, width)
     sources = pools + [e.outvars[0] for e in views]
 
     gathers = [e for e in body.eqns if e.primitive.name == "gather"
@@ -451,16 +457,16 @@ def test_pool_is_indexed_on_its_leading_dimension(lm, shape, page_size):
         assert tuple(dn.start_index_map) == (0,)
         assert tuple(dn.collapsed_slice_dims) == (0,)
         assert tuple(e.params["slice_sizes"]) == (
-            (1, page_size, D_MODEL) if by_page else (1, D_MODEL))
+            (1, page_size, width) if by_page else (1, width))
         # a layer's rows of every slot, as the model takes them
         assert int(np.prod(e.outvars[0].aval.shape)) == \
-            nb * eng.cache_len * D_MODEL
+            nb * eng.cache_len * width
     for e in scatters:
         dn = e.params["dimension_numbers"]
         assert tuple(dn.scatter_dims_to_operand_dims) == (0,)
         assert tuple(dn.inserted_window_dims) == (0,)
         # a row a (layer, chunk position)
-        assert e.invars[2].aval.shape == (N_LAYERS * nb * nc, D_MODEL)
+        assert e.invars[2].aval.shape == (N_LAYERS * nb * nc, width)
         # padded positions collide on the trash page
         assert not e.params["unique_indices"]
     # the pools reach nothing but their gathers and their scatter (no
@@ -622,7 +628,9 @@ def wide_gdn_engine():
 # (`_kernel_sha256`: the serialized module names the line of every frame
 # above the kernel's call, so the hash as PR 36 took it changed with any
 # line added to `generate.py`; PR 36's tree gives these two under the
-# new rule and its own two under the old).
+# new rule and its own two under the old).  The expert model's two are
+# re-based since it takes its cache as rows of one (layer, token), as
+# OPT's model does, whose two that change left as they were.
 HLO_JAX = "0.9.0"
 HLO_SHA256 = {
     ("opt", "decode"):
@@ -630,9 +638,9 @@ HLO_SHA256 = {
     ("opt", "prefill"):
         "78c914d33623a7f9f7a783b7521c7a1c5273ab4a0688605444e5d80505690a46",
     ("moe", "decode"):
-        "227c00586e8ac6c03765d93f7acedd1bc477d82a645c949f961c12b880adaed6",
+        "a7928ab9eed9616f03ac148397d3852f26d1eb1541245a08fdd9299f9e21703c",
     ("moe", "prefill"):
-        "cc91289480c4fb1550e9a9007b3f253f910d511be2901770787240ed2f3e0b79",
+        "4b669e78bc01446c874772a2e1f3b43d0c444d6781c2cbf24174d6be9ec779e3",
 }
 
 
@@ -704,9 +712,9 @@ def _compile_for(chip, eng, shape):
 
 
 # the programs' temporaries at those sizes (compiled.memory_analysis()):
-# the gathered views of 32 x 1024 rows and their relayouts (decode), one
-# slot's view and a chunk's expert activations (prefill)
-MOE_TEMP_BYTES = {"decode": 1.4e9, "prefill": 0.5e9}
+# a layer's gathered rows at a time, 0.017 GB (decode), and a chunk's
+# scores and expert activations, 0.018 GB (prefill)
+MOE_TEMP_BYTES = {"decode": 0.05e9, "prefill": 0.05e9}
 # the OPT cell's (ISSUE 32): no view of all layers' caches is built, so
 # at the cell's 24 layers the decode program's are 0.03 GB (2.4 GB with
 # the view; a quarter of either at the fixture's 6 layers)
@@ -746,8 +754,8 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, monkeypatch, model,
     scatter and the result no operation has the pool's size: no copy,
     no loop that gathers page by page, no buffer of zeros.  No weight
     enters as a float32 master to be cast in the program.  The
-    temporaries stay under a stated size.  For the OPT cell's model,
-    whose pool holds a row a (layer, token), nothing in the entry is as
+    temporaries stay under a stated size.  The pool holds a row a
+    (layer, token) for both models, and nothing in the entry is as
     large as a view of all layers' caches either: a layer's rows are
     gathered and attended, and the next layer's after them."""
     import re
@@ -813,26 +821,34 @@ def test_tpu_program_copies_no_pool(v5e_chip, request, monkeypatch, model,
     if model == "opt":
         assert eng.pool_shape == (6 * 513 * 16, 2048)
         assert temp < OPT_TEMP_BYTES[shape], temp
-        # all layers' caches of the dispatch's slots: the view the
-        # program used to build, re-tile twice and slice
-        view = shapes[shape][0] * eng.cache_len * 6 * 2048
-        large = []
-        for line in entry.splitlines():
-            m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([\w\-]+)\(", line)
-            # (the entry's result is a tuple that names the pools; a
-            # bitcast, the pool seen page by page, moves nothing)
-            if m and m.group(2) not in ("tuple", "bitcast") and any(
-                    np.prod([int(d) for d in dims.split(",")]) >= view
-                    for dims in re.findall(r"\[([\d,]+)\]", m.group(1))):
-                large.append(m.group(2))
-        assert sorted(large) == ["fusion", "fusion", "parameter",
-                                 "parameter"], large
-    if model == "moe":
-        assert eng.pool_shape == (2049 * 16, 6 * 4, 128)
+    else:
+        assert eng.pool_shape == (6 * 2049 * 16, 4 * 128)
         assert temp < MOE_TEMP_BYTES[shape], temp
         # no expert matrix is copied to be multiplied
         assert not re.findall(r"= bf16\[2048,6144\]\S* copy\(", entry)
         assert not re.findall(r"= bf16\[6144,2048\]\S* copy\(", entry)
+    if model == "moe" and shape == "prefill":
+        # a chunk's own scores (4 x 2048 x 1024 float32) and routed rows
+        # outgrow one slot's 6 x 1024 rows of 512 lanes: all layers'
+        # caches of 32 slots are looked for in the pass
+        return
+    # all layers' caches of the dispatch's slots: the view the programs
+    # used to build, re-tile twice and slice
+    view = shapes[shape][0] * eng.cache_len * 6 * eng.pool_shape[1]
+    large = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([\w\-]+)\(", line)
+        # (the entry's result is a tuple that names the pools; a
+        # bitcast, the pool seen page by page, moves nothing; a weight
+        # enters as a parameter and is not built)
+        if m and m.group(2) not in ("tuple", "bitcast") and not (
+                m.group(2) == "parameter" and pool not in m.group(1)) \
+                and any(np.prod([int(d) for d in dims.split(",")]) >= view
+                        for dims in re.findall(r"\[([\d,]+)\]",
+                                               m.group(1))):
+            large.append(m.group(2))
+    assert sorted(large) == ["fusion", "fusion", "parameter",
+                             "parameter"], large
 
 
 @pytest.mark.parametrize("shape", ["decode", "prefill"])
@@ -1087,7 +1103,8 @@ def _build(net, **kw):
 
 
 def _moe_lm(block_length=1, **kw):
-    """The zoo's decoder, small: it takes head-split views."""
+    """The zoo's decoder, small: 4 query heads to 2 key/value heads of
+    16, its rows 32 lanes."""
     from mxnet_tpu.gluon.model_zoo.language import MoEDecoderLM
 
     net = MoEDecoderLM(64, 32, 2, 4, 2, 16, 4, 2, 16,
@@ -1208,35 +1225,44 @@ def test_held_weights_keep_their_sharding(lm):
     assert {str(a.dtype) for a in e._params} == {"bfloat16", "float32"}
 
 
-@pytest.mark.parametrize("model", ["rows", "views", "views_blocks"])
+@pytest.mark.parametrize("model", ["rows", "moe", "moe_blocks"])
 def test_spans_name_the_attention_form_and_the_pool(lm, model,
                                                     monkeypatch):
-    """The counter that says the mechanism engaged (ISSUE 32): every
+    """The counter that says the mechanism engaged: every
     ``engine.decode`` / ``engine.prefill`` span carries ``attn``, the
     form the launched program attends in by the engine's rule for that
     model and dispatch shape, and the ``engine.pool`` span written once
-    when the engine is built carries the pool's shape."""
+    when the engine is built carries the pool's shape: rows of one
+    (layer, token) for OPT's model and the expert decoder alike."""
     from mxnet_tpu import tracing
     from mxnet_tpu.ops import attention_rows
 
-    # two heads: a decode step (2 query rows a slot) and a verify step
-    # (6) read the rows as they lie, a prefill chunk of 8 (16) does not
-    monkeypatch.setattr(attention_rows, "BLOCK_DIAGONAL_MAX_QUERY_ROWS", 8)
     before = {r["span_id"] for r in tracing.records()}
     if model == "rows":
+        # two heads: a decode step (2 query rows a slot) and a verify
+        # step (6) read the rows as they lie, a prefill chunk of 8 (16)
+        # does not
+        monkeypatch.setattr(attention_rows,
+                            "BLOCK_DIAGONAL_MAX_QUERY_ROWS", 8)
         eng = _build(lm, spec_k=2)
         want = {"engine.prefill": "heads", "engine.decode": "rows"}
         shape = (N_LAYERS * eng.num_pages * 4, D_MODEL)
         assert [eng._attends_in(c) for _b, c in eng.dispatch_shapes()] \
             == ["heads", "rows", "rows"]
     else:
-        blocks = 4 if model == "views_blocks" else 1
+        # four query heads: a decode step (4 query rows a slot) and a
+        # pass of a block of 4 (16) read the rows as they lie, a prefill
+        # chunk of 8 (32) does not, as SDAR's (32, 4) pass and (1, 256)
+        # chunk at the rule's own 256
+        monkeypatch.setattr(attention_rows,
+                            "BLOCK_DIAGONAL_MAX_QUERY_ROWS", 16)
+        blocks = 4 if model == "moe_blocks" else 1
         eng = generate.PagedGenerationEngine(
             _moe_lm(blocks), slots=2, cache_len=32, page_size=8,
             prefill_chunk=8, spec_k=0, denoise_steps=2 if blocks > 1
             else None)
-        want = {"engine.prefill": "heads", "engine.decode": "heads"}
-        shape = (eng.num_pages * 8, 2, 2 * 16)
+        want = {"engine.prefill": "heads", "engine.decode": "rows"}
+        shape = (N_LAYERS * eng.num_pages * 8, 2 * 16)
     # two prefill chunks of 8 (whole blocks only, under block decoding)
     slot, _tok = eng.admit(_prompt(11 if model == "rows" else 19, seed=7))
     for _ in range(3):
@@ -1249,7 +1275,7 @@ def test_spans_name_the_attention_form_and_the_pool(lm, model,
         assert [r["args"]["attn"] for r in spans] == [form] * len(spans)
     (pool,) = [r for r in mine if r["name"] == "engine.pool"]
     assert tuple(pool["args"]["shape"]) == eng.pool_shape == shape
-    assert pool["args"]["cache_rows"] == (model == "rows")
+    assert "cache_rows" not in pool["args"]      # one form: nothing to say
     assert pool["args"]["bytes"] == 2 * int(np.prod(shape)) * 4
 
 
@@ -1392,43 +1418,56 @@ def test_mixed_batch_matches_full_forward(lm, paged, scenario):
         assert toks == _greedy_reference(lm, prompt, len(toks))
 
 
-@pytest.mark.parametrize("mesh,spec", [
-    ("tp=2", (None, "tp")),
-    ("dp=2,tp=2", ("dp", "tp")),
-    ("fsdp=2,tp=2", ("fsdp", "tp")),
-    ("dp=2,fsdp=2", (("dp", "fsdp"),))])
-def test_pool_layout_rule_under_mesh(lm, paged, mesh, spec):
+@pytest.mark.parametrize("mesh,spec,model", [
+    pytest.param("tp=2", (None, "tp"), "opt", id="tp=2-spec0"),
+    pytest.param("dp=2,tp=2", ("dp", "tp"), "opt", id="dp=2,tp=2-spec1"),
+    pytest.param("fsdp=2,tp=2", ("fsdp", "tp"), "opt",
+                 id="fsdp=2,tp=2-spec2"),
+    pytest.param("dp=2,fsdp=2", (("dp", "fsdp"),), "opt",
+                 id="dp=2,fsdp=2-spec3"),
+    pytest.param("dp=2,tp=2", ("dp", "tp"), "moe", id="moe-dp=2,tp=2"),
+    pytest.param("tp=2", (None, "tp"), "moe", id="moe-tp=2")])
+def test_pool_layout_rule_under_mesh(lm, paged, mesh, spec, model):
     """The pool of rows (rank 2: a row a (layer, token)) resolves under
     its own ``kv_pool`` rule — rows over the data axes, heads over tp —
     and a meshed engine decodes the one-device engine's greedy
-    tokens."""
+    tokens: OPT's model, and the expert decoder, whose rows hold its
+    two key/value heads (one a tp shard)."""
     from jax.sharding import PartitionSpec as P
 
     from mxnet_tpu import parallel
 
+    net, one = lm, paged
+    if model == "moe":
+        net = _moe_lm(1)
+        one = generate.PagedGenerationEngine(
+            net, slots=3, cache_len=16, page_size=4, prefill_chunk=8,
+            sampling=generate.SamplingConfig(greedy=True))
     e = generate.PagedGenerationEngine(
-        lm, slots=3, cache_len=16, page_size=4, prefill_chunk=8,
+        net, slots=3, cache_len=16, page_size=4, prefill_chunk=8,
         mesh=mesh, sampling=generate.SamplingConfig(greedy=True))
     res = parallel.layout.get_layout(e.layout_name).resolve(
         [("pool_k", e.pool_shape), ("pool_v", e.pool_shape)], e._mesh)
     assert res.rule("pool_k") == res.rule("pool_v") == "kv_pool"
-    assert e.pool_shape == (N_LAYERS * 52, D_MODEL)
+    assert e.pool_shape == (N_LAYERS * 52, 32)
     assert not res.fallbacks, "104 rows and 32 lanes divide by 2 and 4"
     assert res.spec("pool_k") == P(*spec)
     assert e._pool_k.sharding.spec == e._pool_v.sharding.spec == P(*spec)
     prompts = [_prompt(5, seed=3), _prompt(7, seed=5)]
-    assert _side_by_side(e, prompts, 4) == _side_by_side(paged, prompts, 4)
+    assert _side_by_side(e, prompts, 4) == _side_by_side(one, prompts, 4)
 
 
 @pytest.mark.parametrize("fault,problem", [
     (None, None), ("page_size", "no whole number of pages"),
-    ("prefill_chunk", "token block width")])
-def test_prewarm_check_reads_the_token_major_rows(lm, tmp_path, fault,
-                                                  problem):
+    ("prefill_chunk", "token block width"),
+    ("token_major", "no pair of page-pool leaves")])
+def test_prewarm_check_reads_the_rows_of_a_layer_and_token(
+        lm, tmp_path, fault, problem):
     """``tools/prewarm.py --check`` judges a stored paged signature by
-    the pool's leaves, here rows of one (layer, token) each: a healthy
-    store passes, a row whose extras disagree with its own recorded
-    shapes is named."""
+    the pool's leaves, rows of one (layer, token) each: a healthy store
+    passes, a row whose extras disagree with its own recorded shapes is
+    named, and so is one whose pools are not rows (rank 3: the
+    token-major pool the engine no longer builds)."""
     import prewarm
     from mxnet_tpu import aot
 
@@ -1444,7 +1483,13 @@ def test_prewarm_check_reads_the_token_major_rows(lm, tmp_path, fault,
         assert row["pool_layout"] == "L%dxtokens%dxHD%d" % (
             N_LAYERS, e.num_pages * e.page_size, D_MODEL)
         assert row["pool_layout"] in row["spec"]
-        if fault is not None:
+        if fault == "token_major":
+            pools = [s for s in row["signature"]
+                     if list(s[0]) == list(e.pool_shape)]
+            assert len(pools) == 2
+            for s in pools:
+                s[0] = [e.num_pages * e.page_size, N_LAYERS, D_MODEL]
+        elif fault is not None:
             row[fault] = 5
     found = [m for row in rows for m in prewarm._check_paged_row(row)]
     if problem is None:
@@ -1498,15 +1543,15 @@ def _staggered(eng, prompts, want):
 
 
 @pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
-@pytest.mark.parametrize("model", ["rows", "views"])
+@pytest.mark.parametrize("model", ["rows", "moe"])
 def test_launched_ahead_yields_the_tokens_of_read_then_launch(lm, model,
                                                               greedy):
     """One step or three left unread a call give, token for token, what
     reading every step before the next launch gives: greedy and sampled
-    (keys are folded in by position), for a model that takes rows and
-    one that takes views, three requests of different lengths joining
-    and leaving a running batch.  No step is launched past a request's
-    last token, whatever the depth."""
+    (keys are folded in by position), for OPT's model and the expert
+    decoder, three requests of different lengths joining and leaving a
+    running batch.  No step is launched past a request's last token,
+    whatever the depth."""
     from mxnet_tpu import telemetry
 
     net = lm if model == "rows" else _moe_lm(1)

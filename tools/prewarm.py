@@ -341,12 +341,12 @@ def _check_paged_row(e):
     """Shape-consistency problems for one ``generate:paged_chunk``
     manifest row (empty list = healthy).  The paged engine compiles a
     closed family of signatures — the two page-pool leaves, which come
-    right before the page table, have one row a (layer, token) (rank 2)
-    or a token (token-major, rank 3), so a whole number of pages of
-    rows either way, and the token block is one of (1, chunk) /
-    (slots, 1) / (slots, K+1) — so a row whose recorded shapes disagree
-    with its own page_size/prefill_chunk/spec_k extras means the store
-    was written by a mismatched build and would miss at load."""
+    right before the page table, have one row a (layer, token) (rank
+    2), so a whole number of pages of rows, and the token block is one
+    of (1, chunk) / (slots, 1) / (slots, K+1) — so a row whose recorded
+    shapes disagree with its own page_size/prefill_chunk/spec_k extras
+    means the store was written by a mismatched build and would miss at
+    load."""
     who = "manifest entry %s (%s)" % (e.get("key", "?")[:12],
                                       e.get("label"))
     page = e.get("page_size")
@@ -365,7 +365,7 @@ def _check_paged_row(e):
     table = next((i for i, (s, d) in enumerate(leaves)
                   if len(s) == 2 and d == "int32"), 0)
     pools = [s for s, d in leaves[max(table - 2, 0):table]
-             if len(s) >= 2 and d != "int32"]
+             if len(s) == 2 and d != "int32"]
     if len(pools) != 2 or pools[0] != pools[1]:
         msgs.append("%s: no pair of page-pool leaves before the page "
                     "table in the recorded signature" % who)
